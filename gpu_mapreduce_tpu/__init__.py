@@ -25,18 +25,11 @@ import jax as _jax
 # kernels cast to u32 lanes internally where it matters.
 _jax.config.update("jax_enable_x64", True)
 
-# jax < 0.5 ships shard_map only under jax.experimental (and spells
-# check_vma as check_rep); every mesh module calls the stable
-# jax.shard_map spelling — alias it so the package runs on both
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map_old
+# compiled programs persist between processes (utils/platform.py):
+# JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_cache
+from .utils.platform import enable_compile_cache as _enable_compile_cache
 
-    def _shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_old(f, **kw)
-
-    _jax.shard_map = _shard_map
+_enable_compile_cache()
 
 from .core.mapreduce import MapReduce, SerialBackend
 from .core.dataset import KeyValue, KeyMultiValue

@@ -14,6 +14,7 @@ oink/kernels.py, apps/invertedindex.py) branch on it.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -23,40 +24,65 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "mrnative.cpp")
-_SO = os.path.join(_DIR, f"mrnative-{sys.implementation.cache_tag}.so")
+_TAG = sys.implementation.cache_tag
 
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
 
 
-def _build() -> Optional[str]:
-    """Compile mrnative.cpp → .so; returns an error string or None."""
+def _so_path() -> Optional[str]:
+    """``mrnative-<sha256 of mrnative.cpp, 16 hex>-<python tag>.so`` —
+    the artifact is keyed by the source it was built from, so what loads
+    is what git holds (an mtime says nothing after a copy or a
+    checkout).  None when there is no source."""
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    return os.path.join(_DIR, f"mrnative-{digest}-{_TAG}.so")
+
+
+def _build(so: str) -> Optional[str]:
+    """Compile mrnative.cpp → ``so`` (via a per-pid temp name, renamed
+    into place: two processes racing the first import never load a
+    half-written file); returns an error string or None."""
     cxx = os.environ.get("CXX", "g++")
-    cmd = [cxx, "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO]
+    tmp = f"{so}.{os.getpid()}.tmp"
+    cmd = [cxx, "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=120)
+        err = None if proc.returncode == 0 \
+            else proc.stderr.strip() or f"{cxx} failed"
     except (OSError, subprocess.TimeoutExpired) as e:
-        return f"{cxx}: {e}"
-    if proc.returncode != 0:
-        return proc.stderr.strip() or f"{cxx} failed"
-    return None
+        err = f"{cxx}: {e}"
+    if err is None:
+        os.replace(tmp, so)
+    elif os.path.exists(tmp):
+        os.unlink(tmp)
+    return err
 
 
 def _load() -> Optional[ctypes.CDLL]:
     global _build_error
-    have_src = os.path.exists(_SRC)
-    stale = (have_src and os.path.exists(_SO)
-             and os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-    if not os.path.exists(_SO) or stale:
-        if not have_src:  # .so absent and nothing to build from
-            _build_error = f"{_SRC} missing"
-            return None
-        _build_error = _build()
+    so = _so_path()
+    if so is None:              # nothing to build from, nothing to trust
+        _build_error = f"{_SRC} missing"
+        return None
+    for name in os.listdir(_DIR):   # stale siblings: another source hash
+        if name.startswith("mrnative") and name.endswith(f"-{_TAG}.so") \
+                and name != os.path.basename(so):
+            try:
+                os.unlink(os.path.join(_DIR, name))
+            except OSError:
+                pass
+    if not os.path.exists(so):
+        _build_error = _build(so)
         if _build_error is not None:
             return None
     try:
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
     except OSError as e:  # pragma: no cover
         _build_error = str(e)
         return None

@@ -34,11 +34,12 @@ never drop a group.
 64-bit values never enter the kernel: keys and sums travel as u32
 hi/lo limb pairs (TPU VPUs have no native 64-bit lanes — the same
 constraint that shaped ``match.py``'s word-packed kernels).  The
-``interpret=True`` path is the tested one on this CPU-only container
-(tier-1 and the fake mesh run it for real); the Mosaic lowering of the
-scalar probe loop is untested until a TPU returns and is gated off by
-simply flipping ``MRTPU_PALLAS_GROUP=0`` (doc/perf.md has the fallback
-matrix).
+``interpret=True`` path is the only one that has run: on the TPU v5e
+(jax 0.9.0, libtpu 0.0.34) Mosaic refuses the scalar probe loop at
+lowering with a ``RecursionError`` (CHANGES.md PR 22), so the kernels
+are off unless ``MRTPU_PALLAS_GROUP=1`` asks for them — and then a
+kernel that does not lower raises; nothing switches back to the sort
+path on its own (doc/perf.md).
 """
 
 from __future__ import annotations
@@ -51,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ...utils.env import env_flag, env_str
+from ...utils.env import env_flag
 from . import note_kernel_launch
 
 # multiplicative-hash constants (Fibonacci / murmur3 finalizer mixers)
@@ -66,18 +67,13 @@ def pallas_group_enabled() -> bool:
     """``MRTPU_PALLAS_GROUP``: route supported fused group chains
     through the table kernels instead of the per-shard sort.
 
-    Default ``auto`` = on exactly where the kernels compile natively
-    (the TPU backend).  On CPU the kernels only exist in interpret
-    mode — a correctness/test vehicle that trades the sort for a
-    sequential emulated scatter and loses badly on wall — so auto
-    keeps the sort path and ``1`` forces the kernels (what the unit
-    goldens and the soak/bench A/Bs do).  Read at call time like
-    ``MRTPU_WIRE``; the resolved flag is threaded into every builder
-    cache key."""
-    raw = env_str("MRTPU_PALLAS_GROUP", "auto")
-    if raw == "auto":
-        import jax
-        return jax.default_backend() == "tpu"
+    Default off: the sort path groups everywhere.  ``1`` is an explicit
+    opt-in — interpret mode on CPU (a correctness/test vehicle that
+    trades the sort for a sequential emulated scatter and loses badly
+    on wall; what the unit goldens and the soak A/B use), Mosaic on a
+    TPU, where a kernel the compiler refuses raises instead of quietly
+    taking the sort path.  Read at call time like ``MRTPU_WIRE``; the
+    resolved flag is threaded into every builder cache key."""
     return env_flag("MRTPU_PALLAS_GROUP", False)
 
 

@@ -429,7 +429,7 @@ def _phase2_wire_build(mesh, transport: int, tiers, cap_out: int, kpack,
 # speculative capacity cache (round 4, VERDICT r3 weak #5): composed
 # iterative commands pay the exchange's ONE host sync — the count-matrix
 # pull that sizes the bucket/round/output shapes — once per op, a full
-# tunnel round-trip on remote TPU setups.  Keyed by (mesh, transport,
+# device round-trip.  Keyed by (mesh, transport,
 # operand shapes/dtypes), the caps that worked last time are assumed
 # again: phase 2 is ENQUEUED immediately with the cached shapes and the
 # count matrix is pulled while it runs.  The pull then verifies the

@@ -1,6 +1,5 @@
 """Shared BASELINE.json publishing — one implementation of the
-read/merge/write pattern soak.py, weakscale.py and record_scale.py
-each hand-rolled (backend-qualified keys so no harness clobbers
+read/merge/write pattern soak.py and weakscale.py each hand-rolled (backend-qualified keys so no harness clobbers
 another's records)."""
 
 import json

@@ -484,33 +484,6 @@ def test_soak_metrics_line_and_final_snapshot(tmp_path, obs_state):
 
 
 # ---------------------------------------------------------------------------
-# probe JSONL summarizer
-# ---------------------------------------------------------------------------
-
-def test_probe_summary_streaks(tmp_path):
-    tv = load_script("trace_view")
-    events = ([{"ts": f"t{i}", "phase": "scan", "rc": 124,
-                "latency_s": 90} for i in range(5)]
-              + [{"ts": "t5", "phase": "scan", "rc": 0, "latency_s": 12},
-                 {"ts": "t6", "phase": "pre.bench", "rc": 1,
-                  "latency_s": 240},
-                 {"ts": "t7", "phase": "step.bench", "rc": 0,
-                  "latency_s": 900}])
-    s = tv.probe_summary(events)
-    assert s["probes"] == 7                  # step.* excluded
-    assert s["ok"] == 1 and s["fail"] == 6
-    assert s["longest_fail_streak"]["len"] == 5
-    assert s["longest_fail_streak"]["start"] == "t0"
-    assert s["longest_fail_streak"]["end"] == "t4"
-    assert s["current_fail_streak"] == 1
-    assert s["phases"]["scan"]["fail_streak"] == 5
-    assert s["phases"]["step.bench"]["ok"] == 1
-    table = tv.probe_table(events)
-    assert "longest fail streak 5" in table
-    assert "step.bench" in table
-
-
-# ---------------------------------------------------------------------------
 # bench_compare: the regression gate
 # ---------------------------------------------------------------------------
 

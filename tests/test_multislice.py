@@ -157,8 +157,6 @@ def test_init_multihost_single_process():
         "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
         "os.environ['XLA_FLAGS'] = "
         "'--xla_force_host_platform_device_count=4'\n"
-        "from gpu_mapreduce_tpu.utils.platform import pin_platform\n"
-        "pin_platform('cpu')\n"
         "from gpu_mapreduce_tpu.parallel.mesh import (init_multihost,"
         " make_mesh, mesh_axis_size)\n"
         "import socket\n"

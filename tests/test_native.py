@@ -172,3 +172,38 @@ def test_intern_ranges2_matches_two_single_family_passes():
     assert ids.tolist() == native.intern_ranges(buf, starts, lens).tolist()
     assert alts.tolist() == \
         native.intern_ranges(buf, starts, lens, ah, al).tolist()
+
+
+def test_so_is_keyed_by_source_and_stale_sibling_not_loaded(tmp_path,
+                                                            monkeypatch):
+    """What loads is built from the mrnative.cpp that is there now: the
+    artifact's name carries the source hash, and a sibling built from
+    other source (or the old unkeyed name) is deleted, never loaded —
+    an mtime says nothing after a copy or a checkout."""
+    import os
+    import shutil
+    src = tmp_path / "mrnative.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    tag = native._TAG
+    # stale siblings, NEWER than the source: the old loader's mtime rule
+    # would have loaded the first of them
+    legacy = tmp_path / f"mrnative-{tag}.so"
+    other = tmp_path / f"mrnative-{'0' * 16}-{tag}.so"
+    legacy.write_bytes(b"not a shared object")
+    other.write_bytes(b"not a shared object")
+    lib = native._load()
+    assert lib is not None, native.build_error()
+    first = native._so_path()
+    assert os.path.exists(first)
+    assert not legacy.exists() and not other.exists()
+    assert lib.mr_hashlittle(native._u8(b"abc"), 3, 0) == \
+        native.hashlittle(b"abc")
+    # the source changes → another artifact; the previous one goes
+    with open(src, "a") as f:
+        f.write("\n// edited\n")
+    assert native._so_path() != first
+    assert native._load() is not None
+    assert os.path.exists(native._so_path())
+    assert not os.path.exists(first)

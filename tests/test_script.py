@@ -228,11 +228,12 @@ def test_set_scratch_maps_to_fpath(tmp_path):
 # printed invariants, SURVEY.md §4.1)
 #
 # Golden values: the RMAT generator seeds jax.random.PRNGKey, whose
-# bit-stream is stable per jax build but NOT across jax upgrades — the
-# container's jax regenerated a different (equally valid) graph from
-# seed 12345, shifting the derived counts.  Regenerated 2026-08-04
-# under the pinned container jax; determinism re-verified by two
-# independent runs producing identical output before re-pinning.
+# bit-stream is stable per jax build but NOT across jax upgrades — each
+# jax regenerates a different (equally valid) graph from seed 12345,
+# shifting the derived counts.  Pinned 2026-09-26 to the installed
+# jax 0.9.0 (the one installation this repo supports), after two
+# independent runs — one with the compile cache, one without — printed
+# identical output.
 # ---------------------------------------------------------------------------
 
 def test_example_in_cc_golden(tmp_path, monkeypatch):
@@ -244,8 +245,8 @@ def test_example_in_cc_golden(tmp_path, monkeypatch):
     assert "RMAT: 65536 rows, 131072 non-zeroes" in text
     # fused engine: 9 pointer-jumping rounds (the composed MR engine's
     # count was 8 zone-propagation rounds; component count is identical)
-    assert "CC_find: 54 components in 9 iterations" in text
-    assert "CCStats: 54 components, 64308 vertices" in text
+    assert "CC_find: 42 components in 9 iterations" in text
+    assert "CCStats: 42 components, 64343 vertices" in text
     assert (tmp_path / "tmp.cc").exists()
 
 
@@ -257,7 +258,7 @@ def test_example_in_luby_golden(tmp_path, monkeypatch):
     text = out.getvalue()
     assert "RMAT: 4096 rows, 16384 non-zeroes" in text
     # fused engine: 5 rounds (composed counted 4 edge-winner rounds)
-    assert "Luby_find: 1129 MIS vertices in 5 iterations" in text
+    assert "Luby_find: 1123 MIS vertices in 5 iterations" in text
 
 
 def test_example_in_tri_golden(tmp_path, monkeypatch):
@@ -267,9 +268,9 @@ def test_example_in_tri_golden(tmp_path, monkeypatch):
     s.run_file("/root/repo/examples/in.tri")
     text = out.getvalue()
     assert "RMAT: 65536 rows, 524288 non-zeroes" in text
-    assert "Tri_find: 692 triangles" in text
+    assert "Tri_find: 670 triangles" in text
     rows = (tmp_path / "tmp.tri").read_text().splitlines()
-    assert len(rows) == 692
+    assert len(rows) == 670
 
 
 def test_example_in_pagerank_golden(tmp_path, monkeypatch):
@@ -279,10 +280,10 @@ def test_example_in_pagerank_golden(tmp_path, monkeypatch):
     s.run_file("/root/repo/examples/in.pagerank")
     text = out.getvalue()
     assert "RMAT: 16384 rows, 131072 non-zeroes" in text
-    assert "PageRank: 11239 vertices, 131072 edges, 7 iterations" in text
+    assert "PageRank: 11227 vertices, 131072 edges, 7 iterations" in text
     import numpy as np
     ranks = np.loadtxt(tmp_path / "tmp.pr", dtype=np.float64)
-    assert len(ranks) == 11239
+    assert len(ranks) == 11227
     assert abs(ranks[:, 1].sum() - 1.0) < 1e-3      # a distribution
 
 

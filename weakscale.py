@@ -41,8 +41,6 @@ def main_invertedindex(mb_per_proc: float):
     mesh-SPMD ingestion (each shard ingests its own file slice,
     cuda_scale/InvertedIndex.cu:276 holds ~20x128 MB per proc fixed).
     Records per-P stage times + the map-stage machinery stats."""
-    from gpu_mapreduce_tpu.utils.platform import pin_platform
-    pin_platform()
     import jax
     from bench import make_corpus
     from gpu_mapreduce_tpu.apps.invertedindex import InvertedIndex
@@ -81,8 +79,6 @@ def main_invertedindex(mb_per_proc: float):
 
 
 def main():
-    from gpu_mapreduce_tpu.utils.platform import pin_platform
-    pin_platform()
     import jax
     from gpu_mapreduce_tpu.core.mapreduce import MapReduce
     from gpu_mapreduce_tpu.core.runtime import Timer
