@@ -169,16 +169,21 @@ def _extract_core(words, file_starts, *, cap: int, use_pallas: bool,
 
     m = words.shape[0]
     nbytes = 4 * m
-    wmask = (mark_words_pallas(words, PATTERN, interpret=interpret,
-                               page_words=page_words)
-             if use_pallas else mark_words_xla(words, PATTERN))
-    starts, nhits = compact_word_matches(wmask, nbytes, cap, mode=compact)
-    ustarts = starts + np.int32(len(PATTERN))
+    with jax.named_scope("mark"):
+        wmask = (mark_words_pallas(words, PATTERN, interpret=interpret,
+                                   page_words=page_words)
+                 if use_pallas else mark_words_xla(words, PATTERN))
+    with jax.named_scope("compact"):
+        starts, nhits = compact_word_matches(wmask, nbytes, cap,
+                                             mode=compact)
+        ustarts = starts + np.int32(len(PATTERN))
 
     def body(st):
-        win = unaligned_words(words, st, w1)
-        length = first_byte_pos(win, QUOTE)
-        ids, alt = _hash2(win, length)
+        with jax.named_scope("gather"):
+            win = unaligned_words(words, st, w1)
+            length = first_byte_pos(win, QUOTE)
+        with jax.named_scope("hash"):
+            ids, alt = _hash2(win, length)
         return ids, alt, length
 
     ids, alts, lengths = lax.map(body, ustarts.reshape(-1, bs))
@@ -205,10 +210,12 @@ def _extract_core(words, file_starts, *, cap: int, use_pallas: bool,
             lst = jnp.where(lidx < cap,
                             jnp.take(ustarts, jnp.minimum(lidx, cap - 1)),
                             jnp.int32(nbytes))
-            lwin = unaligned_words(words, lst, nw)
-            lln = first_byte_pos(lwin, QUOTE)
-            lln = jnp.where(lln >= _W_SHORT * 4, lln, jnp.int32(-1))
-            lids, lalt = _hash2(lwin, lln)
+            with jax.named_scope("gather"):
+                lwin = unaligned_words(words, lst, nw)
+                lln = first_byte_pos(lwin, QUOTE)
+                lln = jnp.where(lln >= _W_SHORT * 4, lln, jnp.int32(-1))
+            with jax.named_scope("hash"):
+                lids, lalt = _hash2(lwin, lln)
             return (ids.at[lidx].set(lids, mode="drop"),
                     alts.at[lidx].set(lalt, mode="drop"),
                     lengths.at[lidx].set(lln, mode="drop"))
@@ -219,19 +226,22 @@ def _extract_core(words, file_starts, *, cap: int, use_pallas: bool,
         # nlong returns RAW (callers compare against cap_long): the
         # stats must show the second gather ran even below the
         # wide-retry threshold
-    docs = (jnp.searchsorted(file_starts, starts, side="right")
-            .astype(jnp.int32) - 1)
-    valid = (starts < nbytes) & (lengths >= 0)
-    npairs = jnp.sum(valid.astype(jnp.int32))
-    order = jnp.argsort(~valid, stable=True)   # valid rows first
-    pack = lambda x: jnp.take(x, order, axis=0)
-    pids, palts = pack(ids), pack(alts)
+    with jax.named_scope("pack"):
+        docs = (jnp.searchsorted(file_starts, starts, side="right")
+                .astype(jnp.int32) - 1)
+        valid = (starts < nbytes) & (lengths >= 0)
+        npairs = jnp.sum(valid.astype(jnp.int32))
+        order = jnp.argsort(~valid, stable=True)   # valid rows first
+        pack = lambda x: jnp.take(x, order, axis=0)
+        pids, palts = pack(ids), pack(alts)
+        packed = (pids, palts, pack(docs).astype(jnp.uint32),
+                  pack(ustarts), pack(lengths))
     # collision check fused into the same dispatch (one id sort over
     # cap rows — cheap next to the corpus passes, and it saves a
     # round trip per run); multi-batch runs re-check globally
-    ncoll = _count_collisions(pids, palts, jnp.arange(cap) < npairs)
-    return (pids, palts, pack(docs).astype(jnp.uint32),
-            pack(ustarts), pack(lengths), nhits, npairs, ncoll, nlong)
+    with jax.named_scope("collisions"):
+        ncoll = _count_collisions(pids, palts, jnp.arange(cap) < npairs)
+    return packed + (nhits, npairs, ncoll, nlong)
 
 
 @functools.lru_cache(maxsize=None)
@@ -266,7 +276,7 @@ def _extract_mesh_build(mesh, cap: int, use_pallas: bool, interpret: bool,
     from ..parallel.mesh import row_spec
     rspec = row_spec(mesh)
 
-    def body(words, fstarts, base):
+    def invindex_extract(words, fstarts, base):
         (ids, alts, docs, ustarts, lengths, nhits, npairs, ncoll,
          nlong) = _extract_core(words, fstarts, cap=cap,
                                 use_pallas=use_pallas,
@@ -282,7 +292,10 @@ def _extract_mesh_build(mesh, cap: int, use_pallas: bool, interpret: bool,
 
     # check_vma=False: pallas_call's out_shape carries no varying-mesh-axes
     # annotation, which the checker would otherwise reject
-    sm = jax.shard_map(body, mesh=mesh, in_specs=(rspec, rspec, rspec),
+    # jit names the program after the shard_map'd function: one name of
+    # its own (obs/names.INVINDEX_EXTRACT), not any body's "jit_body"
+    sm = jax.shard_map(invindex_extract, mesh=mesh,
+                       in_specs=(rspec, rspec, rspec),
                        out_specs=(rspec,) * 6, check_vma=False)
     return jax.jit(sm)
 
@@ -383,12 +396,12 @@ def _mesh_collision_count(checks) -> int:
         valids.append(jnp.asarray(v))
 
     @jax.jit
-    def count(ids, alts, valids):
+    def invindex_collision_count(ids, alts, valids):
         return _count_collisions(jnp.concatenate(ids),
                                  jnp.concatenate(alts),
                                  jnp.concatenate(valids))
 
-    return int(count(ids, alts, valids))
+    return int(invindex_collision_count(ids, alts, valids))
 
 
 def _url_dict_wanted(files, want_urls: bool) -> bool:
@@ -758,19 +771,24 @@ class InvertedIndex:
         rounds; each round appends one ShardedKV frame."""
         from ..parallel.mesh import mesh_axis_size, row_sharding
         from ..parallel.sharded import ShardedKV
+        from ..obs import get_tracer, names
+        tr = get_tracer()
         P = mesh_axis_size(mesh)
         self.docs = list(files)
-        keep_bytes = _url_dict_wanted(files, want_urls)
-        if keep_bytes:
-            self.shard_urls = [{} for _ in range(P)]
-        batch_lists = []
-        for start, chunk, sizes in _balance_files(files, P):
-            bl, base = [], start
-            for b in (self._file_batches(chunk, sizes) if chunk else []):
-                bl.append((base, b))
-                base += len(b)
-            batch_lists.append(bl)
-        nrounds = max((len(b) for b in batch_lists), default=0)
+        with tr.span(names.MAP_PLAN, cat=names.HOST, files=len(files)) as sp:
+            keep_bytes = _url_dict_wanted(files, want_urls)
+            if keep_bytes:
+                self.shard_urls = [{} for _ in range(P)]
+            batch_lists, nbytes = [], 0
+            for start, chunk, sizes in _balance_files(files, P):
+                bl, base = [], start
+                for b in (self._file_batches(chunk, sizes) if chunk else []):
+                    bl.append((base, b))
+                    base += len(b)
+                batch_lists.append(bl)
+                nbytes += int(sum(sizes))
+            nrounds = max((len(b) for b in batch_lists), default=0)
+            sp.set(bytes=nbytes, rounds=nrounds)
         if nrounds == 0:
             return
         sharding = row_sharding(mesh)
@@ -792,16 +810,19 @@ class InvertedIndex:
                 continue
             W = _bucket_words(-(-max_bytes // 4))
             F = max(max(len(c[2]) for c in per), 1)
-            words_host = []
-            fstarts_host = np.full((P, F), np.int32(4 * W), np.int32)
-            base_host = np.zeros(P, np.uint32)
-            for p, (base, corpus, fstarts) in enumerate(per):
-                w = bytes_view_u32(corpus)
-                wp = np.zeros(W, np.uint32)
-                wp[:len(w)] = w
-                words_host.append(wp)
-                fstarts_host[p, :len(fstarts)] = fstarts
-                base_host[p] = base
+            # each shard's corpus copied into a zeroed block of the bucket
+            # size: a second pass over every corpus byte, on the host
+            with tr.span(names.MAP_PAD, cat=names.HOST, bytes=4 * W * P):
+                words_host = []
+                fstarts_host = np.full((P, F), np.int32(4 * W), np.int32)
+                base_host = np.zeros(P, np.uint32)
+                for p, (base, corpus, fstarts) in enumerate(per):
+                    w = bytes_view_u32(corpus)
+                    wp = np.zeros(W, np.uint32)
+                    wp[:len(w)] = w
+                    words_host.append(wp)
+                    fstarts_host[p, :len(fstarts)] = fstarts
+                    base_host[p] = base
             with self.timer.stage("h2d"):
                 words_g = _h2d_sharded(words_host, W, P, sharding)
                 fstarts_g = jax.device_put(fstarts_host.reshape(-1),
@@ -1064,14 +1085,22 @@ class InvertedIndex:
         to host one at a time — the whole dataset never assembles on
         the controller (reference per-proc reduce output,
         cuda/InvertedIndex.cu:463-513; VERDICT r3 #7)."""
+        from ..obs import get_tracer, names
+        tr = get_tracer()
         for p in range(fr.nprocs):
             lookup = (self.shard_urls[p] if self.shard_urls is not None
                       else self._urls)
-            hf = fr.shard_to_host(p)
-            with open(os.path.join(outdir, f"part-{p:05d}"), "w") as out:
-                for k, vals in hf.groups():
-                    url = lookup[int(k)].decode(errors="replace")
-                    names = " ".join(self.docs[int(v)]
-                                     for v in sorted(set(vals)))
-                    out.write(f"{url}\t{names}\n")
+            with tr.span(names.PARTS_PULL, cat=names.HOST, shard=p) as sp:
+                hf = fr.shard_to_host(p)
+                sp.set(groups=len(hf), bytes=hf.nbytes())
+            path = os.path.join(outdir, f"part-{p:05d}")
+            with tr.span(names.PARTS_WRITE, cat=names.HOST, shard=p,
+                         groups=len(hf)) as sp:
+                with open(path, "w") as out:
+                    for k, vals in hf.groups():
+                        url = lookup[int(k)].decode(errors="replace")
+                        docs = " ".join(self.docs[int(v)]
+                                        for v in sorted(set(vals)))
+                        out.write(f"{url}\t{docs}\n")
+                sp.set(bytes=os.path.getsize(path))
 

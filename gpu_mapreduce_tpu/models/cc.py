@@ -35,12 +35,15 @@ def _propagate(lab, src, dst, valid, n):
     """One round: every edge pulls its endpoints toward the smaller
     label, then one pointer-jump hop.  Padded edge rows route to the
     dropped segment n."""
-    seg_dst = jnp.where(valid, dst, n)
-    seg_src = jnp.where(valid, src, n)
-    m1 = jax.ops.segment_min(lab[src], seg_dst, num_segments=n + 1)[:n]
-    m2 = jax.ops.segment_min(lab[dst], seg_src, num_segments=n + 1)[:n]
-    nl = jnp.minimum(lab, jnp.minimum(m1, m2))
-    return jnp.minimum(nl, nl[nl])          # pointer jumping
+    with jax.named_scope("segment_min_dst"):
+        seg_dst = jnp.where(valid, dst, n)
+        m1 = jax.ops.segment_min(lab[src], seg_dst, num_segments=n + 1)[:n]
+    with jax.named_scope("segment_min_src"):
+        seg_src = jnp.where(valid, src, n)
+        m2 = jax.ops.segment_min(lab[dst], seg_src, num_segments=n + 1)[:n]
+    with jax.named_scope("pointer_jump"):
+        nl = jnp.minimum(lab, jnp.minimum(m1, m2))
+        return jnp.minimum(nl, nl[nl])
 
 
 @functools.partial(jax.jit, static_argnames=("n", "maxiter"))
@@ -74,7 +77,7 @@ def _cc_sharded_fn(mesh: Mesh, n: int, maxiter: int):
     rep = NamedSharding(mesh, P())
 
     @functools.partial(jax.jit, out_shardings=(rep, rep))
-    def run(src_d, dst_d, valid_d):
+    def cc_loop(src_d, dst_d, valid_d):
         lab0 = jnp.arange(n, dtype=jnp.int32)
 
         step = jax.shard_map(
@@ -94,7 +97,7 @@ def _cc_sharded_fn(mesh: Mesh, n: int, maxiter: int):
         return lax.while_loop(
             cond, body, (lab0, jnp.bool_(n > 0), jnp.int32(0)))[::2]
 
-    return run
+    return cc_loop
 
 
 def cc_sharded(mesh: Mesh, src: np.ndarray, dst: np.ndarray, n: int,

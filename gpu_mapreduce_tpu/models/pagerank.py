@@ -110,10 +110,13 @@ def _sharded_step(ranks, src, dst, inv_outdeg, valid, damping, axes):
     multi-slice mesh) merges per-shard inflows (replicated ranks in,
     replicated ranks out)."""
     n = ranks.shape[0]
-    contrib = jnp.where(valid, ranks[src] * inv_outdeg[src], 0.0)
-    inflow = lax.psum(jax.ops.segment_sum(contrib, dst, num_segments=n), axes)
-    return ((1.0 - damping) / n +
-            damping * (inflow + _dangling_mass(ranks, inv_outdeg)))
+    with jax.named_scope("scatter_add"):
+        contrib = jnp.where(valid, ranks[src] * inv_outdeg[src], 0.0)
+        inflow = lax.psum(
+            jax.ops.segment_sum(contrib, dst, num_segments=n), axes)
+    with jax.named_scope("normalise"):
+        return ((1.0 - damping) / n +
+                damping * (inflow + _dangling_mass(ranks, inv_outdeg)))
 
 
 def pad_edges_for_mesh(src: np.ndarray, dst: np.ndarray, nprocs: int
@@ -138,7 +141,7 @@ def _sharded_run_fn(mesh: Mesh, n: int, tol: float, maxiter: int,
     rspec = row_spec(mesh)
 
     @functools.partial(jax.jit, out_shardings=(rep, rep))
-    def run(src_d, dst_d, valid_d):
+    def pagerank_loop(src_d, dst_d, valid_d):
         deg = jax.shard_map(
             lambda s, v: lax.psum(out_degrees(s, n, valid=v), axes),
             mesh=mesh, in_specs=(rspec, rspec), out_specs=P())(
@@ -165,7 +168,7 @@ def _sharded_run_fn(mesh: Mesh, n: int, tol: float, maxiter: int,
             cond, body, (r0, jnp.float32(jnp.inf), jnp.int32(0)))
         return ranks, iters
 
-    return run
+    return pagerank_loop
 
 
 def pagerank_sharded(mesh: Mesh, src: np.ndarray, dst: np.ndarray, n: int,

@@ -1,0 +1,105 @@
+"""The names the measurement reads: device programs, host-phase spans.
+
+A device trace lists one ``XLA Modules`` event per execution of a jitted
+program, named ``jit_<function>``; a metric that sums a program's device
+seconds finds it by that name.  So every jitted program on a benchmark
+cell's path is a function with a name of its own (the ``def`` that
+``jax.jit`` sees — no wrapper), declared here once;
+``tests/test_obs_names.py`` lowers each and holds it to its constant.
+
+The span names beside them are the host phases inside an op, stage or
+command where the device waits (category ``host``) and the dispatch of a
+fused device loop up to the pull that ends it (category ``engine``).
+The span sites use these constants, ``benchmark/layer_metrics/*.json``
+quote them, ``doc/observability.md`` lists them with their attrs.
+"""
+
+# -- device programs ----------------------------------------------------------
+# apps/invertedindex.py
+INVINDEX_EXTRACT = "jit_invindex_extract"           # the mesh map stage
+INVINDEX_COLLISIONS = "jit_invindex_collision_count"
+# parallel/group.py
+CONVERT_SORT = "jit_convert_sort"                   # per-shard sort + boundaries
+CONVERT_LAYOUT = "jit_convert_layout"               # grouped layout at gcap
+REDUCE_SEGMENTS = "jit_reduce_segments"
+GROUP_FIRST = "jit_group_first"
+SORT_MULTIVALUES = "jit_sort_multivalues"
+SORT_ROWS = "jit_sort_rows"
+SORT_INTERNED = "jit_sort_interned"
+# parallel/shuffle.py
+SHUFFLE_PHASE1 = "jit_shuffle_phase1"
+SHUFFLE_PHASE2 = "jit_shuffle_phase2"
+SHUFFLE_PHASE2_WIRE = "jit_shuffle_phase2_wire"
+# parallel/staging.py
+STAGE_UNIQUE_VERTS = "jit_stage_unique_verts"
+STAGE_TRIM_VERTS = "jit_stage_trim_verts"
+STAGE_RANK_EDGES = "jit_stage_rank_edges"
+# parallel/devkernels.py
+CONCAT_ROWS = "jit_concat_rows"
+REMAP_IDS = "jit_remap_ids"
+# models/
+CC_LOOP = "jit_cc_loop"
+PAGERANK_LOOP = "jit_pagerank_loop"
+RMAT_EDGES = "jit_rmat_edges"
+
+PROGRAMS = (
+    INVINDEX_EXTRACT, INVINDEX_COLLISIONS, CONVERT_SORT, CONVERT_LAYOUT,
+    REDUCE_SEGMENTS, GROUP_FIRST, SORT_MULTIVALUES, SORT_ROWS, SORT_INTERNED,
+    SHUFFLE_PHASE1, SHUFFLE_PHASE2, SHUFFLE_PHASE2_WIRE, STAGE_UNIQUE_VERTS,
+    STAGE_TRIM_VERTS, STAGE_RANK_EDGES, CONCAT_ROWS, REMAP_IDS, CC_LOOP,
+    PAGERANK_LOOP, RMAT_EDGES,
+)
+
+# parallel/devkernels.py's two generic mappers run one program per kernel
+# body: ``jit_kv_map_<body>`` / ``jit_kmv_map_<body>``
+KV_MAP_PREFIX = "jit_kv_map_"
+KMV_MAP_PREFIX = "jit_kmv_map_"
+PROGRAM_PREFIXES = (KV_MAP_PREFIX, KMV_MAP_PREFIX)
+
+
+def declared_program(module: str) -> bool:
+    """Whether a trace's module name is one declared here."""
+    return module in PROGRAMS or module.startswith(PROGRAM_PREFIXES)
+
+
+# -- span categories ----------------------------------------------------------
+HOST = "host"           # host work inside an op, stage or command
+ENGINE = "engine"       # a device loop, from dispatch to the pull that ends it
+
+# -- host-phase spans (cat HOST unless said) ----------------------------------
+# parallel/shuffle.aggregate_kv, before the exchange / the one-chip early-out
+AGGREGATE_ONE_FRAME = "aggregate.one_frame"     # rows, frames, to_host_bytes
+AGGREGATE_INTERN = "aggregate.intern"           # rows
+AGGREGATE_SHARD = "aggregate.shard"             # rows, bytes
+# parallel/group.convert_sharded: the pull between sort and layout
+CONVERT_COUNT_SYNC = "convert.count_sync"       # groups
+# oink/commands/rmat.py
+RMAT_GENERATE = "rmat.generate"                 # rows
+# oink/objects.py
+OINK_INPUT = "oink.input"                       # source, rows, bytes
+OINK_OUTPUT = "oink.output"                     # path, rows, bytes
+# oink/commands/{cc,pagerank}.py
+CC_STAGE = "cc.stage"                           # n, edges
+CC_EMIT = "cc.emit"                             # n
+CC_ENGINE = "cc.loop"                           # cat ENGINE: iters, n, edges
+PAGERANK_STAGE = "pagerank.stage"
+PAGERANK_EMIT = "pagerank.emit"
+PAGERANK_ENGINE = "pagerank.loop"               # cat ENGINE
+# apps/invertedindex.py
+MAP_PLAN = "map.plan"                           # files, bytes
+MAP_PAD = "map.pad"                             # bytes
+PARTS_PULL = "parts.pull"                       # groups, bytes
+PARTS_WRITE = "parts.write"                     # groups, bytes
+
+# older spans that metrics quote by name
+SHUFFLE_EXCHANGE = "shuffle.exchange"
+SHUFFLE_COUNT_SYNC = "shuffle.count_sync"
+OINK_RMAT = "oink.rmat"                         # rounds
+
+SPANS = (
+    AGGREGATE_ONE_FRAME, AGGREGATE_INTERN, AGGREGATE_SHARD,
+    CONVERT_COUNT_SYNC, RMAT_GENERATE, OINK_INPUT, OINK_OUTPUT, CC_STAGE,
+    CC_EMIT, CC_ENGINE, PAGERANK_STAGE, PAGERANK_EMIT, PAGERANK_ENGINE,
+    MAP_PLAN, MAP_PAD, PARTS_PULL, PARTS_WRITE, SHUFFLE_EXCHANGE,
+    SHUFFLE_COUNT_SYNC, OINK_RMAT,
+)

@@ -41,7 +41,6 @@ _DELTA_FIELDS = (
     ("cspad", "shuffle_pad_bytes"),
     ("wsize", "spill_write_bytes"),
     ("rsize", "spill_read_bytes"),
-    ("commtime", "comm_secs"),
     ("ndispatch", "dispatches"),
 )
 

@@ -269,47 +269,59 @@ class CCFind(Command):
         # device staging (VERDICT r2 #2): shard the edge KV once, rank
         # vertices ON DEVICE — the O(E) edge columns never reach the
         # controller; only n and the [n] id table do
+        from ...obs import get_tracer, names
         from ...parallel.staging import stage_graph
-        sg = stage_graph(mre, obj.comm)
-        # (sg.n == 0 cannot happen here: empty datasets return None and
-        # without drop_self every valid edge row has real endpoints)
-        if sg is not None:
-            from ...models.cc import _cc_sharded_fn
-            labels_d, iters = _cc_sharded_fn(mesh, sg.n, max(sg.n, 1))(
-                sg.src, sg.dst, sg.valid)
-            verts = sg.verts
-            labels, iters = np.asarray(labels_d), int(iters)
-        else:
-            edges: list = []
-            mre.scan_kv(lambda fr, p: edges.append(kv_keys(fr)),
-                        batch=True)
-            e = (np.concatenate(edges) if edges
-                 else np.zeros((0, 2), np.uint64))
-            verts, inv = np.unique(e.reshape(-1), return_inverse=True)
-            n = len(verts)
-            if n == 0:
-                self.ncc, self.niterate = 0, 0
-                mrv = obj.create_mr()
-                obj.output(1, mrv, print_vertex_value)
-                self.message("CC_find: 0 components in 0 iterations")
-                obj.cleanup()
-                return
-            src = inv.reshape(-1, 2)[:, 0]
-            dst = inv.reshape(-1, 2)[:, 1]
-
-            from ...models.cc import cc, cc_sharded
-            if mesh is not None:
-                labels, iters = cc_sharded(mesh, src, dst, n)
+        tr = get_tracer()
+        with tr.span(names.CC_STAGE, cat=names.HOST) as sp:
+            sg = stage_graph(mre, obj.comm)
+            # (sg.n == 0 cannot happen here: empty datasets return None
+            # and without drop_self every valid edge row has real
+            # endpoints)
+            if sg is not None:
+                verts, n, nedges = sg.verts, sg.n, int(mre.kv.nkv)
             else:
-                labels, iters = cc(src.astype(np.int32),
-                                   dst.astype(np.int32), n)
-                labels, iters = np.asarray(labels), int(iters)
+                edges: list = []
+                mre.scan_kv(lambda fr, p: edges.append(kv_keys(fr)),
+                            batch=True)
+                e = (np.concatenate(edges) if edges
+                     else np.zeros((0, 2), np.uint64))
+                verts, inv = np.unique(e.reshape(-1), return_inverse=True)
+                n, nedges = len(verts), len(e)
+                src = inv.reshape(-1, 2)[:, 0]
+                dst = inv.reshape(-1, 2)[:, 1]
+            sp.set(n=n, edges=nedges)
+        if n == 0:
+            self.ncc, self.niterate = 0, 0
+            mrv = obj.create_mr()
+            obj.output(1, mrv, print_vertex_value)
+            self.message("CC_find: 0 components in 0 iterations")
+            obj.cleanup()
+            return
 
-        zones = verts[labels]               # min vertex id per component
-        self.ncc = int(len(np.unique(labels)))
-        self.niterate = int(iters)
-        mrv = obj.create_mr()
-        mrv.map(1, lambda i, kv, p: kv.add_batch(verts, zones))
+        # the fused loop, from dispatch to the pull that ends it
+        with tr.span(names.CC_ENGINE, cat=names.ENGINE, n=n,
+                     edges=nedges) as sp:
+            if sg is not None:
+                from ...models.cc import _cc_sharded_fn
+                labels_d, iters = _cc_sharded_fn(mesh, n, max(n, 1))(
+                    sg.src, sg.dst, sg.valid)
+                labels, iters = np.asarray(labels_d), int(iters)
+            else:
+                from ...models.cc import cc, cc_sharded
+                if mesh is not None:
+                    labels, iters = cc_sharded(mesh, src, dst, n)
+                else:
+                    labels, iters = cc(src.astype(np.int32),
+                                       dst.astype(np.int32), n)
+                    labels, iters = np.asarray(labels), int(iters)
+            sp.set(iters=iters)
+
+        with tr.span(names.CC_EMIT, cat=names.HOST, n=n):
+            zones = verts[labels]           # min vertex id per component
+            self.ncc = int(len(np.unique(labels)))
+            self.niterate = int(iters)
+            mrv = obj.create_mr()
+            mrv.map(1, lambda i, kv, p: kv.add_batch(verts, zones))
         obj.output(1, mrv, print_vertex_value)
         self.message(f"CC_find: {self.ncc} components in "
                      f"{self.niterate} iterations")

@@ -58,36 +58,45 @@ class PageRankCommand(Command):
         obj = self.obj
         mre = obj.input(1, _read_edges_sniff)
 
-        edges: list = []
-        mre.scan_kv(lambda fr, p: edges.append(kv_keys(fr)), batch=True)
-        e = (np.concatenate(edges) if edges
-             else np.zeros((0, 2), np.uint64))
-        # compact arbitrary u64 ids to dense 0..n-1 for the dense-rank model
-        verts, inv = np.unique(e.reshape(-1), return_inverse=True)
-        n = len(verts)
-        if n == 0:
-            raise MRError("pagerank: empty edge list")
-        src, dst = inv.reshape(-1, 2)[:, 0], inv.reshape(-1, 2)[:, 1]
+        from ...obs import get_tracer, names
+        tr = get_tracer()
+        with tr.span(names.PAGERANK_STAGE, cat=names.HOST) as sp:
+            edges: list = []
+            mre.scan_kv(lambda fr, p: edges.append(kv_keys(fr)), batch=True)
+            e = (np.concatenate(edges) if edges
+                 else np.zeros((0, 2), np.uint64))
+            # compact arbitrary u64 ids to dense 0..n-1 for the dense-rank
+            # model
+            verts, inv = np.unique(e.reshape(-1), return_inverse=True)
+            n = len(verts)
+            sp.set(n=n, edges=len(e))
+            if n == 0:
+                raise MRError("pagerank: empty edge list")
+            src, dst = inv.reshape(-1, 2)[:, 0], inv.reshape(-1, 2)[:, 1]
 
         from jax.sharding import Mesh
         mesh = obj.comm if isinstance(obj.comm, Mesh) else None
-        if mesh is not None:
-            ranks, iters = pagerank_sharded(
-                mesh, src, dst, n, tol=self.tolerance,
-                maxiter=self.maxiter, damping=self.alpha)
-        else:
-            ranks, iters = pagerank(src, dst, n, tol=self.tolerance,
-                                    maxiter=self.maxiter,
-                                    damping=self.alpha)
-            ranks, iters = np.asarray(ranks), int(iters)
+        # the fused loop, from the edges' transfer to the pull that ends it
+        with tr.span(names.PAGERANK_ENGINE, cat=names.ENGINE, n=n,
+                     edges=len(src)) as sp:
+            if mesh is not None:
+                ranks, iters = pagerank_sharded(
+                    mesh, src, dst, n, tol=self.tolerance,
+                    maxiter=self.maxiter, damping=self.alpha)
+            else:
+                ranks, iters = pagerank(src, dst, n, tol=self.tolerance,
+                                        maxiter=self.maxiter,
+                                        damping=self.alpha)
+                ranks, iters = np.asarray(ranks), int(iters)
+            sp.set(iters=iters)
 
-        self.ranks = {int(v): float(r) for v, r in zip(verts, ranks)}
-        self.niterate = iters
-        self.nvert = n
-
-        mrr = obj.create_mr()
-        mrr.map(1, lambda i, kv, p: kv.add_batch(
-            verts, ranks.astype(np.float64)))
+        with tr.span(names.PAGERANK_EMIT, cat=names.HOST, n=n):
+            self.ranks = {int(v): float(r) for v, r in zip(verts, ranks)}
+            self.niterate = iters
+            self.nvert = n
+            mrr = obj.create_mr()
+            mrr.map(1, lambda i, kv, p: kv.add_batch(
+                verts, ranks.astype(np.float64)))
         obj.output(1, mrr, lambda k, v, fp: fp.write(f"{k} {v:.8g}\n"))
         self.message(f"PageRank: {n} vertices, {len(src)} edges, "
                      f"{iters} iterations")

@@ -14,6 +14,7 @@ import numpy as np
 
 from ...core.runtime import MRError
 from ...models.rmat import rmat_edges
+from ...obs import get_tracer, names
 from ..command import Command, command
 from ..kernels import cull, print_edge
 
@@ -41,10 +42,13 @@ class _RmatBase(Command):
         edge count, not of the shrinking remainder) so the jitted
         generator compiles once per command, not once per cull round."""
         m = max(8, 1 << (self.order * self.nnonzero - 1).bit_length())
-        vi, vj = rmat_edges(key, m, self.nlevels, np.asarray(self.abcd),
-                            self.frac, noisy=self.frac > 0.0)
-        return np.stack([np.asarray(vi)[:nremain],
-                         np.asarray(vj)[:nremain]], axis=1)
+        with get_tracer().span(names.RMAT_GENERATE, cat=names.HOST,
+                               rows=nremain):
+            vi, vj = rmat_edges(key, m, self.nlevels,
+                                np.asarray(self.abcd), self.frac,
+                                noisy=self.frac > 0.0)
+            return np.stack([np.asarray(vi)[:nremain],
+                             np.asarray(vj)[:nremain]], axis=1)
 
 
 @command("rmat")
@@ -69,6 +73,7 @@ class RMAT(_RmatBase):
             nremain = ntotal - nunique
         self.nunique = ntotal
         self.niterate = niterate
+        get_tracer().annotate(rounds=niterate)    # on the oink.<command> span
         obj.output(1, mr, print_edge)
         self.message(f"RMAT: {self.order} rows, {ntotal} non-zeroes, "
                      f"{niterate} iterations")
@@ -101,6 +106,7 @@ class RMAT2(_RmatBase):
             nremain = ntotal - nunique
         self.nunique = ntotal
         self.niterate = niterate
+        get_tracer().annotate(rounds=niterate)    # on the oink.<command> span
         obj.output(1, mr, print_edge)
         self.message(f"RMAT2: {self.order} rows, {ntotal} non-zeroes, "
                      f"{niterate} iterations")

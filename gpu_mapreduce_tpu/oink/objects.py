@@ -25,6 +25,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 from ..core.mapreduce import MapReduce
 from ..core.runtime import MRError
+from ..obs import get_tracer, names
 
 
 @dataclass
@@ -175,8 +176,12 @@ class ObjectManager:
             return self.get_mr(d.mr_name)
         if parser is None:
             raise MRError("file input requires a parser callback")
-        mr = self.create_mr()
-        mr.map_files(d.paths, parser, ptr)
+        with get_tracer().span(names.OINK_INPUT, cat=names.HOST,
+                               source=" ".join(d.paths)) as sp:
+            mr = self.create_mr()
+            rows = mr.map_files(d.paths, parser, ptr)
+            sp.set(rows=int(rows), bytes=sum(
+                os.path.getsize(p) for p in d.paths if os.path.isfile(p)))
         return mr
 
     def output(self, index: int, mr: MapReduce,
@@ -199,31 +204,39 @@ class ObjectManager:
             return
         d = self.outputs[index - 1]
         if d.path is not None:
-            _ensure_parent(d.path)
-            fr = _mesh_frame(mr)
-            if fr is not None and fr.nprocs > 1:
-                for p in range(fr.nprocs):
-                    if "%" in d.path:
-                        path = d.path.replace("%", str(p), 1)
-                    else:
-                        path = f"{d.path}.{p}"
-                    host = fr.shard_to_host(p)
-                    with open(path, "w") as fp:
-                        rows = (host.pairs() if hasattr(host, "pairs")
-                                else host.groups())
-                        if printer is None:
-                            for k, v in rows:
-                                fp.write(f"{k} {v}\n")
+            with get_tracer().span(names.OINK_OUTPUT, cat=names.HOST,
+                                   path=d.path) as sp:
+                _ensure_parent(d.path)
+                nbytes = 0
+                fr = _mesh_frame(mr)
+                if fr is not None and fr.nprocs > 1:
+                    for p in range(fr.nprocs):
+                        if "%" in d.path:
+                            path = d.path.replace("%", str(p), 1)
                         else:
-                            for k, v in rows:
+                            path = f"{d.path}.{p}"
+                        host = fr.shard_to_host(p)
+                        with open(path, "w") as fp:
+                            rows = (host.pairs() if hasattr(host, "pairs")
+                                    else host.groups())
+                            if printer is None:
+                                for k, v in rows:
+                                    fp.write(f"{k} {v}\n")
+                            else:
+                                for k, v in rows:
+                                    printer(k, v, fp)
+                        nbytes += os.path.getsize(path)
+                else:
+                    with open(d.path, "w") as fp:
+                        if printer is None:
+                            mr_dump(mr, fp)
+                        else:
+                            for k, v in _iter_pairs(mr):
                                 printer(k, v, fp)
-            else:
-                with open(d.path, "w") as fp:
-                    if printer is None:
-                        mr_dump(mr, fp)
-                    else:
-                        for k, v in _iter_pairs(mr):
-                            printer(k, v, fp)
+                    nbytes = os.path.getsize(d.path)
+                sp.set(rows=(mr.kv.nkv if mr.kv is not None
+                             else mr.kmv.nkmv if mr.kmv is not None else 0),
+                       bytes=nbytes)
         if d.mr_name is not None:
             self.name_mr(d.mr_name, mr)
 

@@ -119,6 +119,7 @@ def mark_pallas(buf, pattern: bytes, interpret: bool = False):
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, _i32(0)),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="mark_bytes",
     )(buf_2d, buf_2d)
     return out.reshape(-1)[:n]
 
@@ -318,6 +319,7 @@ def _mark_words_call(words, masks, vals, interpret: bool):
                                lambda i: (i, _i32(0)),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="mark_words",
     )(words_2d, words_2d)
     return out.reshape(-1)[:m]
 

@@ -43,6 +43,13 @@ def is_sharded_kmv(fr) -> bool:
     return isinstance(fr, ShardedKMV)
 
 
+def _body_name(fn) -> str:
+    """A kernel body's name as the device trace should show it:
+    ``_edge_upper_dev`` → ``edge_upper``."""
+    name = getattr(fn, "__name__", type(fn).__name__)
+    return name.strip("_").removesuffix("_dev")
+
+
 def _pack(ok, ov, valid):
     """Stable front-packing via prefix-sum + scatter-with-drop — the same
     idiom compact_word_matches documents (~20× cheaper than the sort-based
@@ -59,7 +66,6 @@ def _pack(ok, ov, valid):
 def _skv_map_jit(mesh, fn, static, nextra):
     spec = row_spec(mesh)
 
-    @jax.jit
     def run(key, value, count, *extra):
         def body(k, v, c, *ex):
             return _pack(*fn(k, v, c[0], *ex, *static))
@@ -67,7 +73,9 @@ def _skv_map_jit(mesh, fn, static, nextra):
             body, mesh=mesh, in_specs=(spec, spec, spec) + (P(),) * nextra,
             out_specs=(spec, spec, spec))(key, value, count, *extra)
 
-    return run
+    # one program per kernel body, named for it (obs/names.KV_MAP_PREFIX)
+    run.__name__ = "kv_map_" + _body_name(fn)
+    return jax.jit(run)
 
 
 def _check_decodes(fr, preserve_decodes: bool, what: str):
@@ -113,7 +121,6 @@ def skv_map(skv: ShardedKV, fn, static=(), extra=(),
 def _skmv_map_jit(mesh, fn, static, nextra):
     spec = row_spec(mesh)
 
-    @jax.jit
     def run(ukey, nval, voff, values, gcount, vcount, *extra):
         def body(uk, nv, vo, vals, gc, vc, *ex):
             return _pack(*fn(uk, nv, vo, vals, gc[0], vc[0], *ex, *static))
@@ -122,7 +129,8 @@ def _skmv_map_jit(mesh, fn, static, nextra):
             out_specs=(spec, spec, spec))(ukey, nval, voff, values,
                                           gcount, vcount, *extra)
 
-    return run
+    run.__name__ = "kmv_map_" + _body_name(fn)
+    return jax.jit(run)
 
 
 def skmv_map(kmv: ShardedKMV, fn, static=(), extra=(),
@@ -150,7 +158,7 @@ def _concat_jit(mesh):
     spec = row_spec(mesh)
 
     @jax.jit
-    def run(k1, v1, c1, k2, v2, c2):
+    def concat_rows(k1, v1, c1, k2, v2, c2):
         def body(ka, va, ca, kb, vb, cb):
             na, nb = ka.shape[0], kb.shape[0]
             valid = jnp.concatenate([jnp.arange(na) < ca[0],
@@ -161,7 +169,7 @@ def _concat_jit(mesh):
                              out_specs=(spec, spec, spec))(k1, v1, c1,
                                                            k2, v2, c2)
 
-    return run
+    return concat_rows
 
 
 def _merge_decode(ta, tb, what: str):
@@ -196,12 +204,12 @@ def _remap_ids_jit(mesh, m: int):
 
     @functools.partial(jax.jit,
                        out_shardings=NamedSharding(mesh, row_spec(mesh)))
-    def run(col, old_sorted, new_by_old):
+    def remap_ids(col, old_sorted, new_by_old):
         pos = jnp.clip(jnp.searchsorted(old_sorted, col), 0, m - 1)
         hit = old_sorted[pos] == col
         return jnp.where(hit, new_by_old[pos], col)
 
-    return run
+    return remap_ids
 
 
 def _reintern_pickle_domain(col, table, mesh):

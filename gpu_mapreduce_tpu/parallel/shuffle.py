@@ -310,7 +310,7 @@ def _phase1_build(mesh, dest, donate: bool = False, wire=None):
             return phase1_shard_body(nprocs, dest_of, wire, k, v, c)
         nouts = 4
 
-    def phase1(key, value, count):
+    def shuffle_phase1(key, value, count):
         return jax.shard_map(
             body, mesh=mesh, in_specs=(spec, spec, spec),
             out_specs=(spec,) * nouts)(key, value, count)
@@ -318,7 +318,7 @@ def _phase1_build(mesh, dest, donate: bool = False, wire=None):
     # phase 1 is shape-preserving (dest-sorted rows), so donation always
     # aliases — the biggest win, on every aggregate/gather
     from ..exec import donated_jit
-    return donated_jit(phase1, (0, 1) if donate else ())
+    return donated_jit(shuffle_phase1, (0, 1) if donate else ())
 
 
 def phase2_shard_body(nprocs: int, transport: int, mesh, B: int,
@@ -379,7 +379,7 @@ def _phase2_build(mesh, transport: int, B: int, nrounds: int, cap_out: int,
     nprocs = mesh_axis_size(mesh)
     spec = row_spec(mesh)
 
-    def phase2(skey, svalue, counts_local):
+    def shuffle_phase2(skey, svalue, counts_local):
         def body(k, v, cl):
             out_k, out_v, _ = phase2_shard_body(
                 nprocs, transport, mesh, B, nrounds, cap_out, k, v, cl)
@@ -389,7 +389,7 @@ def _phase2_build(mesh, transport: int, B: int, nrounds: int, cap_out: int,
             out_specs=(spec, spec))(skey, svalue, counts_local)
 
     from ..exec import donated_jit
-    return donated_jit(phase2, (0, 1) if donate else ())
+    return donated_jit(shuffle_phase2, (0, 1) if donate else ())
 
 
 def _phase2_wire_jit(mesh, transport: int, tiers, cap_out: int, kpack,
@@ -411,7 +411,7 @@ def _phase2_wire_build(mesh, transport: int, tiers, cap_out: int, kpack,
     nprocs = mesh_axis_size(mesh)
     spec = row_spec(mesh)
 
-    def phase2(skey, svalue, counts_local, stats_local):
+    def shuffle_phase2_wire(skey, svalue, counts_local, stats_local):
         def body(k, v, cl, st):
             out_k, out_v, _ = phase2_wire_shard_body(
                 nprocs, transport, mesh, tiers, cap_out, kpack, vpack,
@@ -423,7 +423,7 @@ def _phase2_wire_build(mesh, transport: int, tiers, cap_out: int, kpack,
                                     stats_local)
 
     from ..exec import donated_jit
-    return donated_jit(phase2, (0, 1) if donate else ())
+    return donated_jit(shuffle_phase2_wire, (0, 1) if donate else ())
 
 
 # speculative capacity cache (round 4, VERDICT r3 weak #5): composed
@@ -802,11 +802,28 @@ def aggregate_kv(backend, mr, hash_fn: Optional[Callable]):
         # partition host-side, then place the blocks on the mesh
         _aggregate_host_hash(backend, mr, hash_fn)
         return
-    frame = kv.one_frame()
+    from ..obs import get_tracer, names
+    tr = get_tracer()
+    with tr.span(names.AGGREGATE_ONE_FRAME, cat=names.HOST) as sp:
+        frame = kv.one_frame()
+        if tr.enabled:
+            sp.set(**_one_frame_attrs(kv))
     ktable = vtable = None
     if isinstance(frame, KVFrame):
-        frame, ktable, vtable = _intern_frame(
-            frame, mesh_axis_size(backend.mesh))
+        with tr.span(names.AGGREGATE_INTERN, cat=names.HOST,
+                     rows=len(frame)):
+            frame, ktable, vtable = _intern_frame(
+                frame, mesh_axis_size(backend.mesh))
+
+    def _shard(frame):
+        with tr.span(names.AGGREGATE_SHARD, cat=names.HOST,
+                     rows=len(frame)) as sp:
+            skv = shard_frame(frame, backend.mesh)
+            sp.set(bytes=skv.nbytes())
+        skv.key_decode = ktable
+        skv.value_decode = vtable
+        return skv
+
     if mesh_axis_size(backend.mesh) == 1:
         # reference early-out for nprocs==1 (src/mapreduce.cpp:403-406):
         # no exchange — but a dense host frame still moves onto the device
@@ -814,19 +831,11 @@ def aggregate_kv(backend, mr, hash_fn: Optional[Callable]):
         # computed multi-frame concat is kept (one_frame above was not free)
         if isinstance(frame, KVFrame):
             if frame.is_dense():
-                skv = shard_frame(frame, backend.mesh)
-                skv.key_decode = ktable
-                skv.value_decode = vtable
-                _replace_kv_frames(kv, skv)
+                _replace_kv_frames(kv, _shard(frame))
         else:
             _replace_kv_frames(kv, frame)
         return
-    if isinstance(frame, KVFrame):
-        skv = shard_frame(frame, backend.mesh)
-        skv.key_decode = ktable
-        skv.value_decode = vtable
-    else:
-        skv = frame  # already sharded
+    skv = _shard(frame) if isinstance(frame, KVFrame) else frame
     t = Timer()
     try:
         out = exchange(skv, ("hash", hash_fn),
@@ -840,6 +849,17 @@ def aggregate_kv(backend, mr, hash_fn: Optional[Callable]):
     # each keep their own last_exchange
     mr.last_exchange = getattr(out, "exchange_stats", None)
     _replace_kv_frames(kv, out)
+
+
+def _one_frame_attrs(kv) -> dict:
+    """What ``kv.one_frame()`` had to do, for its span: a dataset of
+    plain AND sharded frames compacts through the host
+    (core/dataset.one_frame), so the sharded frames' bytes came back."""
+    frames = kv._frames
+    sharded = [f for f in frames if isinstance(f, ShardedKV)]
+    mixed = 0 < len(sharded) < len(frames)
+    return {"rows": kv.nkv, "frames": len(frames),
+            "to_host_bytes": sum(f.nbytes() for f in sharded) if mixed else 0}
 
 
 def _key_bytes_rows(col) -> list:

@@ -114,7 +114,7 @@ def _unique_fn(mesh, nrows: int, drop_self: bool):
     # trim (second dispatch below) replicates — forcing rep on the full
     # array would put O(E) on every device
     @functools.partial(jax.jit, out_shardings=(shard, rep, rep))
-    def run(key, counts):
+    def stage_unique_verts(key, counts):
         valid = _valid_rows(nrows, nprocs, counts)
         if drop_self:
             valid = valid & (key[:, 0] != key[:, 1])
@@ -140,7 +140,7 @@ def _unique_fn(mesh, nrows: int, drop_self: bool):
         verts = jnp.full(m, SENTINEL).at[tgt].set(s, mode="drop")
         return verts, n, nbad
 
-    return run
+    return stage_unique_verts
 
 
 @functools.lru_cache(maxsize=None)
@@ -148,10 +148,10 @@ def _trim_fn(mesh, nout: int):
     rep = NamedSharding(mesh, PartitionSpec())
 
     @functools.partial(jax.jit, out_shardings=rep)
-    def run(x):
+    def stage_trim_verts(x):
         return x[:nout]
 
-    return run
+    return stage_trim_verts
 
 
 def unique_verts(fr: ShardedKV, drop_self: bool = False
@@ -176,7 +176,7 @@ def _rank_fn(mesh, nrows: int, nvp: int, drop_self: bool):
     nprocs = mesh_axis_size(mesh)
 
     @functools.partial(jax.jit, out_shardings=(shard, shard, shard))
-    def run(key, counts, verts):
+    def stage_rank_edges(key, counts, verts):
         valid = _valid_rows(nrows, nprocs, counts)
         if drop_self:
             valid = valid & (key[:, 0] != key[:, 1])
@@ -184,7 +184,7 @@ def _rank_fn(mesh, nrows: int, nvp: int, drop_self: bool):
         dst = jnp.searchsorted(verts, key[:, 1]).astype(jnp.int32)
         return src, dst, valid
 
-    return run
+    return stage_rank_edges
 
 
 def rank_edges(fr: ShardedKV, verts: jax.Array, drop_self: bool = False
