@@ -31,9 +31,8 @@ SHUFFLE_PHASE1 = "jit_shuffle_phase1"
 SHUFFLE_PHASE2 = "jit_shuffle_phase2"
 SHUFFLE_PHASE2_WIRE = "jit_shuffle_phase2_wire"
 # parallel/staging.py
-STAGE_UNIQUE_VERTS = "jit_stage_unique_verts"
+STAGE_RANK_GRAPH = "jit_stage_rank_graph"           # vertex table + edge ranks
 STAGE_TRIM_VERTS = "jit_stage_trim_verts"
-STAGE_RANK_EDGES = "jit_stage_rank_edges"
 # parallel/devkernels.py
 CONCAT_ROWS = "jit_concat_rows"
 REMAP_IDS = "jit_remap_ids"
@@ -45,9 +44,9 @@ RMAT_EDGES = "jit_rmat_edges"
 PROGRAMS = (
     INVINDEX_EXTRACT, INVINDEX_COLLISIONS, CONVERT_SORT, CONVERT_LAYOUT,
     REDUCE_SEGMENTS, GROUP_FIRST, SORT_MULTIVALUES, SORT_ROWS, SORT_INTERNED,
-    SHUFFLE_PHASE1, SHUFFLE_PHASE2, SHUFFLE_PHASE2_WIRE, STAGE_UNIQUE_VERTS,
-    STAGE_TRIM_VERTS, STAGE_RANK_EDGES, CONCAT_ROWS, REMAP_IDS, CC_LOOP,
-    PAGERANK_LOOP, RMAT_EDGES,
+    SHUFFLE_PHASE1, SHUFFLE_PHASE2, SHUFFLE_PHASE2_WIRE, STAGE_RANK_GRAPH,
+    STAGE_TRIM_VERTS, CONCAT_ROWS, REMAP_IDS, CC_LOOP, PAGERANK_LOOP,
+    RMAT_EDGES,
 )
 
 # parallel/devkernels.py's two generic mappers run one program per kernel

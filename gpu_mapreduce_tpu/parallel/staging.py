@@ -7,15 +7,21 @@ numpy and ``np.unique`` ranked it — a funnel the mesh cannot outgrow
 (the reference gives every rank its own slice and never funnels,
 ``cuda/InvertedIndex.cu:284-312``).
 
-Here the ranking runs on device over the mesh-resident edge KV:
+Here the ranking runs on device over the mesh-resident edge KV, in ONE
+jitted program (:func:`rank_graph`, ``jit_stage_rank_graph``) built on
+one global sort of the flattened endpoints of the sharded [rows, 2] u64
+edge keys:
 
-* :func:`unique_verts` — ONE jitted global sort-unique over the sharded
-  [rows, 2] u64 edge keys produces the sorted vertex table (replicated,
-  sentinel-padded, trimmed to ``round_cap(n)``) and the count.  Only the
-  scalar ``n`` syncs to the host.
-* :func:`rank_edges` — a second jitted searchsorted maps each edge
-  endpoint to its rank; outputs stay row-sharded in the SAME layout as
-  the input frame, ready for the fused models' shard_map loops.
+* the sort carries each endpoint's position as a payload; the first
+  occurrences of the sorted ids, brought to the front by one more sort,
+  are the sorted vertex table (sentinel-padded; a second tiny program
+  trims it to ``round_cap(n)`` and replicates it), and their count is
+  ``n``.  Only the scalars ``n`` and ``nbad`` sync to the host.
+* the prefix sum of the first-occurrence flags IS each sorted endpoint's
+  rank, so nothing is searched for: a second sort, keyed by the carried
+  positions, puts the ranks back in edge order.  ``src``/``dst``/``valid``
+  stay row-sharded in the SAME layout as the input frame, ready for the
+  fused models' shard_map loops.
 
 The O(E) edge columns never touch the host; commands pull only the [n]
 vertex-id table afterwards for their printed output.  Vertex id
@@ -30,6 +36,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec
 
 from .mesh import mesh_axis_size, row_spec
@@ -89,11 +96,10 @@ def stage_graph(mr, comm, drop_self: bool = False,
         return None
     if need_weights and fr.value_decode is not None:
         return None
-    verts_d, n = unique_verts(fr, drop_self=drop_self)
+    verts_d, n, src_d, dst_d, valid_d = rank_graph(fr, drop_self=drop_self)
     if n == 0:
         return StagedGraph(np.zeros(0, np.uint64), 0, None, None, None,
                            None)
-    src_d, dst_d, valid_d = rank_edges(fr, verts_d, drop_self=drop_self)
     return StagedGraph(np.asarray(verts_d)[:n], n, src_d, dst_d, valid_d,
                        fr.value if need_weights else None)
 
@@ -105,16 +111,22 @@ def _valid_rows(nrows: int, nprocs: int, counts):
 
 
 @functools.lru_cache(maxsize=None)
-def _unique_fn(mesh, nrows: int, drop_self: bool):
+def _rank_fn(mesh, nrows: int, drop_self: bool):
     rep = NamedSharding(mesh, PartitionSpec())
     shard = NamedSharding(mesh, row_spec(mesh))
     nprocs = mesh_axis_size(mesh)
+    m = 2 * nrows
+    # each endpoint's position rides the sort as a payload; at pod scale
+    # the flattened endpoints can exceed 2^31 and an i32 position (or an
+    # i32 cumsum below) would wrap
+    pos_t = jnp.int32 if m < 2 ** 31 else jnp.int64
 
     # the sorted 2E table stays ROW-SHARDED here; only the [round_cap(n)]
     # trim (second dispatch below) replicates — forcing rep on the full
     # array would put O(E) on every device
-    @functools.partial(jax.jit, out_shardings=(shard, rep, rep))
-    def stage_unique_verts(key, counts):
+    @functools.partial(jax.jit,
+                       out_shardings=(shard, rep, rep, shard, shard, shard))
+    def stage_rank_graph(key, counts):
         valid = _valid_rows(nrows, nprocs, counts)
         if drop_self:
             valid = valid & (key[:, 0] != key[:, 1])
@@ -123,24 +135,32 @@ def _unique_fn(mesh, nrows: int, drop_self: bool):
         # silently dropping the vertex
         nbad = jnp.sum((valid[:, None] & (key == SENTINEL))
                        .astype(jnp.int32))
-        flat = jnp.where(valid[:, None], key, SENTINEL).reshape(-1)
-        s = jnp.sort(flat)
-        first = jnp.concatenate([jnp.ones(1, bool), s[1:] != s[:-1]])
-        isu = first & (s != SENTINEL)
-        n = jnp.sum(isu.astype(jnp.int64))
-        # compact uniques to the front with prefix-sum + scatter-drop
-        # (positions unique by construction) — ~20× cheaper than a
-        # second sort; the sentinel fill keeps the table globally
-        # sorted for searchsorted
-        m = s.shape[0]
-        # int64 positions: at pod scale the flattened endpoints can
-        # exceed 2^31 rows and an i32 cumsum would wrap (silent drop)
-        pos = jnp.cumsum(isu.astype(jnp.int64)) - 1
-        tgt = jnp.where(isu, pos, m)
-        verts = jnp.full(m, SENTINEL).at[tgt].set(s, mode="drop")
-        return verts, n, nbad
+        # the two columns end to end, not interleaved: position p is
+        # row p % nrows, and no [2E] <-> [E, 2] relayout is needed (on
+        # the chip that one is a 64x tile-padded copy)
+        flat = jnp.concatenate([jnp.where(valid, key[:, 0], SENTINEL),
+                                jnp.where(valid, key[:, 1], SENTINEL)])
+        with jax.named_scope("sort"):
+            s, origin = lax.sort((flat, lax.iota(pos_t, m)), num_keys=1)
+        with jax.named_scope("rank"):
+            first = jnp.concatenate([jnp.ones(1, bool), s[1:] != s[:-1]])
+            isu = first & (s != SENTINEL)
+            # a sorted endpoint's rank is the number of uniques up to
+            # it, less one; the sentinels behind them read n - 1
+            rank = jnp.cumsum(isu.astype(pos_t)) - 1
+            n = rank[-1].astype(jnp.int64) + 1
+        with jax.named_scope("table"):
+            # uniques to the front, sentinel fill behind them: one more
+            # sort, because on the chip the scatter-drop that did this
+            # took 1.8 s at 16.8 M endpoints and a sort takes 0.05 s
+            verts = jnp.sort(jnp.where(isu, s, SENTINEL))
+        with jax.named_scope("return"):
+            # the ranks go back to edge order by the carried positions
+            _, back = lax.sort((origin, rank.astype(jnp.int32)),
+                               num_keys=1)
+        return verts, n, nbad, back[:nrows], back[nrows:], valid
 
-    return stage_unique_verts
+    return stage_rank_graph
 
 
 @functools.lru_cache(maxsize=None)
@@ -154,43 +174,22 @@ def _trim_fn(mesh, nout: int):
     return stage_trim_verts
 
 
-def unique_verts(fr: ShardedKV, drop_self: bool = False
-                 ) -> Tuple[jax.Array, int]:
-    """Sorted unique endpoint ids of a mesh-resident [rows,2] edge frame:
-    (replicated sentinel-padded table of length round_cap(n), n).  With
-    ``drop_self`` endpoints of self-loop-only vertices are excluded (the
-    luby convention)."""
-    verts, n, nbad = _unique_fn(fr.mesh, fr.key.shape[0], drop_self)(
-        fr.key, jnp.asarray(fr.counts))
+def rank_graph(fr: ShardedKV, drop_self: bool = False
+               ) -> Tuple[jax.Array, int, jax.Array, jax.Array, jax.Array]:
+    """Vertex table and ranked edges of a mesh-resident [rows,2] edge
+    frame: (verts, n, src, dst, valid).  ``verts`` is the sorted unique
+    endpoint ids, replicated, sentinel-padded to ``round_cap(n)``;
+    ``src``/``dst`` are each endpoint's int32 rank in it and ``valid``
+    the row mask, each [rows] row-sharded like the frame — feed directly
+    to the fused models' sharded loops (invalid/padding rows carry
+    valid=False and some rank in [0, n]).  With ``drop_self`` self-loop
+    rows are invalid, so endpoints of self-loop-only vertices are
+    excluded (the luby convention)."""
+    verts, n, nbad, src, dst, valid = _rank_fn(
+        fr.mesh, fr.key.shape[0], drop_self)(fr.key, jnp.asarray(fr.counts))
     if int(nbad):
         raise ValueError(
             f"vertex id {SENTINEL} is reserved as the device staging "
             f"sentinel ({int(nbad)} occurrences in the edge list)")
     n = int(n)
-    return _trim_fn(fr.mesh, round_cap(n))(verts), n
-
-
-@functools.lru_cache(maxsize=None)
-def _rank_fn(mesh, nrows: int, nvp: int, drop_self: bool):
-    shard = NamedSharding(mesh, row_spec(mesh))
-    nprocs = mesh_axis_size(mesh)
-
-    @functools.partial(jax.jit, out_shardings=(shard, shard, shard))
-    def stage_rank_edges(key, counts, verts):
-        valid = _valid_rows(nrows, nprocs, counts)
-        if drop_self:
-            valid = valid & (key[:, 0] != key[:, 1])
-        src = jnp.searchsorted(verts, key[:, 0]).astype(jnp.int32)
-        dst = jnp.searchsorted(verts, key[:, 1]).astype(jnp.int32)
-        return src, dst, valid
-
-    return stage_rank_edges
-
-
-def rank_edges(fr: ShardedKV, verts: jax.Array, drop_self: bool = False
-               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Edge endpoints as vertex ranks: (src, dst, valid), each [rows]
-    row-sharded like the frame — feed directly to the fused models'
-    sharded loops (invalid/padding rows carry valid=False)."""
-    return _rank_fn(fr.mesh, fr.key.shape[0], verts.shape[0], drop_self)(
-        fr.key, jnp.asarray(fr.counts), verts)
+    return _trim_fn(fr.mesh, round_cap(n))(verts), n, src, dst, valid
