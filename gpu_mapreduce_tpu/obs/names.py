@@ -85,13 +85,14 @@ PAGERANK_STAGE = "pagerank.stage"
 PAGERANK_EMIT = "pagerank.emit"
 PAGERANK_ENGINE = "pagerank.loop"               # cat ENGINE
 # apps/invertedindex.py
-MAP_PLAN = "map.plan"                           # files, bytes
-MAP_PAD = "map.pad"                             # bytes
+MAP_PLAN = "map.plan"                           # files, bytes, rounds
+MAP_PAD = "map.pad"                             # bytes, shard_bytes
+MAP_COLLISIONS = "map.collisions"               # rows, rounds, shards
 PARTS_PULL = "parts.pull"                       # groups, bytes
 PARTS_WRITE = "parts.write"                     # groups, bytes
 
 # older spans that metrics quote by name
-SHUFFLE_EXCHANGE = "shuffle.exchange"
+SHUFFLE_EXCHANGE = "shuffle.exchange"           # ..., recv_rows_max, _mean
 SHUFFLE_COUNT_SYNC = "shuffle.count_sync"
 OINK_RMAT = "oink.rmat"                         # rounds
 
@@ -99,6 +100,6 @@ SPANS = (
     AGGREGATE_ONE_FRAME, AGGREGATE_INTERN, AGGREGATE_SHARD,
     CONVERT_COUNT_SYNC, RMAT_GENERATE, OINK_INPUT, OINK_OUTPUT, CC_STAGE,
     CC_EMIT, CC_ENGINE, PAGERANK_STAGE, PAGERANK_EMIT, PAGERANK_ENGINE,
-    MAP_PLAN, MAP_PAD, PARTS_PULL, PARTS_WRITE, SHUFFLE_EXCHANGE,
-    SHUFFLE_COUNT_SYNC, OINK_RMAT,
+    MAP_PLAN, MAP_PAD, MAP_COLLISIONS, PARTS_PULL, PARTS_WRITE,
+    SHUFFLE_EXCHANGE, SHUFFLE_COUNT_SYNC, OINK_RMAT,
 )

@@ -758,8 +758,11 @@ def _exchange_impl(skv: ShardedKV, dest, transport: int,
                               rows=int(counts_mat.sum()),
                               speculative=out_spec is not None
                               and (out_k is out_spec[0]))
+    # rows each shard received, from the count matrix already on the host:
+    # max over mean is the skew of the destinations (1.0 = even)
     sp.set(bucket=B_eff, nrounds=nrounds_eff, cap_out=cap_out_eff,
-           rows=stats.rows)
+           rows=stats.rows, recv_rows_max=int(new_counts.max()),
+           recv_rows_mean=float(new_counts.mean()))
     # byte accounting ALWAYS lands on the per-call stats (and so the
     # live metrics + request profile), whether or not a Counters object
     # rides along — a direct reshard/gather caller without counters

@@ -401,3 +401,95 @@ def test_fold_id_check_thread_hammer():
     idx._fold_id_check(np.array([5], np.uint64), np.array([99], np.uint64))
     with pytest.raises(ValueError, match="collision"):
         idx._compact_chk_runs()
+
+
+# -- the four-chip deployment's shapes, tiny (benchmark/configs/
+# puma-invindex-4chip.json): exact against a regex scan written here ----------
+
+def _puma_corpus(d, nfiles=8, file_bytes=6000):
+    """PUMA-density shapes at toy size: one href per filler, a quarter of
+    the references to a 64-URL hot set, 2 % long URLs of 130-210 bytes,
+    some over MAX_URL (dropped), and an href without its closing quote at
+    the end of every file (dropped)."""
+    filler = b"<p>" + b"lorem ipsum dolor sit amet " * 3 + b"</p>\n"
+    hot = [b"http://example.org/hot/%02d" % i for i in range(64)]
+    paths, uid, nref = [], 0, 0
+    for i in range(nfiles):
+        pieces, size = [], 0
+        while size < file_bytes:
+            if nref % 100 == 99:
+                u = b"http://example.org/over/p%08d/" % uid + b"y" * 300
+                uid += 1
+            elif nref % 50 == 49:
+                u = b"http://example.org/long/p%08d/" % uid \
+                    + b"x" * (96 + uid % 80)
+                uid += 1
+            elif nref % 4 == 3:
+                u = hot[(nref // 4) % len(hot)]
+            else:
+                u = b"http://example.org/wiki/page-%08d" % uid
+                uid += 1
+            nref += 1
+            pieces += [filler, PATTERN + u + b'">x</a>']
+            size += len(filler) + len(pieces[-1])
+        pieces.append(filler + PATTERN + b"http://example.org/cut-off/%d" % i)
+        p = d / f"part-{i:05d}.html"
+        p.write_bytes(b"".join(pieces))
+        paths.append(str(p))
+    return paths
+
+
+@pytest.mark.parametrize("rounds", [1, 2])
+@pytest.mark.parametrize("P", [1, 4])
+def test_puma_shapes_on_a_mesh_match_a_regex_scan_exactly(
+        tmp_path, monkeypatch, P, rounds):
+    """The multi-process deployment through the normal path: every url in
+    exactly one part file, in the part file of ``default_hash(id) % P``,
+    P non-empty part files, npairs and nunique equal.  Integers: no
+    tolerance."""
+    import os
+
+    from gpu_mapreduce_tpu.apps.invertedindex import MAX_URL, _GAP
+    from gpu_mapreduce_tpu.ops.hash import hash_bytes64
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    from gpu_mapreduce_tpu.parallel.shuffle import default_hash
+
+    paths = _puma_corpus(tmp_path)
+    want, npairs, dropped = {}, 0, 0
+    for f in paths:
+        data = open(f, "rb").read()
+        assert data.count(PATTERN) == len(oracle_urls(data)) + 1  # cut off
+        for u in oracle_urls(data):
+            if len(u) >= MAX_URL:
+                dropped += 1
+                continue
+            npairs += 1
+            want.setdefault(u, set()).add(f)
+    assert dropped and any(130 <= len(u) <= 210 for u in want)
+
+    if rounds > 1:
+        # a shard's contiguous slice of 8 / P files in `rounds` batches
+        per_batch = len(paths) // P // rounds
+        sizes = [os.path.getsize(f) + _GAP for f in paths]
+        cap = per_batch * max(sizes)
+        assert (per_batch + 1) * min(sizes) > cap
+        monkeypatch.setenv("MR_BATCH_BYTES", str(cap))
+    idx = InvertedIndex(comm=make_mesh(P))
+    outdir = str(tmp_path / "out")
+    assert idx.run(paths, outdir=outdir) == (npairs, len(want))
+    assert idx.stats["nbatches"] == P * rounds
+
+    parts = sorted(os.listdir(outdir))
+    assert parts == [f"part-{p:05d}" for p in range(P)]
+    got = {}
+    for p, part in enumerate(parts):
+        lines = open(os.path.join(outdir, part), "rb").read().splitlines()
+        assert lines                                  # P non-empty files
+        urls = [ln.split(b"\t")[0] for ln in lines]
+        ids = np.asarray([hash_bytes64(u) for u in urls], np.uint64)
+        assert (np.asarray(default_hash(ids)) % P == p).all()
+        for ln in lines:
+            url, names = ln.split(b"\t")
+            assert url not in got                     # in ONE part file
+            got[url] = set(names.decode().split(" "))
+    assert got == want

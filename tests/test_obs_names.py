@@ -44,6 +44,8 @@ def _programs(mesh):
         (names.INVINDEX_EXTRACT,
          app._extract_mesh_fn(mesh, 8, False, False, False).lower(
              SDS((8 * 64,), u32), SDS((8,), i32), SDS((8,), u32))),
+        (names.INVINDEX_COLLISIONS, app._collision_count_jit.lower(
+            (col,), (col,), (SDS((64,), jnp.bool_),))),
         (names.CONVERT_SORT,
          group._convert_phase1_jit(mesh).lower(key, val, cnt)),
         (names.CONVERT_LAYOUT, group._convert_phase2_jit(mesh, 8).lower(
@@ -92,31 +94,60 @@ def test_every_program_lowers_under_its_declared_name(mesh):
         assert names.declared_program(got)
         assert got not in seen, f"{got} names two programs"
         seen.add(got)
-    # the only declared program not lowered here is jitted inside its
-    # caller (next test)
-    assert set(names.PROGRAMS) - seen == {names.INVINDEX_COLLISIONS}
+    assert set(names.PROGRAMS) <= seen
     assert len(set(names.PROGRAMS)) == len(names.PROGRAMS)
     assert len(set(names.SPANS)) == len(names.SPANS)
     for old in ("jit_run", "jit_body", "jit_phase1", "jit_phase2"):
         assert not names.declared_program(old)
 
 
+def _collision_checks(mesh, rows_per_shard=8):
+    """One round of (ids, alts, counts) as the mesh map stage hands them
+    to the global check: columns sharded by row, counts on the host."""
+    from gpu_mapreduce_tpu.parallel.mesh import mesh_axis_size, row_sharding
+    P = mesh_axis_size(mesh)
+    ids = jax.device_put(jnp.arange(P * rows_per_shard, dtype=jnp.uint64),
+                         row_sharding(mesh))
+    return ((ids, ids, np.full(P, 2, np.int32)),)
+
+
 def test_the_collision_count_program_has_its_name(mesh):
-    """It is jitted inside its caller, so read the name off a dispatch."""
+    """Hoisted out of its caller (PR 26): the program the caller
+    dispatches is the module-level function, lowered here at the caller's
+    own arguments."""
     from gpu_mapreduce_tpu.apps import invertedindex as app
-    seen = []
-    real = jax.jit
+    assert app._collision_count_jit.__wrapped__ \
+        is app.invindex_collision_count
+    (ids, alts, _counts), = _collision_checks(mesh)
+    valid = SDS(ids.shape, jnp.bool_)
+    text = app._collision_count_jit.lower((ids,), (alts,),
+                                          (valid,)).as_text()
+    assert re.search(r"module @(\w+)", text).group(1) \
+        == names.INVINDEX_COLLISIONS
+    assert app._mesh_collision_count(_collision_checks(mesh)) == 0
 
-    def spy(fn, **kw):
-        seen.append("jit_" + fn.__name__)
-        return real(fn, **kw)
 
-    ids = jnp.arange(16, dtype=jnp.uint64)
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(app.jax, "jit", spy)
-        assert app._mesh_collision_count(
-            ((ids, ids, np.full(8, 2, np.int32)),)) == 0
-    assert seen == [names.INVINDEX_COLLISIONS]
+def test_the_collision_count_program_is_built_once(mesh, monkeypatch):
+    """Two jobs at one shape trace the program once: the second call
+    dispatches the first one's executable (it was jitted inside its caller,
+    a new function object and a new trace per job)."""
+    from gpu_mapreduce_tpu.apps import invertedindex as app
+    traced = []
+    real = app._count_collisions
+
+    def counting(*a):
+        traced.append(a[0].shape)
+        return real(*a)
+
+    monkeypatch.setattr(app, "_count_collisions", counting)
+    app._collision_count_jit.clear_cache()
+    for _job in range(2):
+        assert app._mesh_collision_count(_collision_checks(mesh, 24)) == 0
+    assert len(traced) == 1
+    # another shape (a second round) is another program
+    two = _collision_checks(mesh, 24) + _collision_checks(mesh, 24)
+    assert app._mesh_collision_count(two) == 0
+    assert len(traced) == 2
 
 
 # -- spans ---------------------------------------------------------------------
@@ -183,6 +214,14 @@ def _tree(events):
         return p["name"] if p else None
 
     return [(e["name"], e["cat"], parent(e), e["args"]) for e in events]
+
+
+def _attrs(tree):
+    """name -> [attrs of each span of that name, in order]."""
+    out = {}
+    for name, _cat, _parent, a in tree:
+        out.setdefault(name, []).append(a)
+    return out
 
 
 def _where(tree):
@@ -253,9 +292,7 @@ def test_invertedindex_emits_the_map_and_part_file_spans(mesh, traced,
     assert where[names.MAP_PAD] == {(H, "map")}
     assert where[names.PARTS_PULL] == {(H, "stage.reduce")}
     assert where[names.PARTS_WRITE] == {(H, "stage.reduce")}
-    args = {}
-    for n, _c, _p, a in tree:
-        args.setdefault(n, []).append(a)
+    args = _attrs(tree)
     assert args[names.MAP_PLAN][0]["bytes"] == sum(
         os.path.getsize(p) for p in corpus)
     assert args[names.MAP_PAD][0]["bytes"] >= args[names.MAP_PLAN][0]["bytes"]
@@ -263,6 +300,33 @@ def test_invertedindex_emits_the_map_and_part_file_spans(mesh, traced,
     assert sum(a["groups"] for a in args[names.PARTS_WRITE]) == nunique
     assert sum(a["bytes"] for a in args[names.PARTS_WRITE]) == sum(
         len(b) for b in parts.values())
+
+
+def test_invertedindex_on_four_devices_says_which_shard(traced, corpus,
+                                                         tmp_path):
+    """What exists only when P > 1 (PR 26): the global collision check has
+    a span of its own, the serial per-shard staging says which shard, and
+    the exchange says how evenly its rows landed."""
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    (npairs, nunique), parts, _idx = _invindex(make_mesh(4), corpus,
+                                               str(tmp_path))
+    assert len(parts) == 4
+    tree = _tree(traced.events())
+    where = _where(tree)
+    assert where[names.MAP_COLLISIONS] == {(names.HOST, "stage.map_device")}
+    args = _attrs(tree)
+    (coll,) = args[names.MAP_COLLISIONS]
+    assert (coll["rows"], coll["rounds"], coll["shards"]) == (npairs, 1, 4)
+    reads = args["stage.read"]
+    assert [a["shard"] for a in reads] == [0, 1, 2, 3]
+    (pad,) = args[names.MAP_PAD]
+    assert pad["shard_bytes"] == [a["bytes"] for a in reads]
+    assert sum(pad["shard_bytes"]) <= pad["bytes"]
+    (ud,) = args["stage.url_dict"]
+    assert (ud["urls"], ud["shards"]) == (npairs, 4)
+    (ex,) = args[names.SHUFFLE_EXCHANGE]
+    assert ex["recv_rows_mean"] == npairs / 4
+    assert ex["recv_rows_mean"] <= ex["recv_rows_max"] <= npairs
 
 
 def test_tracer_off_constructs_no_span_and_changes_nothing(
