@@ -239,26 +239,57 @@ class KeyValue:
         for f in self._frames:
             yield f.load(self.counters) if isinstance(f, _Spilled) else f
 
-    def one_frame(self):
+    def one_frame(self, moved: Optional[dict] = None):
         """Whole dataset as a single frame (in-core fast path).  Returns the
-        ShardedKV directly when that's the sole frame; several sharded
-        frames on one mesh concatenate per-shard ON DEVICE (the add() path
-        of iterative mesh commands); a mixed plain+sharded dataset compacts
-        to host."""
+        ShardedKV directly when that's the sole frame.  A dataset that
+        lives on a mesh is assembled THERE: several sharded frames
+        concatenate per-shard on device (the add() path of iterative mesh
+        commands), and dense host frames added beside them go UP — only
+        their rows are placed on the mesh, the short shards first, and
+        appended; a sharded frame never comes down to be concatenated.
+        Only a host frame of interned byte/object rows (or of another
+        row type) still compacts the dataset to the host: its ids are
+        not in the sharded frames' space.  ``moved``, when given, is
+        told the bytes that crossed: ``to_device_bytes``,
+        ``to_host_bytes``."""
         frames = list(self.frames())
+        moved = {} if moved is None else moved
+        moved.update(to_device_bytes=0, to_host_bytes=0)
         if not frames:
             from .frame import empty_kv
             return empty_kv()
         if len(frames) == 1:
             return frames[0]
+        parts = _mesh_parts(frames)
+        if parts is None:
+            moved["to_host_bytes"] = sum(
+                f.nbytes() for f in frames if not isinstance(f, KVFrame))
+            return _merge_frames([f if isinstance(f, KVFrame)
+                                  else f.to_host() for f in frames])
+        import functools as _ft
+        from ..parallel.devkernels import concat_sharded
+        sharded, host = parts
+        out = _ft.reduce(concat_sharded, sharded)
+        if host:
+            from ..parallel.sharded import (fill_counts,
+                                            shard_frame_with_counts)
+            new = _merge_frames(host)
+            moved["to_device_bytes"] = new.nbytes()
+            out = concat_sharded(out, shard_frame_with_counts(
+                new, out.mesh, fill_counts(out.counts, len(new))))
+        return out
+
+    def shard_rows(self, mesh) -> np.ndarray:
+        """Valid rows each shard of ``mesh`` holds in this dataset's
+        sharded frames: what a producer that adds rows on the device
+        balances its own against (``parallel.sharded.fill_counts``)."""
+        from ..parallel.mesh import mesh_axis_size
         from ..parallel.sharded import ShardedKV
-        if all(isinstance(f, ShardedKV) for f in frames) \
-                and len({f.mesh for f in frames}) == 1:
-            import functools as _ft
-            from ..parallel.devkernels import concat_sharded
-            return _ft.reduce(concat_sharded, frames)
-        frames = [f if isinstance(f, KVFrame) else f.to_host() for f in frames]
-        return _merge_frames(frames)
+        have = np.zeros(mesh_axis_size(mesh), np.int64)
+        for f in self._frames:
+            if isinstance(f, ShardedKV) and f.mesh == mesh:
+                have += f.counts
+        return have
 
     def nbytes(self) -> int:
         return sum(f.bytes_ if isinstance(f, _Spilled) else f.nbytes()
@@ -440,6 +471,35 @@ def _coerce_rows(rows: list) -> Column:
         # arbitrary objects, not data; keep the originals via pickle
         return ObjectColumn(rows)
     return DenseColumn(arr)
+
+
+def _mesh_parts(frames):
+    """``(sharded frames, host frames)`` of a dataset that can be
+    assembled on the mesh its sharded frames live on: they share one
+    mesh, and every other frame is a dense host KVFrame of the same
+    plain row type (no intern tables on either side, which
+    ``devkernels._merge_decode`` would refuse to mix).  ``None`` when it
+    has to go through the host."""
+    from ..parallel.sharded import ShardedKV
+    sharded = [f for f in frames if isinstance(f, ShardedKV)]
+    host = [f for f in frames if not isinstance(f, ShardedKV)]
+    if not sharded or len({f.mesh for f in sharded}) != 1:
+        return None
+    if not host:
+        return sharded, host
+    first = sharded[0]
+
+    def same_rows(col, arr):
+        data = col.data
+        return data.dtype == arr.dtype and data.shape[1:] == arr.shape[1:]
+
+    plain = all(f.key_decode is None and f.value_decode is None
+                for f in sharded)
+    if plain and all(isinstance(f, KVFrame) and f.is_dense()
+                     and same_rows(f.key, first.key)
+                     and same_rows(f.value, first.value) for f in host):
+        return sharded, host
+    return None
 
 
 def _merge_frames(frames: Sequence[KVFrame]) -> KVFrame:

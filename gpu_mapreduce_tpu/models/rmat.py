@@ -60,6 +60,14 @@ def rmat_edges(key, m: int, nlevels: int, abcd, frac: float, noisy: bool
     return vi, vj
 
 
+@jax.jit
+def rmat_edge_rows(vi, vj):
+    """One generated batch as KV rows, where the columns are: the [m, 2]
+    edge keys and their NULL (one zero byte) values — what the ``rmat``
+    commands add to a dataset that lives on a mesh."""
+    return jnp.stack([vi, vj], axis=1), jnp.zeros(vi.shape, jnp.uint8)
+
+
 def generate_unique(seed: int, nlevels: int, nnonzero: int,
                     abcd=(0.25, 0.25, 0.25, 0.25), frac: float = 0.0,
                     add_edges=None) -> Tuple[np.ndarray, int]:

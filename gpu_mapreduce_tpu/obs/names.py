@@ -33,20 +33,24 @@ SHUFFLE_PHASE2_WIRE = "jit_shuffle_phase2_wire"
 # parallel/staging.py
 STAGE_RANK_GRAPH = "jit_stage_rank_graph"           # vertex table + edge ranks
 STAGE_TRIM_VERTS = "jit_stage_trim_verts"
+# parallel/sharded.py
+PLACE_ROWS = "jit_place_rows"                       # device rows → shard blocks
 # parallel/devkernels.py
-CONCAT_ROWS = "jit_concat_rows"
+CONCAT_ROWS = "jit_concat_rows"                     # append, per shard: a copy
+LEVEL_ROWS = "jit_level_rows"                       # excess rows → short shards
 REMAP_IDS = "jit_remap_ids"
 # models/
 CC_LOOP = "jit_cc_loop"
 PAGERANK_LOOP = "jit_pagerank_loop"
 RMAT_EDGES = "jit_rmat_edges"
+RMAT_EDGE_ROWS = "jit_rmat_edge_rows"               # [m, 2] keys + NULL values
 
 PROGRAMS = (
     INVINDEX_EXTRACT, INVINDEX_COLLISIONS, CONVERT_SORT, CONVERT_LAYOUT,
     REDUCE_SEGMENTS, GROUP_FIRST, SORT_MULTIVALUES, SORT_ROWS, SORT_INTERNED,
     SHUFFLE_PHASE1, SHUFFLE_PHASE2, SHUFFLE_PHASE2_WIRE, STAGE_RANK_GRAPH,
-    STAGE_TRIM_VERTS, CONCAT_ROWS, REMAP_IDS, CC_LOOP, PAGERANK_LOOP,
-    RMAT_EDGES,
+    STAGE_TRIM_VERTS, PLACE_ROWS, CONCAT_ROWS, LEVEL_ROWS, REMAP_IDS,
+    CC_LOOP, PAGERANK_LOOP, RMAT_EDGES, RMAT_EDGE_ROWS,
 )
 
 # parallel/devkernels.py's two generic mappers run one program per kernel
@@ -67,13 +71,14 @@ ENGINE = "engine"       # a device loop, from dispatch to the pull that ends it
 
 # -- host-phase spans (cat HOST unless said) ----------------------------------
 # parallel/shuffle.aggregate_kv, before the exchange / the one-chip early-out
-AGGREGATE_ONE_FRAME = "aggregate.one_frame"     # rows, frames, to_host_bytes
+AGGREGATE_ONE_FRAME = "aggregate.one_frame"     # rows, frames, cap,
+#                                                 to_host_bytes, to_device_bytes
 AGGREGATE_INTERN = "aggregate.intern"           # rows
 AGGREGATE_SHARD = "aggregate.shard"             # rows, bytes
 # parallel/group.convert_sharded: the pull between sort and layout
 CONVERT_COUNT_SYNC = "convert.count_sync"       # groups
 # oink/commands/rmat.py
-RMAT_GENERATE = "rmat.generate"                 # rows
+RMAT_GENERATE = "rmat.generate"                 # rows, d2h_bytes
 # oink/objects.py
 OINK_INPUT = "oink.input"                       # source, rows, bytes
 OINK_OUTPUT = "oink.output"                     # path, rows, bytes

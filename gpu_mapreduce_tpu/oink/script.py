@@ -25,6 +25,7 @@ import os
 import shutil
 import sys
 import time as _time
+import weakref
 from typing import List, Optional, TextIO
 
 from ..core.runtime import MRError
@@ -61,8 +62,12 @@ class OinkScript:
         self.echo_screen = False       # reference default: echo log only
         self.echo_log = True
         self.deltatime = 0.0           # `time` keyword (input.cpp:463)
-        self.variables.specials["time"] = lambda: self.deltatime
-        self.variables.specials["nprocs"] = lambda: self._nprocs()
+        # through a weak reference: a closure over ``self`` here makes a
+        # cycle, and a dropped script's datasets (device memory) would wait
+        # for the cyclic collector (PERF.md §6, PR 27)
+        me = weakref.ref(self)
+        self.variables.specials["time"] = lambda: me().deltatime
+        self.variables.specials["nprocs"] = lambda: me()._nprocs()
         # label scanning + file stack (reference label_active/infiles)
         self._label_active = False
         self._labelstr = ""

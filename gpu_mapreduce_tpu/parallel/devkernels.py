@@ -26,11 +26,13 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from .group import _local_segment_ids
-from .mesh import row_sharding, row_spec
-from .sharded import ShardedKMV, ShardedKV, SyncStats
+from .mesh import mesh_axes, mesh_axis_size, row_sharding, row_spec
+from .sharded import (ShardedKMV, ShardedKV, SyncStats, fill_counts,
+                      round_cap, rows_below, window_rows)
 
 U64MAX = np.uint64(0xFFFFFFFFFFFFFFFF)
 
@@ -52,8 +54,13 @@ def _body_name(fn) -> str:
 
 def _pack(ok, ov, valid):
     """Stable front-packing via prefix-sum + scatter-with-drop — the same
-    idiom compact_word_matches documents (~20× cheaper than the sort-based
-    form on TPU; positions are unique by construction)."""
+    idiom compact_word_matches documents (positions are unique by
+    construction).  "~20× cheaper than the sort-based form" was read on
+    the CPU backend: on the v5e a 16.8 M-element u64 scatter with dropped
+    rows costs 1.79 s against 0.055 s for a sort of the same size
+    (PERF.md §6, PR 25), and this pack of 8.4 M rows is 0.97 s
+    (``jit_kv_map_edge_upper``, PERF.md §5).  Inputs that are already
+    front-packed never need it: :func:`_append`."""
     n = valid.shape[0]
     pos = jnp.cumsum(valid.astype(jnp.int32)) - 1
     tgt = jnp.where(valid, pos, n)
@@ -153,23 +160,34 @@ def skmv_map(kmv: ShardedKMV, fn, static=(), extra=(),
 # shard-resident concat (MapReduce.add of two mesh datasets)
 # ---------------------------------------------------------------------------
 
+def _append(a, b, ca, cb, cap: int):
+    """``[cap, ...]`` rows: ``a``'s first ``ca``, then ``b``'s first
+    ``cb``, zero rows after.  Both inputs are front-packed, so row i of
+    the result is ``a[i]`` below ``ca`` and ``b[i - ca]`` from there: a
+    select between two :func:`window_rows`, of ``a`` and of ``b`` shifted
+    down by ``ca`` rows (behind ``cap`` zero rows) — a copy, no scatter,
+    no sort."""
+    lead = jnp.zeros((cap,) + b.shape[1:], b.dtype)
+    shifted = window_rows(jnp.concatenate([lead, b]), cap - ca, ca + cb,
+                          cap)
+    return jnp.where(rows_below(ca, cap, a.ndim),
+                     window_rows(a, 0, ca, cap), shifted)
+
+
 @functools.lru_cache(maxsize=None)
-def _concat_jit(mesh):
+def _concat_jit(mesh, cap: int):
     spec = row_spec(mesh)
 
-    @jax.jit
     def concat_rows(k1, v1, c1, k2, v2, c2):
         def body(ka, va, ca, kb, vb, cb):
-            na, nb = ka.shape[0], kb.shape[0]
-            valid = jnp.concatenate([jnp.arange(na) < ca[0],
-                                     jnp.arange(nb) < cb[0]])
-            return _pack(jnp.concatenate([ka, kb]),
-                         jnp.concatenate([va, vb]), valid)
+            return (_append(ka, kb, ca[0], cb[0], cap),
+                    _append(va, vb, ca[0], cb[0], cap))
         return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 6,
-                             out_specs=(spec, spec, spec))(k1, v1, c1,
-                                                           k2, v2, c2)
+                             out_specs=(spec, spec))(k1, v1, c1,
+                                                     k2, v2, c2)
 
-    return concat_rows
+    rows = row_sharding(mesh)
+    return jax.jit(concat_rows, out_shardings=(rows, rows))
 
 
 def _merge_decode(ta, tb, what: str):
@@ -288,11 +306,95 @@ def concat_sharded(a: ShardedKV, b: ShardedKV) -> ShardedKV:
     av, bv, vta, vtb = _align_domains(a, b, "value")
     put = lambda s: jax.device_put(s.counts.astype(np.int32),
                                    row_sharding(a.mesh))
-    k, v, c = _concat_jit(a.mesh)(ak, av, put(a), bk, bv, put(b))
+    # valid rows are a prefix of every shard and the counts are on the
+    # host, so the result's counts and capacity are known before the
+    # program runs: the fullest shard's rows, not cap_a + cap_b
+    counts = (a.counts + b.counts).astype(np.int32)
+    # the op's one sync is on completion, nothing is pulled: a program's
+    # buffers are allocated when it is dispatched, so the caller's next
+    # program (a sort) would otherwise be allocated beside both inputs
+    # and everything that made them (PERF.md §6, PR 27)
+    k, v = jax.block_until_ready(
+        _concat_jit(a.mesh, round_cap(int(counts.max())))(
+            ak, av, put(a), bk, bv, put(b)))
     SyncStats.bump()
-    return ShardedKV(a.mesh, k, v, np.asarray(c).astype(np.int32),
+    return ShardedKV(a.mesh, k, v, counts,
                      key_decode=_merge_decode(kta, ktb, "key"),
                      value_decode=_merge_decode(vta, vtb, "value"))
+
+
+# ---------------------------------------------------------------------------
+# levelling before an exchange
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _level_jit(mesh, cap: int, xcap: int, ycap: int):
+    """Each shard cuts its rows over the level into an ``[xcap]`` block,
+    all blocks are gathered (they are small), and each short shard takes
+    its run of the gathered overflow behind its own rows: copies only."""
+    spec = row_spec(mesh)
+    nshards = mesh_axis_size(mesh)
+    axes = mesh_axes(mesh)
+    ax = axes[0] if len(axes) == 1 else axes
+
+    def level_rows(key, value, keep, over, start, take):
+        def body(k, v, kp, ov, st, tk):
+            st, tk = st[0], tk[0]               # this shard's row: [P]
+
+            def levelled(x):
+                spill = lax.all_gather(
+                    window_rows(x, kp[0], ov[0], xcap), ax)   # [P, xcap, ...]
+                inc = jnp.zeros((ycap,) + x.shape[1:], x.dtype)
+                got = jnp.int32(0)
+                for s in range(nshards):
+                    inc = _append(inc, window_rows(spill[s], st[s], tk[s],
+                                                   xcap), got, tk[s], ycap)
+                    got = got + tk[s]
+                return _append(x, inc, kp[0], got, cap)
+
+            return levelled(k), levelled(v)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 6,
+                             out_specs=(spec, spec))(key, value, keep, over,
+                                                     start, take)
+
+    rows = row_sharding(mesh)
+    return jax.jit(level_rows, out_shardings=(rows, rows))
+
+
+def level_sharded(skv: ShardedKV) -> ShardedKV:
+    """Even out a frame that an exchange is about to re-home anyway: where
+    a row waits for the exchange is free, and the programs of the round
+    are compiled at the fullest shard's capacity, rounded up to a power of
+    two.  When moving the rows over the even level to the short shards
+    lowers that capacity, do it (on the device: the few rows over the
+    level are gathered, nothing else moves); otherwise the frame is
+    returned as it is.  Never for a frame whose rows must stay where
+    they are (``convert`` after ``add`` counts on equal keys sharing a
+    shard)."""
+    counts = skv.counts.astype(np.int64)
+    nshards = skv.nprocs
+    level = -(-int(counts.sum()) // nshards)
+    if nshards == 1 or round_cap(level) >= round_cap(int(counts.max())):
+        return skv
+    keep = np.minimum(counts, level)
+    over = counts - keep
+    give = fill_counts(keep, int(over.sum())).astype(np.int64)
+    # shard d takes the run [dst[d], dst[d] + give[d]) of the gathered
+    # overflow, in which shard s's rows are [src[s], src[s] + over[s])
+    src = np.cumsum(over) - over
+    dst = np.cumsum(give) - give
+    lo = np.maximum(src[None, :], dst[:, None])
+    hi = np.minimum((src + over)[None, :], (dst + give)[:, None])
+    take = np.maximum(hi - lo, 0).astype(np.int32)           # [dest, src]
+    start = np.where(take > 0, lo - src[None, :], 0).astype(np.int32)
+    put = lambda a: jax.device_put(np.asarray(a, np.int32),
+                                   row_sharding(skv.mesh))
+    new = (keep + give).astype(np.int32)
+    k, v = _level_jit(skv.mesh, round_cap(int(new.max())),
+                      round_cap(int(over.max())), round_cap(int(give.max())))(
+        skv.key, skv.value, put(keep), put(over), put(start), put(take))
+    return ShardedKV(skv.mesh, k, v, new, key_decode=skv.key_decode,
+                     value_decode=skv.value_decode)
 
 
 def clone_sharded(skv: ShardedKV) -> ShardedKMV:

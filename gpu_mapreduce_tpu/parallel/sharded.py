@@ -20,17 +20,19 @@ vs ``KMVFrame``.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Iterator, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax import lax
+from jax.sharding import Mesh, PartitionSpec
 
 from ..core.column import BytesColumn, DenseColumn
 from ..core.frame import KMVFrame, KVFrame
-from .mesh import mesh_axis_size, row_sharding
+from .mesh import mesh_axis_size, replicated, row_sharding, row_spec
 
 
 import threading
@@ -125,6 +127,28 @@ def narrowest_uint(maxval: int):
         if maxval <= (1 << (8 * width)) - 1:
             return name, width
     return "uint64", 8
+
+
+def fill_counts(have: np.ndarray, n: int) -> np.ndarray:
+    """Split ``n`` new rows over shards that already hold ``have[i]`` rows
+    so that the fullest shard ends as low as it can: the short shards fill
+    first, up to one common level.  Which shard a new row starts on is
+    free (the exchange re-homes it by hash), and the capacity every later
+    program is compiled at is the fullest shard's, rounded up."""
+    have = np.asarray(have, np.int64)
+    lo, hi = int(have.min()), int(have.max()) + n
+    while lo < hi:                  # lowest level that takes all n rows
+        mid = (lo + hi) // 2
+        if int(np.maximum(mid - have, 0).sum()) >= n:
+            hi = mid
+        else:
+            lo = mid + 1
+    give = np.maximum(lo - have, 0)
+    # the level below takes fewer than n, so the surplus is smaller than
+    # the number of shards that were given rows: one row back from each
+    surplus = int(give.sum()) - n
+    give[np.flatnonzero(give)[:surplus]] -= 1
+    return give.astype(np.int32)
 
 
 def _pad_rows(arr: np.ndarray, cap: int) -> np.ndarray:
@@ -374,3 +398,61 @@ def shard_frame_with_counts(frame: KVFrame, mesh: Mesh,
     key = device_put_chunked(np.concatenate(kb), sharding)
     value = device_put_chunked(np.concatenate(vb), sharding)
     return ShardedKV(mesh, key, value, counts.astype(np.int32))
+
+
+def rows_below(n, cap: int, ndim: int):
+    """Inside a program: the mask of a ``[cap, ...]`` block's first ``n``
+    rows, shaped to broadcast over a rank-``ndim`` block."""
+    return jnp.arange(cap).reshape((cap,) + (1,) * (ndim - 1)) < n
+
+
+def window_rows(x, start, count, cap: int):
+    """Inside a program: a ``[cap, ...]`` block holding ``x[start :
+    start + count]`` at its front and zero rows after — one dynamic
+    slice of ``x`` (zero rows appended so the slice never clamps) and a
+    select; no gather, no scatter."""
+    tail = jnp.zeros((cap,) + x.shape[1:], x.dtype)
+    block = lax.dynamic_slice_in_dim(jnp.concatenate([x, tail]), start, cap)
+    return jnp.where(rows_below(count, cap, x.ndim), block,
+                     jnp.zeros((), x.dtype))
+
+
+@functools.lru_cache(maxsize=None)
+def _place_rows_jit(mesh, cap: int):
+    spec = row_spec(mesh)
+
+    def place_rows(key, value, start, count):
+        def body(k, v, s, c):
+            return (window_rows(k, s[0], c[0], cap),
+                    window_rows(v, s[0], c[0], cap))
+        return jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(PartitionSpec(), PartitionSpec(), spec, spec),
+            out_specs=(spec, spec))(key, value, start, count)
+
+    # said, not inferred: on a one-device mesh jax would hand back the
+    # replicated inputs' sharding, and every later program of the dataset
+    # would be another program to the compile cache (PERF.md §6, PR 27)
+    rows = row_sharding(mesh)
+    return jax.jit(place_rows, out_shardings=(rows, rows))
+
+
+def place_rows(mesh: Mesh, key: jax.Array, value: jax.Array,
+               counts: np.ndarray) -> ShardedKV:
+    """:func:`shard_frame_with_counts` for rows that are already on the
+    device: shard i takes the next ``counts[i]`` rows of ``key``/``value``
+    (device arrays, replicated over the mesh), rows past ``sum(counts)``
+    are dropped.  Nothing comes to the host; the call returns when the
+    frame is there (one sync, like every sharded op), so that what made
+    ``key``/``value`` can be freed before the next program is allocated."""
+    counts = np.asarray(counts, np.int32)
+    starts = (np.cumsum(counts) - counts).astype(np.int32)
+    sharding = row_sharding(mesh)
+    rep = replicated(mesh)
+    k, v = jax.block_until_ready(
+        _place_rows_jit(mesh, round_cap(int(counts.max())))(
+            jax.device_put(key, rep), jax.device_put(value, rep),
+            jax.device_put(starts, sharding),
+            jax.device_put(counts, sharding)))
+    SyncStats.bump()
+    return ShardedKV(mesh, k, v, counts)

@@ -808,9 +808,17 @@ def aggregate_kv(backend, mr, hash_fn: Optional[Callable]):
     from ..obs import get_tracer, names
     tr = get_tracer()
     with tr.span(names.AGGREGATE_ONE_FRAME, cat=names.HOST) as sp:
-        frame = kv.one_frame()
-        if tr.enabled:
-            sp.set(**_one_frame_attrs(kv))
+        moved = {}
+        frame = kv.one_frame(moved)
+        if isinstance(frame, ShardedKV) and mesh_axis_size(backend.mesh) > 1:
+            # the exchange below re-homes every row, so the shards over
+            # the even level may hand their excess to the short ones
+            from .devkernels import level_sharded
+            frame = level_sharded(frame)
+        # cap: the per-shard capacity every later program of the round is
+        # compiled at (0: a host frame, sharded below)
+        sp.set(rows=kv.nkv, frames=kv.nframes,
+               cap=getattr(frame, "cap", 0), **moved)
     ktable = vtable = None
     if isinstance(frame, KVFrame):
         with tr.span(names.AGGREGATE_INTERN, cat=names.HOST,
@@ -852,17 +860,6 @@ def aggregate_kv(backend, mr, hash_fn: Optional[Callable]):
     # each keep their own last_exchange
     mr.last_exchange = getattr(out, "exchange_stats", None)
     _replace_kv_frames(kv, out)
-
-
-def _one_frame_attrs(kv) -> dict:
-    """What ``kv.one_frame()`` had to do, for its span: a dataset of
-    plain AND sharded frames compacts through the host
-    (core/dataset.one_frame), so the sharded frames' bytes came back."""
-    frames = kv._frames
-    sharded = [f for f in frames if isinstance(f, ShardedKV)]
-    mixed = 0 < len(sharded) < len(frames)
-    return {"rows": kv.nkv, "frames": len(frames),
-            "to_host_bytes": sum(f.nbytes() for f in sharded) if mixed else 0}
 
 
 def _key_bytes_rows(col) -> list:

@@ -506,10 +506,15 @@ def test_copy_then_aggregate_never_corrupts_sibling(monkeypatch):
     assert n == n2 == 200
 
 
-def test_failed_exchange_after_donation_leaves_clean_state(monkeypatch):
+@pytest.mark.parametrize("nkeys,levelled", [(3000, False), (1 << 12, True)])
+def test_failed_exchange_after_donation_leaves_clean_state(monkeypatch,
+                                                           nkeys, levelled):
     """A phase-2 failure after the donated phase-1 dispatch must leave
     the dataset EMPTY (clean MRError on next op), never frames holding
-    deleted buffers (cryptic RuntimeError deep in XLA)."""
+    deleted buffers (cryptic RuntimeError deep in XLA).  Where aggregate
+    levelled its input first (PR 27: the hash spread of 4096 keys puts a
+    shard over 512 rows), the exchange consumed the levelled copy and
+    the installed frame is intact: the op can simply be made again."""
     from gpu_mapreduce_tpu.core.runtime import MRError
     from gpu_mapreduce_tpu.parallel import shuffle
     monkeypatch.setenv("MRTPU_DONATE", "1")
@@ -518,9 +523,12 @@ def test_failed_exchange_after_donation_leaves_clean_state(monkeypatch):
         raise RuntimeError("phase2 exploded")
 
     mr = MapReduce(make_mesh(8))
-    keys = np.arange(1 << 12, dtype=np.uint64)
+    keys = np.arange(nkeys, dtype=np.uint64)
     mr.map(1, lambda i, kv, p: kv.add_batch(keys, keys))
     mr.aggregate()                      # install the sharded frame
+    from gpu_mapreduce_tpu.parallel.devkernels import level_sharded
+    installed = mr.kv.one_frame()
+    assert (level_sharded(installed) is not installed) == levelled
     # both phase-2 variants: the wire codec (MRTPU_WIRE, default on)
     # dispatches _phase2_wire_jit instead of _phase2_jit
     monkeypatch.setattr(shuffle, "_phase2_jit", boom)
@@ -528,8 +536,12 @@ def test_failed_exchange_after_donation_leaves_clean_state(monkeypatch):
     shuffle._SPEC_CACHE.clear()
     with pytest.raises(RuntimeError, match="phase2 exploded"):
         mr.aggregate()                  # phase 1 donated, phase 2 died
-    with pytest.raises(MRError):
-        mr.convert()                    # clean error, not deleted-array
+    if levelled:
+        assert not installed.key.is_deleted()
+        assert mr.convert() == nkeys    # intact, no deleted array
+    else:
+        with pytest.raises(MRError):
+            mr.convert()                # clean error, not deleted-array
 
 
 def test_failed_fused_group_after_donation_leaves_clean_state(

@@ -33,8 +33,8 @@ def _programs(mesh):
     shapes on the 8-device CPU mesh."""
     from gpu_mapreduce_tpu.apps import invertedindex as app
     from gpu_mapreduce_tpu.models import cc, pagerank, rmat
-    from gpu_mapreduce_tpu.parallel import (devkernels, group, shuffle,
-                                            staging)
+    from gpu_mapreduce_tpu.parallel import (devkernels, group, sharded,
+                                            shuffle, staging)
     u64, i32, u32 = jnp.uint64, jnp.int32, jnp.uint32
     key, val = SDS((64, 2), u64), SDS((64,), u64)
     cnt, cnt2 = SDS((8,), i32), SDS((64,), i32)
@@ -71,8 +71,12 @@ def _programs(mesh):
         (names.STAGE_RANK_GRAPH,
          staging._rank_fn(mesh, 64, False).lower(key, cnt)),
         (names.STAGE_TRIM_VERTS, staging._trim_fn(mesh, 8).lower(col)),
-        (names.CONCAT_ROWS, devkernels._concat_jit(mesh).lower(
+        (names.PLACE_ROWS, sharded._place_rows_jit(mesh, 8).lower(
+            key, val, cnt, cnt)),
+        (names.CONCAT_ROWS, devkernels._concat_jit(mesh, 8).lower(
             key, val, cnt, key, val, cnt)),
+        (names.LEVEL_ROWS, devkernels._level_jit(mesh, 8, 8, 8).lower(
+            key, val, cnt, cnt, SDS((8, 8), i32), SDS((8, 8), i32))),
         (names.REMAP_IDS,
          devkernels._remap_ids_jit(mesh, 8).lower(col, small, small)),
         (names.KV_MAP_PREFIX + "edge_upper", devkernels._skv_map_jit(
@@ -83,6 +87,7 @@ def _programs(mesh):
         (names.RMAT_EDGES, rmat.rmat_edges.lower(
             jax.random.PRNGKey(0), 64, 4,
             np.asarray([0.57, 0.19, 0.19, 0.05]), 0.0, noisy=False)),
+        (names.RMAT_EDGE_ROWS, rmat.rmat_edge_rows.lower(col, col)),
     ]
 
 
@@ -169,6 +174,17 @@ def _graph_script(mesh, out):
     return s.screen.getvalue(), files
 
 
+def _host_batch(mesh):
+    """A user map callback that adds a HOST batch to a mesh MR, then
+    ``aggregate``: the one way left onto ``aggregate.intern`` and
+    ``aggregate.shard`` (rmat's rows stay on the mesh since PR 27)."""
+    from gpu_mapreduce_tpu import MapReduce
+    mr = MapReduce(mesh)
+    mr.map(1, lambda i, kv, p: kv.add_batch(
+        np.arange(64, dtype=np.uint64), np.zeros(64, np.uint8)))
+    return mr.aggregate()
+
+
 def _invindex(mesh, corpus, out):
     from gpu_mapreduce_tpu.apps.invertedindex import InvertedIndex
     idx = InvertedIndex(comm=mesh)
@@ -235,6 +251,7 @@ def _where(tree):
 def test_graph_commands_emit_the_host_and_engine_spans(mesh, traced,
                                                        tmp_path):
     screen, _files = _graph_script(mesh, str(tmp_path))
+    assert _host_batch(mesh) == 64
     tree = _tree(traced.events())
     parents = _where(tree)
     H, E = names.HOST, names.ENGINE
@@ -269,11 +286,16 @@ def test_graph_commands_emit_the_host_and_engine_spans(mesh, traced,
 
     # attrs the metrics and PERF.md quote
     one = [a for n, _c, _p, a in tree if n == names.AGGREGATE_ONE_FRAME]
-    assert all({"rows", "frames", "to_host_bytes"} <= set(a) for a in one)
-    # rmat's second round adds a host batch to a sharded dataset: the
-    # sharded frame comes back through the host, and the span says so
-    assert any(a["frames"] == 2 and a["to_host_bytes"] > 0 for a in one)
-    assert args[names.AGGREGATE_SHARD]["bytes"] > 0
+    assert all({"rows", "frames", "to_host_bytes", "to_device_bytes"}
+               <= set(a) for a in one)
+    # rmat's second round adds its rows to a sharded dataset ON the mesh
+    # (PR 27): two frames, and nothing comes back through the host, in
+    # any aggregate of the script; the generator pulls nothing either
+    assert any(a["frames"] == 2 for a in one)
+    assert all(a["to_host_bytes"] == 0 for a in one)
+    gen = [a for n, _c, _p, a in tree if n == names.RMAT_GENERATE]
+    assert gen and all(a["d2h_bytes"] == 0 for a in gen)
+    assert args[names.AGGREGATE_SHARD]["bytes"] > 0      # the host batch
     assert args[names.CONVERT_COUNT_SYNC]["groups"] > 0
     out = [a for n, _c, _p, a in tree if n == names.OINK_OUTPUT]
     assert all(a["rows"] > 0 and a["bytes"] > 0 and a["path"] for a in out)
@@ -347,6 +369,7 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
     (tmp_path / "g0").mkdir(), (tmp_path / "i0").mkdir()
     off_graph = _graph_script(mesh, str(tmp_path / "g0"))
     off_counts, off_parts, _ = _invindex(mesh, corpus, str(tmp_path / "i0"))
+    off_rows = _host_batch(mesh)
     assert built == []          # every site returned NULL_SPAN
 
     tr.enable(ring=1 << 16)
@@ -355,13 +378,15 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
         on_graph = _graph_script(mesh, str(tmp_path / "g1"))
         on_counts, on_parts, _ = _invindex(mesh, corpus,
                                            str(tmp_path / "i1"))
+        on_rows = _host_batch(mesh)
     finally:
         tr.clear()
         tr.disable()
     # every declared span name is one the program really opens
     assert set(names.SPANS) <= set(built)
     assert on_graph == off_graph
-    assert (on_counts, on_parts) == (off_counts, off_parts)
+    assert (on_counts, on_parts, on_rows) == (off_counts, off_parts,
+                                              off_rows)
 
 
 # -- scopes --------------------------------------------------------------------
