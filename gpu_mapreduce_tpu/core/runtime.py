@@ -326,8 +326,7 @@ def bump_dispatch(n: int = 1) -> None:
     """Count one compiled-program launch (the jitted shuffle/convert/
     reduce/sort programs, fused plan programs AND eager pallas_call
     kernel launches — via ops/pallas.note_kernel_launch — all report
-    here) — the denominator of the plan/ fusion win (bench
-    detail.plan_ab).  Also bumps a per-thread counter so a caller can
+    here) — the denominator of the plan/ fusion win.  Also bumps a per-thread counter so a caller can
     meter ITS OWN dispatches (thread_dispatches) without concurrent
     workers contaminating the delta."""
     _GLOBAL_COUNTERS.add(ndispatch=n)

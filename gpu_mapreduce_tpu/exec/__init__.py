@@ -27,8 +27,9 @@ eager run:
   buffers (``jax.jit(donate_argnums=...)``) so XLA aliases instead of
   re-materialising — ``MRTPU_DONATE`` (default 1); and the per-op
   ``block_until_ready`` timing syncs can be deferred to the natural
-  barriers (``MRTPU_DEFER_SYNC=1``, default 0 because exact per-stage
-  attribution is what the bench headline quotes).
+  barriers (``MRTPU_DEFER_SYNC=1``, default 0 because the stage
+  timers and the benchmark's host spans need exact per-stage
+  attribution).
 
 Every overlap reports: ``exec.prefetch`` / ``exec.spill_write`` obs
 spans, a ``mrtpu_overlap_ratio{path}`` gauge (obs/metrics.py) and the
@@ -92,8 +93,8 @@ def can_donate(frame) -> bool:
 def defer_sync() -> bool:
     """``MRTPU_DEFER_SYNC=1``: skip per-op ``block_until_ready`` timing
     syncs so eager chains only sync at real barriers (count pulls, host
-    reads).  Default off — exact per-stage attribution is what the bench
-    headline quotes; see doc/perf.md."""
+    reads).  Default off — the stage timers and the benchmark's host
+    spans need exact per-stage attribution; see doc/perf.md."""
     return env_knob("MRTPU_DEFER_SYNC", int, 0) != 0
 
 

@@ -10,7 +10,7 @@ callgraph (``callgraph.py``):
   mutation splits (locks.py);
 * ``cache-key`` — knob reads reachable from cached builders must key
   the cache (cachekey.py);
-* ``knob-registry`` — MRTPU_*/SOAK_* knobs route through utils/env.py
+* ``knob-registry`` — MRTPU_* knobs route through utils/env.py
   and match doc/settings.md (knobs.py);
 * ``metric-catalog`` — mrtpu_* metrics match doc/observability.md
   (metrics_doc.py, formerly scripts/check_metrics_doc.py);

@@ -13,7 +13,7 @@ Vocabulary shared by every checker:
 * :class:`Module` — one parsed source file (relpath, dotted name, AST,
   source lines).
 * :class:`Project` — the loaded tree: package modules (analyzed by all
-  checkers) plus ``extra`` modules (harness scripts such as soak.py
+  checkers) plus ``extra`` modules (scripts such as scripts/mrctl.py
   that only opted-in checkers scan).
 * :class:`Finding` — (rule, path, line, message, symbol), with a
   line-independent fingerprint so baselines survive unrelated edits.
@@ -301,7 +301,7 @@ def write_baseline(path: str, findings: List[Finding]) -> None:
 
 def summary(findings: List[Finding]) -> dict:
     """The --json payload: per-rule counts of live and suppressed
-    findings (what ci.sh publishes so counts are trackable across PRs)."""
+    findings (what ci.sh writes to mrlint.json)."""
     by_rule: Dict[str, int] = {}
     nsupp = 0
     for f in findings:

@@ -1,4 +1,4 @@
-"""knob-registry: every ``MRTPU_*``/``SOAK_*`` knob routes through
+"""knob-registry: every ``MRTPU_*`` knob routes through
 ``utils/env.py`` and has a row in ``doc/settings.md``.
 
 ``utils/env.py`` is the one place knob parsing is allowed to live: the
@@ -10,11 +10,10 @@ bypasses that contract; an undocumented knob is invisible to operators;
 a documented-but-removed knob sends them setting a variable nothing
 reads.
 
-Scope: the package plus the harness scripts (soak.py, bench.py,
-weakscale.py — Project ``extra`` modules).  Only the reserved
-``MRTPU_``/``SOAK_`` namespaces are enforced; legacy ``MR_*``/
-``GPUMR_*`` app knobs predate the registry and stay out of it until
-renamed.
+Scope: the package plus the Project's ``extra`` modules (the scripts
+scripts/mrlint.py names).  Only the reserved ``MRTPU_`` namespace is
+enforced; legacy ``MR_*``/``GPUMR_*`` app knobs predate the registry
+and stay out of it until renamed.
 
 Rules:
 
@@ -35,8 +34,8 @@ from typing import Dict, List, Tuple
 from .callgraph import env_reads, is_env_helper_call
 from .driver import Finding, Project, register
 
-_KNOB = re.compile(r"^(MRTPU|SOAK)_[A-Z0-9_]+$")
-_DOC_KNOB = re.compile(r"\b(?:MRTPU|SOAK)_[A-Z0-9_]+\b")
+_KNOB = re.compile(r"^MRTPU_[A-Z0-9_]+$")
+_DOC_KNOB = re.compile(r"\bMRTPU_[A-Z0-9_]+\b")
 
 
 def check(project: Project) -> List[Finding]:
@@ -82,6 +81,6 @@ def check(project: Project) -> List[Finding]:
 
 register(
     "knob-registry", check,
-    "MRTPU_*/SOAK_* knobs must route through utils/env.py and have a "
+    "MRTPU_* knobs must route through utils/env.py and have a "
     "doc/settings.md row (and doc rows must match live knobs)",
     global_findings=("knob-undocumented", "knob-stale"))

@@ -32,8 +32,7 @@ that is what "a top-level programmatic run gets a trace_id" means, and
 it is what ``scripts/trace_view.py --trace`` filters on for
 non-serve runs.  ``MRTPU_PROFILE=0`` disables the fallback (and the
 implicit per-script scopes), returning the pre-context behavior: one
-ContextVar read per counter bump, nothing else — the disarmed cost the
-bench's ``detail.profile_overhead_pct`` row keeps honest.
+ContextVar read per counter bump, nothing else.
 """
 
 from __future__ import annotations
@@ -130,8 +129,7 @@ class RequestAccount:
         self.plan: Dict[str, Dict[str, int]] = {}
         self.fusion: Dict[str, int] = {
             "groups": 0, "fused_groups": 0, "mega_groups": 0,
-            "pallas_groups": 0, "dispatches": 0,
-            "dispatches_saved": 0}
+            "dispatches": 0, "dispatches_saved": 0}
         self.stages: Dict[str, dict] = {}
         # per-sync-site straggler evidence (parallel/dist guarded
         # collectives, fed via obs/fleetobs.SyncObserver): worst spread,
@@ -191,7 +189,7 @@ class RequestAccount:
             c["hits" if hit else "misses"] += 1
 
     def note_fusion(self, fused: bool, mega: bool, dispatches: int,
-                    saved: int, pallas: bool) -> None:
+                    saved: int) -> None:
         """One executed plan group charged to this request: fusion
         effectiveness (plan/cache.note_fusion's per-request twin —
         which classifies the kind/mode strings ONCE and hands the
@@ -202,8 +200,6 @@ class RequestAccount:
                 self.fusion["fused_groups"] += 1
                 if mega:
                     self.fusion["mega_groups"] += 1
-                if pallas:
-                    self.fusion["pallas_groups"] += 1
             self.fusion["dispatches"] += int(dispatches)
             self.fusion["dispatches_saved"] += int(saved)
 
@@ -509,14 +505,14 @@ def note_plan(cache: str, hit: bool) -> None:
         acct.note_plan(cache, hit)
 
 
-def note_fusion(fused: bool, mega: bool, dispatches: int, saved: int,
-                pallas: bool) -> None:
+def note_fusion(fused: bool, mega: bool, dispatches: int,
+                saved: int) -> None:
     """Feed point for plan/cache.note_fusion — per-request fusion
     effectiveness (``profile()["fusion"]``, the serve per-request
     profile's "did this job's pipelines megafuse" section)."""
     acct = active_account()
     if acct is not None:
-        acct.note_fusion(fused, mega, dispatches, saved, pallas)
+        acct.note_fusion(fused, mega, dispatches, saved)
 
 
 def note_span(name: str, cat: str, dur_s: float, attrs: dict) -> None:

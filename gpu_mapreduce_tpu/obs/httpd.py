@@ -3,7 +3,7 @@
 ``curl localhost:$MRTPU_METRICS_PORT/metrics`` during a run returns the
 Prometheus exposition text (op latency histograms, exchange byte
 counters, plan-cache hit ratio, HBM hi-water, ...) — the "watch a
-running soak" exposure the printf reports and post-hoc traces lack.
+running job" exposure the printf reports and post-hoc traces lack.
 
 Built-in routes:
 

@@ -1,8 +1,8 @@
 """Per-op aggregation + table formatting over span events.
 
-The one summarizer every consumer shares: ``mr.stats()["ops"]``,
-``scripts/trace_view.py``, bench's detail record and soak's end-of-run
-table all call :func:`aggregate_ops` / :func:`per_op_table`.
+The one summarizer every consumer shares: ``mr.stats()["ops"]``, the
+tracer's ``summary()`` and ``scripts/trace_view.py`` all call
+:func:`aggregate_ops` / :func:`per_op_table`.
 """
 
 from __future__ import annotations

@@ -369,7 +369,7 @@ _LOCK = threading.Lock()
 
 def configure(objectives: List[SLOObjective]) -> SLOEngine:
     """Programmatic twin of ``MRTPU_SLO`` (replaces the active engine;
-    soak.py's serve workload uses this for its short windows)."""
+    for callers that need windows shorter than an env spec is worth)."""
     import os
     global _ENGINE, _ENV_APPLIED
     with _LOCK:

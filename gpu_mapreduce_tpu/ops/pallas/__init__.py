@@ -1,17 +1,15 @@
 """Purpose-built Pallas kernels (TPU twins of the CUDA hot paths).
 
 Modules: :mod:`.match` (substring mark / compaction — the InvertedIndex
-GPU kernels), :mod:`.group` (paged segment-group + fused segment-reduce
-— the grouping hot path the plan/ megafused programs compose instead of
-a full ``lax.sort``).
+GPU kernels).
 
 Kernel-launch accounting: every *eager* ``pallas_call`` invocation is a
 compiled-program launch exactly like a jit dispatch, so it must land in
 ``Counters.ndispatch`` — otherwise "N dispatches per pipeline" could be
 faked by moving work into uncounted kernels (doc/perf.md).  Call sites
 route through :func:`note_kernel_launch`; launches traced *inside* an
-enclosing jit program ride that program's dispatch count (the whole
-point of megafusion) and are skipped via the tracer check.
+enclosing jit program ride that program's dispatch count and are
+skipped via the tracer check.
 """
 
 from __future__ import annotations

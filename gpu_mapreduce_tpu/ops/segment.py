@@ -139,40 +139,3 @@ def segment_reduce(values, segment_ids, num_segments: int, op: str = "sum"):
 def kmv_segment_ids(kmv: KMVFrame):
     """[n] segment ids for a KMVFrame's flat value column."""
     return np.repeat(np.arange(len(kmv), dtype=np.int64), kmv.nvalues)
-
-
-# ---------------------------------------------------------------------------
-# table epilogue for the Pallas group kernels (ops/pallas/group.py)
-# ---------------------------------------------------------------------------
-
-def table_to_groups(table, T: int, gcap: int, reduce_op: str,
-                    key_dtype, value_dtype):
-    """Accumulation-table slots → the grouped output layout (jittable).
-
-    ``table`` is ``(tkh, tkl, occ, cnt[, shi, slo])`` from
-    ``ops/pallas/group.segment_table`` (slots [0, T) live, slot T
-    invalid-row trash, slot T+1 the probe-overflow counter).  Orders
-    the slots — occupied first, ascending reconstructed key — and
-    emits ``(ukey, uval, g, overflow)`` sized [gcap], byte-identical
-    to the sort path's grouped layout: ascending unique keys with the
-    eager zero fill, counts as int64, sums at the value dtype's width
-    (the limb accumulate wraps mod 2^64, truncation wraps mod
-    2^width — exactly what the sorted ``segment_sum`` does)."""
-    from .pallas.group import join_limbs
-    from .sort import argsort_slots
-    tkh, tkl, occ, cnt = table[:4]
-    occb = occ[:T] == 1
-    key = join_limbs(tkh[:T], tkl[:T], key_dtype)
-    order = argsort_slots(key, occb)[:gcap]
-    ok = jnp.take(occb, order)
-    ukey = jnp.where(ok, jnp.take(key, order),
-                     jnp.zeros((), jnp.dtype(key_dtype)))
-    if reduce_op == "count":
-        uval = jnp.where(ok, jnp.take(cnt[:T], order), 0) \
-            .astype(jnp.int64)
-    else:
-        sval = join_limbs(table[4][:T], table[5][:T], value_dtype)
-        uval = jnp.where(ok, jnp.take(sval, order),
-                         jnp.zeros((), jnp.dtype(value_dtype)))
-    g = jnp.sum(occb.astype(jnp.int32))
-    return ukey, uval, g, cnt[T + 1]

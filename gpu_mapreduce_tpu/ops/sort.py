@@ -67,16 +67,6 @@ def argsort_column(col: Column, descending: bool = False,
     return idx
 
 
-def argsort_slots(sortval, occupied):
-    """Jittable slot ordering for the Pallas group tables
-    (``ops/pallas/group.py``): occupied slots first, ascending by the
-    reconstructed key value — the only sort the kernel-backed group
-    path ever runs, over O(groups) table slots instead of O(rows)
-    received rows (the whole point of replacing the lexsort hot path).
-    lexsort's LAST key is primary: the emptiness flag, then the key."""
-    return jnp.lexsort((sortval, ~occupied))
-
-
 def sorted_dense(data, descending: bool = False):
     """Direct value sort of a dense [n] or [n,w] array (device-friendly)."""
     if data.ndim == 1:

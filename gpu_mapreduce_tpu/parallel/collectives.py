@@ -49,7 +49,7 @@ def gather_kv(backend, mr, nprocs: int):
         free_if_donated(mr.kv, skv)
         raise
     # per-call stats like aggregate's: gather/scrunch exchanges were
-    # invisible to mr.last_exchange (the bench --wire A/B reads it)
+    # invisible to mr.last_exchange (the wire-codec tests read it)
     mr.last_exchange = getattr(out, "exchange_stats", None)
     _replace_kv_frames(mr.kv, out)
 

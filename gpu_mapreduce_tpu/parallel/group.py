@@ -164,32 +164,18 @@ def convert_sharded(skv: ShardedKV, counters=None) -> ShardedKMV:
                       value_decode=skv.value_decode)
 
 
-def fused_group_body(k, v, nrecv, gcap: int, out_kind: str, reduce_op,
-                     pallas_cfg=None):
+def fused_group_body(k, v, nrecv, gcap: int, out_kind: str, reduce_op):
     """THE fused convert(+reduce) shard-local body — composed by the
     plan/ fuser's exchange/local/megafused programs over packed valid
-    rows.  Two interchangeable engines, byte-identical by construction:
+    rows: sort by key, boundary-detect, then either the grouped layout
+    (``out_kind='kmv'``) or a segment reduce to one pair per group
+    (``out_kind='kv'``) — the SAME shard-local bodies the eager tier
+    jits (`_local_sort`/`_boundary`/`grouped_layout`/
+    `segment_reduce_rows`).
 
-    * sort path (default): sort by key, boundary-detect, then either
-      the grouped layout (``out_kind='kmv'``) or a segment reduce to
-      one pair per group (``out_kind='kv'``) — the SAME shard-local
-      bodies the eager tier jits (`_local_sort`/`_boundary`/
-      `grouped_layout`/`segment_reduce_rows`).
-    * table path (``pallas_cfg`` set, kv + count/sum only — the fuser
-      gates support via ``ops/pallas/group.group_supported``): the
-      paged Pallas bucketed-scatter kernel accumulates per-key
-      count/sum with NO row sort, then orders only the table slots.
-
-    Returns ``(..., meta)`` where meta = [groups, nrecv, overflow]:
-    ``overflow`` is the table path's probe-exhaustion count (always 0
-    on the sort path) the megafused executor validates host-side."""
-    if pallas_cfg is not None and out_kind == "kv" \
-            and reduce_op in ("count", "sum"):
-        from ..ops.pallas.group import segment_group_reduce
-        ukey, uval, g, overflow = segment_group_reduce(
-            k, v, nrecv, gcap, reduce_op, pallas_cfg)
-        meta = jnp.stack([g, nrecv.astype(jnp.int32), overflow])
-        return ukey, uval, meta
+    Returns ``(..., meta)`` where meta = [groups, nrecv, overflow];
+    ``overflow`` is always 0 (a sort drops no group) and keeps the
+    position the fused executors' host-side validation reads."""
     sk, sv, valid = _local_sort(k, v, nrecv)
     mask = _boundary(sk, valid)
     ukey, sizes, voff, seg, g = grouped_layout(sk, mask, nrecv, gcap)

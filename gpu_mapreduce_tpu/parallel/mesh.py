@@ -83,16 +83,6 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec())
 
 
-def shard_map_kernels(body, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` for bodies that embed a ``pallas_call`` (the
-    plan/ megafused group programs): jax has no replication rule for
-    the pallas primitive, so the rep/vma check must be disabled — the
-    fused bodies are plain per-shard SPMD with explicit specs, which
-    is exactly the case the check waives."""
-    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-
-
 def h2d_chunk_bytes(default: int = 32 << 20) -> int:
     """The per-message H2D budget, with the MR_H2D_CHUNK_WORDS override
     (u32 words, ×4 bytes) — ONE parse shared by every chunked-transfer

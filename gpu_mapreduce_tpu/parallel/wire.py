@@ -56,7 +56,7 @@ _MAX_TIERS = 16            # same bound as shuffle._MAX_ROUNDS
 
 def wire_enabled() -> bool:
     """``MRTPU_WIRE`` (default on; ``0`` = raw exchange).  Read at call
-    time like the exec/ knobs so tests and the bench A/B flip it per
+    time like the exec/ knobs so tests flip it per
     run without re-importing."""
     from ..utils.env import env_flag
     return env_flag("MRTPU_WIRE", True)

@@ -158,12 +158,12 @@ def cache_stats() -> dict:
 
 _FUSION_LOCK = threading.Lock()
 _FUSION = {"groups": 0, "fused_groups": 0, "eager_groups": 0,
-           "mega_groups": 0, "pallas_groups": 0, "dispatches": 0,
+           "mega_groups": 0, "dispatches": 0,
            "eager_dispatch_estimate": 0, "dispatches_saved": 0}
 
 
-def note_fusion(kind: str, mode: str, dispatches: int, eager_est: int,
-                pallas: bool = False) -> None:
+def note_fusion(kind: str, mode: str, dispatches: int,
+                eager_est: int) -> None:
     """One executed plan group: its fusion kind ("exchange"/"local"/
     "eager"), execution mode ("mega"/"local1" = single-dispatch warm,
     "v1"/"local" = cold or fallback, "eager" = replay), the compiled-
@@ -182,14 +182,12 @@ def note_fusion(kind: str, mode: str, dispatches: int, eager_est: int,
             _FUSION["fused_groups"] += 1
             if mega:
                 _FUSION["mega_groups"] += 1
-            if pallas:
-                _FUSION["pallas_groups"] += 1
         _FUSION["dispatches"] += int(dispatches)
         _FUSION["eager_dispatch_estimate"] += int(eager_est)
         _FUSION["dispatches_saved"] += saved
     try:
         from ..obs.context import note_fusion as _ctx_note
-        _ctx_note(fused, mega, int(dispatches), saved, pallas)
+        _ctx_note(fused, mega, int(dispatches), saved)
     except Exception:
         pass
 
@@ -200,7 +198,7 @@ def fusion_stats() -> dict:
 
 
 def reset_fusion_stats() -> None:
-    """Test/bench isolation: zero the cumulative fusion counters."""
+    """Test isolation: zero the cumulative fusion counters."""
     with _FUSION_LOCK:
         for k in _FUSION:
             _FUSION[k] = 0
@@ -214,8 +212,7 @@ def stats_delta(before: dict, after: Optional[dict] = None) -> dict:
     a single request's "did this recompile?" question is only
     answerable as a delta: the serve/ session runner stamps one into
     every result (``misses == 0`` on a warm identical request is the
-    no-recompile assertion bench's ``detail.serve_ab`` and the
-    acceptance test make)."""
+    no-recompile assertion the acceptance test makes)."""
     after = cache_stats() if after is None else after
     out = {}
     for cname, a in after.items():

@@ -23,16 +23,11 @@ OFF the dispatch path: ONE jit/``shard_map`` program composes phase-1
 dest-sort + wire-encode + exchange + wire-decode + group/segment-reduce
 and *additionally emits* the count/stats/meta matrices, which the host
 pulls AFTER the single dispatch as a speculation check (``plan_holds``
-+ group-capacity coverage + kernel-overflow count).  A failed check
++ group-capacity coverage).  A failed check
 discards the result and re-runs the two-dispatch v1 path on the same
 inputs (megafused programs never donate, precisely so this replay and
 the chaos retry stay possible).  Steady state: **1 dispatch per plan
-group** (``Counters.ndispatch``, the bench ``detail.plan_ab`` target).
-Inside the megafused program, supported group chains (kv out, count/sum
-reduce, ≤8-byte integer columns) replace the per-shard ``lexsort``
-grouping with the paged Pallas table kernels of ``ops/pallas/group.py``
-(``MRTPU_PALLAS_GROUP``); unsupported chains warn once and keep the
-sort path — still fused, still byte-identical.
+group** (``Counters.ndispatch``).
 
 Everything else — host-callback tiers, serial backend, spill/out-of-core
 datasets, over-HBM-budget datasets, comparator sorts — **breaks fusion**:
@@ -71,7 +66,7 @@ FUSED_CACHE = LRUCache(env_knob("MRTPU_JIT_CACHE", int, 64),
 def megafuse_enabled() -> bool:
     """``MRTPU_MEGAFUSE`` (default on): single-dispatch warm groups —
     fusion v2.  ``0`` restores the v1 two-dispatch fuser everywhere
-    (the auto-fallback target; the A/B knob of bench ``--fuse ab``)."""
+    (the auto-fallback target)."""
     return env_flag("MRTPU_MEGAFUSE", True)
 
 
@@ -206,28 +201,8 @@ def _reduce_value_ok(frame, rop: str) -> bool:
 # fused program bodies (composable, shard-local)
 # ---------------------------------------------------------------------------
 # The convert(+reduce) shard body itself lives with its eager siblings
-# in ``parallel/group.fused_group_body`` (sort path + the Pallas table
-# path); the builders here only choose its static knobs and compose it
-# with the exchange bodies.
-
-
-def _pallas_cfg_for(mr, skv, cap: int, out_kind: str, reduce_op,
-                    gcap: int):
-    """The hashable kernel config threaded into the builder cache keys,
-    or None → sort path.  None when the knob is off or the chain is
-    unsupported (``ops/pallas/group.group_supported`` — warn once)."""
-    from ..ops.pallas import group as pgroup
-    if not pgroup.pallas_group_enabled():
-        return None
-    ok, reason = pgroup.group_supported(skv.key, skv.value, out_kind,
-                                        reduce_op)
-    if not ok:
-        pgroup.warn_fallback(reason)
-        return None
-    import jax
-    return ("tbl", pgroup.table_slots(gcap),
-            pgroup.page_rows_for(cap, mr.settings.memsize),
-            jax.default_backend() != "tpu")
+# in ``parallel/group.fused_group_body``; the builders here only choose
+# its static knobs and compose it with the exchange bodies.
 
 
 def _gcap_for(gcounts, cap_out: int) -> int:
@@ -314,26 +289,25 @@ def _fused_exchange_build(mesh, transport, plan, out_kind,
 
 
 def _mega_jit(mesh, transport: int, dest, plan, gcap: int,
-              out_kind: str, reduce_op, elig, pallas_cfg):
+              out_kind: str, reduce_op, elig):
     """The fusion-v2 single-dispatch program: phase-1 dest-sort (+wire
     stats) + exchange (+wire encode/decode) + group/segment-reduce in
     ONE jit/shard_map, with the count/stats/meta matrices as extra
     outputs the host pulls AFTER dispatch (the speculation check).
-    Every static knob — the exchange plan, the group capacity, the
-    kernel config — keys the executable cache."""
+    Every static knob — the exchange plan, the group capacity — keys
+    the executable cache."""
     key = ("mega", mesh, transport, dest, plan, gcap, out_kind,
-           reduce_op, elig, pallas_cfg)
+           reduce_op, elig)
     return FUSED_CACHE.get_or_build(
         key, lambda: _mega_build(mesh, transport, dest, plan, gcap,
-                                 out_kind, reduce_op, elig, pallas_cfg))
+                                 out_kind, reduce_op, elig))
 
 
 def _mega_build(mesh, transport, dest, plan, gcap, out_kind, reduce_op,
-                elig, pallas_cfg):
+                elig):
     import jax
     from ..parallel.group import fused_group_body
-    from ..parallel.mesh import (mesh_axis_size, row_spec,
-                                 shard_map_kernels)
+    from ..parallel.mesh import mesh_axis_size, row_spec
     from ..parallel.shuffle import (_dest_fn, phase1_shard_body,
                                     phase2_shard_body)
     from ..parallel.wire import phase2_wire_shard_body, plan_cap_out
@@ -356,17 +330,12 @@ def _mega_build(mesh, transport, dest, plan, gcap, out_kind, reduce_op,
             out_k, out_v, nrecv = phase2_shard_body(
                 nprocs, transport, mesh, B, nrounds, cap_out, sk, sv, cl)
         gouts = fused_group_body(out_k, out_v, nrecv, gcap, out_kind,
-                                 reduce_op, pallas_cfg)
+                                 reduce_op)
         return (*gouts, cl) if st is None else (*gouts, cl, st)
 
     def run(key, value, count):
-        if pallas_cfg is not None:
-            sm = shard_map_kernels(body, mesh, (spec,) * 3,
-                                   (spec,) * nouts)
-        else:
-            sm = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
-                               out_specs=(spec,) * nouts)
-        return sm(key, value, count)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+                             out_specs=(spec,) * nouts)(key, value, count)
 
     # NEVER donated: a failed speculation check (or a chaos retry)
     # re-runs on the same inputs, which donation would have deleted
@@ -409,22 +378,20 @@ def _maybe_compact(mesh, gcap: int, gcounts, *arrs):
 
 
 def _fused_local_jit(mesh, out_kind: str, reduce_op: Optional[str],
-                     gcap: Optional[int] = None, pallas_cfg=None,
-                     donate_argnums=()):
-    key = ("local", mesh, out_kind, reduce_op, gcap, pallas_cfg,
+                     gcap: Optional[int] = None, donate_argnums=()):
+    key = ("local", mesh, out_kind, reduce_op, gcap,
            tuple(donate_argnums))
     return FUSED_CACHE.get_or_build(
         key, lambda: _fused_local_build(mesh, out_kind, reduce_op,
-                                        gcap, pallas_cfg,
-                                        donate_argnums))
+                                        gcap, donate_argnums))
 
 
 def _fused_local_build(mesh, out_kind, reduce_op, gcap=None,
-                       pallas_cfg=None, donate_argnums=()):
+                       donate_argnums=()):
     import jax
     from ..exec import donated_jit
     from ..parallel.group import fused_group_body
-    from ..parallel.mesh import row_spec, shard_map_kernels
+    from ..parallel.mesh import row_spec
     spec = row_spec(mesh)
     nouts = 5 if out_kind == "kmv" else 3
 
@@ -434,15 +401,10 @@ def _fused_local_build(mesh, out_kind, reduce_op, gcap=None,
             # compiles at the cached compact capacity (fusion v2)
             return fused_group_body(k, v, c[0],
                                     k.shape[0] if gcap is None else gcap,
-                                    out_kind, reduce_op, pallas_cfg)
-        if pallas_cfg is not None:
-            sm = shard_map_kernels(body, mesh, (spec, spec, spec),
-                                   (spec,) * nouts)
-        else:
-            sm = jax.shard_map(
-                body, mesh=mesh, in_specs=(spec, spec, spec),
-                out_specs=(spec,) * nouts)
-        return sm(key, value, counts)
+                                    out_kind, reduce_op)
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(spec, spec, spec),
+            out_specs=(spec,) * nouts)(key, value, counts)
 
     # exec/: the consumed KV is replaced by the grouped output right
     # after (_install_kv) — donating lets the group layout reuse its
@@ -493,7 +455,7 @@ def _install_kmv(mr, skmv):
 
 
 def _exec_exchange_group(mr, stages, reduce_op, compiled: CompiledPlan,
-                         gidx: int, sp, frame) -> tuple:
+                         gidx: int, sp, frame) -> str:
     """Run [aggregate, convert(, reduce)] as a fused exchange group.
     Warm + ``MRTPU_MEGAFUSE``: ONE megafused program (see module doc);
     cold or speculation-failed: phase 1 + ONE fused program (v1).
@@ -506,8 +468,8 @@ def _exec_exchange_group(mr, stages, reduce_op, compiled: CompiledPlan,
     like the eager exchange: the fault point sits before any dispatch,
     and a failure after the v1 path's donated phase-1 dispatch is
     vetoed as non-retryable (the megafused program never donates, so
-    its retries are always safe).  Returns ``(mode, pallas)`` for the
-    fusion telemetry."""
+    its retries are always safe).  Returns the mode for the fusion
+    telemetry."""
     from ..ft.inject import fault_point
     from ..ft.retry import retry_call
     from ..parallel.mesh import mesh_axis_size
@@ -532,7 +494,7 @@ def _exec_exchange_group(mr, stages, reduce_op, compiled: CompiledPlan,
 
 
 def _exchange_group_impl(mr, stages, reduce_op, compiled, gidx, sp,
-                         skv) -> tuple:
+                         skv) -> str:
     import jax
     from ..core.runtime import Timer, bump_dispatch
     from ..parallel import wire as _wire
@@ -557,11 +519,10 @@ def _exchange_group_impl(mr, stages, reduce_op, compiled, gidx, sp,
 
     entry = compiled.mega.get(gidx) if megafuse_enabled() else None
     if entry is not None and entry[0] == "x":
-        pallas = _exec_mega_exchange(mr, stages, reduce_op, compiled,
-                                     gidx, sp, skv, dest, out_kind,
-                                     entry, wire_on, elig, counts_dev, t)
-        if pallas is not None:
-            return "mega", pallas
+        if _exec_mega_exchange(mr, stages, reduce_op, compiled, gidx,
+                               sp, skv, dest, out_kind, entry, wire_on,
+                               elig, counts_dev, t):
+            return "mega"
         # speculation failed — discard and fall through to v1 on the
         # SAME (never-donated) inputs; the commtime Timer keeps running
         # so the failed attempt's wall is charged honestly
@@ -616,15 +577,15 @@ def _exchange_group_impl(mr, stages, reduce_op, compiled, gidx, sp,
     # measured: the plan that ran and the compact group capacity
     if megafuse_enabled():
         compiled.mega[gidx] = ("x", plan, _gcap_for(gcounts, cap_out))
-    return "v1", False
+    return "v1"
 
 
 def _exec_mega_exchange(mr, stages, reduce_op, compiled, gidx, sp, skv,
                         dest, out_kind, entry, wire_on, elig,
                         counts_dev, t):
-    """One megafused attempt.  Returns the pallas flag on success, or
-    None when the post-dispatch speculation check failed (the caller
-    re-runs v1 on the same inputs — nothing was donated)."""
+    """One megafused attempt.  Returns True on success, False when the
+    post-dispatch speculation check failed (the caller re-runs v1 on
+    the same inputs — nothing was donated)."""
     from ..core.runtime import bump_dispatch
     from ..parallel import wire as _wire
     from ..parallel.mesh import mesh_axis_size
@@ -634,11 +595,9 @@ def _exec_mega_exchange(mr, stages, reduce_op, compiled, gidx, sp, skv,
     nprocs = mesh_axis_size(mesh)
     transport = mr.settings.all2all
     _tag, plan, gcap = entry
-    pallas_cfg = _pallas_cfg_for(mr, skv, _wire.plan_cap_out(plan),
-                                 out_kind, reduce_op, gcap)
     bump_dispatch()   # THE one dispatch of the warm group
     prog = _mega_jit(mesh, transport, dest, plan, gcap, out_kind,
-                     reduce_op, elig, pallas_cfg)
+                     reduce_op, elig)
     out = prog(skv.key, skv.value, counts_dev)
     SyncStats.bump()   # still ONE host round-trip — now after dispatch
     ngout = 5 if out_kind == "kmv" else 3
@@ -651,7 +610,7 @@ def _exec_mega_exchange(mr, stages, reduce_op, compiled, gidx, sp, skv,
     vcounts = meta[:, 1].astype(np.int32)
     overflow = int(meta[:, 2].sum())
     # the speculation check: would the compiled shapes have dropped any
-    # row (exchange plan) or group (gcap / kernel table overflow)?
+    # row (exchange plan) or group (gcap)?
     fresh, kvrange, bmax_raw, nmax_out, _nc = _wire.plan_from_pull(
         skv.key, skv.value, counts_mat, stats_mat, wire_on, elig)
     max_g = int(gcounts.max()) if gcounts.size else 0
@@ -659,7 +618,7 @@ def _exec_mega_exchange(mr, stages, reduce_op, compiled, gidx, sp, skv,
             or not _wire.plan_holds(plan, bmax_raw, nmax_out, kvrange)):
         compiled.mega.pop(gidx, None)
         sp.set(mega_miss=True)
-        return None
+        return False
     # right-size a grossly oversized or tag-shifted entry for NEXT time
     # (this run's result is exact and kept)
     if (plan[0] != fresh[0]
@@ -670,14 +629,14 @@ def _exec_mega_exchange(mr, stages, reduce_op, compiled, gidx, sp, skv,
     _finish_exchange_group(mr, stages, sp, skv, out_kind, reduce_op,
                            mesh, nprocs, plan, counts_mat, gcounts,
                            vcounts, gouts, t, compact_from=None,
-                           mega=True, pallas=pallas_cfg is not None)
-    return pallas_cfg is not None
+                           mega=True)
+    return True
 
 
 def _finish_exchange_group(mr, stages, sp, skv, out_kind, reduce_op,
                            mesh, nprocs, plan, counts_mat, gcounts,
                            vcounts, out, t, compact_from=None,
-                           mega=False, pallas=False):
+                           mega=False):
     """Shared tail of the v1 and megafused exchange groups: byte/stat
     accounting, span attrs, stage results and dataset installation —
     ONE copy so the two tiers' telemetry can never diverge."""
@@ -698,7 +657,7 @@ def _finish_exchange_group(mr, stages, sp, skv, out_kind, reduce_op,
     mr.last_exchange = stats
     sp.set(bucket=B_eff, nrounds=nrounds_eff, cap_out=cap_out,
            rows=nrows, groups=ngroups, wire_bytes=stats.wire_bytes,
-           wire_ratio=stats.wire_ratio, mega=mega, pallas=pallas)
+           wire_ratio=stats.wire_ratio, mega=mega)
     stages[0].result = nrows
     stages[1].result = ngroups
     if out_kind == "kv":
@@ -744,13 +703,13 @@ def _account_exchange(mr, skv, counts_mat, plan, nprocs, stats):
 
 
 def _exec_local_group(mr, stages, reduce_op, compiled: CompiledPlan,
-                      gidx: int, sp, frame) -> tuple:
+                      gidx: int, sp, frame) -> str:
     """Run [convert, reduce(kernel)] on a ShardedKV as ONE program.
     Fusion v2: a warm group compiles at the cached compact group
-    capacity (skipping the separate compact dispatch) and may take the
-    Pallas table path; the post-dispatch meta pull validates the
-    capacity and re-runs at full capacity when it no longer covers.
-    Returns ``(mode, pallas)`` for the fusion telemetry."""
+    capacity (skipping the separate compact dispatch); the
+    post-dispatch meta pull validates the capacity and re-runs at full
+    capacity when it no longer covers.  Returns the mode for the fusion
+    telemetry."""
     import jax
     from ..core.runtime import bump_dispatch
     from ..parallel.mesh import mesh_axis_size, row_sharding
@@ -766,10 +725,6 @@ def _exec_local_group(mr, stages, reduce_op, compiled: CompiledPlan,
                                 row_sharding(mesh))
     entry = compiled.mega.get(gidx) if megafuse_enabled() else None
     gcap = entry[1] if entry is not None and entry[0] == "l" else None
-    pallas_cfg = None
-    if gcap is not None:
-        pallas_cfg = _pallas_cfg_for(mr, skv, cap, "kv", reduce_op,
-                                     gcap)
     mode = "local1" if gcap is not None else "local"
     bump_dispatch()
     # donation only when the group outputs alias the inputs byte for
@@ -779,7 +734,6 @@ def _exec_local_group(mr, stages, reduce_op, compiled: CompiledPlan,
                               reduce_op, skv.value)
     ukey, uval, meta = _fused_local_jit(mesh, "kv", reduce_op,
                                         gcap=gcap,
-                                        pallas_cfg=pallas_cfg,
                                         donate_argnums=argnums)(
         skv.key, skv.value, counts_dev)
     SyncStats.bump()
@@ -800,7 +754,6 @@ def _exec_local_group(mr, stages, reduce_op, compiled: CompiledPlan,
         gcounts = m[:, 0].astype(np.int32)
         gcap = None
         mode = "local"
-        pallas_cfg = None
     ngroups = int(gcounts.sum())
     if gcap is None:
         ukey, uval = _maybe_compact(mesh, cap, gcounts, ukey, uval)
@@ -811,11 +764,10 @@ def _exec_local_group(mr, stages, reduce_op, compiled: CompiledPlan,
     if reduce_op == "first":
         skv_out.value_decode = skv.value_decode
     _install_kv(mr, skv_out)
-    sp.set(groups=ngroups, mega=gcap is not None,
-           pallas=pallas_cfg is not None)
+    sp.set(groups=ngroups, mega=gcap is not None)
     stages[0].result = ngroups
     stages[1].result = ngroups
-    return mode, pallas_cfg is not None
+    return mode
 
 
 def _replay(mr, stage: PlanStage):
@@ -893,7 +845,7 @@ def execute_plan(mr, plan: Plan) -> None:
             # per-THREAD meter: concurrent serve workers' dispatches
             # must not contaminate this group's count (review fix)
             d0 = thread_dispatches()
-            mode, pallas = "eager", False
+            mode = "eager"
             if kind is None:
                 _replay(mr, run[0])
             else:
@@ -902,10 +854,10 @@ def execute_plan(mr, plan: Plan) -> None:
                                  reduce_op=rop or "") as sp:
                     try:
                         if kind == "exchange":
-                            mode, pallas = _exec_exchange_group(
+                            mode = _exec_exchange_group(
                                 mr, run, rop, compiled, gidx, sp, frame)
                         else:
-                            mode, pallas = _exec_local_group(
+                            mode = _exec_local_group(
                                 mr, run, rop, compiled, gidx, sp, frame)
                     except BaseException:
                         # same contract as the eager exchange callers:
@@ -922,8 +874,7 @@ def execute_plan(mr, plan: Plan) -> None:
             # of this group vs the eager tier's known per-op counts
             note_fusion(
                 kind or "eager", mode, thread_dispatches() - d0,
-                sum(_EAGER_DISPATCHES.get(s.op, 1) for s in run),
-                pallas=pallas)
+                sum(_EAGER_DISPATCHES.get(s.op, 1) for s in run))
             desc["mode"] = mode
             i += n
             gidx += 1

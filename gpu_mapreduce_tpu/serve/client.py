@@ -1,7 +1,6 @@
 """Thin HTTP client for the serve/ daemon (stdlib urllib only).
 
-Used by ``scripts/mrctl.py``, ``bench.py --serve``, the soak serve
-workload, and the tests — one implementation of the wire protocol so
+Used by ``scripts/mrctl.py`` and the tests — one implementation of the wire protocol so
 "what does a 429 look like" has a single answer.
 """
 
@@ -44,7 +43,7 @@ class ServeClient:
         # tenant bearer token (MRTPU_SERVE_TOKENS on the daemon side):
         # rides every request, including the /events stream and the
         # healthz probe; defaults from MRTPU_SERVE_TOKEN so mrctl and
-        # the soak/bench harnesses inherit it — doc/serve.md#tenant-auth
+        # embedding programs inherit it — doc/serve.md#tenant-auth
         if token is None:
             from ..utils.env import env_str
             token = env_str("MRTPU_SERVE_TOKEN", "") or None

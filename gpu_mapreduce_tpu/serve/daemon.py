@@ -119,7 +119,7 @@ def _collect_serve(reg) -> None:
 class Server:
     """The daemon object.  ``start()`` recovers the state directory,
     mounts the HTTP routes, and spins up the worker pool; it is safe to
-    embed in-process (tests, bench.py --serve) or drive via
+    embed in-process (tests) or drive via
     ``python -m gpu_mapreduce_tpu.serve``."""
 
     def __init__(self, port: Optional[int] = None,

@@ -2,7 +2,7 @@
 
 Observability knobs share one rule (doc/settings.md): a malformed value
 must degrade with a stderr warning, never crash the run it was meant to
-observe.  Every ``MRTPU_*``/``SOAK_*`` knob reads through one of the
+observe.  Every ``MRTPU_*`` knob reads through one of the
 three helpers here so the warn-and-fall-back behavior cannot drift
 between sites — ``env_knob`` for numerics, ``env_str`` for
 paths/specs, ``env_flag`` for booleans.  mrlint's ``knob-registry``

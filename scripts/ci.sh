@@ -2,14 +2,10 @@
 # CI gate: the ROADMAP tier-1 suite plus fast subsets (fused-plan
 # equivalence, metrics/flight-recorder, exec overlap/donation golden
 # equivalence, ft chaos-golden/resume, serve API/admission) so a
-# regression there fails loudly even when only the quick gate runs,
-# and an ADVISORY bench regression check (scripts/bench_compare.py)
-# that prints its verdict table into the CI log but never fails the
-# build.
+# regression there fails loudly even when only the quick gate runs.
 #
 #   scripts/ci.sh          # tier-1 + plan/metrics/exec/ft subsets
 #                          # + full serve subset (kill-9 queue replay)
-#                          # + advisory
 #   scripts/ci.sh quick    # plan/metrics/exec/ft/serve fast subsets (~1 min)
 #   scripts/ci.sh lint     # mrlint only (all 5 rules, whole package)
 #   scripts/ci.sh fleet    # serve-fleet subset only (lease/ring units
@@ -70,8 +66,7 @@ run_context_subset() {
 # check_metrics_doc call is folded in — metric-catalog is rule 5).
 # quick: report only files changed vs HEAD/HEAD~1 (analysis still sees
 # the whole package, so cross-module rules stay sound); full: whole
-# package, JSON + finding counts published into BASELINE.json so
-# they're trackable across PRs alongside the bench/soak records.
+# package, findings and counts as JSON in mrlint.json.
 run_lint_quick() {
   echo "== mrlint (changed-module scope) =="
   python scripts/mrlint.py --changed
@@ -79,11 +74,11 @@ run_lint_quick() {
 
 run_lint_full() {
   echo "== mrlint (whole package) =="
-  python scripts/mrlint.py --json mrlint.json --publish
+  python scripts/mrlint.py --json mrlint.json
 }
 
 run_megafuse_subset_quick() {
-  echo "== megafuse subset (fast): fused-vs-eager goldens + interpret kernels =="
+  echo "== megafuse subset (fast): fused-vs-eager goldens + kernel-launch accounting =="
   env JAX_PLATFORMS=cpu python -m pytest tests/test_megafuse.py -q \
       -k 'golden or kernel' \
       -p no:cacheprovider -p no:xdist -p no:randomly
@@ -217,14 +212,6 @@ run_fleet_subset_full() {
       -p no:cacheprovider -p no:xdist -p no:randomly
 }
 
-bench_compare_advisory() {
-  # advisory only: the verdict table lands in the CI log; a regression
-  # (or a compare bug) must not fail the build — bench.py --gate is the
-  # hard version
-  echo "== bench_compare (advisory) =="
-  python scripts/bench_compare.py --md - || true
-}
-
 if [ "${1:-}" = "lint" ]; then
   run_lint_full
   exit 0
@@ -282,7 +269,6 @@ if [ "${1:-}" = "quick" ]; then
   run_elastic_subset_quick
   run_wire_subset_quick
   run_megafuse_subset_quick
-  bench_compare_advisory
   exit 0
 fi
 
@@ -312,4 +298,3 @@ run_context_subset
 run_elastic_subset_full
 run_wire_subset_full
 run_megafuse_subset_full
-bench_compare_advisory

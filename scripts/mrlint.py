@@ -9,7 +9,6 @@ the full gate runs in a few seconds with zero side effects.
     scripts/mrlint.py -r knob-registry     # one rule
     scripts/mrlint.py --changed            # report only changed files
     scripts/mrlint.py --json -             # machine-readable findings
-    scripts/mrlint.py --json lint.json --publish   # + BASELINE.json row
     scripts/mrlint.py --list-rules
 
 Exit codes: 0 clean, 1 unsuppressed findings, 2 usage/internal error.
@@ -29,11 +28,10 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LINT_DIR = os.path.join(REPO, "gpu_mapreduce_tpu", "lint")
 
-# harness scripts the knob-registry and net-timeout rules scan on top
-# of the package (mrctl/mrlaunch are the client and the data-plane
-# supervisor — both daemon-adjacent enough to hold the timeout line)
-EXTRA_FILES = ("soak.py", "bench.py", "weakscale.py",
-               "scripts/mrctl.py", "scripts/mrlaunch.py")
+# scripts the knob-registry and net-timeout rules scan on top of the
+# package (mrctl/mrlaunch are the client and the data-plane supervisor
+# — both daemon-adjacent enough to hold the timeout line)
+EXTRA_FILES = ("scripts/mrctl.py", "scripts/mrlaunch.py")
 
 
 def _load_lint():
@@ -67,18 +65,6 @@ def _changed_paths() -> set:
     return out
 
 
-def _publish(payload: dict) -> None:
-    """Merge finding counts under published.lint of BASELINE.json via
-    utils/publish.py (loaded by path — same no-package-import rule)."""
-    path = os.path.join(REPO, "gpu_mapreduce_tpu", "utils", "publish.py")
-    spec = importlib.util.spec_from_file_location("mrlint_publish", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    mod.publish("lint", {"counts": payload["counts"],
-                         "total": payload["total"],
-                         "suppressed": payload["suppressed"]})
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="mrlint", description=__doc__,
@@ -96,9 +82,6 @@ def main(argv=None) -> int:
     ap.add_argument("--write-baseline", metavar="FILE",
                     help="write current unsuppressed fingerprints to "
                          "FILE and exit 0")
-    ap.add_argument("--publish", action="store_true",
-                    help="merge finding counts into BASELINE.json "
-                         "(published.lint) for cross-PR tracking")
     ap.add_argument("--root", default=REPO, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
@@ -147,11 +130,6 @@ def main(argv=None) -> int:
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=2)
             f.write("\n")
-    if args.publish:
-        try:
-            _publish(payload)
-        except Exception as e:
-            print(f"mrlint: publish failed: {e!r}", file=sys.stderr)
 
     live = [f for f in findings if not f.suppressed]
     if args.json != "-":

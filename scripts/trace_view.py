@@ -10,7 +10,7 @@ Usage:
 TRACE.jsonl is what a run writes under MRTPU_TRACE=path (or
 MapReduce(trace=path)).  --chrome additionally writes the
 Perfetto-loadable Chrome trace-event file; --cat filters to one span
-category (mr_op / shuffle / ingest / oink / app / soak); --json prints
+category (mr_op / shuffle / ingest / oink / app / host); --json prints
 the aggregate as JSON instead of the table.
 
 A DIRECTORY path is a multi-process run dir (scripts/mrlaunch.py):

@@ -208,7 +208,7 @@ def test_single_file_over_cap_raises(tmp_path, monkeypatch):
 
 
 def test_pipeline_on_single_device_mesh(html_corpus):
-    """The bench's actual tier: P=1 mesh → zero-copy ShardedKV from the
+    """The one-chip benchmark cell's tier: P=1 mesh → zero-copy ShardedKV from the
     fused extract, aggregate early-out, device convert, batch count
     reduce (emit_batch) — must agree with the serial path."""
     from gpu_mapreduce_tpu.parallel.mesh import make_mesh
@@ -266,8 +266,8 @@ def test_mesh_multi_round_batches(html_corpus, monkeypatch):
 
 
 def test_map_stats_multi_batch_and_wide(html_corpus, tmp_path, monkeypatch):
-    """bench.py's detail record surfaces the batching + two-tier window
-    machinery (VERDICT r2 #9): forced multi-batch shows nbatches > 1;
+    """``InvertedIndex.stats`` surfaces the batching + two-tier window
+    machinery: forced multi-batch shows nbatches > 1;
     a long-URL-dense corpus shows a wide fallback."""
     monkeypatch.setattr(InvertedIndex, "_BATCH_BYTES", 4096)
     ii = InvertedIndex()

@@ -93,8 +93,8 @@ def test_purity_walks_partial_wrapped_pallas_kernel(tmp_path):
     """The ops/pallas call-site idiom — the kernel body handed to
     ``pallas_call`` wrapped as ``functools.partial(kernel, static...)``
     — is seeded as a traced entry: a host effect inside the kernel
-    body must be found (the fixture mirrors ops/pallas/group.py's
-    paged table kernel shape)."""
+    body must be found (the fixture has the shape of a paged table
+    kernel)."""
     src = """
         import functools
         import os
@@ -118,17 +118,15 @@ def test_purity_walks_partial_wrapped_pallas_kernel(tmp_path):
 
 
 def test_knob_registry_sees_fusion_v2_knobs():
-    """The fusion-v2 knobs route through utils/env.py and carry
-    doc/settings.md rows — the pair the knob-registry rule reconciles
+    """The fusion-v2 knob routes through utils/env.py and carries a
+    doc/settings.md row — the pair the knob-registry rule reconciles
     (any drift re-opens a knob-undocumented/knob-stale finding in the
     self-check below)."""
     with open(os.path.join(REPO, "doc", "settings.md")) as f:
         doc = f.read()
-    assert "MRTPU_MEGAFUSE" in doc and "MRTPU_PALLAS_GROUP" in doc
-    from gpu_mapreduce_tpu.ops.pallas.group import pallas_group_enabled
+    assert "MRTPU_MEGAFUSE" in doc
     from gpu_mapreduce_tpu.plan.fuser import megafuse_enabled
     assert isinstance(megafuse_enabled(), bool)
-    assert isinstance(pallas_group_enabled(), bool)
 
 
 def test_purity_clean_partial_pallas_kernel(tmp_path):
@@ -575,7 +573,7 @@ def test_baseline_suppression(tmp_path):
 def test_selfcheck_repo_runs_clean():
     """ISSUE 11 acceptance: zero unsuppressed findings on the tree."""
     project = lint.Project(
-        REPO, extra_files=("soak.py", "bench.py", "weakscale.py"))
+        REPO, extra_files=("scripts/mrctl.py", "scripts/mrlaunch.py"))
     findings = lint.run(project)
     live = [f for f in findings if not f.suppressed]
     assert live == [], "\n" + "\n".join(str(f) for f in live)

@@ -1,21 +1,15 @@
-"""Fusion v2 (megafused single-dispatch plan groups, plan/fuser.py) +
-the Pallas segment-group/segment-reduce table kernels
-(ops/pallas/group.py): interpret-mode kernel goldens, fused-vs-eager
-byte identity (wire on/off, pallas on/off, chaos), the "1 dispatch per
+"""Fusion v2 (megafused single-dispatch plan groups, plan/fuser.py):
+fused-vs-eager byte identity (wire on/off, chaos), the "1 dispatch per
 plan group" steady-state assertion, speculation-miss fallbacks, the
 kernel-launch dispatch accounting, and the fusion telemetry surfaces
 (mr.stats()["plan"]["fusion"], the per-request profile)."""
 
-import warnings
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from gpu_mapreduce_tpu.core.mapreduce import MapReduce
 from gpu_mapreduce_tpu.core.runtime import global_counters
-from gpu_mapreduce_tpu.ops.pallas import group as pgroup
 from gpu_mapreduce_tpu.ops.reduces import (count, cull, max_values,
                                            sum_values)
 from gpu_mapreduce_tpu.parallel.mesh import make_mesh
@@ -54,117 +48,13 @@ def warm_pipeline(mr, keys, vals, kernel=count):
 
 
 # ---------------------------------------------------------------------------
-# interpret-mode kernel unit goldens (CPU)
+# kernel-launch dispatch accounting
 # ---------------------------------------------------------------------------
 
-def _table_reference(keys, vals, nvalid):
-    """numpy oracle: per-key count and exact mod-2^64 sum."""
-    cnts, sums = {}, {}
-    for k, v in zip(keys[:nvalid].tolist(), vals[:nvalid].tolist()):
-        cnts[k] = cnts.get(k, 0) + 1
-        sums[k] = (sums.get(k, 0) + int(v)) % (1 << 64)
-    return cnts, sums
-
-
-@pytest.mark.parametrize("reduce_op", ["count", "sum"])
-def test_kernel_table_golden(rng, reduce_op):
-    """The paged table kernel + slot epilogue against a numpy oracle:
-    ascending unique keys, exact counts/sums, zero fill — the layout
-    the sort path emits."""
-    cap, nvalid, gcap = 1024, 900, 256
-    keys = (rng.integers(0, 150, cap).astype(np.uint64)
-            * np.uint64(0x9E3779B97F4A7C15))
-    vals = rng.integers(-(1 << 40), 1 << 40, cap).astype(np.int64)
-    T = pgroup.table_slots(gcap)
-    cfg = ("tbl", T, 256, True)
-    ukey, uval, g, overflow = jax.jit(
-        lambda k, v, n: pgroup.segment_group_reduce(
-            k, v, n, gcap, reduce_op, cfg))(
-        jnp.asarray(keys), jnp.asarray(vals), jnp.int32(nvalid))
-    cnts, sums = _table_reference(keys, vals, nvalid)
-    uk = np.sort(np.asarray(list(cnts), np.uint64))
-    got_k = np.asarray(ukey)
-    got_v = np.asarray(uval)
-    assert int(overflow) == 0
-    assert int(g) == len(uk)
-    assert np.array_equal(got_k[:len(uk)], uk)
-    assert (got_k[len(uk):] == 0).all() and (got_v[len(uk):] == 0).all()
-    for i, k in enumerate(uk.tolist()):
-        if reduce_op == "count":
-            assert int(got_v[i]) == cnts[k]
-        else:
-            assert int(np.uint64(got_v[i].astype(np.uint64))) == sums[k]
-
-
-def test_kernel_paged_matches_single_page(rng):
-    """Page seams are invisible: tiny pages == one page, bit for bit."""
-    cap, gcap = 777, 128
-    keys = rng.integers(0, 60, cap).astype(np.uint64)
-    vals = rng.integers(0, 1 << 30, cap).astype(np.int64)
-    T = pgroup.table_slots(gcap)
-    outs = []
-    for page in (64, 1024):
-        cfg = ("tbl", T, page, True)
-        outs.append(pgroup.segment_group_reduce(
-            jnp.asarray(keys), jnp.asarray(vals), jnp.int32(cap), gcap,
-            "sum", cfg))
-    for a, b in zip(outs[0], outs[1]):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_kernel_overflow_detected(rng):
-    """More distinct keys than table slots: the overflow counter is
-    nonzero (the megafuse validation evidence) — never silent drops."""
-    cap = 512
-    keys = np.arange(cap, dtype=np.uint64) * np.uint64(7919)
-    vals = np.ones(cap, np.int64)
-    cfg = ("tbl", 64, 512, True)   # 64 slots, 512 distinct keys
-    _uk, _uv, _g, overflow = pgroup.segment_group_reduce(
-        jnp.asarray(keys), jnp.asarray(vals), jnp.int32(cap), 64,
-        "count", cfg)
-    assert int(overflow) > 0
-
-
-def test_kernel_signed_and_narrow_dtypes(rng):
-    """int32 keys / int32 values: signed reconstruction is exact and
-    sums wrap mod 2^32 exactly like the eager segment_sum."""
-    cap, gcap = 600, 64
-    keys = rng.integers(-30, 30, cap).astype(np.int32)
-    vals = rng.integers(-(1 << 30), 1 << 30, cap).astype(np.int32)
-    T = pgroup.table_slots(gcap)
-    cfg = ("tbl", T, 1024, True)
-    ukey, uval, g, overflow = pgroup.segment_group_reduce(
-        jnp.asarray(keys), jnp.asarray(vals), jnp.int32(cap), gcap,
-        "sum", cfg)
-    assert int(overflow) == 0
-    uk = np.sort(np.unique(keys))
-    assert np.array_equal(np.asarray(ukey)[:len(uk)], uk)
-    for i, k in enumerate(uk.tolist()):
-        ref = np.int32(vals[keys == k].sum(dtype=np.int32))
-        assert np.asarray(uval)[i] == ref
-    assert int(g) == len(uk)
-
-
-def test_kernel_eager_launch_counts_dispatch(rng):
-    """Satellite: Counters.ndispatch counts pallas_call launches too —
-    one per EAGER page call; launches traced inside a jit ride the
-    enclosing program's count (no double billing), so "1 dispatch per
-    pipeline" cannot be faked by moving work into uncounted kernels."""
-    cap = 512
-    keys = jnp.asarray(rng.integers(0, 40, cap).astype(np.uint64))
-    vals = jnp.asarray(np.ones(cap, np.int64))
-    d0 = ndispatch()
-    pgroup.segment_table(keys, vals, jnp.int32(cap), 128, 256, False,
-                         True)   # 2 pages, eager
-    assert ndispatch() - d0 == 2
-    d0 = ndispatch()
-    jax.jit(lambda k, v: pgroup.segment_table(
-        k, v, jnp.int32(cap), 128, 256, False, True))(keys, vals)
-    assert ndispatch() - d0 == 0   # rides the (uncounted-here) jit
-
-
 def test_kernel_mark_launch_counts_dispatch():
-    """The pre-existing mark kernels report their eager launches too."""
+    """Counters.ndispatch counts eager pallas_call launches too, so
+    "1 dispatch per pipeline" cannot be faked by moving work into
+    uncounted kernels: the mark kernel reports its launch."""
     from gpu_mapreduce_tpu.ops.pallas.match import mark_words_pallas
     words = jnp.zeros(1 << 10, jnp.uint32)
     d0 = ndispatch()
@@ -195,21 +85,9 @@ def test_megafuse_golden_wire_modes(monkeypatch, wire):
     assert eager == fused_warm
 
 
-@pytest.mark.slow
-def test_megafuse_golden_pallas_forced_matches_sort(monkeypatch):
-    """MRTPU_PALLAS_GROUP=1 (the table kernels, interpret mode on this
-    CPU) produces results identical to the sort path, warm and cold."""
-    keys, vals = intcount_keys()
-    sort_path = run_chain(make_mesh(8), 1, count, keys, vals)
-    monkeypatch.setenv("MRTPU_PALLAS_GROUP", "1")
-    on_cold = run_chain(make_mesh(8), 1, count, keys, vals)
-    on_warm = run_chain(make_mesh(8), 1, count, keys, vals)
-    assert sort_path == on_cold == on_warm
-
-
 def test_megafuse_golden_kmv_chain():
-    """[aggregate, convert] (collate for a host reduce) megafuses on
-    the sort path (KMV is kernel-unsupported) — output identical."""
+    """[aggregate, convert] (collate for a host reduce) megafuses to a
+    grouped KMV — output identical."""
     from gpu_mapreduce_tpu.apps.wordfreq import _sum
     keys, _ = intcount_keys()
     vals = np.ones(len(keys), np.int64)
@@ -264,21 +142,6 @@ def test_single_dispatch_per_pipeline(monkeypatch, wire):
     n2 = warm_pipeline(mr, keys, vals)
     assert ndispatch() - d0 == 1
     assert n1 == n2
-
-
-@pytest.mark.slow
-def test_single_dispatch_with_pallas_kernels(monkeypatch):
-    """Still exactly 1 dispatch with the table kernels forced on: the
-    paged pallas_calls ride the single megafused jit program (the
-    launch counter's tracer check), never a second host dispatch."""
-    monkeypatch.setenv("MRTPU_PALLAS_GROUP", "1")
-    keys, vals = intcount_keys()
-    mr = MapReduce(make_mesh(8), fuse=1)
-    warm_pipeline(mr, keys, vals)
-    warm_pipeline(mr, keys, vals)
-    d0 = ndispatch()
-    warm_pipeline(mr, keys, vals)
-    assert ndispatch() - d0 == 1
 
 
 def test_megafuse_off_takes_v1_dispatches(monkeypatch):
@@ -343,8 +206,8 @@ def test_speculation_miss_pack_overflow_falls_back():
 @pytest.mark.slow
 def test_speculation_miss_group_growth_falls_back():
     """Warm on few distinct keys, then many: the cached group capacity
-    (and kernel table) overflow, detected host-side — the sort-path v1
-    replay keeps the output exact."""
+    no longer covers, detected host-side — the v1 replay keeps the
+    output exact."""
     few, vals = intcount_keys(card=17)
     many, _ = intcount_keys(card=3000)
     mr = MapReduce(make_mesh(8), fuse=1)
@@ -354,22 +217,6 @@ def test_speculation_miss_group_growth_falls_back():
     mre = MapReduce(make_mesh(8), fuse=0)
     ref = warm_pipeline(mre, many, vals), scan_pairs(mre)
     assert got == ref
-
-
-def test_fallback_warns_once(monkeypatch):
-    """Unsupported chains warn exactly once per reason (then silent)."""
-    monkeypatch.setenv("MRTPU_PALLAS_GROUP", "1")
-    keys, vals = intcount_keys()
-    mr = MapReduce(make_mesh(8), fuse=1)
-    warm_pipeline(mr, keys, vals, kernel=max_values)   # arm megafuse
-    pgroup._WARNED.clear()   # AFTER arming: a shared plan-cache entry
-    #                          may have megafused (and warned) already
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        warm_pipeline(mr, keys, vals, kernel=max_values)
-        warm_pipeline(mr, keys, vals, kernel=max_values)
-    ours = [w for w in rec if "MRTPU_PALLAS_GROUP" in str(w.message)]
-    assert len(ours) == 1
 
 
 # ---------------------------------------------------------------------------

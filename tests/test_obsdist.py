@@ -482,20 +482,6 @@ def test_mrctl_top_table_renders_members():
         "(no federation members)")
 
 
-def test_bench_compare_extracts_obsdist_row():
-    bc = load_script("bench_compare")
-    rec = {"metric": "m", "value": 10.0, "backend": "cpu",
-           "engine": "native",
-           "detail": {"obs_dist_ab": {"off_s": 10.0, "on_s": 10.2,
-                                      "overhead_pct": 2.0}}}
-    m = bc.record_metrics(rec)
-    assert m["obs_dist_overhead_pct"] == 2.0
-    assert ("obs_dist_overhead_pct", -1) in bc.ADVISORY_METRICS
-    # an errored A/B contributes nothing
-    rec["detail"]["obs_dist_ab"] = {"error": "boom"}
-    assert "obs_dist_overhead_pct" not in bc.record_metrics(rec)
-
-
 # ---------------------------------------------------------------------------
 # multi-process goldens (slow)
 # ---------------------------------------------------------------------------

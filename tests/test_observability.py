@@ -67,32 +67,6 @@ def test_spill_delta_reported(tmp_path, capsys):
     assert "Mb spilled" in outp
 
 
-def test_publish_preserves_corrupt_baseline(tmp_path):
-    """r4 review: publish() over a corrupt BASELINE.json must not
-    silently destroy the previous records — the unparsable file moves
-    aside to .corrupt and the write is atomic (tmp+rename)."""
-    import json
-    import os
-
-    from gpu_mapreduce_tpu.utils.publish import publish, read_published
-
-    path = str(tmp_path / "BASELINE.json")
-    publish("a", {"x": 1}, path=path)
-    assert read_published("a", path=path) == {"x": 1}
-
-    with open(path) as f:
-        truncated = f.read()[:-5]          # rip off the closing braces
-    with open(path, "w") as f:
-        f.write(truncated)
-    publish("b", {"y": 2}, path=path)
-    assert read_published("b", path=path) == {"y": 2}
-    corrupt = path + ".corrupt"
-    assert os.path.exists(corrupt)         # old records survive for repair
-    assert '"a"' in open(corrupt).read()
-    assert not os.path.exists(path + ".tmp")
-    json.load(open(path))                  # the new file parses
-
-
 # ---------------------------------------------------------------------------
 # obs/ tracing subsystem (PR 1): spans, sinks, export, stats
 # ---------------------------------------------------------------------------
