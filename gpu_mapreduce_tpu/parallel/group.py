@@ -3,6 +3,11 @@
 The reference's convert is purely local per rank (SURVEY.md §3.3: "No MPI at
 all — the parallelism came from aggregate").  Same here: each shard sorts its
 own block and finds group boundaries under ``shard_map``; no collectives.
+
+Both halves are sorts: the grouped layout (`grouped_layout`) packs each
+group's first row to the front by a sort with the key columns as
+payloads, because a scatter is what the chip does worst (the readings
+are in that function's docstring).
 """
 
 from __future__ import annotations
@@ -70,37 +75,55 @@ def grouped_layout(sk, mask, nrows, gcap: int):
     """Shard-local group layout of SORTED rows → (ukey, sizes, voff,
     seg, g).  THE one copy of the convert phase-2 math — shared by the
     eager `_convert_phase2_jit` and the plan/ fuser's fused programs, so
-    fused output can never drift from eager."""
+    fused output can never drift from eager.
+
+    The first row of every group is flagged in ``mask``; the layout is
+    those rows brought to the front in their order.  That is ONE sort,
+    keyed by the row index (flagged) or ``cap`` (not), with the key
+    columns riding as payloads: the sorted key column is ``voff`` with
+    its ``cap`` fill, the payloads are ``ukey``, and the sizes are the
+    differences of consecutive offsets.  The key is the row index, so
+    no key value is a sentinel.  A sort and not scatter-drops, because
+    on the v5e a scatter of 16.8 M u64 elements with dropped rows costs
+    1.79 s and a sort of them with a payload 0.056 s (PERF.md §6,
+    PR 25); here, at u64[8388608, 2], 1.05 s against 0.038 s (PR 29)."""
     cap = sk.shape[0]
     with jax.named_scope("segment_ids"):
-        seg = jnp.cumsum(mask.astype(jnp.int32)) - 1
-        in_group = seg >= 0  # rows before the first boundary are invalid
-        tgt = jnp.where(mask, seg, gcap)
-    # unique keys: first row of each group
-    with jax.named_scope("unique_keys"):
-        ushape = (gcap,) + sk.shape[1:]
-        ukey = jnp.zeros(ushape, sk.dtype).at[tgt].set(sk, mode="drop")
-    # group start offsets (shard-local row index)
-    with jax.named_scope("group_offsets"):
-        voff = jnp.full(gcap, cap, jnp.int32).at[tgt].set(
-            jnp.arange(cap, dtype=jnp.int32), mode="drop")
-    # per-group sizes: count rows whose running seg == g
+        flags = mask.astype(jnp.int32)
+        seg = jnp.cumsum(flags) - 1
+        g = jnp.sum(flags)
+    # columns, not [cap, w] blocks, go into the sort: the chip stores
+    # u64[cap, 2] column-major and a reshape of it is tile-padded 64x
+    cols = (sk,) if sk.ndim == 1 else tuple(
+        sk[:, j] for j in range(sk.shape[1]))
+    # unstable: only unflagged rows tie (at ``cap``), and what they
+    # carry is replaced by the fills below
+    with jax.named_scope("flagged_rows_first"):
+        row = jnp.arange(cap, dtype=jnp.int32)
+        first, *ucols = jax.lax.sort(
+            (jnp.where(mask, row, cap),) + cols, num_keys=1,
+            is_stable=False)
+    # the last group ends at nrows: padding rows sorted past the valid
+    # count are not counted; slots past the g-th group hold the fills
     with jax.named_scope("group_sizes"):
-        sizes = jax.ops.segment_sum(
-            jnp.where(in_group, 1, 0).astype(jnp.int32),
-            jnp.where(in_group, seg, gcap), num_segments=gcap + 1)[:gcap]
-    # clamp ON DEVICE: padding rows sorted past the valid count
-    # inherit the last group's seg id — the last group must end
-    # at nrows, groups past the shard's group count zero out (was a
-    # host loop + second round-trip, VERDICT r2 #8)
-    with jax.named_scope("clamp_sizes"):
-        g = jnp.sum(mask.astype(jnp.int32))
-        gi = jnp.arange(gcap)
-        last = jnp.maximum(g - 1, 0)
-        sizes = jnp.where(gi < g, sizes, 0)
-        sizes = jnp.where((gi == last) & (g > 0),
-                          nrows.astype(jnp.int32) - voff[last], sizes)
-    return ukey, sizes.astype(jnp.int32), voff, seg, g
+        nxt = jnp.concatenate([first[1:], jnp.full(1, cap, jnp.int32)])
+        sizes = jnp.where(
+            row < g, jnp.minimum(nxt, nrows.astype(jnp.int32)) - first, 0)
+    with jax.named_scope("unique_keys"):
+        ucols = [_fit(jnp.where(row < g, c, 0), gcap, 0) for c in ucols]
+        ukey = ucols[0] if sk.ndim == 1 else jnp.stack(ucols, axis=1)
+    with jax.named_scope("group_offsets"):
+        voff = _fit(first, gcap, cap)
+    return ukey, _fit(sizes, gcap, 0), voff, seg, g
+
+
+def _fit(x, n: int, fill):
+    """The first ``n`` entries of 1-D ``x``, padded with ``fill`` where
+    ``n`` exceeds its length (`round_cap` has a floor, so a tiny shard's
+    ``gcap`` can exceed its ``cap``)."""
+    if n <= x.shape[0]:
+        return x[:n]
+    return jnp.concatenate([x, jnp.full(n - x.shape[0], fill, x.dtype)])
 
 
 def segment_reduce_rows(x, seg, valid, gcap: int, op: str):
