@@ -8,8 +8,8 @@ Two paths through the same MapReduce algebra:
   (exactly the reference's flow: fileread callback emitting one KV per word,
   sum reduce, descending value sort, gather to 1, print top N).
 * :func:`wordfreq_interned` — device path: words interned to u64 ids
-  (BytesColumn.intern) so collate/reduce run columnar; the id→word
-  dictionary decodes the top-N at the end.  This is what scales on TPU.
+  (``BytesColumn.intern_sharded``, by ranges) so collate/reduce run
+  columnar; the id→word tables decode the top-N at the end.
 """
 
 from __future__ import annotations
@@ -18,10 +18,9 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..core.column import BytesColumn, DenseColumn
 from ..core.mapreduce import MapReduce
+from ..utils.io import read_words, word_ranges
 from .common import top_n
-from ..utils.io import read_words
 
 
 def _fileread(itask, filename, kv, ptr):
@@ -55,54 +54,25 @@ def wordfreq(files: Sequence[str], ntop: int = 10, comm=None,
 
 def wordfreq_interned(files: Sequence[str], ntop: int = 10, comm=None
                       ) -> Tuple[int, int, List[Tuple[bytes, int]]]:
-    """Device-path wordfreq: u64-interned words, columnar count reduce."""
-    import jax.numpy as jnp
-
-    from ..ops.segment import kmv_segment_ids, segment_reduce
-
-    from .. import native
+    """Device-path wordfreq: u64-interned words, columnar count reduce.
+    The words come from the shared word map (``utils/io.word_ranges``:
+    ranges of the file's buffer, no object per word) and intern into one
+    ``ShardTables``, whose ``absorb`` is the collision guard across files
+    and whose tables decode the top-N at the end."""
+    from ..core.column import ShardTables
+    from ..ops.reduces import count
 
     mr = MapReduce(comm)
-    vocab = {}
-
-    def _guard(h, w):
-        prev = vocab.get(h)
-        if prev is not None and prev != w:
-            raise ValueError(
-                "64-bit intern collision between %r and %r" % (prev, w))
-        vocab[h] = w
+    tables = ShardTables(mr.backend.nprocs)
 
     def fileread_ids(itask, filename, kv, ptr):
         with open(filename, "rb") as f:
-            raw = f.read()
-        if native.available():
-            # zero per-token Python: C++ tokenizer + in-place range
-            # interning; only each file's UNIQUE words slice out for the
-            # vocab (the decode dict for the top-N output)
-            data = np.frombuffer(raw, np.uint8)
-            starts, lens = native.tokenize(data)
-            ids = native.intern_ranges(data, starts, lens)
-            uniq, first = np.unique(ids, return_index=True)
-            for h, fi in zip(uniq.tolist(), first.tolist()):
-                _guard(h, raw[starts[fi]:starts[fi] + lens[fi]])
-            kv.add_batch(ids, np.ones(len(ids), np.int64))
-            return
-        col, table = BytesColumn(read_words(raw)).intern()
-        for h, w in table.items():  # cross-file collision guard
-            _guard(h, w)
-        kv.add_batch(col, np.ones(len(col.data), np.int64))
+            ids = word_ranges(f.read()).intern_sharded(tables)
+        kv.add_batch(ids, np.ones(len(ids), np.int64))
 
     nwords = mr.map_files(list(files), fileread_ids)
     mr.collate()
-    from ..ops.reduces import count
     nunique = mr.reduce(count, batch=True)
-    mr.gather(1)
-    mr.sort_values(-1)
-    top = []
-
-    def take(k, v, ptr):
-        if len(top) < ntop:
-            top.append((vocab[int(k)], int(v)))
-
-    mr.scan_kv(take)
-    return nwords, nunique, top
+    top = top_n(mr, ntop)
+    words = tables.decode_batch(np.array([k for k, _ in top], np.uint64))
+    return nwords, nunique, [(w, int(v)) for w, (_, v) in zip(words, top)]

@@ -861,6 +861,13 @@ class MapReduce:
             frame = kv.one_frame()
             if isinstance(frame, KVFrame):
                 kmv_frame = group_frame(frame)
+                if self.tracer.enabled:
+                    from ..obs import names
+                    self.tracer.annotate(**{
+                        names.ATTR_ROWS: len(frame),
+                        names.ATTR_GROUPS: len(kmv_frame),
+                        names.ATTR_GROUP_ROWS_MAX: int(
+                            kmv_frame.nvalues.max(initial=0))})
             else:  # ShardedKV → per-shard sort+segment under shard_map
                 from ..parallel.group import convert_sharded
                 kmv_frame = convert_sharded(frame, self.counters)

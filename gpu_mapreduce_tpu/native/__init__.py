@@ -101,6 +101,9 @@ def _load() -> Optional[ctypes.CDLL]:
     lib.mr_intern_ranges2.argtypes = [u8p, p(i64), p(i64), i64, u32, u32,
                                       u32, u32, p(u64), p(u64)]
     lib.mr_intern_ranges2.restype = None
+    lib.mr_unique_ranges.restype = i64
+    lib.mr_unique_ranges.argtypes = [u8p, p(i64), p(i64), p(u64), i64,
+                                     p(i64), p(i64)]
     lib.mr_parse_table.restype = i64
     lib.mr_parse_table.argtypes = [u8p, i64, i64, p(ctypes.c_int32),
                                    p(ctypes.c_void_p), i64]
@@ -184,6 +187,29 @@ def intern_ranges2(buf: np.ndarray, starts: np.ndarray, lens: np.ndarray,
                            alt_hi, alt_lo, _arr(out0, ctypes.c_uint64),
                            _arr(out1, ctypes.c_uint64))
     return out0, out1
+
+
+def unique_ranges(buf: np.ndarray, starts: np.ndarray, lens: np.ndarray,
+                  ids: np.ndarray):
+    """Row index of the first occurrence of every distinct id, in order
+    of appearance (int64[u]) — or ``(row, row)``, a tuple, when two rows
+    share an id and differ in their bytes (a 64-bit intern collision)."""
+    n = len(ids)
+    starts = np.ascontiguousarray(starts, np.int64)
+    lens = np.ascontiguousarray(lens, np.int64)
+    ids = np.ascontiguousarray(ids, np.uint64)
+    first = np.empty(n, np.int64)
+    clash = np.zeros(2, np.int64)
+    u = _lib.mr_unique_ranges(
+        _arr(np.ascontiguousarray(buf, np.uint8), ctypes.c_uint8),
+        _arr(starts, ctypes.c_int64), _arr(lens, ctypes.c_int64),
+        _arr(ids, ctypes.c_uint64), n, _arr(first, ctypes.c_int64),
+        _arr(clash, ctypes.c_int64))
+    if u == -1:
+        return int(clash[0]), int(clash[1])
+    if u < 0:
+        raise MemoryError("mr_unique_ranges: no memory for its table")
+    return first[:u].copy()
 
 
 def intern64_batch(buf: bytes, offsets: np.ndarray) -> np.ndarray:

@@ -257,6 +257,56 @@ int64_t mr_tokenize(const uint8_t *buf, int64_t len, int64_t *starts,
   return n <= max ? n : -n;
 }
 
+// first occurrence of every distinct id among n ranges of one buffer,
+// in order of appearance — the dedupe behind interning a whole file's
+// words (core/column._intern_ranges): the decode tables take each
+// DISTINCT word once, and a numpy argsort of every row's id to find them
+// cost more than hashing the rows did.  Open addressing over the ids,
+// doubling at half load.  Two rows that share an id must hold the same
+// bytes: the first pair that does not is a 64-bit intern collision, its
+// rows go to clash[0..1] and the count returned is -1.
+int64_t mr_unique_ranges(const uint8_t *buf, const int64_t *starts,
+                         const int64_t *lens, const uint64_t *ids,
+                         int64_t n, int64_t *first, int64_t *clash) {
+  int64_t cap = 1 << 16, nuniq = 0;
+  int64_t *slots = (int64_t *)malloc(cap * sizeof(int64_t));
+  if (slots == nullptr) return -2;
+  memset(slots, 0xFF, cap * sizeof(int64_t));     // -1: empty
+  for (int64_t i = 0; i < n; i++) {
+    uint64_t id = ids[i];
+    int64_t at = (id * 0x9E3779B97F4A7C15ull) >> 16 & (cap - 1);
+    int64_t k;
+    while ((k = slots[at]) >= 0 && ids[first[k]] != id)
+      at = (at + 1) & (cap - 1);
+    if (k >= 0) {
+      int64_t f = first[k];
+      if (lens[f] != lens[i] ||
+          memcmp(buf + starts[f], buf + starts[i], lens[i]) != 0) {
+        clash[0] = f; clash[1] = i;
+        free(slots);
+        return -1;
+      }
+      continue;
+    }
+    slots[at] = nuniq;
+    first[nuniq++] = i;
+    if (2 * nuniq > cap) {                        // grow and re-seat
+      cap *= 2;
+      free(slots);
+      slots = (int64_t *)malloc(cap * sizeof(int64_t));
+      if (slots == nullptr) return -2;
+      memset(slots, 0xFF, cap * sizeof(int64_t));
+      for (int64_t j = 0; j < nuniq; j++) {
+        int64_t a = (ids[first[j]] * 0x9E3779B97F4A7C15ull) >> 16 & (cap - 1);
+        while (slots[a] >= 0) a = (a + 1) & (cap - 1);
+        slots[a] = j;
+      }
+    }
+  }
+  free(slots);
+  return nuniq;
+}
+
 // href-URL extraction — the host equivalent of the CUDA mark /
 // compute_url_length kernels (cuda/InvertedIndex.cu:79-135) and the CPU
 // FSM parser (cpu/InvertedIndex.cpp:144-265): find every `<a href="`,

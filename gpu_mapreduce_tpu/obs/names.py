@@ -96,6 +96,14 @@ MAP_COLLISIONS = "map.collisions"               # rows, rounds, shards
 PARTS_PULL = "parts.pull"                       # groups, bytes
 PARTS_WRITE = "parts.write"                     # groups, bytes
 
+# utils/io.word_ranges / parallel/ingest._intern_side: the word map of a
+# file map, by ranges (cat HOST; ``shard`` when the map runs shard by shard)
+INGEST_TOKENIZE = "ingest.tokenize"             # shard, bytes, words
+INGEST_INTERN = "ingest.intern"                 # shard, words, unique,
+#                                                 table_bytes
+# oink/commands/wordfreq.py: gather(1) + sort_values + the first ntop rows
+WORDFREQ_TOPN = "wordfreq.topn"                 # rows
+
 # older spans that metrics quote by name
 SHUFFLE_EXCHANGE = "shuffle.exchange"           # ..., recv_rows_max, _mean
 SHUFFLE_COUNT_SYNC = "shuffle.count_sync"
@@ -107,4 +115,13 @@ SPANS = (
     CC_EMIT, CC_ENGINE, PAGERANK_STAGE, PAGERANK_EMIT, PAGERANK_ENGINE,
     MAP_PLAN, MAP_PAD, MAP_COLLISIONS, PARTS_PULL, PARTS_WRITE,
     SHUFFLE_EXCHANGE, SHUFFLE_COUNT_SYNC, OINK_RMAT,
+    INGEST_TOKENIZE, INGEST_INTERN, WORDFREQ_TOPN,
 )
+
+# -- attrs that metrics quote by name -----------------------------------------
+# on the ``convert`` op span (core/mapreduce.convert): the rows grouped, the
+# groups they fell into, and the rows of the largest group
+CONVERT_SPAN = "convert"
+ATTR_ROWS = "rows"
+ATTR_GROUPS = "groups"
+ATTR_GROUP_ROWS_MAX = "group_rows_max"

@@ -208,6 +208,16 @@ class Tracer:
         if stack:
             stack[-1].attrs.update(attrs)
 
+    def inherited(self, key: str):
+        """The attr ``key`` of the nearest open span of this thread that
+        carries it, or None — how a span deep in a callback learns the
+        ``shard`` its enclosing ingest span runs for."""
+        if self.enabled:
+            for sp in reversed(self._stack()):
+                if key in sp.attrs:
+                    return sp.attrs[key]
+        return None
+
     def current(self):
         stack = self._stack() if self.enabled else None
         return stack[-1] if stack else None

@@ -5,7 +5,9 @@ word:count KV."""
 
 from __future__ import annotations
 
+from ...apps.common import top_n
 from ...core.runtime import MRError
+from ...obs import get_tracer, names
 from ..command import Command, command
 from ..kernels import count, read_words
 
@@ -35,14 +37,9 @@ class WordFreq(Command):
         if self.ntop:
             if obj.permanent(mr):
                 mr = obj.copy_mr(mr)
-            mr.gather(1)
-            mr.sort_values(-1)
-
-            def take(k, v, ptr):
-                if len(self.top) < self.ntop:
-                    self.top.append((k, int(v)))
-
-            mr.scan_kv(take)
+            with get_tracer().span(names.WORDFREQ_TOPN, cat=names.HOST,
+                                   rows=int(nunique)):
+                self.top = [(k, int(v)) for k, v in top_n(mr, self.ntop)]
         self.nfiles, self.nwords, self.nunique = len(files), nwords, nunique
         self.message(f"WordFreq: {len(files)} files, {nwords} words, "
                      f"{nunique} unique")

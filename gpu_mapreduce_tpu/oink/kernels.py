@@ -142,9 +142,12 @@ def read_vertex_weight(itask, filename, kv, ptr):
 
 
 def read_words(itask, filename, kv, ptr):
-    """whitespace words → key=word bytes, value=NULL (map_read_words.cpp)."""
+    """whitespace words → key=word bytes, value=NULL (map_read_words.cpp).
+    The words go to the dataset as ranges of the file's buffer
+    (utils/io.word_ranges): no Python object per word."""
+    from ..utils.io import word_ranges
     with open(filename, "rb") as f:
-        words = f.read().split()
+        words = word_ranges(f.read())
     if ptr is not None and isinstance(ptr, list):
         ptr.append(filename)  # nfiles counter (reference int* ptr)
     kv.add_batch(words, _null(len(words)))

@@ -153,10 +153,10 @@ def _convert_phase2_jit(mesh, gcap: int):
             with jax.named_scope("layout"):
                 ukey, sizes, voff, _seg, _g = grouped_layout(sk, m, c[0],
                                                              gcap)
-            return ukey, sizes, voff
+            # the largest group's rows: read only by a traced run
+            return ukey, sizes, voff, jnp.max(sizes)[None]
         return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=(spec, spec, spec))(skey, mask,
-                                                           count)
+                             out_specs=(spec,) * 4)(skey, mask, count)
 
     return convert_layout
 
@@ -180,8 +180,14 @@ def convert_sharded(skv: ShardedKV, counters=None) -> ShardedKMV:
     gcap = round_cap(int(gcounts.max())) if gcounts.max() else 8
 
     bump_dispatch()
-    ukey, nvalues, voffsets = _convert_phase2_jit(mesh, gcap)(
+    ukey, nvalues, voffsets, gmax = _convert_phase2_jit(mesh, gcap)(
         skey, mask, counts_dev)
+    tracer = get_tracer()
+    if tracer.enabled:          # on the ``convert`` op span; one more pull
+        tracer.annotate(**{
+            names.ATTR_ROWS: int(skv.counts.sum()),
+            names.ATTR_GROUPS: int(gcounts.sum()),
+            names.ATTR_GROUP_ROWS_MAX: int(np.asarray(gmax).max())})
     return ShardedKMV(skv.mesh, ukey, nvalues, voffsets, svalue,
                       gcounts, skv.counts.copy(), key_decode=skv.key_decode,
                       value_decode=skv.value_decode)

@@ -95,8 +95,9 @@ def run_sinks(payloads, call: Callable, threaded: bool = True,
     from ..ft.retry import ingest_task
     from ..obs import get_tracer
     sinks = [_TaskSink() for _ in payloads]
+    where = {} if shard is None else {"shard": shard}
     with get_tracer().span("ingest.read", cat="ingest",
-                           ntasks=len(payloads), threaded=threaded):
+                           ntasks=len(payloads), threaded=threaded, **where):
         if not threaded or len(payloads) <= 1:
             for i, p in enumerate(payloads):
                 ingest_task(call, base + i, p, sinks[i],
@@ -172,16 +173,20 @@ def _intern_side(cols, P: int):
         raise Unshardable("mixed byte and numeric rows across shards")
     kind = ("object" if any(isinstance(c, ObjectColumn) for c in cols)
             else "bytes")
+    from ..obs import get_tracer, names
     tables = ShardTables(P, kind=kind)
     out = []
-    for c in cols:
+    for shard, c in enumerate(cols):
         if kind == "object" and isinstance(c, BytesColumn):
             # one shard emitted objects: EVERY shard's rows must hash in
             # the pickle domain, or the same logical bytes key would get
             # two ids (host concat() promotes the same way — r5 review)
             c = ObjectColumn(c.data)
         if isinstance(c, (BytesColumn, ObjectColumn)):
-            out.append(c.intern_sharded(tables))
+            # the column says what it absorbed: unique, table_bytes
+            with get_tracer().span(names.INGEST_INTERN, cat=names.HOST,
+                                   shard=shard, words=len(c)):
+                out.append(c.intern_sharded(tables))
         elif len(c):
             raise Unshardable("mixed byte and numeric rows across shards")
         else:

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Iterator, Tuple
+from typing import Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -213,13 +213,17 @@ class ShardedKV:
                    else DenseColumn(v[keep]))
         return KVFrame(key_col, val_col)
 
-    def shard_to_host(self, p: int) -> KVFrame:
+    def shard_to_host(self, p: int, limit: Optional[int] = None) -> KVFrame:
         """Host KVFrame of ONE shard's valid rows — device_get of just
         that shard's block (the HBM-budget demotion streams blocks one
-        at a time; ``to_host`` would materialise the whole dataset)."""
+        at a time; ``to_host`` would materialise the whole dataset).
+        ``limit``: only the shard's first rows are kept and decoded (a
+        top-N decodes N words, not the shard's)."""
         ToHostStats.bump("kv")
         cap = self.cap
         n = int(self.counts[p])
+        if limit is not None:
+            n = min(n, limit)
         k = v = None
         for sh in self.key.addressable_shards:
             if (sh.index[0].start or 0) == p * cap:

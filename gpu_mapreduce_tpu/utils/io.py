@@ -89,8 +89,45 @@ def file_chunks(filename: str, nchunks: int, sep: bytes = b"\n",
             start += cut
 
 
-def read_words(chunk: bytes, whitespace: bytes = b" \t\n\r\f\v") -> List[bytes]:
+WHITESPACE = b" \t\n\r\f\v"      # what bytes.split() and the native tokenizer
+#                                  split at: ASCII whitespace
+
+
+def read_words(chunk: bytes, whitespace: bytes = WHITESPACE) -> List[bytes]:
     """Whitespace tokenizer (the oink read_words map callback,
     oink/map_read_words.cpp)."""
     table = bytes.maketrans(whitespace, b" " * len(whitespace))
     return chunk.translate(table).split()
+
+
+def word_ranges(raw: bytes):
+    """The words of ``raw`` as a ``BytesColumn`` by ranges: one buffer
+    with the start and length of every word, no Python object per word
+    (``core/column.BytesColumn.from_ranges``).  Splits exactly as
+    ``bytes.split()`` and :func:`read_words` do.  The native tokenizer
+    when built, else a numpy pass over the same whitespace set: the same
+    ranges either way.  THE word map of ``map_files`` callbacks
+    (``oink/kernels.read_words``, ``apps/wordfreq``)."""
+    import numpy as np
+
+    from .. import native
+    from ..core.column import BytesColumn
+    from ..obs import get_tracer, names
+    tracer = get_tracer()
+    with tracer.span(names.INGEST_TOKENIZE, cat=names.HOST,
+                     bytes=len(raw)) as sp:
+        shard = tracer.inherited("shard")
+        if shard is not None:
+            sp.set(shard=shard)
+        buf = np.frombuffer(raw, np.uint8)
+        if native.available():
+            starts, lens = native.tokenize(buf)
+        else:
+            space = np.zeros(256, bool)
+            space[list(WHITESPACE)] = True
+            edge = np.diff(np.concatenate(
+                [[True], space[buf], [True]]).astype(np.int8))
+            starts = np.flatnonzero(edge == -1)
+            lens = np.flatnonzero(edge == 1) - starts
+        sp.set(words=len(starts))
+        return BytesColumn.from_ranges(buf, starts, lens)

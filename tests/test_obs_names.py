@@ -356,6 +356,59 @@ def test_invertedindex_on_four_devices_says_which_shard(traced, corpus,
     assert ex["recv_rows_mean"] <= ex["recv_rows_max"] <= npairs
 
 
+def _wordfreq_script(mesh, corpus):
+    from gpu_mapreduce_tpu.oink.script import OinkScript
+    s = OinkScript(comm=mesh, screen=io.StringIO())
+    s.run_string("variable files index " + " ".join(corpus))
+    s.run_string("wordfreq 3 -i v_files -o NULL mrw")
+    return s.screen.getvalue()
+
+
+def test_wordfreq_emits_the_word_map_and_top_n_spans(traced, corpus):
+    """ISSUE 30: the word map of the file map says what it tokenized and
+    interned, shard by shard; ``convert`` says how large its hub group is;
+    the top-N tail has a span."""
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    screen = _wordfreq_script(make_mesh(4), corpus)
+    nwords, nunique = map(int, re.search(
+        r"(\d+) words, (\d+) unique", screen).groups())
+    tree = _tree(traced.events())
+    where, args = _where(tree), _attrs(tree)
+    H = names.HOST
+    assert where[names.INGEST_TOKENIZE] == {(H, "ingest.read")}
+    assert where[names.INGEST_INTERN] == {(H, "map_files")}
+    assert where[names.WORDFREQ_TOPN] == {(H, "oink.wordfreq")}
+    tok, intern = args[names.INGEST_TOKENIZE], args[names.INGEST_INTERN]
+    assert [a["shard"] for a in tok] == [a["shard"] for a in intern] \
+        == [0, 1, 2, 3]
+    assert [a["bytes"] for a in tok] == [os.path.getsize(p) for p in corpus]
+    assert [a["words"] for a in tok] == [a["words"] for a in intern]
+    assert sum(a["words"] for a in tok) == nwords
+    assert all(0 < a["unique"] <= a["words"] and a["table_bytes"] > 0
+               for a in intern)
+    (conv,) = args[names.CONVERT_SPAN]
+    assert conv[names.ATTR_ROWS] == nwords
+    assert conv[names.ATTR_GROUPS] == nunique
+    top = int(screen.splitlines()[1].split()[0])
+    assert conv[names.ATTR_GROUP_ROWS_MAX] == top > 1
+    (topn,) = args[names.WORDFREQ_TOPN]
+    assert topn["rows"] == nunique
+    ex = args[names.SHUFFLE_EXCHANGE]           # the aggregate's, the gather's
+    assert len(ex) == 2 and ex[1]["recv_rows_max"] == nunique
+    assert ex[0]["recv_rows_mean"] == nwords / 4 < ex[0]["recv_rows_max"]
+
+
+def test_host_convert_says_its_hub_group(traced):
+    from gpu_mapreduce_tpu import MapReduce
+    mr = MapReduce()
+    mr.map(1, lambda i, kv, p: kv.add_batch(
+        np.array([7, 7, 7, 8, 9, 9], np.uint64), np.zeros(6, np.uint8)))
+    mr.convert()
+    (conv,) = _attrs(_tree(traced.events()))[names.CONVERT_SPAN]
+    assert (conv[names.ATTR_ROWS], conv[names.ATTR_GROUPS],
+            conv[names.ATTR_GROUP_ROWS_MAX]) == (6, 3, 3)
+
+
 def test_tracer_off_constructs_no_span_and_changes_nothing(
         mesh, corpus, tmp_path, monkeypatch):
     tr = get_tracer()
@@ -375,6 +428,7 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
     off_graph = _graph_script(mesh, str(tmp_path / "g0"))
     off_counts, off_parts, _ = _invindex(mesh, corpus, str(tmp_path / "i0"))
     off_rows = _host_batch(mesh)
+    off_words = _wordfreq_script(mesh, corpus)
     assert built == []          # every site returned NULL_SPAN
 
     tr.enable(ring=1 << 16)
@@ -384,14 +438,15 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
         on_counts, on_parts, _ = _invindex(mesh, corpus,
                                            str(tmp_path / "i1"))
         on_rows = _host_batch(mesh)
+        on_words = _wordfreq_script(mesh, corpus)
     finally:
         tr.clear()
         tr.disable()
     # every declared span name is one the program really opens
     assert set(names.SPANS) <= set(built)
     assert on_graph == off_graph
-    assert (on_counts, on_parts, on_rows) == (off_counts, off_parts,
-                                              off_rows)
+    assert (on_counts, on_parts, on_rows, on_words) == (
+        off_counts, off_parts, off_rows, off_words)
 
 
 # -- scopes --------------------------------------------------------------------
