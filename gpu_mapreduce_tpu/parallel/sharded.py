@@ -410,15 +410,20 @@ def rows_below(n, cap: int, ndim: int):
     return jnp.arange(cap).reshape((cap,) + (1,) * (ndim - 1)) < n
 
 
+def slice_rows(x, start, cap: int):
+    """Inside a program: the ``cap`` rows of ``x`` from ``start``, zero
+    rows where they run past its end — one dynamic slice of ``x`` with
+    ``cap`` zero rows appended, so that the slice never clamps."""
+    tail = jnp.zeros((cap,) + x.shape[1:], x.dtype)
+    return lax.dynamic_slice_in_dim(jnp.concatenate([x, tail]), start, cap)
+
+
 def window_rows(x, start, count, cap: int):
     """Inside a program: a ``[cap, ...]`` block holding ``x[start :
-    start + count]`` at its front and zero rows after — one dynamic
-    slice of ``x`` (zero rows appended so the slice never clamps) and a
-    select; no gather, no scatter."""
-    tail = jnp.zeros((cap,) + x.shape[1:], x.dtype)
-    block = lax.dynamic_slice_in_dim(jnp.concatenate([x, tail]), start, cap)
-    return jnp.where(rows_below(count, cap, x.ndim), block,
-                     jnp.zeros((), x.dtype))
+    start + count]`` at its front and zero rows after — one
+    :func:`slice_rows` and a select; no gather, no scatter."""
+    return jnp.where(rows_below(count, cap, x.ndim),
+                     slice_rows(x, start, cap), jnp.zeros((), x.dtype))
 
 
 @functools.lru_cache(maxsize=None)

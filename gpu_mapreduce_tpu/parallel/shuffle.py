@@ -14,10 +14,12 @@ host: read the [P,P] count matrix, pick the padded bucket size B and the
   replaces the reference's INTMAX/fraction flow-control negotiation
   (``irregular.cpp:95-242``) — static shapes instead of retry loops.
 
-phase 2 (jitted, per shard): scatter sorted rows into a [P,B] send buffer,
-  exchange via ``lax.all_to_all`` (``all2all=1``) or a ppermute ring
-  (``all2all=0`` — the reference's custom Irecv/Send transport,
-  ``irregular.cpp:311-363``), then compact received rows to the front.
+phase 2 (jitted, per shard): window the sorted rows into a [P,B] send
+  buffer (each destination's rows are one contiguous run), exchange via
+  ``lax.all_to_all`` (``all2all=1``) or a ppermute ring (``all2all=0`` —
+  the reference's custom Irecv/Send transport, ``irregular.cpp:311-363``),
+  then append each source's block to its run of the packed output.  Runs
+  move, not rows: no index in phase 2 is a per-row array.
 
 Skew note: padding to the max bucket wastes ICI bandwidth on skewed keys
 (RMAT high-degree vertices); the count matrix is already on the host, so a
@@ -42,7 +44,8 @@ from ..ops.hash import hash_words32
 from ..plan.cache import LRUCache
 from .mesh import (flat_axis_index, mesh_axes, mesh_axis_size,
                    row_sharding, row_spec)
-from .sharded import ShardedKV, SyncStats, round_cap, shard_frame
+from .sharded import (ShardedKV, SyncStats, round_cap, rows_below,
+                      shard_frame, slice_rows)
 
 # ---------------------------------------------------------------------------
 # hashing of device keys
@@ -110,27 +113,91 @@ def phase1_shard_body(nprocs: int, dest_of: Callable, wire_elig, k, v, c):
     return sk, sv, cl, bucket_stats(nprocs, k, v, d, k_elig, v_elig)
 
 
+def _run_starts(counts):
+    """Exclusive prefix sum of ``[P]`` run lengths, int32."""
+    counts = counts.astype(jnp.int32)
+    return jnp.cumsum(counts) - counts
+
+
+def _send_windows(nprocs: int, B: int, start: int, rows, counts_local):
+    """The ``P`` windows of one round, unmasked, and the mask of their
+    valid slots: ``(wins [P, B, ...], valid [P, B, 1...])``.
+
+    After phase 1 destination ``d``'s rows are ONE contiguous run of the
+    dest-sorted shard, ``off[d] .. off[d] + counts_local[d]``, so bucket
+    positions ``[start, start + B)`` of ``d`` are the ``B`` rows at
+    ``off[d] + start``: one ``dynamic_slice`` a destination, indexed by
+    the ``P`` run offsets and by nothing per row (no ``searchsorted``,
+    no ``take``, no scatter).  A window running past ``cap`` reads
+    zeros (:func:`~.sharded.slice_rows`): ``dynamic_slice`` clamps its
+    start, and a clamped window would hold other rows.
+
+    Cost in ``P``: a Python loop, so the trace holds ``P`` slices a
+    column a round (the cells' ``P`` is 4, the tests' 8).  They move
+    ``P * B`` rows in all — the send block itself — whatever ``P`` is,
+    but at ``P = 64+`` and 16 rounds the trace is some thousands of
+    operations: the growth ``_ring_exchange`` was rewritten to avoid.
+    The forms to take there are one batched window (a ``gather`` of
+    ``P`` indices with ``[B, ...]`` slices) here and a ``fori_loop``
+    over the sources in :func:`_place_blocks`: for the v5e both compile
+    to a ``while`` of ``P`` steps of dynamic-slice +
+    dynamic-update-slice (PERF.md §6, PR 31) — copies as well, but a
+    loop where the unrolled form is straight-line code, so at the
+    cells' ``P`` the unrolled form stands."""
+    off = _run_starts(counts_local)
+    wins = jnp.stack([slice_rows(rows, off[d] + start, B)
+                      for d in range(nprocs)])
+    valid = jnp.stack([rows_below(counts_local[d] - start, B, rows.ndim)
+                       for d in range(nprocs)])
+    return wins, valid
+
+
 def _build_send_window(nprocs: int, B: int, start: int, rows,
                        counts_local):
-    """Scatter dest-sorted rows into a [P, B, ...] send buffer, taking
-    only bucket positions [start, start+B) — the window slice of the
-    flow-controlled exchange (uniform rounds use start = r*B; the wire
-    codec's tiered caps use the running tier offset)."""
-    cap = rows.shape[0]
-    cum = jnp.cumsum(counts_local)
-    r = jnp.arange(cap)
-    d = jnp.searchsorted(cum, r, side="right").astype(jnp.int32)  # dest of row r
-    off = jnp.concatenate([jnp.zeros(1, jnp.int32), cum[:-1].astype(jnp.int32)])
-    q0 = r - jnp.take(off, jnp.minimum(d, nprocs - 1))  # slot within bucket
-    # rows outside this round's window must go POSITIVELY out of bounds:
-    # a negative q wraps NumPy-style (idx+B) before mode="drop" checks, so
-    # earlier rounds' rows would scatter into [0,B) and corrupt this round
-    in_window = (q0 >= start) & (q0 < start + B)
-    q = jnp.where(in_window, q0 - start, B)
-    shape = (nprocs, B) + rows.shape[1:]
-    send = jnp.zeros(shape, rows.dtype)
-    # rows with d==nprocs (padding) or q==B (other round) → dropped
-    return send.at[d, q].set(rows, mode="drop")
+    """The ``[P, B, ...]`` send block of one round: row ``d`` holds
+    bucket positions ``[start, start + B)`` of destination ``d``, zeros
+    elsewhere — the window slice of the flow-controlled exchange
+    (uniform rounds use ``start = r * B``; the wire codec's tiered caps
+    use the running tier offset).  Built by :func:`_send_windows`."""
+    wins, valid = _send_windows(nprocs, B, start, rows, counts_local)
+    return jnp.where(valid, wins, jnp.zeros((), rows.dtype))
+
+
+def _recv_buffer(cap_out: int, pad: int, like):
+    """The zeroed output of :func:`_place_blocks`: ``cap_out`` rows plus
+    ``pad`` (the largest block of the schedule) so that no window update
+    that holds a valid row can run past the end and be shifted
+    (``dynamic_update_slice`` clamps its start); the caller trims the
+    extension off again."""
+    return jnp.zeros((cap_out + pad,) + like.shape[1:], like.dtype)
+
+
+def _place_blocks(out, recv, base, counts_from, start: int, rebase=None):
+    """Append one round's received ``[P, B, ...]`` blocks to the packed
+    output: source ``j``'s rows are ONE run of it, from ``base[j]``, so
+    its block of this round goes to ``base[j] + start`` as a window
+    update — read the ``B`` rows there, keep them past
+    ``counts_from[j] - start`` (they are a later source's, or zeros),
+    write the window back.  ``P`` in-place updates of ``B`` rows each,
+    indexed by the run offsets alone; the trace grows with ``P`` as
+    :func:`_send_windows`' does.  ``rebase`` (the wire codec): ``[P]``
+    per-source bases in ``out``'s dtype, added to the block after it is
+    widened — the decode, block by block.
+
+    A window with no valid row may be clamped (its update writes back
+    what it read); one with a valid row starts below ``cap_out`` and the
+    buffer is ``B`` rows longer (:func:`_recv_buffer`)."""
+    B = recv.shape[1]
+    for j in range(recv.shape[0]):
+        pos = base[j] + start
+        block = recv[j]
+        if rebase is not None:
+            block = block.astype(out.dtype) + rebase[j]
+        keep = rows_below(counts_from[j] - start, B, block.ndim)
+        here = lax.dynamic_slice_in_dim(out, pos, B)
+        out = lax.dynamic_update_slice_in_dim(
+            out, jnp.where(keep, block, here), pos, 0)
+    return out
 
 
 def _build_send(nprocs: int, B: int, rows, counts_local, round_idx: int = 0):
@@ -201,16 +268,6 @@ def _exchange_blocks(send, transport: int, mesh):
     if transport == 1:
         return lax.all_to_all(send, axes[0], 0, 0)
     return _ring_exchange(send, mesh)
-
-
-def _compact(recv, counts_from, cap_out: int):
-    """[P,B,...] recv blocks → [cap_out,...] rows packed to the front."""
-    nprocs, B = recv.shape[0], recv.shape[1]
-    flat = recv.reshape((nprocs * B,) + recv.shape[2:])
-    valid = (jnp.arange(B)[None, :] < counts_from[:, None]).reshape(-1)
-    order = jnp.argsort(~valid, stable=True)  # valid rows first, order kept
-    packed = jnp.take(flat, order[:cap_out], axis=0)
-    return packed, jnp.sum(counts_from)
 
 
 def _dest_fn(dest, nprocs: int, mesh) -> Callable:
@@ -333,36 +390,30 @@ def phase2_shard_body(nprocs: int, transport: int, mesh, B: int,
     of skew — the TPU equivalent of the reference's fraction<1.0
     flow-control retry loop (src/mapreduce.cpp:498-513,
     irregular.cpp:95-242), but with statically known round count.
-    Received rows scatter directly to their final packed position
-    (base[src] + round*B + slot), so no per-round compaction pass."""
+    Received rows go directly to their final packed position
+    (base[src] + round*B + slot) as window updates
+    (:func:`_place_blocks`), so no per-round compaction pass — and the
+    send block is windows of the dest-sorted shard
+    (:func:`_send_windows`): both sides move runs, indexed by ``P``
+    offsets, not rows indexed one by one."""
     counts_from = _exchange_counts(cl, transport, mesh)
-    cum = jnp.cumsum(counts_from)
-    base = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), cum[:-1].astype(jnp.int32)])
-    out_k = jnp.zeros((cap_out,) + k.shape[1:], k.dtype)
-    out_v = jnp.zeros((cap_out,) + v.shape[1:], v.dtype)
-    slot = jnp.arange(B, dtype=jnp.int32)
+    base = _run_starts(counts_from)
+    out_k = _recv_buffer(cap_out, B, k)
+    out_v = _recv_buffer(cap_out, B, v)
     for r in range(nrounds):
         recv_k = _exchange_blocks(
             _build_send(nprocs, B, k, cl, r), transport, mesh)
         recv_v = _exchange_blocks(
             _build_send(nprocs, B, v, cl, r), transport, mesh)
-        # position of recv[j, q]: base[j] + r*B + q; invalid slots
-        # (past counts_from[j]) push out of range and drop
-        q_global = r * B + slot[None, :]
-        pos = jnp.where(q_global < counts_from[:, None],
-                        base[:, None] + q_global, cap_out)
-        out_k = out_k.at[pos.reshape(-1)].set(
-            recv_k.reshape((-1,) + k.shape[1:]), mode="drop")
-        out_v = out_v.at[pos.reshape(-1)].set(
-            recv_v.reshape((-1,) + v.shape[1:]), mode="drop")
-    return out_k, out_v, jnp.sum(counts_from)
+        out_k = _place_blocks(out_k, recv_k, base, counts_from, r * B)
+        out_v = _place_blocks(out_v, recv_v, base, counts_from, r * B)
+    return out_k[:cap_out], out_v[:cap_out], jnp.sum(counts_from)
 
 
 def _phase2_jit(mesh, transport: int, B: int, nrounds: int, cap_out: int,
                 donate: bool = False):
     """``donate=True`` donates the dest-sorted skey/svalue (dead after
-    the exchange scatters them into the output blocks).  NEVER used for
+    the exchange has placed them in the output blocks).  NEVER used for
     the SPECULATIVE phase 2: a failed speculation re-runs phase 2 with
     the same inputs, which donation would have deleted.  Callers only
     pass donate=True when cap_out == cap (the caller checks) — the one

@@ -314,22 +314,17 @@ def _base_in(base_bits, dtype):
     return base_bits.astype(dtype)
 
 
-def _encode_col(col, base_bits, dest, pack: str):
-    """``col - base[dest]`` cast to the wire dtype.  Valid rows fit the
-    pack width by construction (the planner checked the ranges); rows
-    past the valid count carry garbage and are dropped by the send
-    scatter."""
-    base = _base_in(base_bits, col.dtype)
-    return (col - jnp.take(base, dest)).astype(jnp.dtype(pack))
-
-
-def _decode_col(packed, base_bits, src, valid, dtype):
-    """``base[src] + delta``, masked to zero off the valid prefix so the
-    decoded block is byte-identical to the raw path's zero-padded
-    output."""
-    base = _base_in(base_bits, dtype)
-    full = jnp.take(base, src) + packed.astype(dtype)
-    return jnp.where(valid, full, jnp.zeros((), dtype))
+def _encode_windows(wins, valid, base_bits, pack: Optional[str]):
+    """One round's ``[P, B]`` send block from its unmasked windows:
+    window ``d`` less ``base[d]``, cast to the wire dtype (``pack``
+    None: the rows as they are), zero off the valid slots.  Valid rows
+    fit the pack width by construction (the planner checked the
+    ranges); the rest of a window is other buckets' rows or padding and
+    is masked, as the raw send block's is."""
+    if pack is not None:
+        base = _base_in(base_bits, wins.dtype)
+        wins = (wins - base[:, None]).astype(jnp.dtype(pack))
+    return jnp.where(valid, wins, jnp.zeros((), wins.dtype))
 
 
 def phase2_wire_shard_body(nprocs: int, transport: int, mesh, tiers,
@@ -341,57 +336,41 @@ def phase2_wire_shard_body(nprocs: int, transport: int, mesh, tiers,
     interconnect delta-packed at the planned widths and the round caps
     follow the tier ladder.  One extra tiny collective replaces the
     counts exchange: ``(count, kbase, vbase)`` per bucket ride together
-    as a [P, 3] uint64 block."""
-    from .shuffle import _build_send_window, _exchange_blocks
+    as a [P, 3] uint64 block.
+
+    The codec works on blocks, not rows: destination ``d``'s base comes
+    off window ``d`` before it is sent, source ``j``'s base goes onto
+    block ``j`` before it is placed (``shuffle._place_blocks``), and
+    only valid rows are ever written, so rows past the valid prefix
+    stay the zeros the raw path leaves there."""
+    from .shuffle import (_exchange_blocks, _place_blocks, _recv_buffer,
+                          _run_starts, _send_windows)
 
     meta_local = jnp.stack([cl.astype(jnp.uint64), stats[:, 0],
                             stats[:, 2]], axis=1)          # [P, 3]
     meta_from = _exchange_blocks(meta_local[:, None, :], transport,
                                  mesh)[:, 0, :]
     counts_from = meta_from[:, 0].astype(jnp.int32)
+    kbase = _base_in(meta_from[:, 1], k.dtype) if kpack else None
+    vbase = _base_in(meta_from[:, 2], v.dtype) if vpack else None
 
-    # encode: dest of each dest-sorted row from the local counts
-    cap = k.shape[0]
-    cum = jnp.cumsum(cl)
-    denc = jnp.minimum(
-        jnp.searchsorted(cum, jnp.arange(cap), side="right"),
-        nprocs - 1).astype(jnp.int32)
-    ke = _encode_col(k, stats[:, 0], denc, kpack) if kpack else k
-    ve = _encode_col(v, stats[:, 2], denc, vpack) if vpack else v
-
-    cumf = jnp.cumsum(counts_from)
-    base = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), cumf[:-1].astype(jnp.int32)])
-    out_k = jnp.zeros((cap_out,) + ke.shape[1:], ke.dtype)
-    out_v = jnp.zeros((cap_out,) + ve.shape[1:], ve.dtype)
+    base = _run_starts(counts_from)
+    out_k = _recv_buffer(cap_out, max(tiers), k)
+    out_v = _recv_buffer(cap_out, max(tiers), v)
     start = 0
     for B in tiers:
-        recv_k = _exchange_blocks(
-            _build_send_window(nprocs, B, start, ke, cl), transport, mesh)
-        recv_v = _exchange_blocks(
-            _build_send_window(nprocs, B, start, ve, cl), transport, mesh)
-        q_global = start + jnp.arange(B, dtype=jnp.int32)[None, :]
-        pos = jnp.where(q_global < counts_from[:, None],
-                        base[:, None] + q_global, cap_out)
-        out_k = out_k.at[pos.reshape(-1)].set(
-            recv_k.reshape((-1,) + ke.shape[1:]), mode="drop")
-        out_v = out_v.at[pos.reshape(-1)].set(
-            recv_v.reshape((-1,) + ve.shape[1:]), mode="drop")
+        send_k = _encode_windows(*_send_windows(nprocs, B, start, k, cl),
+                                 stats[:, 0], kpack)
+        send_v = _encode_windows(*_send_windows(nprocs, B, start, v, cl),
+                                 stats[:, 2], vpack)
+        recv_k = _exchange_blocks(send_k, transport, mesh)
+        recv_v = _exchange_blocks(send_v, transport, mesh)
+        out_k = _place_blocks(out_k, recv_k, base, counts_from, start,
+                              rebase=kbase)
+        out_v = _place_blocks(out_v, recv_v, base, counts_from, start,
+                              rebase=vbase)
         start += B
-    nrecv = jnp.sum(counts_from)
-
-    if kpack or vpack:
-        idx = jnp.arange(cap_out)
-        src = jnp.minimum(jnp.searchsorted(cumf, idx, side="right"),
-                          nprocs - 1).astype(jnp.int32)
-        valid = idx < nrecv
-        if kpack:
-            out_k = _decode_col(out_k, meta_from[:, 1], src, valid,
-                                k.dtype)
-        if vpack:
-            out_v = _decode_col(out_v, meta_from[:, 2], src, valid,
-                                v.dtype)
-    return out_k, out_v, nrecv
+    return out_k[:cap_out], out_v[:cap_out], jnp.sum(counts_from)
 
 
 # ---------------------------------------------------------------------------
