@@ -7,19 +7,22 @@ oink/commands/luby.py.  This model runs the whole thing in ONE jitted
 ``lax.while_loop`` over a dense vertex state vector:
 
 * per-vertex priorities are the SAME splitmix64 stream as the composed
-  engine (``vertex_rand(v, seed)`` on original ids); a vertex joins
-  when its (priority, id) is lexicographically smaller than every
+  engine (``vertex_rand(v, seed)`` on original ids), handed over as
+  their int32 ranks in the order of (priority, id): distinct, so one
+  comparison decides; exact, because the set depends on that order
+  alone; and integers, because the v5e's compiler lowers no float64
+  ``pmin``.  A vertex joins when its rank is smaller than every
   UNDECIDED neighbour's.  With these shared priorities the two engines
   produce identical sets on the golden script input, but only the MIS
   property itself is contractual (the composed rounds cull edges in a
   different order — see the LubyFind docstring);
-* one round = masked segment-mins (neighbour min priority, then min id
-  among holders of it) + neighbour-of-winner exclusion, all
-  vectorised; the mesh version pmin/pmax-combines over ICI.
+* one round = one masked segment-min (the smallest undecided
+  neighbour's rank) + neighbour-of-winner exclusion, all vectorised;
+  the mesh version pmin/pmax-combines over ICI.
 
 States: 0 undecided, 1 in MIS, 2 excluded.  A vertex whose undecided
-neighbourhood empties (everyone excluded) sees +inf and joins — the
-maximality guarantee."""
+neighbourhood empties (everyone excluded) sees the largest int32 and
+joins — the maximality guarantee."""
 
 from __future__ import annotations
 
@@ -39,33 +42,25 @@ def _both_dirs(src, dst, x_by_src):
     """Edge contributions in both directions: (values, targets) where
     value i is x evaluated at the *other* endpoint."""
     return (jnp.concatenate([x_by_src[src], x_by_src[dst]]),
-            jnp.concatenate([dst, src]),
-            jnp.concatenate([src, dst]))
+            jnp.concatenate([dst, src]))
 
 
 def _round(state, prio, src, dst, valid, n, axes=None):
     und = state == 0
-    idx = jnp.arange(n, dtype=jnp.int32)
     active = valid & und[src] & und[dst]
     act2 = jnp.concatenate([active, active])
 
-    pv, tgt, other = _both_dirs(src, dst, prio)
+    pv, tgt = _both_dirs(src, dst, prio)
     seg = jnp.where(act2, tgt, n)
-    ov = other.astype(jnp.int32)
 
-    # min neighbour priority among undecided neighbours
-    m1 = jax.ops.segment_min(jnp.where(act2, pv, jnp.inf), seg,
+    # min neighbour priority among undecided neighbours; no two vertices
+    # share a priority, so the strict comparison decides alone
+    top = jnp.iinfo(prio.dtype).max
+    m1 = jax.ops.segment_min(jnp.where(act2, pv, top), seg,
                              num_segments=n + 1)[:n]
     if axes is not None:
         m1 = lax.pmin(m1, axes)
-    # min neighbour id among holders of that priority (tie-break)
-    hold = act2 & (pv == m1[tgt])
-    mid = jax.ops.segment_min(jnp.where(hold, ov, n), seg,
-                              num_segments=n + 1)[:n]
-    if axes is not None:
-        mid = lax.pmin(mid, axes)
-
-    winner = und & ((prio < m1) | ((prio == m1) & (idx < mid)))
+    winner = und & (prio < m1)
 
     # neighbours of winners become excluded (only undecided ones change)
     wv = jnp.concatenate([winner[src], winner[dst]]).astype(jnp.int32)
@@ -96,7 +91,8 @@ def _loop(step, n, maxiter):
 def luby_mis(src, dst, prio, n: int, maxiter: int = 0
              ) -> Tuple[jax.Array, jax.Array]:
     """Single device.  Returns (state[n] ∈ {1 MIS, 2 excluded}, rounds).
-    ``prio``: per-vertex priorities (vertex_rand on original ids)."""
+    ``prio``: each vertex's int32 rank in the order of (vertex_rand on
+    original ids, id); no two alike."""
     maxiter = maxiter or max(n, 1)
     valid = jnp.ones(src.shape, bool)
     s32, d32 = src.astype(jnp.int32), dst.astype(jnp.int32)
@@ -111,7 +107,7 @@ def _luby_sharded_fn(mesh: Mesh, n: int, maxiter: int):
     rep = NamedSharding(mesh, P())
 
     @functools.partial(jax.jit, out_shardings=(rep, rep))
-    def run(src_d, dst_d, valid_d, prio):
+    def luby_loop(src_d, dst_d, valid_d, prio):
         body = jax.shard_map(
             lambda st, pr, s, d, v: _round(st, pr, s, d, v, n, axes),
             mesh=mesh, in_specs=(P(), P(), rspec, rspec, rspec),
@@ -119,7 +115,7 @@ def _luby_sharded_fn(mesh: Mesh, n: int, maxiter: int):
         return _loop(lambda st: body(st, prio, src_d, dst_d, valid_d),
                      n, maxiter)
 
-    return run
+    return luby_loop
 
 
 def luby_mis_sharded(mesh: Mesh, src: np.ndarray, dst: np.ndarray,

@@ -44,6 +44,14 @@ CC_LOOP = "jit_cc_loop"
 PAGERANK_LOOP = "jit_pagerank_loop"
 RMAT_EDGES = "jit_rmat_edges"
 RMAT_EDGE_ROWS = "jit_rmat_edge_rows"               # [m, 2] keys + NULL values
+TRI_ORIENT = "jit_tri_orient"                       # keys, degrees, neighbour lists
+TRI_WEDGES = "jit_tri_wedges"                       # one batch: expand, join, compact
+TRI_APPEND = "jit_tri_append"                       # a batch's hits into the buffer
+TRI_GROW = "jit_tri_grow"
+TRI_ROWS = "jit_tri_rows"                           # (centre, u, w) id rows
+LUBY_LOOP = "jit_luby_loop"
+SSSP_LOOP = "jit_sssp_loop"
+SSSP_WEIGHTS = "jit_sssp_weights"                   # int32 where exact
 
 PROGRAMS = (
     INVINDEX_EXTRACT, INVINDEX_COLLISIONS, CONVERT_SORT, CONVERT_LAYOUT,
@@ -51,6 +59,8 @@ PROGRAMS = (
     SHUFFLE_PHASE1, SHUFFLE_PHASE2, SHUFFLE_PHASE2_WIRE, STAGE_RANK_GRAPH,
     STAGE_TRIM_VERTS, PLACE_ROWS, CONCAT_ROWS, LEVEL_ROWS, REMAP_IDS,
     CC_LOOP, PAGERANK_LOOP, RMAT_EDGES, RMAT_EDGE_ROWS,
+    TRI_ORIENT, TRI_WEDGES, TRI_APPEND, TRI_GROW, TRI_ROWS, LUBY_LOOP,
+    SSSP_LOOP, SSSP_WEIGHTS,
 )
 
 # parallel/devkernels.py's two generic mappers run one program per kernel
@@ -89,6 +99,19 @@ CC_ENGINE = "cc.loop"                           # cat ENGINE: iters, n, edges
 PAGERANK_STAGE = "pagerank.stage"
 PAGERANK_EMIT = "pagerank.emit"
 PAGERANK_ENGINE = "pagerank.loop"               # cat ENGINE
+# oink/commands/{tri,luby,sssp}.py
+TRI_STAGE = "tri.stage"                         # n, edges
+TRI_ENGINE = "tri.loop"                         # cat ENGINE: wedges, batches,
+#                                                 triangles, edges, n,
+#                                                 max_out_degree
+TRI_EMIT = "tri.emit"                           # triangles
+LUBY_STAGE = "luby.stage"                       # n, edges
+LUBY_ENGINE = "luby.loop"                       # cat ENGINE: iters, n, edges
+LUBY_EMIT = "luby.emit"                         # n
+SSSP_STAGE = "sssp.stage"                       # n, edges
+SSSP_ENGINE = "sssp.loop"                       # cat ENGINE: iters, source,
+#                                                 labeled, n
+SSSP_EMIT = "sssp.emit"                         # n, source
 # apps/invertedindex.py
 MAP_PLAN = "map.plan"                           # files, bytes, rounds
 MAP_PAD = "map.pad"                             # bytes, shard_bytes
@@ -116,6 +139,8 @@ SPANS = (
     MAP_PLAN, MAP_PAD, MAP_COLLISIONS, PARTS_PULL, PARTS_WRITE,
     SHUFFLE_EXCHANGE, SHUFFLE_COUNT_SYNC, OINK_RMAT,
     INGEST_TOKENIZE, INGEST_INTERN, WORDFREQ_TOPN,
+    TRI_STAGE, TRI_ENGINE, TRI_EMIT, LUBY_STAGE, LUBY_ENGINE, LUBY_EMIT,
+    SSSP_STAGE, SSSP_ENGINE, SSSP_EMIT,
 )
 
 # -- attrs that metrics quote by name -----------------------------------------

@@ -287,53 +287,61 @@ class LubyFind(Command):
         # device staging (VERDICT r2 #2): vertex ranking on device;
         # self-loops dropped in the valid mask, matching the host path's
         # pre-unique filter
+        from ...obs import get_tracer, names
         from ...parallel.staging import stage_graph
-        sg = stage_graph(mre, obj.comm, drop_self=True)
-        if sg is not None and sg.n == 0:
-            # a self-loop-only graph (drop_self left no vertices): empty
-            # state falls through to the shared epilogue — no edge pull
-            verts, state, iters = sg.verts, np.zeros(0, np.int8), 0
-        elif sg is not None:
-            from ...models.luby import _luby_sharded_fn
-            verts, n = sg.verts, sg.n
-            prio = vertex_rand(verts, self.seed)
-            state_d, iters = _luby_sharded_fn(mesh, n, max(n, 1))(
-                sg.src, sg.dst, sg.valid, jnp.asarray(prio))
-            state, iters = np.asarray(state_d), int(iters)
-        else:
-            ecols: list = []
-            mre.scan_kv(lambda fr, p: ecols.append(kv_keys(fr)),
-                        batch=True)
-            e = (np.concatenate(ecols) if ecols
-                 else np.zeros((0, 2), np.uint64)).astype(np.uint64)
-            e = e[e[:, 0] != e[:, 1]]        # self-loops never block a MIS
-            verts, inv = np.unique(e.reshape(-1), return_inverse=True)
-            n = len(verts)
-            if n == 0:
-                self.nset, self.niterate = 0, 0
-                mrv = obj.create_mr()
-                obj.output(1, mrv, print_vertex)
-                self.message("Luby_find: 0 MIS vertices in 0 iterations")
-                obj.cleanup()
-                return
-            src = inv.reshape(-1, 2)[:, 0]
-            dst = inv.reshape(-1, 2)[:, 1]
-            prio = vertex_rand(verts, self.seed)
+        tr = get_tracer()
+        with tr.span(names.LUBY_STAGE, cat=names.HOST) as sp:
+            sg = stage_graph(mre, obj.comm, drop_self=True)
+            if sg is not None:
+                verts, n = sg.verts, sg.n
+            else:
+                ecols: list = []
+                mre.scan_kv(lambda fr, p: ecols.append(kv_keys(fr)),
+                            batch=True)
+                e = (np.concatenate(ecols) if ecols
+                     else np.zeros((0, 2), np.uint64)).astype(np.uint64)
+                e = e[e[:, 0] != e[:, 1]]    # self-loops never block a MIS
+                verts, inv = np.unique(e.reshape(-1), return_inverse=True)
+                n = len(verts)
+                src = inv.reshape(-1, 2)[:, 0]
+                dst = inv.reshape(-1, 2)[:, 1]
+            # the loop compares priorities and nothing else, so it gets
+            # each vertex's rank in the order of (priority, id): the same
+            # set exactly, and int32 on a chip whose compiler lowers no
+            # float64 ``pmin``
+            prio = np.empty(n, np.int32)
+            prio[np.lexsort((verts, vertex_rand(verts, self.seed)))] = \
+                np.arange(n, dtype=np.int32)
+            sp.set(n=n, edges=int(mre.kv.nkv) if mre.kv is not None else 0)
 
-            from ...models.luby import luby_mis, luby_mis_sharded
-            if mesh is not None:
+        with tr.span(names.LUBY_ENGINE, cat=names.ENGINE) as sp:
+            # the span ends at the pull of the state vector
+            if n == 0:
+                # no edge but self loops (or none at all): nothing to decide
+                state, iters = np.zeros(0, np.int8), 0
+            elif sg is not None:
+                from ...models.luby import _luby_sharded_fn
+                state, iters = _luby_sharded_fn(mesh, n, max(n, 1))(
+                    sg.src, sg.dst, sg.valid, jnp.asarray(prio))
+            elif mesh is not None:
+                from ...models.luby import luby_mis_sharded
                 state, iters = luby_mis_sharded(mesh, src, dst, prio, n)
             else:
+                from ...models.luby import luby_mis
                 state, iters = luby_mis(src.astype(np.int32),
                                         dst.astype(np.int32),
                                         jnp.asarray(prio), n)
-                state, iters = np.asarray(state), int(iters)
+            state, iters = np.asarray(state), int(iters)
+            sp.set(iters=iters, n=n)
 
-        mis = verts[state == 1]
-        self.nset, self.niterate = int(len(mis)), int(iters)
         mrv = obj.create_mr()
-        mrv.map(1, lambda i, kv, p: kv.add_batch(
-            mis, np.zeros(len(mis), np.uint8)))
+        with tr.span(names.LUBY_EMIT, cat=names.HOST) as sp:
+            mis = verts[state == 1]
+            self.nset, self.niterate = int(len(mis)), iters
+            if self.nset:
+                mrv.map(1, lambda i, kv, p: kv.add_batch(
+                    mis, np.zeros(len(mis), np.uint8)))
+            sp.set(n=self.nset)
         obj.output(1, mrv, print_vertex)
         self.message(f"Luby_find: {self.nset} MIS vertices in "
                      f"{self.niterate} iterations")

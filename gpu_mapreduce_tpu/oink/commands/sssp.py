@@ -304,54 +304,52 @@ class SSSPCommand(Command):
         # weight column is row-sharded aligned with the ranked endpoints
         # (need_weights guards against interned byte values, whose u64
         # ids are not numbers)
+        from ...obs import get_tracer, names
         from ...parallel.staging import stage_graph
-        sg = stage_graph(mredge, obj.comm, need_weights=True)
-        # (sg.n == 0 cannot happen: empty datasets return None and
-        # without drop_self every valid edge row has real endpoints)
-        if sg is not None:
-            from ...models.sssp import _bf_sharded_fn
-            verts, n = sg.verts, sg.n
-            fn = _bf_sharded_fn(mesh, n, max(n, 1))
-
-            def bf(sidx):
-                dist, pred, it = fn(sg.src, sg.dst, sg.weights, sg.valid,
-                                    jnp.int32(sidx))
-                return np.asarray(dist), np.asarray(pred), int(it)
-        else:
-            ecols: list = []
-            mredge.scan_kv(lambda fr, p: ecols.append(
-                (kv_keys(fr), kv_values(fr))), batch=True)
-            if ecols:
-                e = np.concatenate([c[0] for c in ecols]).astype(np.uint64)
-                w = np.concatenate([c[1] for c in ecols]).astype(np.float64)
+        tr = get_tracer()
+        with tr.span(names.SSSP_STAGE, cat=names.HOST) as sp:
+            sg = stage_graph(mredge, obj.comm, need_weights=True)
+            # (sg.n == 0 cannot happen: empty datasets return None and
+            # without drop_self every valid edge row has real endpoints)
+            if sg is not None:
+                from ...models.sssp import _bf_sharded_fn, runner
+                verts, n = sg.verts, sg.n
+                bf = runner(_bf_sharded_fn(mesh, n, max(n, 1)), sg.src,
+                            sg.dst, sg.weights, sg.valid, n)
             else:
-                e = np.zeros((0, 2), np.uint64)
-                w = np.zeros(0, np.float64)
-            verts, inv = np.unique(e.reshape(-1), return_inverse=True)
-            n = len(verts)
-            if n == 0:
-                raise MRError("sssp: empty edge list")
-            src = inv.reshape(-1, 2)[:, 0]
-            dst = inv.reshape(-1, 2)[:, 1]
+                ecols: list = []
+                mredge.scan_kv(lambda fr, p: ecols.append(
+                    (kv_keys(fr), kv_values(fr))), batch=True)
+                if ecols:
+                    e = np.concatenate([c[0] for c in ecols]).astype(np.uint64)
+                    w = np.concatenate([c[1] for c in ecols]).astype(
+                        np.float64)
+                else:
+                    e = np.zeros((0, 2), np.uint64)
+                    w = np.zeros(0, np.float64)
+                verts, inv = np.unique(e.reshape(-1), return_inverse=True)
+                n = len(verts)
+                if n == 0:
+                    raise MRError("sssp: empty edge list")
+                src = inv.reshape(-1, 2)[:, 0]
+                dst = inv.reshape(-1, 2)[:, 1]
 
-            from ...models.sssp import bellman_ford, prepare_bellman_ford
-            if mesh is not None:
-                # pad + upload the edges ONCE; every source reuses the
-                # compiled program and the device-resident arrays
-                bf = prepare_bellman_ford(mesh, src, dst, w, n)
-            else:
-                s32 = src.astype(np.int32)
-                d32 = dst.astype(np.int32)
-                w_h = jnp.asarray(w)
+                from ...models.sssp import (bellman_ford,
+                                            prepare_bellman_ford, runner)
+                if mesh is not None:
+                    # pad + upload the edges ONCE; every source reuses the
+                    # compiled program and the device-resident arrays
+                    bf = prepare_bellman_ford(mesh, src, dst, w, n)
+                else:
+                    bf = runner(
+                        lambda s, d, w, _v, at: bellman_ford(s, d, w, n, at),
+                        src.astype(np.int32), dst.astype(np.int32),
+                        jnp.asarray(w), np.ones(len(w), bool), n)
 
-                def bf(sidx):
-                    dist, pred, it = bellman_ford(s32, d32, w_h, n,
-                                                  jnp.int32(sidx))
-                    return np.asarray(dist), np.asarray(pred), int(it)
-
-        # deterministic-random source list (same ranking as composed)
-        order = np.lexsort((verts, vertex_rand(verts, self.seed)))
-        sources = verts[order][:self.ncnt].tolist()
+            # deterministic-random source list (same ranking as composed)
+            order = np.lexsort((verts, vertex_rand(verts, self.seed)))
+            sources = verts[order][:self.ncnt].tolist()
+            sp.set(n=n, edges=int(mredge.kv.nkv))
 
         self.results = {}
         self.niters = {}
@@ -360,25 +358,31 @@ class SSSPCommand(Command):
         pred = np.full(n, -1, np.int64)
         for cnt, source in enumerate(sources):
             sidx = int(np.searchsorted(verts, np.uint64(source)))
-            dist, pred, niter = bf(sidx)
-            # dict/file view: -1 (source/unreachable) renders as 0 like
-            # the composed output path (np.maximum(..., 0))
-            predv = np.where(pred >= 0, verts[np.maximum(pred, 0)],
-                             np.uint64(0))
-            res = {int(v): (float(d), int(p))
-                   for v, d, p in zip(verts, dist, predv)}
-            self.results[source] = res
-            self.niters[source] = niter
-            nlabeled = int(np.isfinite(dist).sum())
-            self.message(f"SSSP: source {source}: {niter} iterations, "
-                         f"{nlabeled} vertices labeled")
-            if outd is not None and outd.path is not None:
-                path = (f"{outd.path}.{cnt}" if self.ncnt > 1
-                        else outd.path)
-                with open(path, "w") as fp:
-                    for v in sorted(res):
-                        d, p = res[v]
-                        fp.write(f"{v} {d:g} {p}\n")
+            with tr.span(names.SSSP_ENGINE, cat=names.ENGINE) as sp:
+                # the span ends at the pull of dist and pred
+                dist, pred, niter = bf(sidx)
+                nlabeled = int(np.isfinite(dist).sum())
+                sp.set(iters=niter, source=int(source), labeled=nlabeled,
+                       n=n)
+            with tr.span(names.SSSP_EMIT, cat=names.HOST, n=n,
+                         source=int(source)):
+                # dict/file view: -1 (source/unreachable) renders as 0 like
+                # the composed output path (np.maximum(..., 0))
+                predv = np.where(pred >= 0, verts[np.maximum(pred, 0)],
+                                 np.uint64(0))
+                res = {int(v): (float(d), int(p))
+                       for v, d, p in zip(verts, dist, predv)}
+                self.results[source] = res
+                self.niters[source] = niter
+                self.message(f"SSSP: source {source}: {niter} iterations, "
+                             f"{nlabeled} vertices labeled")
+                if outd is not None and outd.path is not None:
+                    path = (f"{outd.path}.{cnt}" if self.ncnt > 1
+                            else outd.path)
+                    with open(path, "w") as fp:
+                        for v in sorted(res):
+                            d, p = res[v]
+                            fp.write(f"{v} {d:g} {p}\n")
         if outd is not None and outd.mr_name is not None:
             # named-MR rows keep the composed engine's persisted shape:
             # [TAG_DIST, pred (original id, NO_PRED sentinel intact),

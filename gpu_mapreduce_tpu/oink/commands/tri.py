@@ -23,6 +23,7 @@ from ..kernels import (_parse_cols, edge_both_directions, host_kmv, kmv_keys,
                        sum_values)
 
 
+import jax
 import jax.numpy as jnp
 
 from ...parallel.devkernels import (is_sharded_kmv, is_sharded_kv,
@@ -204,10 +205,13 @@ class TriFind(Command):
     (Vi,Vj,Vk) line per triangle, Vi = the low-degree "center" vertex that
     emitted the angle (oink/tri_find.cpp:43-81).
 
-    Engines: ``fused`` (default) — vectorised degree-ordered wedge
-    matching (models/tri.py: index arithmetic + batched searchsorted
-    membership, no shuffled angle materialisation); ``composed`` — the
-    reference's 6-stage MR pipeline below (GPUMR_TRI_ENGINE=composed).
+    Engines: ``fused`` (default) — the degree-ordered wedge walk as device
+    programs (models/tri.py: sorts that carry payloads, prefix scans, one
+    gather a batch of wedges; the triangles stay on the device as the
+    output MR), the same programs on every backend; ``composed`` — the
+    reference's 6-stage MR pipeline below (GPUMR_TRI_ENGINE=composed),
+    whose ``nsq_angles`` holds every angle of a shard in one frame and so
+    stops where Σ d(d-1)/2 rows of 40 bytes outgrow the device.
     Identical triangle sets."""
 
     ninputs = 1
@@ -228,31 +232,59 @@ class TriFind(Command):
         obj = self.obj
         mre = obj.input(1, read_edge)
 
-        # device staging (VERDICT r2 #2): rank vertices on device; only
-        # int32 rank columns reach the host wedge walk (whose membership
-        # probes run jitted on the accelerator already)
+        from jax.sharding import Mesh
+        from ...models import tri
+        from ...obs import get_tracer, names
+        from ...parallel.mesh import mesh_axis_size
+        from ...parallel.sharded import ShardedKV
         from ...parallel.staging import stage_graph
-        sg = stage_graph(mre, obj.comm)
-        if sg is not None:
-            from ...models.tri import triangles_ranked
-            valid = np.asarray(sg.valid)
-            tris = triangles_ranked(np.asarray(sg.src)[valid],
-                                    np.asarray(sg.dst)[valid],
-                                    sg.n, sg.verts)
-        else:
-            ecols: list = []
-            mre.scan_kv(lambda fr, p: ecols.append(kv_keys(fr)),
-                        batch=True)
-            e = (np.concatenate(ecols) if ecols
-                 else np.zeros((0, 2), np.uint64)).astype(np.uint64)
+        mesh = obj.comm if isinstance(obj.comm, Mesh) else None
+        tr = get_tracer()
+        # device staging (VERDICT r2 #2): vertices ranked on the device,
+        # the ranked edge rows stay there for the walk; the serial backend
+        # ranks on the host and hands the same programs its rows
+        with tr.span(names.TRI_STAGE, cat=names.HOST) as sp:
+            sg = stage_graph(mre, obj.comm)
+            if sg is not None:
+                verts, src, dst, valid = sg.verts, sg.src, sg.dst, sg.valid
+            else:
+                ecols: list = []
+                mre.scan_kv(lambda fr, p: ecols.append(kv_keys(fr)),
+                            batch=True)
+                e = (np.concatenate(ecols) if ecols
+                     else np.zeros((0, 2), np.uint64)).astype(np.uint64)
+                verts, inv = np.unique(e.reshape(-1), return_inverse=True)
+                inv = inv.reshape(-1, 2).astype(np.int32)
+                src, dst = inv[:, 0], inv[:, 1]
+                valid = np.ones(len(inv), bool)
+            n = len(verts)
+            sp.set(n=n, edges=int(mre.kv.nkv) if mre.kv is not None else 0)
+        if n >= 2**31:
+            raise MRError(f"tri_find: {n} vertices overflow int32 ranks")
 
-            from ...models.tri import triangles
-            tris = triangles(e)
+        with tr.span(names.TRI_ENGINE, cat=names.ENGINE) as sp:
+            # the span ends at the pull of the last batch's hit count
+            w = tri.walk(src, dst, valid, verts, mesh) if n else tri.NO_WALK
+            sp.set(wedges=w.wedges, batches=w.batches, triangles=w.ntri,
+                   edges=w.edges, n=n, max_out_degree=w.max_out_degree)
 
-        self.ntri = len(tris)
+        self.ntri = w.ntri
         mrt = obj.create_mr()
-        mrt.map(1, lambda i, kv, p: kv.add_batch(
-            tris, np.zeros(len(tris), np.uint8)))
+        with tr.span(names.TRI_EMIT, cat=names.HOST) as sp:
+            sp.set(triangles=w.ntri)
+            if w.ntri:
+                key, value = jax.block_until_ready(tri.rows(w, mesh))
+                del w
+                if mesh is not None and mesh_axis_size(mesh) == 1:
+                    frame = ShardedKV(mesh, key, value,
+                                      np.asarray([self.ntri], np.int32))
+                    mrt.map(1, lambda i, kv, p: kv.add_frame(frame))
+                else:
+                    # every device of a wider mesh walked the same wedges:
+                    # the rows are one host frame, the output one file
+                    mrt.map(1, lambda i, kv, p: kv.add_batch(
+                        np.asarray(key)[:self.ntri],
+                        np.zeros(self.ntri, np.uint8)))
         obj.output(1, mrt, print_tri)
         self.message(f"Tri_find: {self.ntri} triangles")
         obj.cleanup()

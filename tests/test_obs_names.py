@@ -32,7 +32,7 @@ def _programs(mesh):
     """(declared name, lowered program) of every named program, at tiny
     shapes on the 8-device CPU mesh."""
     from gpu_mapreduce_tpu.apps import invertedindex as app
-    from gpu_mapreduce_tpu.models import cc, pagerank, rmat
+    from gpu_mapreduce_tpu.models import cc, luby, pagerank, rmat, sssp, tri
     from gpu_mapreduce_tpu.parallel import (devkernels, group, sharded,
                                             shuffle, staging)
     u64, i32, u32 = jnp.uint64, jnp.int32, jnp.uint32
@@ -88,6 +88,23 @@ def _programs(mesh):
             jax.random.PRNGKey(0), 64, 4,
             np.asarray([0.57, 0.19, 0.19, 0.05]), 0.0, noisy=False)),
         (names.RMAT_EDGE_ROWS, rmat.rmat_edge_rows.lower(col, col)),
+        (names.TRI_ORIENT, tri._programs(mesh).orient.lower(
+            *edges, small, canonical=False, by_id=True)),
+        (names.TRI_WEDGES, tri._programs(mesh).wedges.lower(
+            col, cnt2, cnt2, SDS((64,), jnp.int64), SDS((), jnp.int64),
+            SDS((), jnp.int64), batch=64)),
+        (names.TRI_APPEND, tri._programs(mesh).append.lower(
+            SDS((128,), u64), SDS((128,), i32), col, cnt2, SDS((), i32))),
+        (names.TRI_GROW, tri._programs(mesh).grow.lower(col, cnt2)),
+        (names.TRI_ROWS, tri._programs(mesh).rows.lower(
+            col, cnt2, small, rows=16, by_id=False)),
+        (names.LUBY_LOOP, luby._luby_sharded_fn(mesh, 16, 16).lower(
+            *edges, SDS((16,), i32))),
+        (names.SSSP_LOOP, sssp._bf_sharded_fn(mesh, 16, 16).lower(
+            edges[0], edges[1], SDS((64,), jnp.float64), edges[2],
+            SDS((), i32))),
+        (names.SSSP_WEIGHTS, sssp.sssp_weights.lower(
+            SDS((64,), jnp.float64), edges[2])),
     ]
 
 
@@ -104,6 +121,11 @@ def test_every_program_lowers_under_its_declared_name(mesh):
             # 30 sorts there and looks cheap here, so hold the program to it
             ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
             assert "sort" in ops and not ops & {"scatter", "gather"}, ops
+        if want in (names.TRI_ORIENT, names.TRI_WEDGES):
+            # the same rule for the wedge walk: sorts, no scatter, and no
+            # ``while`` (a searchsorted is a gather a round)
+            ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
+            assert "sort" in ops and not ops & {"scatter", "while"}, ops
     assert set(names.PROGRAMS) <= seen
     assert len(set(names.PROGRAMS)) == len(names.PROGRAMS)
     assert len(set(names.SPANS)) == len(names.SPANS)
@@ -177,6 +199,27 @@ def _graph_script(mesh, out):
         with open(os.path.join(out, fn), "rb") as f:
             files[fn] = f.read()
     return s.screen.getvalue(), files
+
+
+def _enum_script(mesh, out):
+    """The rest of the graph suite (ISSUE 32): triangles, Luby's set and
+    shortest paths, the weighted twin made as ``in.sssp`` makes it."""
+    from gpu_mapreduce_tpu.oink.script import OinkScript
+    s = OinkScript(comm=mesh, screen=io.StringIO())
+    for line in (
+            "rmat 7 8 0.57 0.19 0.19 0.05 0.0 1 -o NULL mre",
+            "edge_upper -i mre -o NULL mru",
+            "mr mrw",
+            "mrw map/mr mre add_weight",
+            f"tri_find -i mru -o {out}/tri mrt",
+            f"luby_find 6789 -i mru -o {out}/mis NULL",
+            f"sssp 1 12345 -i mrw -o {out}/sssp NULL"):
+        s.run_string(line)
+    files = {}
+    for fn in sorted(os.listdir(out)):
+        with open(os.path.join(out, fn), "rb") as f:
+            files[fn] = f.read()
+    return s, s.screen.getvalue(), files
 
 
 def _host_batch(mesh):
@@ -308,6 +351,43 @@ def test_graph_commands_emit_the_host_and_engine_spans(mesh, traced,
     assert args[names.CC_STAGE]["n"] == args[names.CC_EMIT]["n"] > 0
 
 
+def test_enumeration_commands_emit_their_spans(mesh, traced, tmp_path):
+    """``tri_find``, ``luby_find`` and ``sssp`` (ISSUE 32): stage, engine
+    and emit spans under their commands, the counts on the engine spans
+    equal to the messages'."""
+    s, screen, files = _enum_script(mesh, str(tmp_path))
+    assert set(files) >= {"mis", "sssp"}
+    tree = _tree(traced.events())
+    parents = _where(tree)
+    H, E = names.HOST, names.ENGINE
+    for cmd, stage, engine, emit in (
+            ("tri_find", names.TRI_STAGE, names.TRI_ENGINE, names.TRI_EMIT),
+            ("luby_find", names.LUBY_STAGE, names.LUBY_ENGINE,
+             names.LUBY_EMIT),
+            ("sssp", names.SSSP_STAGE, names.SSSP_ENGINE, names.SSSP_EMIT)):
+        assert parents.get(stage) == {(H, "oink." + cmd)}, stage
+        assert parents.get(engine) == {(E, "oink." + cmd)}, engine
+        assert parents.get(emit) == {(H, "oink." + cmd)}, emit
+    args = {n: a for n, _c, _p, a in tree}
+    walk = args[names.TRI_ENGINE]
+    ntri = int(re.search(r"Tri_find: (\d+) triangles", screen).group(1))
+    assert walk["triangles"] == ntri == args[names.TRI_EMIT]["triangles"] > 0
+    assert walk["wedges"] >= ntri and walk["batches"] >= 1
+    assert walk["edges"] == s.obj.get_mr("mru").kv.nkv
+    assert walk["n"] == args[names.TRI_STAGE]["n"] > walk["max_out_degree"] > 1
+    nset, rounds = map(int, re.search(
+        r"Luby_find: (\d+) MIS vertices in (\d+) iterations", screen).groups())
+    assert args[names.LUBY_ENGINE]["iters"] == rounds >= 1
+    assert args[names.LUBY_EMIT]["n"] == nset > 0
+    source, iters, labeled = map(int, re.search(
+        r"SSSP: source (\d+): (\d+) iterations, (\d+) vertices labeled",
+        screen).groups())
+    loop = args[names.SSSP_ENGINE]
+    assert (loop["source"], loop["iters"], loop["labeled"]) == (
+        source, iters, labeled)
+    assert args[names.SSSP_EMIT]["source"] == source
+
+
 def test_invertedindex_emits_the_map_and_part_file_spans(mesh, traced,
                                                          corpus, tmp_path):
     (npairs, nunique), parts, _idx = _invindex(mesh, corpus, str(tmp_path))
@@ -429,6 +509,8 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
     off_counts, off_parts, _ = _invindex(mesh, corpus, str(tmp_path / "i0"))
     off_rows = _host_batch(mesh)
     off_words = _wordfreq_script(mesh, corpus)
+    (tmp_path / "e0").mkdir(), (tmp_path / "e1").mkdir()
+    off_enum = _enum_script(mesh, str(tmp_path / "e0"))[1:]
     assert built == []          # every site returned NULL_SPAN
 
     tr.enable(ring=1 << 16)
@@ -439,12 +521,13 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
                                            str(tmp_path / "i1"))
         on_rows = _host_batch(mesh)
         on_words = _wordfreq_script(mesh, corpus)
+        on_enum = _enum_script(mesh, str(tmp_path / "e1"))[1:]
     finally:
         tr.clear()
         tr.disable()
     # every declared span name is one the program really opens
     assert set(names.SPANS) <= set(built)
-    assert on_graph == off_graph
+    assert on_graph == off_graph and on_enum == off_enum
     assert (on_counts, on_parts, on_rows, on_words) == (
         off_counts, off_parts, off_rows, off_words)
 
