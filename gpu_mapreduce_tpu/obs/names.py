@@ -128,7 +128,8 @@ INGEST_INTERN = "ingest.intern"                 # shard, words, unique,
 WORDFREQ_TOPN = "wordfreq.topn"                 # rows
 
 # older spans that metrics quote by name
-SHUFFLE_EXCHANGE = "shuffle.exchange"           # ..., recv_rows_max, _mean
+SHUFFLE_EXCHANGE = "shuffle.exchange"           # ..., recv_rows_max, _mean,
+#                                                 cols_rode, cols_by_index
 SHUFFLE_COUNT_SYNC = "shuffle.count_sync"
 OINK_RMAT = "oink.rmat"                         # rounds
 

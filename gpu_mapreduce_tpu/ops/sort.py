@@ -23,8 +23,90 @@ from typing import Callable, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..core.column import BytesColumn, Column, DenseColumn
+
+# ---------------------------------------------------------------------------
+# one payload sort (ROADMAP C21)
+# ---------------------------------------------------------------------------
+
+# The most 32-bit payload operands one sort carries; what does not fit
+# goes by the sorted row index and one ``take``.  Set by the sort's
+# COMPILE time, not its run time: on the v5e a u32[4194304, w] column
+# riding a sort by an int32 key runs in 0.017 / 0.032 / 0.059 s at
+# w = 4 / 8 / 16 where the index and a take run in 0.033 / 0.084 /
+# 0.138 s, so riding is 2-2.6 times quicker at every width read; but the
+# sort compiles in 42 / 87 / 238 s there and at w = 32 not within 19
+# minutes (the take form: 16 s whatever w).  PERF.md §6, PR 33.
+RIDE_WORDS = 8
+
+
+def sort_operands(col) -> int:
+    """32-bit operands a row of ``col`` puts into a sort: ``[n, w]`` goes
+    in as ``w`` columns, and the chip splits a 64-bit column in two."""
+    width = 1 if col.ndim == 1 else col.shape[1]
+    return width * max(1, col.dtype.itemsize // 4)
+
+
+def riding(carry) -> list:
+    """For each array of ``carry``, whether it reaches its sorted
+    position as a payload of the sort (True) or by the sorted row index
+    and a ``take`` (False) — decided by what the arrays themselves say,
+    dtype and width, in their order: a column rides while the sort's
+    payload operands stay within ``RIDE_WORDS``, and a 64-bit float
+    never does (the v5e sorts none and refuses its ``bitcast-convert``:
+    PERF.md §6, PR 32)."""
+    out, used = [], 0
+    for c in carry:
+        fits = (c.ndim <= 2
+                and not (c.dtype.kind in "fc" and c.dtype.itemsize >= 8)
+                and used + sort_operands(c) <= RIDE_WORDS)
+        used += sort_operands(c) if fits else 0
+        out.append(fits)
+    return out
+
+
+def sort_carrying(keys, carry=(), stable: bool = True):
+    """Sort rows by the 1-D ``keys`` (lexicographic, the first the most
+    significant) and bring every array of ``carry`` (``[n]`` or
+    ``[n, w]``) into the same order: ``(sorted keys, sorted carry)``.
+
+    ONE ``lax.sort``.  A column that rides (:func:`riding`) is an
+    operand of it;
+    ``[n, w]`` is split into its ``w`` columns and stacked again after,
+    because columns, not blocks, go into a sort (the chip stores
+    u64[n, 2] column-major and a reshape of it is tile-padded 64x:
+    PERF.md §6, PR 25).  The others share one more operand, the row
+    index, and are taken by it.  On the v5e a payload sort of 16.8 M
+    rows is 0.04-0.06 s where a scatter of them is 1.8 s and a gather
+    behind a key-only sort twice the sort (PRs 25, 29).
+
+    ``stable=False`` is 5-17 % quicker there (PRs 29, 33); use it where
+    tied rows carry nothing that is kept."""
+    keys = tuple(keys)
+    n = keys[0].shape[0]
+    rides = riding(carry)
+    operands = list(keys)
+    for c, r in zip(carry, rides):
+        if r:
+            operands += [c] if c.ndim == 1 else [
+                c[:, j] for j in range(c.shape[1])]
+    if not all(rides):
+        operands.append(jnp.arange(
+            n, dtype=jnp.int32 if n < 2 ** 31 else jnp.int64))
+    out = lax.sort(tuple(operands), num_keys=len(keys), is_stable=stable)
+    rest = iter(out[len(keys):])
+    scarry = []
+    for c, r in zip(carry, rides):
+        if not r:
+            scarry.append(jnp.take(c, out[-1], axis=0))   # the row index
+        elif c.ndim == 1:
+            scarry.append(next(rest))
+        else:
+            cols = [next(rest) for _ in range(c.shape[1])]
+            scarry.append(jnp.stack(cols, axis=1) if cols else c)
+    return tuple(out[:len(keys)]), scarry
 
 
 def argsort_column(col: Column, descending: bool = False,

@@ -7,7 +7,15 @@ Re-designs the reference's ``MapReduce::aggregate`` + ``Irregular`` stack
 phase 1 (jitted, per shard): hash each valid key to a destination shard
   (user hash or the lookup3 port — same default as
   ``hashlittle(key,bytes,nprocs)%nprocs``, src/mapreduce.cpp:469-472),
-  stable-sort rows by destination, count rows per destination.
+  then ONE stable sort keyed by the destination with the key and value
+  columns riding as its payloads (``ops/sort.sort_carrying``; a float64
+  column or a very wide row goes by the sorted row index and one
+  ``take`` instead, chosen from the array itself), so each destination's
+  rows become one contiguous run in their original order.  Rows per
+  destination, and with the wire codec each destination's key and value
+  range, are reductions over the ``P`` destinations.  No scatter and no
+  gather over the ``cap`` rows: on the v5e each cost tens of sorts
+  (PERF.md §6, PR 33).
 
 host: read the [P,P] count matrix, pick the padded bucket size B and the
   output capacity (rounded to powers of two to bound recompiles).  This
@@ -77,40 +85,38 @@ def default_hash(keys):
 
 _MAX_ROUNDS = 16     # unrolled in the jitted phase2; bounds trace size
 
-def _phase1_core(nprocs: int, dest_of: Callable, key, value, count):
-    """Per-shard: dest per row, stable sort rows by dest, per-dest
-    counts.  Padding rows get dest=nprocs (dropped later).  Returns the
-    per-row dest too so the wire variant's bucket stats share one dest
-    computation."""
-    cap = key.shape[0]
-    valid = jnp.arange(cap) < count
-    dest = jnp.where(valid, dest_of(key).astype(jnp.int32), nprocs)
-    order = jnp.argsort(dest, stable=True)
-    skey = jnp.take(key, order, axis=0)
-    svalue = jnp.take(value, order, axis=0)
-    counts_local = jnp.bincount(dest, length=nprocs + 1)[:nprocs].astype(jnp.int32)
-    return skey, svalue, counts_local, dest
-
-
-def _phase1(nprocs: int, dest_of: Callable, key, value, count):
-    return _phase1_core(nprocs, dest_of, key, value, count)[:3]
+def dest_counts(nprocs: int, dest):
+    """Rows per destination, ``[P]`` int32: ``P`` masked sums over the
+    destination column in one fused reduction, no scatter (a scatter-add
+    of 2^24 rows into five bins cost 1.4 s on the v5e, PERF.md §6,
+    PR 33).  Padding rows carry ``dest = nprocs`` and match nothing."""
+    hit = dest[:, None] == jnp.arange(nprocs, dtype=dest.dtype)
+    return jnp.sum(hit, axis=0, dtype=jnp.int32)
 
 
 def phase1_shard_body(nprocs: int, dest_of: Callable, wire_elig, k, v, c):
     """Per-shard phase-1 body — the composable twin of
     :func:`phase2_shard_body`: dest-sorted rows + per-dest counts, plus
     (``wire_elig`` set) the wire codec's per-bucket min/max stats
-    computed in the SAME pass (``parallel/wire.bucket_stats``).
+    (``parallel/wire.bucket_stats``).
     Returns ``(skey, svalue, counts_local, stats_or_None)``.  Shared by
     the standalone phase-1 program builder and the plan/ fuser's
     megafused single-dispatch programs, so their row layout can never
-    drift."""
-    sk, sv, cl, d = _phase1_core(nprocs, dest_of, k, v, c)
+    drift.
+
+    One stable sort by destination carries the rows (module docstring);
+    padding rows get ``dest = nprocs`` and sort last."""
+    from ..ops.sort import sort_carrying
+    cap = k.shape[0]
+    valid = jnp.arange(cap) < c
+    dest = jnp.where(valid, dest_of(k).astype(jnp.int32), nprocs)
+    _, (sk, sv) = sort_carrying((dest,), (k, v))
+    cl = dest_counts(nprocs, dest)
     if wire_elig is None:
         return sk, sv, cl, None
     from .wire import bucket_stats
     k_elig, v_elig = wire_elig
-    return sk, sv, cl, bucket_stats(nprocs, k, v, d, k_elig, v_elig)
+    return sk, sv, cl, bucket_stats(nprocs, k, v, dest, k_elig, v_elig)
 
 
 def _run_starts(counts):
@@ -311,8 +317,11 @@ def _dest_fn(dest, nprocs: int, mesh) -> Callable:
             # dest is monotone in the row index, so phase1's stable
             # dest-sort is the identity and the packed output preserves
             # exact global row order — reshard's byte-identity contract
+            # P compares a row, not a binary search: a searchsorted is a
+            # gather a round on the chip
             return jnp.searchsorted(jnp.asarray(ends, jnp.int64), g,
-                                    side="right").astype(jnp.int32)
+                                    side="right", method="compare_all"
+                                    ).astype(jnp.int32)
         return ranged
     raise ValueError(dest)
 
@@ -814,6 +823,11 @@ def _exchange_impl(skv: ShardedKV, dest, transport: int,
     sp.set(bucket=B_eff, nrounds=nrounds_eff, cap_out=cap_out_eff,
            rows=stats.rows, recv_rows_max=int(new_counts.max()),
            recv_rows_mean=float(new_counts.mean()))
+    # which form of phase 1 ran: columns that rode its sort, and columns
+    # taken by the sorted row index (ops/sort.riding)
+    from ..ops.sort import riding
+    rode = sum(riding((skv.key, skv.value)))
+    sp.set(cols_rode=rode, cols_by_index=2 - rode)
     # byte accounting ALWAYS lands on the per-call stats (and so the
     # live metrics + request profile), whether or not a Counters object
     # rides along — a direct reshard/gather caller without counters

@@ -5,7 +5,7 @@ values padded to a global per-bucket cap — the pad tax
 ``mrtpu_exchange_bytes_total{pad}`` measures on every run.  EQuARX
 (PAPERS.md) compresses collectives inside XLA at near-zero cost; here
 the compression can stay **byte-exact** because the metadata it needs is
-already on the host (the count matrix) or one tiny scatter away (per-
+already on the host (the count matrix) or one reduction away (per-
 bucket min/max stats, computed by phase 1 in the same program):
 
 * **delta-packed keys** — phase 1 records each per-destination bucket's
@@ -95,16 +95,21 @@ def _bits64(x):
 def bucket_stats(nprocs: int, key, value, dest, k_elig: bool,
                  v_elig: bool):
     """Per-destination (kmin, kmax, vmin, vmax) of this shard's valid
-    rows, [P, 4] uint64 bit patterns.  ``dest`` carries ``nprocs`` for
-    padding rows, so the scatters drop them; empty buckets keep their
+    rows, [P, 4] uint64 bit patterns.  A min and a max over each
+    destination's rows, as one fused reduction of a ``[cap, P]`` select
+    a column: no scatter (two of them over 2^24 rows cost 2.8 s on the
+    v5e, PERF.md §6, PR 33).  ``dest`` carries ``nprocs`` for padding
+    rows, which match no destination; empty buckets keep their
     sentinels and the host masks them via the count matrix."""
+    hit = dest[:, None] == jnp.arange(nprocs, dtype=dest.dtype)
+
     def minmax(col):
-        w = _widen(col)
+        w = _widen(col)[:, None]
         info = jnp.iinfo(w.dtype)
-        mn = jnp.full((nprocs,), info.max, w.dtype).at[dest].min(
-            w, mode="drop")
-        mx = jnp.full((nprocs,), info.min, w.dtype).at[dest].max(
-            w, mode="drop")
+        mn = jnp.min(jnp.where(hit, w, jnp.full((), info.max, w.dtype)),
+                     axis=0)
+        mx = jnp.max(jnp.where(hit, w, jnp.full((), info.min, w.dtype)),
+                     axis=0)
         return _bits64(mn), _bits64(mx)
 
     zero = jnp.zeros((nprocs,), jnp.uint64)

@@ -121,6 +121,12 @@ def test_every_program_lowers_under_its_declared_name(mesh):
             # 30 sorts there and looks cheap here, so hold the program to it
             ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
             assert "sort" in ops and not ops & {"scatter", "gather"}, ops
+        if want == names.SHUFFLE_PHASE1:
+            # the same rule a fourth time (PR 33): the rows ride one sort
+            # by destination, counts are masked sums; no ``while`` either
+            ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
+            assert "sort" in ops and not ops & {
+                "scatter", "gather", "while"}, ops
         if want in (names.TRI_ORIENT, names.TRI_WEDGES):
             # the same rule for the wedge walk: sorts, no scatter, and no
             # ``while`` (a searchsorted is a gather a round)
