@@ -1006,6 +1006,13 @@ class InvertedIndex:
         """Returns (total hits, unique urls).  Writes `url \\t files` lines
         to outdir/part-<proc> when outdir is given (reference myreduce,
         cuda/InvertedIndex.cu:463-513)."""
+        from ..obs import get_tracer, names
+        # the job's root span: its CPU and off-CPU seconds, context
+        # switches and what JAX built under it (doc/observability.md)
+        with get_tracer().span(names.INVINDEX_RUN, cat=names.ENTRY):
+            return self._run(paths, outdir, nfiles)
+
+    def _run(self, paths, outdir, nfiles) -> Tuple[int, int]:
         mr = MapReduce(self.comm, mapstyle=self.mapstyle)
         self._mr = mr
         self._reset_stats()

@@ -1383,9 +1383,11 @@ class MapReduce:
     def stats(self) -> dict:
         """The structured cumulative snapshot that ``cummulative_stats``
         prints: every Counters field by name (msizemax, rsize, wsize,
-        cssize, crsize, cspad, commtime, msize, ndispatch), plus — when
-        tracing is enabled (obs/) — an ``"ops"`` per-op aggregate over
-        the span ring (count / total_s / byte sums per op name), plus a
+        cssize, crsize, cspad, commtime, msize, ndispatch, the four
+        jit_*), plus — when tracing is enabled (obs/) — an ``"ops"``
+        per-op aggregate over the span ring (count / total_s / byte sums
+        per op name) and ``"programs"``, per program what JAX lowered,
+        compiled and loaded since (obs.programs()), plus a
         ``"plan"`` section with the compile-cache telemetry (plan cache
         + bounded shuffle jit caches: hits/misses/evictions) and the
         cumulative fusion-effectiveness counters (``"fusion"``:
@@ -1400,6 +1402,8 @@ class MapReduce:
         out = self.counters.snapshot()
         if self.tracer.enabled:
             out["ops"] = self.tracer.stats()
+            from ..obs import programs
+            out["programs"] = programs()
         from ..plan.cache import cache_stats
         out["plan"] = cache_stats()
         # overlap telemetry (exec/): per-path busy/wait seconds and the

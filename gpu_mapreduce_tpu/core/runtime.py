@@ -138,6 +138,14 @@ class Counters:
     #                         plan/ fusion is meant to shrink; a kernel
     #                         traced inside a jit rides that program's
     #                         count, so megafused pipelines read 1
+    # JAX's own compile-path reports (jax.monitoring), fed by the obs/
+    # tracer's listener while a tracer is on and 0 otherwise:
+    jit_lowerings: int = 0      # programs traced and lowered, whatever
+    #                             the compile cache did next
+    jit_lower_s: float = 0.0    # their seconds (jaxpr → MLIR module)
+    jit_backend_s: float = 0.0  # seconds in the backend: a compile, or
+    #                             a load from the persistent cache
+    jit_cache_loads: int = 0    # of those, the loads
 
     def __post_init__(self):
         import threading
@@ -177,7 +185,11 @@ class Counters:
                     "rsize": self.rsize, "wsize": self.wsize,
                     "cssize": self.cssize, "crsize": self.crsize,
                     "cspad": self.cspad, "commtime": self.commtime,
-                    "ndispatch": self.ndispatch}
+                    "ndispatch": self.ndispatch,
+                    "jit_lowerings": self.jit_lowerings,
+                    "jit_lower_s": self.jit_lower_s,
+                    "jit_backend_s": self.jit_backend_s,
+                    "jit_cache_loads": self.jit_cache_loads}
 
 
 class PageAccount:

@@ -28,7 +28,7 @@ the Perfetto how-to.
 """
 
 from .tracer import (NULL_SPAN, Span, Tracer, configure_from_env,
-                     get_tracer)
+                     get_tracer, programs)
 from .sinks import (CallbackSink, JsonlSink, RingSink, chrome_trace,
                     read_jsonl, write_chrome_trace)
 from .report import aggregate_ops, per_op_table
@@ -38,6 +38,7 @@ from .context import (RequestAccount, current_trace_id, new_trace_id,
 
 __all__ = [
     "Tracer", "Span", "NULL_SPAN", "get_tracer", "configure_from_env",
+    "programs",
     "RingSink", "JsonlSink", "CallbackSink",
     "chrome_trace", "write_chrome_trace", "read_jsonl",
     "aggregate_ops", "per_op_table",

@@ -78,6 +78,13 @@ def declared_program(module: str) -> bool:
 # -- span categories ----------------------------------------------------------
 HOST = "host"           # host work inside an op, stage or command
 ENGINE = "engine"       # a device loop, from dispatch to the pull that ends it
+ENTRY = "entry"         # one root span a job: the call into an entry point
+
+# -- entry-point spans (cat ENTRY) --------------------------------------------
+# every span says cpu_s / off_cpu_s; these also proc_cpu_s, sys_cpu_s,
+# vol_switches, invol_switches and the four jit_* deltas, zero or not
+INVINDEX_RUN = "invindex.run"       # apps/invertedindex.InvertedIndex.run
+OINK_SCRIPT = "oink.script"         # oink/script: the outermost script run
 
 # -- host-phase spans (cat HOST unless said) ----------------------------------
 # parallel/shuffle.aggregate_kv, before the exchange / the one-chip early-out
@@ -142,6 +149,7 @@ SPANS = (
     INGEST_TOKENIZE, INGEST_INTERN, WORDFREQ_TOPN,
     TRI_STAGE, TRI_ENGINE, TRI_EMIT, LUBY_STAGE, LUBY_ENGINE, LUBY_EMIT,
     SSSP_STAGE, SSSP_ENGINE, SSSP_EMIT,
+    INVINDEX_RUN, OINK_SCRIPT,
 )
 
 # -- attrs that metrics quote by name -----------------------------------------
@@ -151,3 +159,24 @@ CONVERT_SPAN = "convert"
 ATTR_ROWS = "rows"
 ATTR_GROUPS = "groups"
 ATTR_GROUP_ROWS_MAX = "group_rows_max"
+# on every span (obs/tracer.Span): the thread's CPU seconds, and the rest of
+# the span's wall (blocked on the device, a file, a lock, or descheduled)
+ATTR_CPU_S = "cpu_s"
+ATTR_OFF_CPU_S = "off_cpu_s"
+# on ENTRY spans only: every thread's CPU seconds, the kernel's share of
+# the calling thread's cpu_s, its context switches, and what JAX lowered
+# and loaded under the span
+ATTR_PROC_CPU_S = "proc_cpu_s"
+ATTR_SYS_CPU_S = "sys_cpu_s"
+ATTR_VOL_SWITCHES = "vol_switches"
+ATTR_INVOL_SWITCHES = "invol_switches"
+ATTR_JIT_LOWERINGS = "jit_lowerings"
+ATTR_JIT_LOWER_S = "jit_lower_s"
+ATTR_JIT_BACKEND_S = "jit_backend_s"
+ATTR_JIT_CACHE_LOADS = "jit_cache_loads"
+SPAN_ATTRS = (
+    ATTR_ROWS, ATTR_GROUPS, ATTR_GROUP_ROWS_MAX, ATTR_CPU_S, ATTR_OFF_CPU_S,
+    ATTR_PROC_CPU_S, ATTR_SYS_CPU_S, ATTR_VOL_SWITCHES, ATTR_INVOL_SWITCHES,
+    ATTR_JIT_LOWERINGS, ATTR_JIT_LOWER_S, ATTR_JIT_BACKEND_S,
+    ATTR_JIT_CACHE_LOADS,
+)

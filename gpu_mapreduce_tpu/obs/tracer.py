@@ -1,8 +1,10 @@
 """Thread-safe tracer with nested spans.
 
-A span records wall time, deltas of the cumulative ``runtime.Counters``
-(bytes shuffled/padded/spilled, HBM hi-water), execution tier and
-arbitrary op metadata.  Nesting is per thread (a thread-local stack), so
+A span records wall time, the CPU seconds of its own thread beside it
+(``cpu_s``; ``off_cpu_s`` is the rest: blocked or descheduled), deltas of
+the cumulative ``runtime.Counters`` (bytes shuffled/padded/spilled, HBM
+hi-water, programs lowered and loaded), execution tier and arbitrary op
+metadata.  Nesting is per thread (a thread-local stack), so
 ``collate`` naturally parents ``aggregate``/``convert``, which parent
 the shuffle's ``exchange`` span, and the ``-partition`` universe's
 concurrent interpreter threads each get their own stack.
@@ -17,20 +19,32 @@ MapReduce objects, like the reference's static stats): when concurrent
 ``-partition`` worlds overlap, a span may attribute another world's
 bytes to itself.  Wall time and nesting stay correct per thread.
 
+JAX reports what its compile path costs (``jax.monitoring``): seconds to
+lower a traced program, seconds in the backend (a compile, or a load from
+the persistent cache), each with the program's name.  The first
+``enable()`` of a process registers one duration listener and one event
+listener; while a tracer is on they feed its counters' ``jit_*`` fields
+(so every span shows what was built under it) and a per-program table,
+:func:`programs`.  With every tracer off they return at once.
+
 Zero-cost when disabled: ``span()`` returns the shared :data:`NULL_SPAN`
-singleton — one attribute check, no allocation.
+singleton — one attribute check, no allocation, no clock read; nothing
+is registered with JAX until a tracer is enabled.
 """
 
 from __future__ import annotations
 
 import os
+import resource
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional
 
-from ..utils.env import env_flag, env_knob, env_str
+from ..utils.env import env_knob, env_str
 from .context import current_trace_id as _ctx_trace_id
 from .context import note_span as _ctx_note_span
+from . import names
 
 # Counters fields snapshotted at span entry; the exit delta lands in the
 # span's args under the mapped name (only when nonzero, to keep traces
@@ -42,7 +56,15 @@ _DELTA_FIELDS = (
     ("wsize", "spill_write_bytes"),
     ("rsize", "spill_read_bytes"),
     ("ndispatch", "dispatches"),
+    ("jit_lowerings", names.ATTR_JIT_LOWERINGS),
+    ("jit_lower_s", names.ATTR_JIT_LOWER_S),
+    ("jit_backend_s", names.ATTR_JIT_BACKEND_S),
+    ("jit_cache_loads", names.ATTR_JIT_CACHE_LOADS),
 )
+# an ``entry`` span states these even at zero: "nothing was rebuilt under
+# this job" is a reading, not a missing one
+_JIT_LABELS = tuple(label for _, label in _DELTA_FIELDS
+                    if label.startswith("jit_"))
 
 
 class _NullSpan:
@@ -73,7 +95,8 @@ class Span:
     """
 
     __slots__ = ("tracer", "name", "cat", "attrs", "span_id", "parent_id",
-                 "t0", "t1", "_snap", "_mem0", "_jax_ctx", "trace_id")
+                 "t0", "t1", "_cpu0", "_proc0", "_snap", "_mem0",
+                 "_jax_ctx", "trace_id")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict):
         self.tracer = tracer
@@ -83,6 +106,8 @@ class Span:
         self.span_id = 0
         self.parent_id = 0
         self.t0 = self.t1 = 0.0
+        self._cpu0 = 0.0
+        self._proc0 = None
         self._snap = None
         self._mem0 = 0
         self._jax_ctx = None
@@ -105,18 +130,43 @@ class Span:
         c = tr.counters
         self._snap = tuple(getattr(c, f) for f, _ in _DELTA_FIELDS)
         self._mem0 = c.msizemax
-        if tr.jax_annotations:
-            try:
-                import jax
-                self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
-            except Exception:
-                self._jax_ctx = None  # no profiler backend: spans still work
+        # on the profiler's host plane the annotation is what attributes
+        # a device idle gap to this span; with no profiler running it
+        # costs next to nothing
+        try:
+            import jax
+            self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
+            self._jax_ctx.__enter__()
+        except Exception:
+            self._jax_ctx = None  # no profiler backend: spans still work
+        if self.cat == names.ENTRY:
+            # one span a job: what costs a system call is read here only
+            ru = resource.getrusage(resource.RUSAGE_THREAD)
+            self._proc0 = (time.process_time(), ru.ru_stime, ru.ru_nvcsw,
+                           ru.ru_nivcsw)
+        self._cpu0 = time.thread_time()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.t1 = time.perf_counter()
+        dur = self.t1 - self.t0
+        # a thread cannot use more CPU than wall: a kernel that charges
+        # CPU time by the 10 ms tick (the TPU hosts do) can hand a short
+        # span a whole tick
+        cpu = min(time.thread_time() - self._cpu0, dur)
+        self.attrs[names.ATTR_CPU_S] = round(cpu, 6)
+        self.attrs[names.ATTR_OFF_CPU_S] = round(dur - cpu, 6)
+        if self._proc0 is not None:
+            proc0, sys0, vol0, invol0 = self._proc0
+            ru = resource.getrusage(resource.RUSAGE_THREAD)
+            self.attrs[names.ATTR_PROC_CPU_S] = round(
+                time.process_time() - proc0, 6)
+            self.attrs[names.ATTR_SYS_CPU_S] = round(ru.ru_stime - sys0, 6)
+            self.attrs[names.ATTR_VOL_SWITCHES] = ru.ru_nvcsw - vol0
+            self.attrs[names.ATTR_INVOL_SWITCHES] = ru.ru_nivcsw - invol0
+            # stated even at zero; the deltas below overwrite what moved
+            self.attrs.update(dict.fromkeys(_JIT_LABELS, 0))
         if self._jax_ctx is not None:
             try:
                 self._jax_ctx.__exit__(exc_type, exc, tb)
@@ -131,7 +181,7 @@ class Span:
         for (field, label), before in zip(_DELTA_FIELDS, self._snap):
             d = getattr(c, field) - before
             if d:
-                self.attrs[label] = d
+                self.attrs[label] = round(d, 6) if isinstance(d, float) else d
         if c.msizemax != self._mem0:
             self.attrs["hbm_hiwater_bytes"] = c.msizemax
         if exc_type is not None:
@@ -139,7 +189,7 @@ class Span:
         # per-request stage profile (obs/context.py): the finished
         # span's wall + counter deltas land on the active account too —
         # same numbers, scoped to the request instead of the process
-        _ctx_note_span(self.name, self.cat, self.t1 - self.t0, self.attrs)
+        _ctx_note_span(self.name, self.cat, dur, self.attrs)
         tr._emit(self)
         return False
 
@@ -160,6 +210,78 @@ class Span:
         return ev
 
 
+# -- JAX's compile path, as it reports itself ---------------------------------
+# jaxpr_trace_duration is left out: it nests (a jitted function calling a
+# jitted jnp function reports the inner trace inside the outer one's
+# seconds), so a sum of its events counts twice.
+_JAX_LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_JAX_BACKEND = "/jax/core/compile/backend_compile_duration"
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+_JAX_LOCK = threading.Lock()
+_JAX_TLS = threading.local()        # .hit: a cache hit not yet charged
+_JAX_REGISTERED = False
+_LISTENING: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_PROGRAMS: Dict[str, dict] = {}
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    if event == _JAX_CACHE_HIT:
+        _JAX_TLS.hit = True     # the same thread's backend event follows
+
+
+def _on_jax_duration(event: str, seconds: float, fun_name: str = "?",
+                     **_kw) -> None:
+    if event == _JAX_LOWERED:
+        deltas = {"jit_lowerings": 1, "jit_lower_s": seconds}
+    elif event == _JAX_BACKEND:
+        loaded = getattr(_JAX_TLS, "hit", False)
+        _JAX_TLS.hit = False
+        deltas = {"jit_backend_s": seconds, "jit_cache_loads": int(loaded)}
+    else:
+        return
+    # jax says "jit(convert_sort)"; a device trace, and obs/names.py,
+    # say "jit_convert_sort"
+    name = fun_name.replace("(", "_").rstrip(")")
+    with _JAX_LOCK:
+        # the counters of the tracers that are on, each once (private
+        # tracers may share the process's)
+        live = {id(t.counters): t.counters for t in _LISTENING if t.enabled}
+        if not live:
+            return
+        row = _PROGRAMS.setdefault(name, {"lowerings": 0, "lower_s": 0.0,
+                                          "backend_s": 0.0, "cache_loads": 0})
+        for field, d in deltas.items():
+            row[field[len("jit_"):]] += d
+    for counters in live.values():
+        counters.add(**deltas)
+
+
+def _listen(tracer: "Tracer") -> None:
+    """Feed ``tracer``'s counters from now on; the first call of a process
+    registers the two listeners with JAX (never an import)."""
+    global _JAX_REGISTERED
+    with _JAX_LOCK:
+        _LISTENING.add(tracer)
+        if _JAX_REGISTERED:
+            return
+        _JAX_REGISTERED = True
+    import jax.monitoring
+    jax.monitoring.register_event_listener(_on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def programs() -> Dict[str, dict]:
+    """What was built while a tracer was on, a program: ``{name:
+    {lowerings, lower_s, backend_s, cache_loads}}``.  ``lowerings`` counts
+    the times the program was traced and lowered (whatever the cache did
+    next), ``backend_s`` is compiling or loading from the persistent
+    cache, ``cache_loads`` how many of those were loads.  Names are the
+    device trace's (``jit_convert_sort``)."""
+    with _JAX_LOCK:
+        return {name: dict(row) for name, row in _PROGRAMS.items()}
+
+
 class Tracer:
     """Span factory + sink fan-out.  One per process normally
     (:func:`get_tracer`); tests may build private instances."""
@@ -174,7 +296,6 @@ class Tracer:
         # stamps rank= here so one multi-rank trace merge stays
         # attributable without threading rank through call signatures)
         self.proc_attrs: dict = {}
-        self.jax_annotations = env_flag("MRTPU_TRACE_JAX", True)
         self.epoch = time.perf_counter()
         # wall-clock origin of the perf_counter timeline: lets a
         # cross-process merge (trace_view over per-rank shards) rebase
@@ -243,6 +364,7 @@ class Tracer:
                 sink = JsonlSink(jsonl)
                 self._jsonl[jsonl] = sink
                 self._sinks.append(sink)
+        _listen(self)
         self.enabled = True
         return self
 
@@ -287,8 +409,12 @@ class Tracer:
                                    and s.fn == fn)]
 
     def reset(self) -> None:
-        """Drop sinks/events and disable (test isolation)."""
+        """Drop sinks/events and the per-program table, and disable (test
+        isolation).  JAX's listeners stay registered and do nothing until
+        a tracer is enabled again."""
         self.enabled = False
+        with _JAX_LOCK:
+            _PROGRAMS.clear()
         with self._lock:
             for s in self._sinks:
                 close = getattr(s, "close", None)

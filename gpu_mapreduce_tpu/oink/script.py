@@ -164,8 +164,14 @@ class OinkScript:
                 # request) and no-ops under MRTPU_PROFILE=0; nested
                 # include/jump runs arrive at depth > 1 and never
                 # re-scope
+                from ..obs import get_tracer, names
                 from ..obs.context import ensure_scope
-                with ensure_scope(label=f"oink:{name}"):
+                # ... and one root span (cat entry): the run's CPU and
+                # off-CPU seconds, context switches and what JAX built
+                # under it; an include opens none
+                with ensure_scope(label=f"oink:{name}"), \
+                        get_tracer().span(names.OINK_SCRIPT,
+                                          cat=names.ENTRY, script=name):
                     self._run_lines(lines, name)
             else:
                 self._run_lines(lines, name)

@@ -1477,13 +1477,15 @@ class Server:
 
     # -- request-scoped observability (obs/context.py) ---------------------
     def _span_feed(self, ev: dict) -> None:
-        """Tracer sink: a finished TOP-LEVEL span whose trace_id maps
-        to a watched session becomes one event on that session's
-        stream.  Must never raise (the tracer drops raising sinks) and
-        must stay cheap — it runs on every span emission process-wide."""
+        """Tracer sink: a finished script COMMAND span (cat ``oink``,
+        under the run's ``oink.script`` root) or parentless span whose
+        trace_id maps to a watched session becomes one event on that
+        session's stream.  Must never raise (the tracer drops raising
+        sinks) and must stay cheap — it runs on every span emission
+        process-wide."""
         try:
             tid = ev.get("trace")
-            if not tid or ev.get("parent"):
+            if not tid or (ev.get("parent") and ev.get("cat") != "oink"):
                 return
             with self._watch_lock:
                 sid = self._trace_sids.get(tid)

@@ -495,6 +495,66 @@ def test_host_convert_says_its_hub_group(traced):
             conv[names.ATTR_GROUP_ROWS_MAX]) == (6, 3, 3)
 
 
+def _ancestors(events):
+    by_id = {e["id"]: e for e in events}
+
+    def chain(e):
+        while e["parent"]:
+            e = by_id[e["parent"]]
+            yield e["id"]
+
+    return {e["id"]: set(chain(e)) for e in events}
+
+
+@pytest.mark.parametrize("entry", ["invindex", "oink", "oink-include"])
+def test_an_entry_point_call_is_one_root_span(mesh, traced, corpus,
+                                              tmp_path, entry):
+    """ISSUE 34: one ``entry`` span a call into an entry point, parent 0,
+    above every other span of the calling thread; an ``include`` runs
+    inside its caller's and opens none; every span of the run carries its
+    thread's CPU seconds and the rest of its wall."""
+    if entry == "invindex":
+        _invindex(mesh, corpus, str(tmp_path))
+        name, calls = names.INVINDEX_RUN, 1
+    else:
+        from gpu_mapreduce_tpu.oink.script import OinkScript
+        lines = ["rmat 6 4 0.57 0.19 0.19 0.05 0.0 1 -o NULL mre",
+                 "edge_upper -i mre -o NULL mru"]
+        s = OinkScript(comm=mesh, screen=io.StringIO())
+        if entry == "oink":
+            for line in lines:              # a job of two calls: two roots
+                s.run_string(line)
+            calls = 2
+        else:
+            inc = tmp_path / "in.upper"
+            inc.write_text(lines[1] + "\n")
+            s.run_string(f"{lines[0]}\ninclude {inc}\n")
+            calls = 1
+        name = names.OINK_SCRIPT
+    events = traced.events()
+    roots = [e for e in events if e["cat"] == names.ENTRY]
+    assert [e["name"] for e in roots] == [name] * calls
+    assert all(e["parent"] == 0 for e in roots)
+    above = _ancestors(events)
+    root_ids = {e["id"] for e in roots}
+    (tid,) = {e["tid"] for e in roots}
+    mine = [e for e in events if e["tid"] == tid and e["id"] not in root_ids]
+    assert mine and all(len(above[e["id"]] & root_ids) == 1 for e in mine)
+    if entry == "oink-include":
+        assert {"oink.rmat", "oink.edge_upper"} <= {e["name"] for e in mine}
+    for e in events:
+        a, dur = e["args"], e["dur"] * 1e-6
+        assert a[names.ATTR_CPU_S] >= 0 and a[names.ATTR_OFF_CPU_S] >= 0
+        assert abs(a[names.ATTR_CPU_S] + a[names.ATTR_OFF_CPU_S] - dur) \
+            <= max(0.01 * dur, 1e-3), (e["name"], a, dur)
+    for e in roots:
+        assert {names.ATTR_PROC_CPU_S, names.ATTR_SYS_CPU_S,
+                names.ATTR_VOL_SWITCHES,
+                names.ATTR_INVOL_SWITCHES, names.ATTR_JIT_LOWERINGS,
+                names.ATTR_JIT_LOWER_S, names.ATTR_JIT_BACKEND_S,
+                names.ATTR_JIT_CACHE_LOADS} <= set(e["args"])
+
+
 def test_tracer_off_constructs_no_span_and_changes_nothing(
         mesh, corpus, tmp_path, monkeypatch):
     tr = get_tracer()

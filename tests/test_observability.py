@@ -4,6 +4,7 @@ spill/comm deltas, and tier notes — plus the structured obs/ tracing
 layer (spans, sinks, Chrome export, mr.stats())."""
 
 import json
+import os
 
 import numpy as np
 import pytest
@@ -265,3 +266,313 @@ def test_dump_trace_script_command(tmp_path, tracer):
     names = {e["name"] for e in doc["traceEvents"]}
     assert "oink.wordfreq" in names            # script-command span
     assert {"map_files", "collate", "reduce"} <= names
+
+
+# ---------------------------------------------------------------------------
+# the host's half of a span (PR 34): CPU beside wall, JAX's own compile
+# seconds as counter deltas, the per-program table
+# ---------------------------------------------------------------------------
+
+def _burn(seconds):
+    """Spin until this THREAD has used ``seconds`` of CPU."""
+    import time
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < seconds:
+        pass
+
+
+def _private_tracer():
+    from gpu_mapreduce_tpu.core.runtime import Counters
+    from gpu_mapreduce_tpu.obs import Tracer
+    return Tracer(counters=Counters()).enable()
+
+
+def _sums_to_wall(ev):
+    a = ev["args"]
+    dur = ev["dur"] * 1e-6
+    return abs(a["cpu_s"] + a["off_cpu_s"] - dur) <= max(0.01 * dur, 1e-3)
+
+
+@pytest.mark.parametrize("what", ["busy", "sleep"])
+def test_span_says_cpu_beside_wall(what):
+    import time
+    tr = _private_tracer()
+    try:
+        # a busy loop can lose its core to the other test workers: the
+        # CPU seconds are exact, their share of the wall is best of three
+        for _ in range(3):
+            tr.clear()
+            with tr.span(what):
+                _burn(0.2) if what == "busy" else time.sleep(0.2)
+            (ev,) = tr.events()
+            a, wall = ev["args"], ev["dur"] * 1e-6
+            assert _sums_to_wall(ev)
+            if what == "sleep":
+                assert a["off_cpu_s"] >= 0.18 and a["cpu_s"] < 0.05
+                break
+            assert 0.2 <= a["cpu_s"] <= wall + 1e-3
+            if a["cpu_s"] >= 0.8 * wall:
+                break
+        else:
+            pytest.fail(f"a busy loop read {a} of {wall} s wall")
+        # a plain span costs no system call: the process-wide readings
+        # are the entry category's
+        assert not {"proc_cpu_s", "sys_cpu_s", "vol_switches",
+                    "invol_switches"} & set(a)
+    finally:
+        tr.reset()
+
+
+def test_a_coarse_cpu_clock_cannot_pass_the_wall(monkeypatch):
+    """Where the kernel charges CPU time by the tick, a span of half a
+    millisecond can be handed 10 ms: ``cpu_s`` stops at the wall."""
+    import time
+    ticks = iter([1.00, 1.01])
+    monkeypatch.setattr(time, "thread_time", lambda: next(ticks))
+    tr = _private_tracer()
+    try:
+        with tr.span("short"):
+            pass
+        (ev,) = tr.events()
+        assert ev["dur"] * 1e-6 < 0.01
+        assert ev["args"]["cpu_s"] == pytest.approx(ev["dur"] * 1e-6, abs=2e-6)
+        assert ev["args"]["off_cpu_s"] == pytest.approx(0, abs=2e-6)
+    finally:
+        tr.reset()
+
+
+def test_span_cpu_is_its_own_threads():
+    """A span in a second thread carries that thread's CPU seconds, the
+    main thread's span its own, whichever of the two was busy."""
+    import threading
+    import time
+    tr = _private_tracer()
+
+    def worker(busy):
+        with tr.span("worker", busy=busy):
+            _burn(0.2) if busy else time.sleep(0.25)
+
+    try:
+        for worker_busy in (True, False):
+            t = threading.Thread(target=worker, args=(worker_busy,))
+            with tr.span("main", busy=not worker_busy):
+                t.start()
+                time.sleep(0.25) if worker_busy else _burn(0.2)
+                t.join(timeout=60)
+            assert not t.is_alive()
+        evs = {(e["name"], e["args"]["busy"]): e for e in tr.events()}
+        assert len(evs) == 4 and all(map(_sums_to_wall, evs.values()))
+        for name in ("main", "worker"):
+            assert evs[name, True]["args"]["cpu_s"] >= 0.2
+            assert evs[name, False]["args"]["cpu_s"] < 0.05
+            assert evs[name, False]["args"]["off_cpu_s"] >= 0.18
+        assert evs["main", True]["tid"] != evs["worker", True]["tid"]
+    finally:
+        tr.reset()
+
+
+def test_entry_span_reads_the_process_and_the_switches():
+    import threading
+    import time
+    from gpu_mapreduce_tpu.obs import names
+    tr = _private_tracer()
+    try:
+        t = threading.Thread(target=_burn, args=(0.2,))
+        with tr.span("job", cat=names.ENTRY):
+            t.start()
+            for _ in range(5):
+                time.sleep(0.01)        # a voluntary switch each
+            t.join(timeout=60)
+            with tr.span("child"):
+                pass
+        child, job = tr.events()
+        a = job["args"]
+        # every thread's CPU, not only the caller's
+        assert a["proc_cpu_s"] >= 0.2 > a["cpu_s"]
+        assert a["vol_switches"] >= 5 and a["invol_switches"] >= 0
+        # the kernel's share of the calling thread's CPU seconds (the
+        # two clocks tick apart: a tick of slack)
+        assert 0 <= a["sys_cpu_s"] <= a["cpu_s"] + 0.011
+        # "nothing was built under this job" is stated, not left out
+        assert (a["jit_lowerings"], a["jit_lower_s"], a["jit_backend_s"],
+                a["jit_cache_loads"]) == (0, 0, 0, 0)
+        assert "jit_lowerings" not in child["args"]
+        assert "proc_cpu_s" not in child["args"]
+    finally:
+        tr.reset()
+
+
+def _fresh_programs():
+    """Two jitted functions nobody has traced yet, one calling a jitted
+    ``jnp`` function."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def obs_probe_plain(x):
+        return x * 3 + 1
+
+    @jax.jit
+    def obs_probe_nested(x):
+        return jnp.sort(x)[::-1] - 2        # jnp.sort is jitted itself
+
+    return obs_probe_plain, obs_probe_nested
+
+
+def test_jit_seconds_land_on_the_span_and_in_the_program_table(tracer):
+    import jax.numpy as jnp
+    from gpu_mapreduce_tpu import obs
+    plain, nested = _fresh_programs()
+    x = jnp.arange(64.0)
+    x.block_until_ready()
+    tracer.enable()
+    tracer.clear()
+    with tracer.span("outer"):
+        with tracer.span("first"):
+            plain(x).block_until_ready()
+        with tracer.span("second"):
+            plain(x).block_until_ready()
+        with tracer.span("nested"):
+            nested(x).block_until_ready()
+    args = {e["name"]: e["args"] for e in tracer.events()}
+    first = args["first"]
+    assert first["jit_lowerings"] == 1
+    assert first["jit_lower_s"] > 0 and first["jit_backend_s"] > 0
+    # a second call is a dispatch: nothing is lowered, nothing loaded
+    assert not {k for k in args["second"] if k.startswith("jit_")}
+    # the inner jitted function is traced into its caller: one program
+    assert args["nested"]["jit_lowerings"] == 1
+    # the enclosing span saw both
+    assert args["outer"]["jit_lowerings"] == 2
+    assert args["outer"]["jit_lower_s"] == pytest.approx(
+        first["jit_lower_s"] + args["nested"]["jit_lower_s"], abs=1e-5)
+    table = obs.programs()
+    for fn, span in ((plain, "first"), (nested, "nested")):
+        row = table["jit_" + fn.__name__]
+        assert row["lowerings"] == 1
+        assert row["lower_s"] == pytest.approx(args[span]["jit_lower_s"],
+                                               abs=1e-5)
+        assert row["backend_s"] == pytest.approx(args[span]["jit_backend_s"],
+                                                 abs=1e-5)
+    # mr.stats() is the operator's way to the same table and totals
+    stats = MapReduce().stats()
+    assert stats["programs"]["jit_obs_probe_plain"]["lowerings"] == 1
+    assert stats["jit_lowerings"] >= 2 and stats["jit_backend_s"] > 0
+
+
+@pytest.fixture
+def tmp_compile_cache(tmp_path):
+    """JAX's persistent cache in a directory of the test's own, every
+    program kept; the process's settings back afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update(keys[0], str(tmp_path / "jaxcache"))
+    jax.config.update(keys[1], 0.0)
+    jax.config.update(keys[2], -1)
+    compilation_cache.reset_cache()
+    yield
+    for k, v in was.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_a_load_from_the_persistent_cache_is_counted(tracer,
+                                                     tmp_compile_cache):
+    import jax
+    import jax.numpy as jnp
+    from gpu_mapreduce_tpu import obs
+    plain, _ = _fresh_programs()
+    x = jnp.arange(32.0)
+    x.block_until_ready()
+    tracer.enable()
+    tracer.clear()
+    with tracer.span("cold"):
+        plain(x).block_until_ready()
+    jax.clear_caches()              # the process forgets; the disk does not
+    with tracer.span("warm"):
+        plain(x).block_until_ready()
+    cold, warm = (e["args"] for e in tracer.events())
+    assert cold["jit_lowerings"] == 1 and "jit_cache_loads" not in cold
+    # traced and lowered again, then served by the cache: what
+    # requests − hits reads as 0
+    assert warm["jit_lowerings"] == 1 and warm["jit_cache_loads"] == 1
+    assert warm["jit_lower_s"] > 0 and warm["jit_backend_s"] > 0
+    row = obs.programs()["jit_obs_probe_plain"]
+    assert (row["lowerings"], row["cache_loads"]) == (2, 1)
+
+
+def test_reset_clears_the_program_table_and_leaves_the_listener_inert(
+        tracer):
+    import jax.numpy as jnp
+    from gpu_mapreduce_tpu import obs
+    from gpu_mapreduce_tpu.core.runtime import global_counters
+    plain, nested = _fresh_programs()
+    x = jnp.arange(16.0)
+    x.block_until_ready()
+    tracer.enable()
+    plain(x).block_until_ready()
+    assert "jit_obs_probe_plain" in obs.programs()
+    tracer.reset()
+    assert obs.programs() == {}
+    before = global_counters().snapshot()
+    nested(x).block_until_ready()           # lowered with every tracer off
+    after = global_counters().snapshot()
+    assert obs.programs() == {}
+    assert [after[k] for k in after if k.startswith("jit_")] == [
+        before[k] for k in before if k.startswith("jit_")]
+
+
+def test_off_means_off(tracer, tmp_path, monkeypatch):
+    """With the tracer off a span site is one attribute check: no clock
+    of the thread or the process is read, no rusage, and importing the
+    package has registered nothing with ``jax.monitoring``."""
+    import resource
+    import subprocess
+    import sys
+    import time
+
+    from gpu_mapreduce_tpu.obs import NULL_SPAN, names
+    from gpu_mapreduce_tpu.oink.script import OinkScript
+
+    def boom(*_a, **_kw):
+        raise AssertionError("read with the tracer off")
+
+    words = tmp_path / "w.txt"
+    words.write_text("a b b c c c\n")
+    monkeypatch.setattr(time, "thread_time", boom)
+    monkeypatch.setattr(time, "process_time", boom)
+    monkeypatch.setattr(resource, "getrusage", boom)
+    assert tracer.span("x") is NULL_SPAN
+    assert tracer.span(names.OINK_SCRIPT, cat=names.ENTRY) is NULL_SPAN
+    with tracer.span(names.INVINDEX_RUN, cat=names.ENTRY) as sp:
+        sp.set(anything=1)
+    interp = OinkScript(screen=False)
+    try:
+        interp.run_string(f"wordfreq 2 -i {words} -o NULL NULL")
+    finally:
+        interp.close()
+    assert tracer.events() == []
+    monkeypatch.undo()
+
+    code = (
+        "import os; os.environ.pop('MRTPU_TRACE', None)\n"
+        "import jax._src.monitoring as M\n"
+        "import gpu_mapreduce_tpu\n"
+        "from gpu_mapreduce_tpu.obs import get_tracer, NULL_SPAN\n"
+        "tr = get_tracer()\n"
+        "assert not tr.enabled and tr.span('x') is NULL_SPAN\n"
+        "assert M.get_event_listeners() == []\n"
+        "assert M.get_event_duration_listeners() == []\n"
+        "tr.enable()\n"
+        "assert len(M.get_event_listeners()) == 1\n"
+        "assert len(M.get_event_duration_listeners()) == 1\n"
+        "tr.reset(); tr.enable()\n"          # registered once a process
+        "assert len(M.get_event_duration_listeners()) == 1\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    res = subprocess.run([sys.executable, "-c", code], cwd=root,
+                         capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stderr

@@ -812,14 +812,25 @@ def test_concurrent_sessions_meta_deltas_exact(tmp_path):
         srv.shutdown()
 
 
-def test_session_trace_id_links_every_artifact(server, tmp_path):
+@pytest.fixture
+def process_tracer():
+    """The process tracer, reset when the test is done: left on, every
+    later test of this worker pays for spans and for the jax.monitoring
+    listener that feeds them (ROADMAP C15)."""
+    from gpu_mapreduce_tpu.obs import get_tracer
+    yield get_tracer()
+    get_tracer().reset()
+
+
+def test_session_trace_id_links_every_artifact(server, tmp_path,
+                                               process_tracer):
     """One request, one id: the 202, result meta, /profile, the
     session journal records, and the session's spans on any trace sink
     (the serve-worker half of the propagation goldens)."""
     import gpu_mapreduce_tpu.obs as obs
     from gpu_mapreduce_tpu.ft.journal import read_journal
     trace_path = str(tmp_path / "serve_trace.jsonl")
-    obs.get_tracer().enable(jsonl=trace_path)
+    process_tracer.enable(jsonl=trace_path)
     c = client(server)
     corpus = write_corpus(tmp_path / "w.txt", ["to", "be", "or"], 40)
     r = c.submit(script=wf_script(corpus), tenant="acme")
