@@ -57,8 +57,9 @@ def wordfreq_interned(files: Sequence[str], ntop: int = 10, comm=None
     """Device-path wordfreq: u64-interned words, columnar count reduce.
     The words come from the shared word map (``utils/io.word_ranges``:
     ranges of the file's buffer, no object per word) and intern into one
-    ``ShardTables``, whose ``absorb`` is the collision guard across files
-    and whose tables decode the top-N at the end."""
+    ``ShardTables``, whose absorb is the collision guard across files
+    (under the tables' lock: with mapstyle 2 the callbacks run on pool
+    threads) and whose tables decode the top-N at the end."""
     from ..core.column import ShardTables
     from ..ops.reduces import count
 

@@ -104,6 +104,10 @@ def _load() -> Optional[ctypes.CDLL]:
     lib.mr_unique_ranges.restype = i64
     lib.mr_unique_ranges.argtypes = [u8p, p(i64), p(i64), p(u64), i64,
                                      p(i64), p(i64)]
+    lib.mr_gather_ranges.restype = None
+    lib.mr_gather_ranges.argtypes = [u8p, p(i64), p(i64), i64, u8p]
+    lib.mr_differ_ranges.restype = i64
+    lib.mr_differ_ranges.argtypes = [u8p, p(i64), u8p, p(i64), p(i64), i64]
     lib.mr_parse_table.restype = i64
     lib.mr_parse_table.argtypes = [u8p, i64, i64, p(ctypes.c_int32),
                                    p(ctypes.c_void_p), i64]
@@ -210,6 +214,45 @@ def unique_ranges(buf: np.ndarray, starts: np.ndarray, lens: np.ndarray,
     if u < 0:
         raise MemoryError("mr_unique_ranges: no memory for its table")
     return first[:u].copy()
+
+
+def _ranges_args(buf: np.ndarray, starts: np.ndarray, lens: np.ndarray):
+    """Contiguous (buf, starts, lens) whose every range lies inside
+    ``buf`` — checked here because the callee copies or compares by
+    pointer."""
+    buf = np.ascontiguousarray(buf, np.uint8)
+    starts = np.ascontiguousarray(starts, np.int64)
+    lens = np.ascontiguousarray(lens, np.int64)
+    if len(starts) != len(lens) or (len(starts) and (
+            starts.min() < 0 or lens.min() < 0
+            or (starts + lens).max() > len(buf))):
+        raise ValueError("ranges outside their buffer")
+    return buf, starts, lens
+
+
+def gather_ranges(buf: np.ndarray, starts: np.ndarray, lens: np.ndarray,
+                  total: int) -> np.ndarray:
+    """The ranges' bytes end to end, u8[total]; ``total`` is
+    ``lens.sum()``, which the caller has from its offsets."""
+    buf, starts, lens = _ranges_args(buf, starts, lens)
+    out = np.empty(total, np.uint8)
+    _lib.mr_gather_ranges(_arr(buf, ctypes.c_uint8),
+                          _arr(starts, ctypes.c_int64),
+                          _arr(lens, ctypes.c_int64), len(starts),
+                          _arr(out, ctypes.c_uint8))
+    return out
+
+
+def differ_ranges(a: np.ndarray, astarts: np.ndarray, b: np.ndarray,
+                  bstarts: np.ndarray, lens: np.ndarray) -> int:
+    """Index of the first pair of ranges ``a[astarts[i]:+lens[i]]``,
+    ``b[bstarts[i]:+lens[i]]`` that differ in a byte, or -1."""
+    a, astarts, lens = _ranges_args(a, astarts, lens)
+    b, bstarts, lens = _ranges_args(b, bstarts, lens)
+    return int(_lib.mr_differ_ranges(
+        _arr(a, ctypes.c_uint8), _arr(astarts, ctypes.c_int64),
+        _arr(b, ctypes.c_uint8), _arr(bstarts, ctypes.c_int64),
+        _arr(lens, ctypes.c_int64), len(lens)))
 
 
 def intern64_batch(buf: bytes, offsets: np.ndarray) -> np.ndarray:

@@ -307,6 +307,29 @@ int64_t mr_unique_ranges(const uint8_t *buf, const int64_t *starts,
   return nuniq;
 }
 
+// n ranges of one buffer copied end to end into `out` (sum of lens bytes)
+// — a shard's DISTINCT words leave the file buffer as one blob for the
+// destination tables (core/column._gather_ranges), no object per word.
+void mr_gather_ranges(const uint8_t *buf, const int64_t *starts,
+                      const int64_t *lens, int64_t n, uint8_t *out) {
+  for (int64_t i = 0; i < n; i++) {
+    memcpy(out, buf + starts[i], lens[i]);
+    out += lens[i];
+  }
+}
+
+// the first i whose ranges a[astarts[i], +lens[i]) and b[bstarts[i],
+// +lens[i]) differ in a byte, or -1 — the cross-shard half of the 64-bit
+// intern guard: the words a destination table already holds against the
+// words that arrive under the same ids (core/column._ByteTable.absorb).
+int64_t mr_differ_ranges(const uint8_t *a, const int64_t *astarts,
+                         const uint8_t *b, const int64_t *bstarts,
+                         const int64_t *lens, int64_t n) {
+  for (int64_t i = 0; i < n; i++)
+    if (memcmp(a + astarts[i], b + bstarts[i], lens[i]) != 0) return i;
+  return -1;
+}
+
 // href-URL extraction — the host equivalent of the CUDA mark /
 // compute_url_length kernels (cuda/InvertedIndex.cu:79-135) and the CPU
 // FSM parser (cpu/InvertedIndex.cpp:144-265): find every `<a href="`,

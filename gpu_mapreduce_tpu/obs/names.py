@@ -126,11 +126,15 @@ MAP_COLLISIONS = "map.collisions"               # rows, rounds, shards
 PARTS_PULL = "parts.pull"                       # groups, bytes
 PARTS_WRITE = "parts.write"                     # groups, bytes
 
-# utils/io.word_ranges / parallel/ingest._intern_side: the word map of a
-# file map, by ranges (cat HOST; ``shard`` when the map runs shard by shard)
+# utils/io.word_ranges / parallel/ingest._intern_shard: the word map of a
+# file map, by ranges (cat HOST; ``shard`` when the map runs shard by shard).
+# The interns run on pool threads, one span a shard: they overlap one
+# another and the next shard's tokenize, so a metric over them is a union
+# (wall covered); the enclosing map_files span says ``intern_busy_s``, their
+# thread-seconds
 INGEST_TOKENIZE = "ingest.tokenize"             # shard, bytes, words
-INGEST_INTERN = "ingest.intern"                 # shard, words, unique,
-#                                                 table_bytes
+INGEST_INTERN = "ingest.intern"                 # shard, words, unique, added,
+#                                                 checked, table_bytes
 # oink/commands/wordfreq.py: gather(1) + sort_values + the first ntop rows
 WORDFREQ_TOPN = "wordfreq.topn"                 # rows
 

@@ -233,7 +233,7 @@ def _remap_ids_jit(mesh, m: int):
 def _reintern_pickle_domain(col, table, mesh):
     """Re-intern a bytes-kind decode table + its device id column through
     the PICKLE id domain (the object tier's): every stored bytes row
-    re-hashes over its pickle — exactly what _intern_side's
+    re-hashes over its pickle — exactly what _SideInterns'
     BytesColumn→ObjectColumn promotion does at ingest
     (parallel/ingest.py) — and the id column remaps old→new in one
     jitted lookup.  Returns (new column, new object-kind table)."""

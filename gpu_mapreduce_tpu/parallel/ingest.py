@@ -19,7 +19,12 @@ file-driven OINK command — on a mesh backend:
   table of the shard the aggregate will route the id to, so the exchange
   moves u64 ids and shard d's output later decodes from table d alone —
   no controller-global dict (the reference shuffles raw bytes fully
-  distributed, ``src/mapreduce.cpp:453-473``).
+  distributed, ``src/mapreduce.cpp:453-473``);
+* the shards' interns run on pool threads, each from the moment its
+  shard's frame is assembled (hash, dedupe, split and gather are array
+  and native work that releases the GIL), beside the reader of the next
+  shard; the tables take the shards' batches in shard order, so nothing
+  depends on which thread finished first.
 
 Anything unshardable (mixed dtypes across shards, frames added via
 ``add_frame``, out-of-core datasets) falls back to replaying the recorded
@@ -29,7 +34,11 @@ callbacks never run twice.
 
 from __future__ import annotations
 
+import concurrent.futures
+import contextlib
 import os
+import threading
+import time
 from typing import Callable, List, Sequence
 
 import jax
@@ -160,38 +169,122 @@ def _sink_frame(sinks) -> KVFrame:
         raise Unshardable(str(e))
 
 
-def _intern_side(cols, P: int):
-    """Intern one side's byte/object columns into shared dest-sharded
-    tables.  All-or-nothing: one shard emitting bytes while another
-    emits numbers is two incompatible key spaces (Unshardable → host
-    fallback).  Returns (new columns, tables-or-None)."""
-    stringy = [isinstance(c, (BytesColumn, ObjectColumn))
-               for c in cols if len(c)]
-    if not any(stringy):
-        return cols, None
-    if not all(stringy):
-        raise Unshardable("mixed byte and numeric rows across shards")
-    kind = ("object" if any(isinstance(c, ObjectColumn) for c in cols)
-            else "bytes")
+class _InOrder:
+    """Turns for the shards' absorbs.  A shard's thread hashes, dedupes
+    and splits when it likes, then waits here for every ticket before
+    its own: the tables take the shards' batches in shard order, so the
+    tables, a collision's message and the job's digest do not depend on
+    which thread finished first.  A ticket is a task's place in the
+    order of submission, and the pool starts tasks in that order, so a
+    waiting ticket only ever waits for tasks that are running or done."""
+
+    def __init__(self):
+        self._cv = threading.Condition()
+        self._next = 0
+
+    @contextlib.contextmanager
+    def turn(self, k: int):
+        with self._cv:
+            self._cv.wait_for(lambda: self._next >= k)
+        try:
+            yield
+        finally:
+            self.done(k)
+
+    def done(self, k: int) -> None:
+        """Ticket k has had its turn, or will never take it (its task
+        failed first): nobody waits for it any more."""
+        with self._cv:
+            self._next = max(self._next, k + 1)
+            self._cv.notify_all()
+
+
+def _intern_shard(shard: int, col, tables: ShardTables, order: _InOrder,
+                  ticket: int):
+    """One shard's column interned into the shared tables, on a pool
+    thread: ``(id column, seconds)``."""
     from ..obs import get_tracer, names
-    tables = ShardTables(P, kind=kind)
-    out = []
-    for shard, c in enumerate(cols):
-        if kind == "object" and isinstance(c, BytesColumn):
+    t0 = time.perf_counter()
+    try:
+        # the column says what it absorbed: unique, added, checked,
+        # table_bytes
+        with get_tracer().span(names.INGEST_INTERN, cat=names.HOST,
+                               shard=shard, words=len(col)):
+            ids = col.intern_sharded(tables, turn=order.turn(ticket))
+    finally:
+        order.done(ticket)
+    return ids, time.perf_counter() - t0
+
+
+class _SideInterns:
+    """One side (the keys, or the values) of the shards' frames,
+    interned into shared dest-sharded tables by pool threads.
+
+    ``add`` takes shard k's column as soon as its frame exists and, while
+    every shard so far holds byte rows, starts its intern at once, so it
+    runs beside the tokenizer of shard k+1 and beside the other shards'
+    interns.  ``finish`` decides as a whole, when every shard is in.
+    All-or-nothing: one shard emitting bytes while another emits numbers
+    is two incompatible key spaces (Unshardable → host fallback), and
+    one shard emitting objects moves EVERY shard's rows into the pickle
+    domain; what was interned in the byte domain by then is thrown away,
+    never patched."""
+
+    def __init__(self, P: int, submit: Callable):
+        self.P = P
+        self.submit = submit        # (fn, *args) → Future, on the pool
+        self.cols: list = []
+        self.futs: dict = {}        # shard → its intern, in flight or done
+        self.tables = ShardTables(P)
+        self.order = _InOrder()
+        self.bytes_so_far = True
+        self.busy_s = 0.0
+
+    def _start(self, shard: int, col) -> None:
+        self.futs[shard] = self.submit(_intern_shard, shard, col, self.tables,
+                                       self.order, len(self.futs))
+
+    def add(self, col) -> None:
+        self.bytes_so_far &= isinstance(col, BytesColumn) or not len(col)
+        if self.bytes_so_far and len(col):
+            self._start(len(self.cols), col)
+        self.cols.append(col)
+
+    def discard(self) -> None:
+        """Let what is in flight run out (a cancelled task would never
+        take its turn) and forget it."""
+        concurrent.futures.wait(self.futs.values())
+        self.futs = {}
+
+    def finish(self):
+        """(new columns, tables-or-None) of the whole side."""
+        cols = self.cols
+        stringy = [isinstance(c, (BytesColumn, ObjectColumn))
+                   for c in cols if len(c)]
+        if not any(stringy):
+            return cols, None
+        if not all(stringy):
+            self.discard()
+            raise Unshardable("mixed byte and numeric rows across shards")
+        if not self.bytes_so_far:
             # one shard emitted objects: EVERY shard's rows must hash in
             # the pickle domain, or the same logical bytes key would get
             # two ids (host concat() promotes the same way — r5 review)
-            c = ObjectColumn(c.data)
-        if isinstance(c, (BytesColumn, ObjectColumn)):
-            # the column says what it absorbed: unique, table_bytes
-            with get_tracer().span(names.INGEST_INTERN, cat=names.HOST,
-                                   shard=shard, words=len(c)):
-                out.append(c.intern_sharded(tables))
-        elif len(c):
-            raise Unshardable("mixed byte and numeric rows across shards")
-        else:
-            out.append(DenseColumn(np.zeros(0, np.uint64)))
-    return out, tables
+            self.discard()
+            self.tables, self.order = ShardTables(self.P, "object"), _InOrder()
+            cols = [ObjectColumn(c.data) if isinstance(c, BytesColumn) else c
+                    for c in cols]
+        for shard, c in enumerate(cols):    # an empty byte column; all of
+            if shard not in self.futs and isinstance(   # them after a discard
+                    c, (BytesColumn, ObjectColumn)):
+                self._start(shard, c)
+        concurrent.futures.wait(self.futs.values())
+        out = [DenseColumn(np.zeros(0, np.uint64))] * len(cols)
+        for shard in sorted(self.futs):     # the first shard's error, if any
+            out[shard], seconds = self.futs[shard].result()
+            self.busy_s += seconds
+        self.futs = {}
+        return out, self.tables
 
 
 def _common_spec(arrs: List[np.ndarray]):
@@ -239,53 +332,136 @@ def _put_blocks(blocks: List[np.ndarray], cap: int, mesh):
                                                         shards)
 
 
-def build_sharded(frames: List[KVFrame], mesh):
+class _ShardedBuilder:
     """Per-shard host frames → one ShardedKV, interning byte/object
-    columns into dest-sharded tables.  Rows normally stay on the shard
-    whose file slice produced them — EXCEPT a severely lopsided ingest
-    (max shard > 2× the even share, e.g. one file on an 8-shard mesh),
-    which re-splits rows evenly: the padded cap tracks the fullest
-    shard, so keeping the skew would move ~P× the real rows through
-    every downstream collective.  Raises Unshardable when the frames
-    cannot agree."""
-    from .sharded import ShardedKV, round_cap, _pad_rows
-    P = len(frames)
-    kcols, ktables = _intern_side([f.key for f in frames], P)
-    vcols, vtables = _intern_side([f.value for f in frames], P)
-    karrs = [np.asarray(c.to_host().data) for c in kcols]
-    varrs = [np.asarray(c.to_host().data) for c in vcols]
-    kdt, kshape = _common_spec(karrs)
-    vdt, vshape = _common_spec(varrs)
-    counts = np.array([a.shape[0] for a in karrs], np.int32)
-    total = int(counts.sum())
-    if P > 1 and total and int(counts.max()) > 2 * (-(-total // P)):
-        # lopsided ingest (fewer files than shards — e.g. one edge file
-        # on an 8-shard mesh): the padded cap tracks the FULLEST shard,
-        # so every downstream collective would move ~P x the real rows.
-        # Re-split evenly — free on a single controller (the bytes are
-        # already in host RAM), and order-preserving.  A multi-host
-        # runtime would keep locality instead; with one file only one
-        # host has the data anyway (r5 P=8 soak regression).
-        kall = np.concatenate([a.astype(kdt, copy=False)
-                               .reshape((-1,) + kshape) for a in karrs])
-        vall = np.concatenate([a.astype(vdt, copy=False)
-                               .reshape((-1,) + vshape) for a in varrs])
-        per = -(-total // P)
-        starts = np.minimum(np.arange(P) * per, total)
-        ends = np.minimum(starts + per, total)
-        karrs = [kall[s:e] for s, e in zip(starts, ends)]
-        varrs = [vall[s:e] for s, e in zip(starts, ends)]
-        counts = (ends - starts).astype(np.int32)
-    cap = round_cap(int(counts.max()) if counts.max() else 0)
-    kb = [_pad_rows(a.astype(kdt, copy=False).reshape((-1,) + kshape), cap)
-          for a in karrs]
-    vb = [_pad_rows(a.astype(vdt, copy=False).reshape((-1,) + vshape), cap)
-          for a in varrs]
-    key = _put_blocks(kb, cap, mesh)
-    value = _put_blocks(vb, cap, mesh)
-    return ShardedKV(mesh, key, value, counts,
-                     key_decode=ktables, value_decode=vtables)
+    columns into dest-sharded tables.  ``add`` takes the shards' frames
+    in shard order, each as soon as it is assembled, and starts its
+    interns on ``pool`` (a private one for standalone callers, torn down
+    on exit); ``build`` waits for them and places the rows."""
 
+    def __init__(self, mesh, pool=None):
+        from ..obs.context import bind
+        from .mesh import mesh_axis_size
+        self.mesh = mesh
+        P = mesh_axis_size(mesh)
+        self._own = None
+        if pool is None:
+            pool = self._own = concurrent.futures.ThreadPoolExecutor(
+                max(1, min(os.cpu_count() or 4, P)),
+                thread_name_prefix="mrtpu-intern")
+
+        def submit(fn, *args):
+            # the worker runs the SUBMITTING request's trace context, so
+            # its ingest.intern span lands in the job's trace
+            return pool.submit(bind(fn), *args)
+        self.keys = _SideInterns(P, submit)
+        self.values = _SideInterns(P, submit)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.keys.discard()
+        self.values.discard()
+        if self._own is not None:
+            self._own.shutdown()
+        return False
+
+    def add(self, frame: KVFrame) -> None:
+        self.keys.add(frame.key)
+        self.values.add(frame.value)
+
+    def build(self):
+        """Rows normally stay on the shard whose file slice produced
+        them — EXCEPT a severely lopsided ingest (max shard > 2× the even
+        share, e.g. one file on an 8-shard mesh), which re-splits rows
+        evenly: the padded cap tracks the fullest shard, so keeping the
+        skew would move ~P× the real rows through every downstream
+        collective.  Raises Unshardable when the frames cannot agree."""
+        from ..obs import get_tracer
+        from .sharded import ShardedKV, round_cap, _pad_rows
+        mesh = self.mesh
+        kcols, ktables = self.keys.finish()
+        vcols, vtables = self.values.finish()
+        # thread-seconds of the shards' interns, beside the wall their
+        # spans cover: onto the enclosing map_files / map_file_* span
+        get_tracer().annotate(intern_busy_s=round(
+            self.keys.busy_s + self.values.busy_s, 6))
+        P = len(kcols)
+        karrs = [np.asarray(c.to_host().data) for c in kcols]
+        varrs = [np.asarray(c.to_host().data) for c in vcols]
+        kdt, kshape = _common_spec(karrs)
+        vdt, vshape = _common_spec(varrs)
+        counts = np.array([a.shape[0] for a in karrs], np.int32)
+        total = int(counts.sum())
+        if P > 1 and total and int(counts.max()) > 2 * (-(-total // P)):
+            # lopsided ingest (fewer files than shards — e.g. one edge file
+            # on an 8-shard mesh): the padded cap tracks the FULLEST shard,
+            # so every downstream collective would move ~P x the real rows.
+            # Re-split evenly — free on a single controller (the bytes are
+            # already in host RAM), and order-preserving.  A multi-host
+            # runtime would keep locality instead; with one file only one
+            # host has the data anyway (r5 P=8 soak regression).
+            kall = np.concatenate([a.astype(kdt, copy=False)
+                                   .reshape((-1,) + kshape) for a in karrs])
+            vall = np.concatenate([a.astype(vdt, copy=False)
+                                   .reshape((-1,) + vshape) for a in varrs])
+            per = -(-total // P)
+            starts = np.minimum(np.arange(P) * per, total)
+            ends = np.minimum(starts + per, total)
+            karrs = [kall[s:e] for s, e in zip(starts, ends)]
+            varrs = [vall[s:e] for s, e in zip(starts, ends)]
+            counts = (ends - starts).astype(np.int32)
+        cap = round_cap(int(counts.max()) if counts.max() else 0)
+        kb = [_pad_rows(a.astype(kdt, copy=False).reshape((-1,) + kshape), cap)
+              for a in karrs]
+        vb = [_pad_rows(a.astype(vdt, copy=False).reshape((-1,) + vshape), cap)
+              for a in varrs]
+        key = _put_blocks(kb, cap, mesh)
+        value = _put_blocks(vb, cap, mesh)
+        return ShardedKV(mesh, key, value, counts,
+                         key_decode=ktables, value_decode=vtables)
+
+
+def build_sharded(frames: List[KVFrame], mesh, pool=None):
+    """One ShardedKV of per-shard host frames that all exist already
+    (see :class:`_ShardedBuilder`)."""
+    with _ShardedBuilder(mesh, pool) as builder:
+        for frame in frames:
+            builder.add(frame)
+        return builder.build()
+
+
+def _frames_to_kv(mr, kv, sink_stream, stats: dict) -> dict:
+    """The consumer half of both mesh map paths: every shard's sinks
+    become its frame, the frames one mesh dataset in ``kv``.  When they
+    cannot (Unshardable), every sink replays into the host ``kv`` in
+    task order instead, once, and ``stats`` says so."""
+    shard_sinks: List[list] = []      # kept for the fallback
+    failed = skv = None
+    with _ShardedBuilder(mr.backend.mesh, mr._ingest_pool()) as builder:
+        for sinks in sink_stream:
+            shard_sinks.append(sinks)
+            if failed is None:
+                try:
+                    builder.add(_sink_frame(sinks))
+                except Unshardable as e:
+                    failed = str(e)[:200]
+        if failed is None:
+            try:
+                skv = builder.build()
+            except Unshardable as e:
+                failed = str(e)[:200]
+    if failed is not None:
+        for sinks in shard_sinks:
+            for s in sinks:
+                s.replay(kv)
+        stats["mode"] = "host"
+        stats["fallback"] = failed
+        return stats
+    kv.add_frame(skv)
+    stats["rows_per_shard"] = skv.counts.tolist()
+    return stats
 
 
 def _balanced_shards(names: Sequence[str], P: int,
@@ -384,40 +560,8 @@ def mesh_map_files(mr, kv, names: Sequence[str], call: Callable) -> dict:
     else:
         stream = _shard_sink_stream(shards, call, False, None,
                                     onfault=onfault)
-    frames: List[KVFrame] = []
-    done_sinks: List[list] = []   # per-shard sinks kept for fallback
-    failed = None
-    for sinks in prefetch_iter(stream, path="ingest.files"):
-        if failed is not None:
-            for s in sinks:
-                s.replay(kv)
-            continue
-        try:
-            frames.append(_sink_frame(sinks))
-            done_sinks.append(sinks)
-        except Unshardable as e:
-            failed = str(e)[:200]
-            for ss in done_sinks:
-                for s in ss:
-                    s.replay(kv)
-            for s in sinks:
-                s.replay(kv)
-            frames, done_sinks = [], []
-    if failed is None:
-        try:
-            skv = build_sharded(frames, mr.backend.mesh)
-        except Unshardable as e:
-            failed = str(e)[:200]
-            for ss in done_sinks:
-                for s in ss:
-                    s.replay(kv)
-    if failed is not None:
-        stats["mode"] = "host"
-        stats["fallback"] = failed
-        return stats
-    kv.add_frame(skv)
-    stats["rows_per_shard"] = skv.counts.tolist()
-    return stats
+    return _frames_to_kv(mr, kv, prefetch_iter(stream, path="ingest.files"),
+                         stats)
 
 
 def mesh_map_chunks(mr, kv, names: Sequence[str], per_file: int, sep: bytes,
@@ -466,41 +610,9 @@ def mesh_map_chunks(mr, kv, names: Sequence[str], per_file: int, sep: bytes,
             counts["ntasks"] += len(payloads)
             yield payloads
 
-    frames: List[KVFrame] = []
-    done_sinks: List[list] = []   # per-shard sinks kept for fallback
-    failed = None
-    for sinks in prefetch_iter(
-            _shard_sink_stream(shard_payloads(), call, threaded, pool,
-                               onfault=onfault),
-            path="ingest.chunks"):
-        if failed is not None:
-            for s in sinks:
-                s.replay(kv)
-            continue
-        try:
-            frames.append(_sink_frame(sinks))
-            done_sinks.append(sinks)
-        except Unshardable as e:
-            failed = str(e)[:200]
-            for ss in done_sinks:
-                for s in ss:
-                    s.replay(kv)
-            for s in sinks:
-                s.replay(kv)
-            frames, done_sinks = [], []
+    _frames_to_kv(mr, kv, prefetch_iter(
+        _shard_sink_stream(shard_payloads(), call, threaded, pool,
+                           onfault=onfault),
+        path="ingest.chunks"), stats)
     stats["ntasks"] = counts["ntasks"]
-    if failed is None:
-        try:
-            skv = build_sharded(frames, mr.backend.mesh)
-        except Unshardable as e:
-            failed = str(e)[:200]
-            for ss in done_sinks:
-                for s in ss:
-                    s.replay(kv)
-    if failed is not None:
-        stats["mode"] = "host"
-        stats["fallback"] = failed
-        return stats
-    kv.add_frame(skv)
-    stats["rows_per_shard"] = skv.counts.tolist()
     return stats

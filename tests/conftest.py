@@ -29,3 +29,15 @@ import pytest
 @pytest.fixture
 def rng():
     return np.random.default_rng(12345)
+
+
+@pytest.fixture(params=["native", "numpy"])
+def library(request, monkeypatch):
+    """A test twice: with the native library, and with every caller of
+    ``native.available()`` on its numpy/Python branch (``_lib`` gone)."""
+    from gpu_mapreduce_tpu import native
+    if request.param == "numpy":
+        monkeypatch.setattr(native, "_lib", None)
+    elif not native.available():
+        pytest.skip(f"no native library: {native.build_error()}")
+    return request.param

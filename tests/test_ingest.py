@@ -224,3 +224,169 @@ def test_checkpoint_roundtrip_mesh_ingested(corpus, tmp_path):
     assert nunique == len(oracle)
     got = dict(mr2.kv.one_frame().to_host().pairs())
     assert got == dict(oracle)
+
+
+# -- ISSUE 35: the shards' interns on pool threads ----------------------------
+
+def _map_words(files, monkeypatch, schedule: str, P: int = 4):
+    """``map_files(read_words)`` on a P-way mesh with the shards' interns
+    scheduled one way: everything about the result, to compare."""
+    import concurrent.futures
+    import threading
+    from gpu_mapreduce_tpu.core import column
+    mr = MapReduce(make_mesh(P))
+    real = column._intern_ranges
+    hashed = [threading.Event() for _ in range(P + 1)]
+    hashed[P].set()
+    sizes = {}          # file buffer length → its shard (one file a shard)
+    for k, f in enumerate(files[:P]):
+        sizes[os.path.getsize(f)] = k
+    assert len(sizes) == P
+    finished = []
+
+    def reversed_hashing(buf, starts, lens):
+        # shard k's hash and dedupe return only after shard k+1's have
+        out = real(buf, starts, lens)
+        k = sizes[len(buf)]
+        assert hashed[k + 1].wait(timeout=20)
+        finished.append(k)
+        hashed[k].set()
+        return out
+
+    pool = None
+    if schedule == "reverse":       # a worker a shard, whatever the host has
+        pool = concurrent.futures.ThreadPoolExecutor(P)
+        monkeypatch.setattr(column, "_intern_ranges", reversed_hashing)
+    elif schedule == "one after another":
+        pool = concurrent.futures.ThreadPoolExecutor(1)
+    if pool is not None:
+        monkeypatch.setattr(mr, "_ingest_pool", lambda: pool)
+    try:
+        n = mr.map_files(files[:P], read_words)
+    finally:
+        if pool is not None:
+            pool.shutdown()
+    if schedule == "reverse":
+        assert finished == list(range(P))[::-1]
+    fr = mr.kv.one_frame()
+    return (n, mr.last_ingest, np.asarray(fr.key).tolist(),
+            fr.counts.tolist(),
+            [list(fr.key_decode.shard(d).items()) for d in range(P)])
+
+
+@pytest.mark.parametrize("schedule", ["as it comes", "reverse"])
+def test_the_tables_do_not_depend_on_which_intern_finishes_first(
+        corpus, monkeypatch, schedule):
+    files, _ = corpus
+    want = _map_words(files, monkeypatch, "one after another")
+    assert want[1]["mode"] == "mesh" and sum(map(len, want[4])) == 120
+    assert _map_words(files, monkeypatch, schedule) == want
+
+
+def test_wordfreq_interned_on_pool_threads_equals_the_serial_count(
+        corpus, monkeypatch):
+    """Under mapstyle 2 the callbacks run on the ingest pool and intern
+    into ONE shared ``ShardTables``, whose lock keeps every absorb whole."""
+    import functools
+    from gpu_mapreduce_tpu.apps import wordfreq as app
+    files, oracle = corpus
+    serial = app.wordfreq_interned(files, ntop=7)
+    monkeypatch.setattr(app, "MapReduce",
+                        functools.partial(MapReduce, mapstyle=2))
+    nwords, nunique, top = app.wordfreq_interned(files, ntop=7,
+                                                 comm=make_mesh(8))
+    assert (nwords, nunique) == serial[:2] == (sum(oracle.values()),
+                                               len(oracle))
+    assert [c for _w, c in top] == [c for _w, c in serial[2]]
+    assert all(oracle[w] == c for w, c in top)
+
+
+@pytest.mark.parametrize("odd_one", ["float values", "numbers", "objects"])
+def test_fallbacks_with_interns_in_flight(corpus, odd_one):
+    """After the first shards' interns were started in the byte domain the
+    last shard turns out to hold values of another dtype (Unshardable:
+    every sink replays into the host dataset, once), numbers for keys
+    (Unshardable too, and the host dataset refuses the mix as it always
+    did) or objects (every shard's rows move to the pickle domain).  What
+    the byte domain made is thrown away, and the result is the host
+    path's."""
+    files, oracle = corpus
+    calls = collections.Counter()
+
+    def emit(itask, fname, kv, ptr):
+        calls[itask] += 1
+        with open(fname, "rb") as f:
+            words = f.read().split()
+        if itask < len(files) - 1:
+            kv.add_batch(words, np.ones(len(words), np.int64))
+        elif odd_one == "float values":
+            kv.add_batch(words, np.full(len(words), 0.5))
+        elif odd_one == "numbers":
+            kv.add_batch(np.arange(3, dtype=np.uint64),
+                         np.ones(3, np.int64))
+        else:
+            kv.add(("a", "tuple"), 1)
+            kv.add(b"w000", 1)
+
+    def counts(mr):
+        from gpu_mapreduce_tpu.ops.reduces import sum_values
+        mr.collate()
+        mr.reduce(sum_values, batch=True)
+        got = {}
+        mr.scan_kv(lambda k, v, p: got.__setitem__(k, float(v)))
+        return got
+
+    mesh, host = MapReduce(make_mesh(4)), MapReduce()
+    if odd_one == "numbers":
+        for mr in (mesh, host):
+            with pytest.raises(TypeError, match="byte rows with numeric"):
+                mr.map_files(files, emit)
+        assert set(calls.values()) == {2}       # once a run, never replayed
+        return
+    n = mesh.map_files(files, emit)
+    assert set(calls.values()) == {1}           # every callback ran once
+    assert n == host.map_files(files, emit)
+    if odd_one == "float values":
+        assert mesh.last_ingest["mode"] == "host"
+        assert "mismatch" in mesh.last_ingest["fallback"]
+    else:
+        assert mesh.last_ingest["mode"] == "mesh"
+        kd = mesh.kv.one_frame().key_decode
+        assert kd.kind == "object" and ("a", "tuple") in list(
+            dict(kd.items()).values())
+    got = counts(mesh)
+    assert got == counts(host) and len(got) > 100
+
+
+def test_a_cross_shard_collision_under_concurrent_interns(tmp_path,
+                                                          monkeypatch):
+    """Four files, each clean on its own; two of their words share a
+    forged id and meet in a destination table.  The shards intern at
+    once, absorb in shard order, and the job fails naming both words,
+    the earlier shard's first."""
+    from gpu_mapreduce_tpu import native
+    from gpu_mapreduce_tpu.core import column
+    from gpu_mapreduce_tpu.parallel.ingest import build_sharded
+    from gpu_mapreduce_tpu.core.frame import KVFrame
+    from gpu_mapreduce_tpu.utils.io import word_ranges
+    texts = [b"one one fine", b"left fine left", b"fine right", b"fine"]
+    clash = {b"left", b"right"}
+
+    def forged(real):
+        def ids_of(buf, starts, lens, hi=0, lo=0xDEADBEEF):
+            ids = real(buf, starts, lens, hi, lo)
+            if (hi, lo) == (0, 0xDEADBEEF):
+                for i, (s, n) in enumerate(zip(starts, lens)):
+                    if bytes(buf[s:s + n]) in clash:
+                        ids[i] = 99
+            return ids
+        return ids_of
+    if not native.available():
+        pytest.skip("the forgery goes through native.intern_ranges")
+    monkeypatch.setattr(native, "intern_ranges", forged(native.intern_ranges))
+    frames = [KVFrame(word_ranges(t), np.ones(len(t.split()), np.int64))
+              for t in texts]
+    for _ in range(5):
+        with pytest.raises(ValueError, match="64-bit intern collision: "
+                           "b'left' vs b'right'"):
+            build_sharded(frames, make_mesh(4))

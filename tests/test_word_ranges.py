@@ -77,17 +77,6 @@ def oracle(files):
     return c
 
 
-@pytest.fixture(params=["native", "numpy"])
-def library(request, monkeypatch):
-    """Every caller asks ``native.available()``: with ``_lib`` gone they
-    all take their numpy/Python branch."""
-    if request.param == "numpy":
-        monkeypatch.setattr(native, "_lib", None)
-    elif not native.available():
-        pytest.skip(f"no native library: {native.build_error()}")
-    return request.param
-
-
 def object_read_words(itask, filename, kv, ptr):
     """``oink/kernels.read_words`` as it was before the ranges: a list of
     one ``bytes`` object per word."""
@@ -347,8 +336,9 @@ def test_ranges_path_equals_object_path_on_the_serial_backend(files):
 
 
 def test_the_file_map_builds_no_object_per_word(files, monkeypatch):
-    """On a mesh the words go from the callback to the ids as ranges: the
-    column's objects are never asked for."""
+    """On a mesh the words go from the callback to the ids, and the distinct
+    ones on into the shards' tables, as ranges: no ``bytes`` object is asked
+    for, per word or (since ISSUE 35) per distinct word."""
     if not native.available():
         pytest.skip("without the library the rows are sliced to hash them")
     asked = []
@@ -361,5 +351,8 @@ def test_the_file_map_builds_no_object_per_word(files, monkeypatch):
     mr = MapReduce(make_mesh(4))
     nwords = mr.map_files(files, kernels.read_words, [])
     assert mr.last_ingest["mode"] == "mesh"
-    # one slice per DISTINCT word of a shard, not per word
-    assert len(asked) == 4 and sum(asked) < nwords // 2
+    assert nwords > 0 and asked == []
+    fr = mr.kv.one_frame()
+    assert sum(asked) == 0 and len(fr.key_decode) > 0
+    top = fr.key_decode.decode_batch(np.asarray(fr.key)[:3])
+    assert sum(asked) == 3 and all(isinstance(w, bytes) for w in top)
