@@ -13,6 +13,10 @@ A TPU wants fixed-width lanes, so we go columnar instead (SURVEY.md §7):
   host-only; the analogue of the reference's variable-length byte path.  It
   can be *interned* to a u64 DenseColumn plus a host-side id→bytes dictionary
   so shuffles/group-bys run on device (SURVEY.md §7 "hard parts").
+* fixed-width byte rows need neither: ``[n, w]`` bytes are a DenseColumn of
+  u32 words (:func:`fixed_key_words` when the rows must ORDER on the device
+  as their bytes do, :func:`fixed_value_words` when they only travel), and
+  come back as the same bytes with no table.
 
 Both support the minimal op set the runtime needs: ``take`` (gather by row
 index), ``concat``, ``slice``, and conversion to/from host.
@@ -108,6 +112,54 @@ class DenseColumn(Column):
     def __repr__(self):
         where = "dev" if _is_device(self.data) else "host"
         return f"DenseColumn<{self.data.dtype}{list(self.data.shape)}@{where}>"
+
+
+def _padded_rows(raw: np.ndarray, out=None) -> np.ndarray:
+    """``[n, w]`` bytes as ``[n, 4 * ceil(w / 4)]``, zeros after each row
+    (into ``out``'s bytes when given: ``[n, ceil(w / 4)]`` u32)."""
+    n, w = raw.shape
+    words = -(-w // 4)
+    if out is None:
+        out = np.empty((n, words), np.uint32)
+    rows = out.view(np.uint8).reshape(n, 4 * words)
+    rows[:, :w] = raw
+    rows[:, w:] = 0
+    return rows
+
+
+def fixed_key_words(raw: np.ndarray, out=None) -> np.ndarray:
+    """Fixed-width byte keys ``[n, w]`` (u8) as dense words ``[n,
+    ceil(w/4)]`` (u32) whose unsigned lexicographic order, the first word
+    the most significant, IS the ``memcmp`` order of the bytes: each word
+    is four key bytes big-endian, the last one zero-filled below (every
+    row has the same width, so the fill decides nothing).  No table:
+    :func:`fixed_key_bytes` gives the bytes back.  ``out``: the ``[n,
+    words]`` u32 array to fill (a slice of a shard's block)."""
+    rows = _padded_rows(raw, out)
+    words = rows.view(np.uint32).reshape(rows.shape[0], -1)
+    if np.little_endian:
+        words.byteswap(inplace=True)
+    return words
+
+
+def fixed_key_bytes(words: np.ndarray, width: int) -> np.ndarray:
+    """The ``[n, width]`` key bytes of :func:`fixed_key_words`' words."""
+    be = np.ascontiguousarray(words, np.uint32).astype(">u4")
+    return be.view(np.uint8).reshape(len(words), -1)[:, :width]
+
+
+def fixed_value_words(raw: np.ndarray, out=None) -> np.ndarray:
+    """Fixed-width byte values ``[n, w]`` as ``[n, ceil(w/4)]`` u32, the
+    bytes in place (host byte order) and zeros after them: a payload
+    that travels with its key and is never compared."""
+    rows = _padded_rows(raw, out)
+    return rows.view(np.uint32).reshape(rows.shape[0], -1)
+
+
+def fixed_value_bytes(words: np.ndarray, width: int) -> np.ndarray:
+    """The ``[n, width]`` value bytes of :func:`fixed_value_words`' words."""
+    rows = np.ascontiguousarray(words, np.uint32)
+    return rows.view(np.uint8).reshape(len(words), -1)[:, :width]
 
 
 class BytesColumn(Column):

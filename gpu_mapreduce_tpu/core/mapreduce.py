@@ -32,7 +32,7 @@ import numpy as np
 
 from ..ops.segment import group_frame
 from ..ops.sort import argsort_column
-from ..utils.io import file_chunks, findfiles
+from ..utils.io import RecordFormat, file_chunks, findfiles
 from .column import BytesColumn, Column, DenseColumn, as_column, concat
 from .dataset import KeyMultiValue, KeyValue
 from .frame import BlockedMultivalue, KMVFrame, KVFrame
@@ -599,14 +599,22 @@ class MapReduce:
         interned into dest-sharded decode tables — the reference's
         'every rank reads its own files' map stage
         (src/mapreduce.cpp:1102-1225).  ``last_ingest`` records which
-        path ran."""
+        path ran.  Files of fixed-width binary records take a
+        ``utils/io.RecordFormat`` as ``func``: the record map."""
         t = self._begin_op()
         if isinstance(files, str):
             files = [files]
         names = self._find_inputs(files, recurse, readflag)
         kv = self._start_map(addflag)
         call = lambda itask, fname, sink: func(itask, fname, sink, ptr)
-        if self._mesh_ingest_ok(addflag):
+        if isinstance(func, RecordFormat) and self._mesh_ingest_ok(
+                addflag, min_shards=1):
+            # fixed-width records: cut into the shards' blocks, on one
+            # shard too (the generic map leaves one shard's rows on the
+            # host for aggregate to place)
+            from ..parallel.ingest import mesh_map_records
+            self.last_ingest = mesh_map_records(self, kv, names, func)
+        elif self._mesh_ingest_ok(addflag):
             from ..parallel.ingest import mesh_map_files
             self.last_ingest = mesh_map_files(self, kv, names, call)
         else:
@@ -616,14 +624,14 @@ class MapReduce:
         self._time("map_files", t)
         return n
 
-    def _mesh_ingest_ok(self, addflag: int) -> bool:
+    def _mesh_ingest_ok(self, addflag: int, min_shards: int = 2) -> bool:
         """Per-shard file ingest preconditions: a multi-shard mesh, a
         fresh KV (addflag appends into an existing — possibly host —
         dataset), and in-core (the out-of-core page/spill budget is the
         host frames' machinery)."""
         from ..parallel.backend import MeshBackend
         return (isinstance(self.backend, MeshBackend)
-                and self.backend.nprocs > 1
+                and self.backend.nprocs >= min_shards
                 and not addflag
                 and self.settings.outofcore != 1)
 

@@ -85,6 +85,7 @@ ENTRY = "entry"         # one root span a job: the call into an entry point
 # vol_switches, invol_switches and the four jit_* deltas, zero or not
 INVINDEX_RUN = "invindex.run"       # apps/invertedindex.InvertedIndex.run
 OINK_SCRIPT = "oink.script"         # oink/script: the outermost script run
+TERASORT_RUN = "terasort.run"       # apps/terasort.TeraSort.run
 
 # -- host-phase spans (cat HOST unless said) ----------------------------------
 # parallel/shuffle.aggregate_kv, before the exchange / the one-chip early-out
@@ -138,6 +139,21 @@ INGEST_INTERN = "ingest.intern"                 # shard, words, unique, added,
 # oink/commands/wordfreq.py: gather(1) + sort_values + the first ntop rows
 WORDFREQ_TOPN = "wordfreq.topn"                 # rows
 
+# parallel/ingest.mesh_map_records: the fixed-width record map.  One plan
+# span (the shards' blocks sized and allocated, every file's cut handed to
+# the pool), then a read and an h2d span a shard (a shard's read waits for
+# ITS files; every file is in flight from the plan on, so a later shard's
+# read is mostly over when it opens)
+INGEST_RECORDS_PLAN = "ingest.records.plan"     # shards, files, block_bytes
+INGEST_RECORDS_READ = "ingest.records.read"     # shard, files, bytes
+INGEST_RECORDS_H2D = "ingest.records.h2d"       # shard, bytes; the puts'
+#                             dispatch, the host block let go; the last
+#                             shard's also waits for every shard's blocks
+# apps/terasort.py
+TERASORT_SAMPLE = "terasort.sample"             # sampled, splitters
+TERASORT_PULL = "terasort.pull"                 # shard, records, d2h_bytes
+TERASORT_WRITE = "terasort.write"               # shard, records, bytes
+
 # older spans that metrics quote by name
 SHUFFLE_EXCHANGE = "shuffle.exchange"           # ..., recv_rows_max, _mean,
 #                                                 cols_rode, cols_by_index
@@ -154,6 +170,8 @@ SPANS = (
     TRI_STAGE, TRI_ENGINE, TRI_EMIT, LUBY_STAGE, LUBY_ENGINE, LUBY_EMIT,
     SSSP_STAGE, SSSP_ENGINE, SSSP_EMIT,
     INVINDEX_RUN, OINK_SCRIPT,
+    INGEST_RECORDS_PLAN, INGEST_RECORDS_READ, INGEST_RECORDS_H2D,
+    TERASORT_RUN, TERASORT_SAMPLE, TERASORT_PULL, TERASORT_WRITE,
 )
 
 # -- attrs that metrics quote by name -----------------------------------------
@@ -163,6 +181,17 @@ CONVERT_SPAN = "convert"
 ATTR_ROWS = "rows"
 ATTR_GROUPS = "groups"
 ATTR_GROUP_ROWS_MAX = "group_rows_max"
+# on the ``sort_keys`` / ``sort_values`` op spans of a mesh dataset
+# (parallel/group.sort_sharded): the rows sorted, the 32-bit key operands
+# of the sort, the carried words that rode it and those taken by the row
+# index, and the bytes a row of the frame occupies in HBM
+SORT_KEYS_SPAN = "sort_keys"
+SORT_VALUES_SPAN = "sort_values"
+ATTR_RECORDS = "records"
+ATTR_KEY_WORDS = "key_words"
+ATTR_RODE_WORDS = "rode_words"
+ATTR_TAKEN_WORDS = "taken_words"
+ATTR_HBM_ROW_BYTES = "hbm_row_bytes"
 # on every span (obs/tracer.Span): the thread's CPU seconds, and the rest of
 # the span's wall (blocked on the device, a file, a lock, or descheduled)
 ATTR_CPU_S = "cpu_s"
@@ -179,7 +208,9 @@ ATTR_JIT_LOWER_S = "jit_lower_s"
 ATTR_JIT_BACKEND_S = "jit_backend_s"
 ATTR_JIT_CACHE_LOADS = "jit_cache_loads"
 SPAN_ATTRS = (
-    ATTR_ROWS, ATTR_GROUPS, ATTR_GROUP_ROWS_MAX, ATTR_CPU_S, ATTR_OFF_CPU_S,
+    ATTR_ROWS, ATTR_GROUPS, ATTR_GROUP_ROWS_MAX, ATTR_RECORDS, ATTR_KEY_WORDS,
+    ATTR_RODE_WORDS, ATTR_TAKEN_WORDS, ATTR_HBM_ROW_BYTES,
+    ATTR_CPU_S, ATTR_OFF_CPU_S,
     ATTR_PROC_CPU_S, ATTR_SYS_CPU_S, ATTR_VOL_SWITCHES, ATTR_INVOL_SWITCHES,
     ATTR_JIT_LOWERINGS, ATTR_JIT_LOWER_S, ATTR_JIT_BACKEND_S,
     ATTR_JIT_CACHE_LOADS,

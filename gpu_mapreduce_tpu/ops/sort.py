@@ -49,6 +49,13 @@ def sort_operands(col) -> int:
     return width * max(1, col.dtype.itemsize // 4)
 
 
+def columns(col) -> list:
+    """The 1-D columns of a ``[n]`` or ``[n, w]`` array, in their order:
+    what a sort takes as operands, the first the most significant."""
+    return [col] if col.ndim == 1 else [col[:, j]
+                                        for j in range(col.shape[1])]
+
+
 def riding(carry) -> list:
     """For each array of ``carry``, whether it reaches its sorted
     position as a payload of the sort (True) or by the sorted row index
@@ -90,8 +97,7 @@ def sort_carrying(keys, carry=(), stable: bool = True):
     operands = list(keys)
     for c, r in zip(carry, rides):
         if r:
-            operands += [c] if c.ndim == 1 else [
-                c[:, j] for j in range(c.shape[1])]
+            operands += columns(c)
     if not all(rides):
         operands.append(jnp.arange(
             n, dtype=jnp.int32 if n < 2 ** 31 else jnp.int64))

@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding
 
 from ..core.runtime import bump_dispatch
 from ..obs import get_tracer, names
+from ..ops.sort import columns, riding, sort_carrying, sort_operands
 from .mesh import mesh_axis_size, row_sharding, row_spec
 from .sharded import (ShardedKMV, ShardedKV, SyncStats, _decode_col,
                       round_cap)
@@ -363,9 +364,13 @@ def sort_multivalues_sharded(kmv: ShardedKMV,
 
 
 def _desc_key(v):
-    if jnp.issubdtype(v.dtype, jnp.unsignedinteger):
-        return ~v  # bitwise complement reverses unsigned order
-    return -v
+    """A column whose ascending order is ``v``'s descending order, and
+    the way back (applied twice it is ``v``): the bitwise complement of
+    an integer of either sign (no value overflows, as ``-v`` does at
+    the least int), the negative of a float."""
+    if jnp.issubdtype(v.dtype, jnp.floating):
+        return -v
+    return ~v
 
 
 # ---------------------------------------------------------------------------
@@ -379,16 +384,23 @@ def _sort_jit(mesh, by: str, descending: bool):
     @jax.jit
     def sort_rows(key, value, count):
         def body(k, v, c):
-            col = k if by == "key" else v
-            cap = col.shape[0]
-            valid = jnp.arange(cap) < c
-            order = jnp.lexsort(_sort_key_tuple(col, valid))
+            # ONE sort (ops/sort.sort_carrying): the sorted-by column's
+            # words are its keys, behind a flag that puts the rows past
+            # the count last whatever they hold; the other column rides
+            # or comes by the row index, as its width says.  Descending
+            # is the same sort on complemented words: a reversal by
+            # scatter is what the chip does worst (PERF.md §6, PR 25)
+            col, other = (k, v) if by == "key" else (v, k)
+            past = (jnp.arange(col.shape[0], dtype=jnp.int32)
+                    >= c[0]).astype(jnp.uint8)
+            cols = columns(col)
             if descending:
-                r = jnp.arange(cap)
-                pos = jnp.where(r < c, c - 1 - r, r)
-                inv = jnp.zeros(cap, order.dtype).at[pos].set(r)
-                order = jnp.take(order, inv)
-            return jnp.take(k, order, axis=0), jnp.take(v, order, axis=0)
+                cols = [_desc_key(x) for x in cols]
+            (_, *scols), (sother,) = sort_carrying((past, *cols), (other,))
+            if descending:
+                scols = [_desc_key(x) for x in scols]
+            scol = scols[0] if col.ndim == 1 else jnp.stack(scols, axis=1)
+            return (scol, sother) if by == "key" else (sother, scol)
         return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=(spec, spec))(key, value, count)
 
@@ -399,8 +411,27 @@ def sort_sharded(skv: ShardedKV, by: str = "key",
                  descending: bool = False) -> ShardedKV:
     counts_dev = jax.device_put(skv.counts.astype(np.int32),
                                 row_sharding(skv.mesh))
+    tracer = get_tracer()
+    if tracer.enabled:          # on the sort_keys / sort_values op span
+        col, other = ((skv.key, skv.value) if by == "key"
+                      else (skv.value, skv.key))
+        rode = sort_operands(other) if riding([other])[0] else 0
+        tracer.annotate(**{
+            names.ATTR_RECORDS: int(skv.counts.sum()),
+            names.ATTR_KEY_WORDS: sort_operands(col),
+            names.ATTR_RODE_WORDS: rode,
+            names.ATTR_TAKEN_WORDS: sort_operands(other) - rode,
+            names.ATTR_HBM_ROW_BYTES: round(
+                (skv.key.on_device_size_in_bytes()
+                 + skv.value.on_device_size_in_bytes())
+                / max(1, skv.key.shape[0]), 3)})
     bump_dispatch()
-    k, v = _sort_jit(skv.mesh, by, descending)(skv.key, skv.value, counts_dev)
+    # the op's one sync is on completion, nothing is pulled (as
+    # sharded.place_rows): the op span runs from dispatch to ready, and
+    # the first reader of the result (a pull, a top-N) waits for no sort
+    k, v = jax.block_until_ready(
+        _sort_jit(skv.mesh, by, descending)(skv.key, skv.value, counts_dev))
+    SyncStats.bump()
     return ShardedKV(skv.mesh, k, v, skv.counts.copy(),
                      key_decode=skv.key_decode,
                      value_decode=skv.value_decode)

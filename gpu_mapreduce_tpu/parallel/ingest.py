@@ -301,33 +301,44 @@ def _common_spec(arrs: List[np.ndarray]):
     return spec or (np.dtype(np.uint8), ())
 
 
+def _put_block(block: np.ndarray, dev, budget: int):
+    """One shard's row block onto its device, in messages of at most
+    ``budget`` bytes joined there."""
+    host = np.ascontiguousarray(block)
+    cap = host.shape[0]
+    rowbytes = max(1, int(host.nbytes // max(1, cap)))
+    chunk = max(1, budget // rowbytes)
+    if cap <= chunk:
+        return jax.device_put(host, dev)
+    import jax.numpy as jnp
+    return jnp.concatenate([jax.device_put(host[o:o + chunk], dev)
+                            for o in range(0, cap, chunk)])
+
+
+def _shard_devices(mesh, cap: int):
+    """(row sharding, the device of every shard in shard order) of
+    arrays ``[P*cap, ...]`` sharded by rows."""
+    from .mesh import mesh_axis_size, row_sharding
+    sharding = row_sharding(mesh)
+    dmap = sharding.addressable_devices_indices_map(
+        (mesh_axis_size(mesh) * cap,))
+    return sharding, sorted(dmap, key=lambda dev: dmap[dev][0].start or 0)
+
+
 def _put_blocks(blocks: List[np.ndarray], cap: int, mesh):
     """Device-put per-shard row blocks [cap,...] each onto ITS device in
     bounded messages (mesh.h2d_chunk_bytes — honors MR_H2D_CHUNK_WORDS
     like every other chunked-transfer site); assemble the row-sharded
     global [P*cap,...]."""
     from ..obs import get_tracer
-    from .mesh import h2d_chunk_bytes, row_sharding
-    P = len(blocks)
-    sharding = row_sharding(mesh)
-    shape = (P * cap,) + blocks[0].shape[1:]
-    dmap = sharding.addressable_devices_indices_map(shape)
+    from .mesh import h2d_chunk_bytes
+    sharding, devices = _shard_devices(mesh, cap)
+    shape = (len(blocks) * cap,) + blocks[0].shape[1:]
     budget = h2d_chunk_bytes(H2D_CHUNK_BYTES)
-    with get_tracer().span("ingest.h2d", cat="ingest", shards=P,
+    with get_tracer().span("ingest.h2d", cat="ingest", shards=len(blocks),
                            bytes=int(sum(b.nbytes for b in blocks))):
-        shards = []
-        for dev, idx in dmap.items():
-            p = (idx[0].start or 0) // cap
-            host = np.ascontiguousarray(blocks[p])
-            rowbytes = max(1, int(host.nbytes // max(1, cap)))
-            chunk = max(1, budget // rowbytes)
-            if cap > chunk:
-                import jax.numpy as jnp
-                parts = [jax.device_put(host[o:o + chunk], dev)
-                         for o in range(0, cap, chunk)]
-                shards.append(jnp.concatenate(parts))
-            else:
-                shards.append(jax.device_put(host, dev))
+        shards = [_put_block(blocks[p], dev, budget)
+                  for p, dev in enumerate(devices)]
         return jax.make_array_from_single_device_arrays(shape, sharding,
                                                         shards)
 
@@ -562,6 +573,111 @@ def mesh_map_files(mr, kv, names: Sequence[str], call: Callable) -> dict:
                                     onfault=onfault)
     return _frames_to_kv(mr, kv, prefetch_iter(stream, path="ingest.files"),
                          stats)
+
+
+# rows a shard's block of fixed-width records is rounded up to: a whole
+# number of the chip's 1024-element tiles, and NOT ``round_cap``'s power
+# of two — 10^7 records a shard would be held, moved and sorted as 2^24
+RECORD_ROWS = 1024
+
+
+def mesh_map_records(mr, kv, names: Sequence[str], reader) -> dict:
+    """The mesh path of ``map_files`` over fixed-width records
+    (``reader``: a ``utils/io.RecordFormat``), on one shard or many: no
+    tokenizer, no intern, no sink.  A file is ``n`` records by its size,
+    so every shard's block is sized before a byte is read; the pool cuts
+    each file's keys and values straight into its rows of the block
+    (every file in flight at once), and a shard's block goes to its
+    device when its last file is in, beside the reads of the later
+    shards.  A ``ShardedKV`` is born with the rows on the shard that read
+    them, the key words ordered as the key bytes are.
+
+    Under an armed fault policy (retries, ``onfault="skip"``) a file may
+    contribute nothing, which a block sized beforehand cannot take: those
+    runs go through :func:`mesh_map_files` with the reader as an ordinary
+    callback, the same rows by the generic path."""
+    from ..ft.retry import ingest_active, ingest_task
+    from ..obs import get_tracer, names as obs
+    from ..obs.context import bind
+    from .mesh import h2d_chunk_bytes, mesh_axis_size
+    from .sharded import ShardedKV
+    onfault = mr.settings.onfault
+    if ingest_active(onfault):
+        return mesh_map_files(mr, kv, names, reader)
+    mesh = mr.backend.mesh
+    P = mesh_axis_size(mesh)
+    tracer = get_tracer()
+    with tracer.span(obs.INGEST_RECORDS_PLAN, cat=obs.HOST, shards=P,
+                     files=len(names)) as sp:
+        try:
+            shards = balance_by_bytes(names, P)
+        except OSError as e:
+            from ..ft.retry import input_unreadable
+            raise input_unreadable(e) from e
+        rows = [[reader.rows(f, int(b)) for f, b in zip(files, sizes)]
+                for _, files, sizes in shards]
+        counts = np.array([sum(r) for r in rows], np.int32)
+        cap = max(RECORD_ROWS, -(-int(counts.max(initial=0)) // RECORD_ROWS)
+                  * RECORD_ROWS)
+        pool = mr._ingest_pool()
+        task = bind(ingest_task)
+        blocks, pending = [], []
+        for p, (first, files, _) in enumerate(shards):
+            key = np.empty((cap, reader.key_words), np.uint32)
+            value = np.empty((cap, reader.value_words), np.uint32)
+            key[counts[p]:] = 0         # the rows past the count: padding
+            value[counts[p]:] = 0
+            blocks.append((key, value))
+            futs, at = [], 0
+            for i, (fname, n) in enumerate(zip(files, rows[p])):
+                def cut(_itask, f, _sink, k=key[at:at + n],
+                        v=value[at:at + n]):
+                    reader.read_into(f, k, v)
+                futs.append(pool.submit(task, cut, first + i, fname, None,
+                                        onfault=onfault, shard=p,
+                                        private_sink=False))
+                at += n
+            pending.append(futs)
+        sp.set(block_bytes=P * (key.nbytes + value.nbytes))
+    budget = h2d_chunk_bytes(H2D_CHUNK_BYTES)
+    sharding, devices = _shard_devices(mesh, cap)
+    kparts, vparts = [], []
+    try:
+        for p, ((_, files, sizes), futs) in enumerate(zip(shards, pending)):
+            with tracer.span(obs.INGEST_RECORDS_READ, cat=obs.HOST, shard=p,
+                             files=len(files), bytes=int(sizes.sum())):
+                for f in futs:
+                    f.result()      # the first file's error, in file order
+            key, value = blocks[p]
+            with tracer.span(obs.INGEST_RECORDS_H2D, cat=obs.HOST, shard=p,
+                             bytes=key.nbytes + value.nbytes):
+                kparts.append(_put_block(key, devices[p], budget))
+                vparts.append(_put_block(value, devices[p], budget))
+                if p == P - 1:
+                    # the map's one wait, for every shard's blocks, once
+                    # all are on their way: a program's buffers are
+                    # allocated when it is dispatched, so the sort behind
+                    # this map would stand beside the messages of a block
+                    # still being joined (PERF.md §6, PR 36: 8.06 GiB
+                    # against 5.98 at 2 x 10^7 records)
+                    jax.block_until_ready((kparts, vparts))
+                # the host copy goes as soon as the device has it (a
+                # transfer holds its own reference until then)
+                key = value = blocks[p] = None
+    except BaseException:
+        for futs in pending:
+            for f in futs:
+                f.cancel()
+        raise
+    skv = ShardedKV(mesh, *(
+        jax.make_array_from_single_device_arrays(
+            (P * cap, width), sharding, parts)
+        for width, parts in ((reader.key_words, kparts),
+                             (reader.value_words, vparts))), counts)
+    kv.add_frame(skv)
+    return {"mode": "records", "shards": P,
+            "files_per_shard": [len(files) for _, files, _ in shards],
+            "rows_per_shard": counts.tolist()}
 
 
 def mesh_map_chunks(mr, kv, names: Sequence[str], per_file: int, sep: bytes,

@@ -131,3 +131,70 @@ def word_ranges(raw: bytes):
             lens = np.flatnonzero(edge == 1) - starts
         sp.set(words=len(starts))
         return BytesColumn.from_ranges(buf, starts, lens)
+
+
+class RecordFormat:
+    """Files of fixed-width binary records: ``record_bytes`` a record,
+    the first ``key_bytes`` of it the key, the rest the value; a file is
+    a whole number of records and nothing else (TeraSort's 100 / 10).
+
+    An instance IS the ``map_files`` callback of such files — THE record
+    map: no tokenizer and no intern.  A file's keys become
+    ``core/column.fixed_key_words`` (dense u32 words whose order is the
+    bytes' ``memcmp`` order) and its values ``fixed_value_words``; on a
+    mesh ``map_files`` hands the instance to
+    ``parallel/ingest.mesh_map_records``, which cuts every file straight
+    into its shard's block.  ``join`` is the way back, for a writer."""
+
+    def __init__(self, record_bytes: int, key_bytes: int):
+        if not 0 < key_bytes < record_bytes:
+            raise ValueError(f"a {record_bytes}-byte record with a "
+                             f"{key_bytes}-byte key")
+        self.record_bytes = int(record_bytes)
+        self.key_bytes = int(key_bytes)
+        self.value_bytes = self.record_bytes - self.key_bytes
+        self.key_words = -(-self.key_bytes // 4)
+        self.value_words = -(-self.value_bytes // 4)
+
+    def rows(self, fname: str, nbytes: int) -> int:
+        """Records in a file of ``nbytes`` bytes; a ragged tail is an
+        error, never cut or padded."""
+        from ..core.runtime import MRError
+        if nbytes % self.record_bytes:
+            raise MRError(f"{fname}: {nbytes} bytes is no whole number of "
+                          f"{self.record_bytes}-byte records")
+        return nbytes // self.record_bytes
+
+    def read_into(self, fname: str, key, value) -> None:
+        """Cut ``fname``'s records into ``key`` ``[n, key_words]`` and
+        ``value`` ``[n, value_words]`` (u32, rows of a shard's block)."""
+        import numpy as np
+
+        from ..core.column import fixed_key_words, fixed_value_words
+        from ..core.runtime import MRError
+        raw = np.fromfile(fname, np.uint8)
+        if raw.size != len(key) * self.record_bytes:
+            raise MRError(f"{fname}: {raw.size} bytes read where "
+                          f"{len(key)} records were expected")
+        raw = raw.reshape(-1, self.record_bytes)
+        fixed_key_words(raw[:, :self.key_bytes], key)
+        fixed_value_words(raw[:, self.key_bytes:], value)
+
+    def __call__(self, itask, fname, kv, ptr=None) -> None:
+        import numpy as np
+        n = self.rows(fname, os.path.getsize(fname))
+        key = np.empty((n, self.key_words), np.uint32)
+        value = np.empty((n, self.value_words), np.uint32)
+        self.read_into(fname, key, value)
+        kv.add_batch(key, value)
+
+    def join(self, key, value):
+        """``[n, record_bytes]`` bytes of the records whose key and value
+        words these are: what ``read_into`` cut, put together again."""
+        import numpy as np
+
+        from ..core.column import fixed_key_bytes, fixed_value_bytes
+        out = np.empty((len(key), self.record_bytes), np.uint8)
+        out[:, :self.key_bytes] = fixed_key_bytes(key, self.key_bytes)
+        out[:, self.key_bytes:] = fixed_value_bytes(value, self.value_bytes)
+        return out

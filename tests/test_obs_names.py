@@ -127,6 +127,18 @@ def test_every_program_lowers_under_its_declared_name(mesh):
             ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
             assert "sort" in ops and not ops & {
                 "scatter", "gather", "while"}, ops
+        if want == names.SORT_ROWS:
+            # and a fifth (PR 36): the rows ride ONE sort through
+            # ops/sort.sort_carrying; descending is complemented words,
+            # not a reversal by scatter
+            from gpu_mapreduce_tpu.parallel import group
+            for descending in (False, True):
+                text = group._sort_jit(mesh, "value", descending).lower(
+                    SDS((64,), jnp.uint64), SDS((64,), jnp.uint64),
+                    SDS((8,), jnp.int32)).as_text()
+                ops = re.findall(r"stablehlo\.(\w+)", text)
+                assert ops.count("sort") == 1 and not set(ops) & {
+                    "scatter", "gather", "while"}, ops
         if want in (names.TRI_ORIENT, names.TRI_WEDGES):
             # the same rule for the wedge walk: sorts, no scatter, and no
             # ``while`` (a searchsorted is a gather a round)
@@ -442,6 +454,24 @@ def test_invertedindex_on_four_devices_says_which_shard(traced, corpus,
     assert ex["recv_rows_mean"] <= ex["recv_rows_max"] <= npairs
 
 
+def _terasort(mesh, out):
+    """TeraSort (ISSUE 36) over three small files of seeded records: the
+    record map, the sampled splitters, the part files' bytes."""
+    from gpu_mapreduce_tpu.apps.terasort import TeraSort
+    rng = np.random.default_rng(36)
+    paths = []
+    for i in range(3):
+        paths.append(os.path.join(out, f"in-{i}.dat"))
+        rng.integers(0, 256, (200 + i, 100), dtype=np.uint8).tofile(paths[-1])
+    ts = TeraSort(comm=mesh)
+    n = ts.run(paths, outdir=os.path.join(out, "parts"))
+    parts = []
+    for path in ts.parts:
+        with open(path, "rb") as f:
+            parts.append(f.read())
+    return n, parts
+
+
 def _wordfreq_script(mesh, corpus):
     from gpu_mapreduce_tpu.oink.script import OinkScript
     s = OinkScript(comm=mesh, screen=io.StringIO())
@@ -517,7 +547,8 @@ def _ancestors(events):
     return {e["id"]: set(chain(e)) for e in events}
 
 
-@pytest.mark.parametrize("entry", ["invindex", "oink", "oink-include"])
+@pytest.mark.parametrize("entry", ["invindex", "oink", "oink-include",
+                                   "terasort"])
 def test_an_entry_point_call_is_one_root_span(mesh, traced, corpus,
                                               tmp_path, entry):
     """ISSUE 34: one ``entry`` span a call into an entry point, parent 0,
@@ -527,6 +558,9 @@ def test_an_entry_point_call_is_one_root_span(mesh, traced, corpus,
     if entry == "invindex":
         _invindex(mesh, corpus, str(tmp_path))
         name, calls = names.INVINDEX_RUN, 1
+    elif entry == "terasort":
+        _terasort(mesh, str(tmp_path))
+        name, calls = names.TERASORT_RUN, 1
     else:
         from gpu_mapreduce_tpu.oink.script import OinkScript
         lines = ["rmat 6 4 0.57 0.19 0.19 0.05 0.0 1 -o NULL mre",
@@ -588,6 +622,8 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
     off_words = _wordfreq_script(mesh, corpus)
     (tmp_path / "e0").mkdir(), (tmp_path / "e1").mkdir()
     off_enum = _enum_script(mesh, str(tmp_path / "e0"))[1:]
+    (tmp_path / "t0").mkdir(), (tmp_path / "t1").mkdir()
+    off_sorted = _terasort(mesh, str(tmp_path / "t0"))
     assert built == []          # every site returned NULL_SPAN
 
     tr.enable(ring=1 << 16)
@@ -599,12 +635,14 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
         on_rows = _host_batch(mesh)
         on_words = _wordfreq_script(mesh, corpus)
         on_enum = _enum_script(mesh, str(tmp_path / "e1"))[1:]
+        on_sorted = _terasort(mesh, str(tmp_path / "t1"))
     finally:
         tr.clear()
         tr.disable()
     # every declared span name is one the program really opens
     assert set(names.SPANS) <= set(built)
     assert on_graph == off_graph and on_enum == off_enum
+    assert on_sorted == off_sorted and on_sorted[0] == 603
     assert (on_counts, on_parts, on_rows, on_words) == (
         off_counts, off_parts, off_rows, off_words)
 

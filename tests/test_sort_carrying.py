@@ -1,8 +1,8 @@
 """``ops/sort.sort_carrying``: one payload sort, written once (ROADMAP
-C21).  Phase 1 of the shuffle is its one caller today
-(``tests/test_shuffle_phase1.py``); ``_local_sort``, ``_pack`` and
-``rank_graph`` can take it up as call-site changes, so the helper is
-held to numpy here, alone."""
+C21).  Phase 1 of the shuffle (``tests/test_shuffle_phase1.py``) and the
+per-shard ``sort_keys`` / ``sort_values`` program (``tests/test_terasort.py``)
+call it; ``_local_sort``, ``_pack`` and ``rank_graph`` can take it up as
+call-site changes, so the helper is held to numpy here, alone."""
 
 import re
 
@@ -63,6 +63,29 @@ def test_sort_carrying_equals_numpy(carry, nkeys, stable):
     for got, c in zip(scols, cols):
         assert got.dtype == c.dtype and got.shape == c.shape
         assert np.array_equal(np.asarray(got), c[order])
+
+
+@pytest.mark.parametrize("stable", [True, False], ids=["stable", "unstable"])
+def test_three_key_words_carry_a_wide_row_by_index(stable):
+    """TeraSort's row (ISSUE 36): three u32 key words ahead of 23 payload
+    words, which is past ``RIDE_WORDS`` and so comes by the row index; a
+    narrow column after it still rides."""
+    rng = np.random.default_rng(36)
+    keys = [rng.integers(0, 3, N).astype(np.uint32) for _ in range(3)]
+    if not stable:
+        keys.append(rng.permutation(N).astype(np.int32))
+    wide = rng.integers(0, 1 << 32, (N, 23), dtype=np.uint64).astype(np.uint32)
+    narrow = rng.integers(0, 250, N).astype(np.uint16)
+    assert riding([wide, narrow]) == [False, True]
+    skeys, (swide, snarrow) = jax.jit(
+        lambda ks, cs: sort_carrying(ks, cs, stable=stable))(
+            keys, [wide, narrow])
+    order = np.lexsort(tuple(reversed(keys)))
+    for got, k in zip(skeys, keys):
+        assert np.array_equal(np.asarray(got), k[order])
+    assert swide.dtype == wide.dtype and swide.shape == wide.shape
+    assert np.array_equal(np.asarray(swide), wide[order])
+    assert np.array_equal(np.asarray(snarrow), narrow[order])
 
 
 def _sds(dt, w, n=N):
