@@ -4,10 +4,12 @@ The reference's convert is purely local per rank (SURVEY.md §3.3: "No MPI at
 all — the parallelism came from aggregate").  Same here: each shard sorts its
 own block and finds group boundaries under ``shard_map``; no collectives.
 
-Both halves are sorts: the grouped layout (`grouped_layout`) packs each
-group's first row to the front by a sort with the key columns as
-payloads, because a scatter is what the chip does worst (the readings
-are in that function's docstring).
+Both halves are sorts whose rows ride them: `_local_sort` orders a shard
+by key with the value as payload, and the grouped layout
+(`grouped_layout`) packs each group's first row to the front by a sort
+with the key columns as payloads, because a scatter is what the chip
+does worst and a gather behind a key-only sort next to worst (the
+readings are in the two functions' docstrings).
 """
 
 from __future__ import annotations
@@ -30,18 +32,32 @@ from .sharded import (ShardedKMV, ShardedKV, SyncStats, _decode_col,
                       round_cap)
 
 
-def _sort_key_tuple(key, valid):
-    """lexsort key tuple putting invalid rows last, then by key ascending.
-    numpy/jnp lexsort: LAST key is primary."""
-    cols = [key] if key.ndim == 1 else [key[:, j] for j in range(key.shape[1] - 1, -1, -1)]
-    return tuple(cols) + (~valid,)
-
-
 def _local_sort(key, value, count):
-    cap = key.shape[0]
-    valid = jnp.arange(cap) < count
-    order = jnp.lexsort(_sort_key_tuple(key, valid))
-    return (jnp.take(key, order, axis=0), jnp.take(value, order, axis=0), valid)
+    """A shard's rows in key order, the rows past ``count`` last whatever
+    they hold: ``(sorted key, sorted value, valid)``.  ONE stable sort
+    (ops/sort.sort_carrying), as `sort_rows` below: the key's columns are
+    its keys behind the flag of the rows past the count, so they come out
+    of the sort itself, and the value rides or comes by the sorted row
+    index, as its dtype and width say.  Stable, so a group's values keep
+    their arrival order, which `reduce` callbacks and `first_sharded`
+    see.  A key-only sort and two gathers behind it was 2.33 s a job of
+    `graph-build-1chip` where the sort was 0.42 s (PERF.md §6, PR 38)."""
+    valid = jnp.arange(key.shape[0], dtype=jnp.int32) < count
+    past = (~valid).astype(jnp.uint8)
+    (_, *scols), (svalue,) = sort_carrying((past, *columns(key)), (value,))
+    skey = scols[0] if key.ndim == 1 else jnp.stack(scols, axis=1)
+    return skey, svalue, valid
+
+
+def _sort_words(col, other) -> dict:
+    """What a traced op span says of a sort by ``col`` that carries
+    ``other``: the key's 32-bit operands, and the carried words that rode
+    as payloads or were taken by the sorted row index (ops/sort.riding).
+    ``taken_words`` 0 is a program without a gather."""
+    rode = sort_operands(other) if riding([other])[0] else 0
+    return {names.ATTR_KEY_WORDS: sort_operands(col),
+            names.ATTR_RODE_WORDS: rode,
+            names.ATTR_TAKEN_WORDS: sort_operands(other) - rode}
 
 
 def _boundary(skey, valid):
@@ -188,7 +204,8 @@ def convert_sharded(skv: ShardedKV, counters=None) -> ShardedKMV:
         tracer.annotate(**{
             names.ATTR_ROWS: int(skv.counts.sum()),
             names.ATTR_GROUPS: int(gcounts.sum()),
-            names.ATTR_GROUP_ROWS_MAX: int(np.asarray(gmax).max())})
+            names.ATTR_GROUP_ROWS_MAX: int(np.asarray(gmax).max()),
+            **_sort_words(skv.key, skv.value)})
     return ShardedKMV(skv.mesh, ukey, nvalues, voffsets, svalue,
                       gcounts, skv.counts.copy(), key_decode=skv.key_decode,
                       value_decode=skv.value_decode)
@@ -415,12 +432,9 @@ def sort_sharded(skv: ShardedKV, by: str = "key",
     if tracer.enabled:          # on the sort_keys / sort_values op span
         col, other = ((skv.key, skv.value) if by == "key"
                       else (skv.value, skv.key))
-        rode = sort_operands(other) if riding([other])[0] else 0
         tracer.annotate(**{
             names.ATTR_RECORDS: int(skv.counts.sum()),
-            names.ATTR_KEY_WORDS: sort_operands(col),
-            names.ATTR_RODE_WORDS: rode,
-            names.ATTR_TAKEN_WORDS: sort_operands(other) - rode,
+            **_sort_words(col, other),
             names.ATTR_HBM_ROW_BYTES: round(
                 (skv.key.on_device_size_in_bytes()
                  + skv.value.on_device_size_in_bytes())

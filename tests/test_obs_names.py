@@ -536,6 +536,26 @@ def test_host_convert_says_its_hub_group(traced):
             conv[names.ATTR_GROUP_ROWS_MAX]) == (6, 3, 3)
 
 
+@pytest.mark.parametrize("vdtype,words", [(np.uint8, (2, 1, 0)),
+                                          (np.float64, (2, 0, 2))],
+                         ids=["u8_rides", "f64_by_index"])
+def test_mesh_convert_says_how_its_sort_carried_the_value(mesh, traced,
+                                                          vdtype, words):
+    """ISSUE 38: ``jit_convert_sort`` is one payload sort, and the
+    ``convert`` span says what `ops/sort.riding` decided for the value:
+    ``taken_words`` 0 is a program without a gather."""
+    from gpu_mapreduce_tpu import MapReduce
+    mr = MapReduce(mesh)
+    mr.map(1, lambda i, kv, p: kv.add_batch(
+        np.arange(64, dtype=np.uint64) % 5, np.arange(64).astype(vdtype)))
+    mr.aggregate()
+    assert mr.convert() == 5
+    (conv,) = _attrs(_tree(traced.events()))[names.CONVERT_SPAN]
+    assert (conv[names.ATTR_KEY_WORDS], conv[names.ATTR_RODE_WORDS],
+            conv[names.ATTR_TAKEN_WORDS]) == words
+    assert (conv[names.ATTR_ROWS], conv[names.ATTR_GROUPS]) == (64, 5)
+
+
 def _ancestors(events):
     by_id = {e["id"]: e for e in events}
 
