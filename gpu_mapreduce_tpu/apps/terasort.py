@@ -9,10 +9,15 @@ In MR-MPI's terms, and built from ``MapReduce`` operations only:
   map: a file is ``n`` records, the key bytes become dense u32 words
   whose order is the bytes' ``memcmp`` order, the value bytes travel
   beside them; no tokenizer, no intern, no table;
-* ``aggregate(range partitioner)`` — the reference's ``aggregate`` takes
-  a user hash (``src/mapreduce.cpp:469-472``), and a total-order
-  partitioner is one: the number of sampled splitters a key is not
-  below.  On one shard it is MR-MPI's no-op;
+* ``aggregate(TotalOrder(splitters))`` — the reference's ``aggregate``
+  takes a user hash (``src/mapreduce.cpp:469-472``); the total-order
+  partitioner is handed in as one and is a destination spec of the
+  exchange in its own right (``parallel/shuffle.TotalOrder``): the
+  number of sampled splitters a key is not below, the splitters an
+  operand of the cached phase 1, so that every job of a process runs one
+  program whatever its records.  The splitters come from ``SAMPLE`` keys,
+  each shard's share read at an even stride over its own rows by one
+  small device program.  On one shard it is MR-MPI's no-op;
 * ``sort_keys(1)`` — one device sort a shard
   (``parallel/group.sort_sharded``: the key words are the sort's keys,
   the value comes by the row index);
@@ -27,14 +32,19 @@ Benchmark's Indy rules ask for no stable sort).
 from __future__ import annotations
 
 import collections
+import functools
 import os
-from typing import Callable, Optional, Sequence
+from typing import Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.frame import KVFrame
 from ..core.mapreduce import MapReduce
 from ..obs import get_tracer, names
+from ..parallel.mesh import row_sharding, row_spec
+from ..parallel.shuffle import TotalOrder
 from ..utils.io import RecordFormat, findfiles
 
 RECORD_BYTES = 100
@@ -44,25 +54,33 @@ WRITE_ROWS = 1 << 20    # records put together and written at a time
 WRITE_AHEAD = 4         # blocks the pool joins ahead of the write
 
 
-def range_partitioner(splitters: np.ndarray) -> Callable:
-    """The user hash of a total-order ``aggregate``: ``keys [n, w]`` (the
-    record map's key words) → the number of ``splitters [s, w]`` (sorted)
-    each key is not below, so that destination *i* takes the keys in
-    ``[splitter i-1, splitter i)``.  Every key is compared with every
-    splitter (a ``searchsorted`` is a gather a round on the chip,
-    ``parallel/shuffle._dest_fn``); the words compare as unsigned
-    numbers, the first the most significant."""
-    import jax.numpy as jnp
-    splitters = np.ascontiguousarray(splitters, np.uint32)
+def sample_shares(counts) -> np.ndarray:
+    """Keys each shard gives to the sample: its share of ``SAMPLE`` by its
+    share of the rows, rounded up, and no more than it holds."""
+    # 10^5 keys times a shard's 5 x 10^6 rows is past the counts' int32
+    counts = np.asarray(counts, np.int64)
+    total = max(int(counts.sum()), 1)
+    return np.minimum(counts, -(-SAMPLE * counts // total))
 
-    def dest(keys):
-        k, s = keys[:, None, :], jnp.asarray(splitters)[None, :, :]
-        w = splitters.shape[1]
-        ge = k[..., w - 1] >= s[..., w - 1]
-        for j in range(w - 2, -1, -1):
-            ge = (k[..., j] > s[..., j]) | ((k[..., j] == s[..., j]) & ge)
-        return jnp.sum(ge, axis=1, dtype=jnp.uint32)
-    return dest
+
+@functools.lru_cache(maxsize=8)
+def _sample_jit(mesh, slots: int):
+    """The sample's device program: ``slots`` key rows a shard, slot *i*
+    the shard's row ``i * count // take`` — an even stride over its own
+    rows, read where they lie (no gather over the sharded column).  A
+    shard's slots past its ``take`` hold rows the host drops."""
+    spec = row_spec(mesh)
+
+    @jax.jit
+    def terasort_sample(key, count, take):
+        def body(k, c, t):
+            at = jnp.arange(slots, dtype=jnp.int64) * c[0] // jnp.maximum(
+                t[0], 1)
+            return jnp.take(k, at, axis=0, mode="clip")
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+                             out_specs=spec)(key, count, take)
+
+    return terasort_sample
 
 
 class TeraSort:
@@ -99,43 +117,45 @@ class TeraSort:
         return int(nrecords)
 
     # -- the splitters --------------------------------------------------------
-    def _partitioner(self) -> Optional[Callable]:
-        """The range partitioner of this dataset, from ``SAMPLE`` of its
-        keys at an even stride over every shard's rows; None on one
-        shard, where nothing is partitioned and nothing is sampled."""
+    def _partitioner(self) -> Optional[TotalOrder]:
+        """The total order of this dataset, from ``SAMPLE`` of its keys at
+        an even stride over every shard's rows; None on one shard, where
+        nothing is partitioned and nothing is sampled."""
         nshards = self.mr.backend.nprocs
         if nshards == 1:
             return None
         with get_tracer().span(names.TERASORT_SAMPLE, cat=names.HOST) as sp:
-            keys = self._sample_keys()
+            keys, pulled = self._sample_keys()
             if len(keys):
                 # ascending, the first word the most significant
                 keys = keys[np.lexsort(keys.T[::-1])]
                 at = (np.arange(1, nshards) * len(keys)) // nshards
                 self.splitters = keys[at]
-            sp.set(sampled=len(keys), splitters=len(self.splitters))
-        return range_partitioner(self.splitters)
+            sp.set(sampled=len(keys), splitters=len(self.splitters),
+                   d2h_bytes=pulled)
+        return TotalOrder(self.splitters)
 
-    def _sample_keys(self) -> np.ndarray:
+    def _sample_keys(self) -> tuple:
+        """``(keys [n, words], bytes pulled from the mesh)``: each shard's
+        share of ``SAMPLE`` by its share of the rows."""
         fr = self.mr.kv.one_frame()
-        if isinstance(fr, KVFrame):     # the map fell back to the host
-            counts, cap = np.array([len(fr)]), len(fr)
-            key = np.asarray(fr.key.data)
-        else:
-            counts, cap, key = fr.counts, fr.cap, fr.key
-        total = int(counts.sum())
-        rows = []
-        for p, c in enumerate(counts.tolist()):
-            take = min(c, -(-SAMPLE * c // max(total, 1)))
-            if take:
-                rows.append(p * cap + (np.arange(take) * c) // take)
-        if not rows:
-            return np.zeros((0, self.format.key_words), np.uint32)
-        rows = np.concatenate(rows)
-        if isinstance(key, np.ndarray):
-            return key[rows]
-        import jax.numpy as jnp
-        return np.asarray(jnp.take(key, jnp.asarray(rows), axis=0))
+        on_host = isinstance(fr, KVFrame)   # the map fell back to the host
+        counts = np.array([len(fr)]) if on_host else np.asarray(fr.counts)
+        take = sample_shares(counts)
+        if not take.any():
+            return np.zeros((0, self.format.key_words), np.uint32), 0
+        if on_host:
+            rows = np.arange(take[0]) * counts[0] // take[0]
+            return np.asarray(fr.key.data)[rows], 0
+        slots = int(take.max())
+        on_mesh = functools.partial(jax.device_put,
+                                    device=row_sharding(fr.mesh))
+        got = np.asarray(_sample_jit(fr.mesh, slots)(
+            fr.key, on_mesh(counts.astype(np.int32)),
+            on_mesh(take.astype(np.int32))))
+        got = got.reshape(len(take), slots, -1)
+        return (np.concatenate([got[p, :t] for p, t in enumerate(take)]),
+                got.nbytes)
 
     # -- the part files -------------------------------------------------------
     def _write_parts(self, outdir: str) -> None:

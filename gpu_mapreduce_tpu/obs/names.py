@@ -30,6 +30,8 @@ SORT_INTERNED = "jit_sort_interned"
 SHUFFLE_PHASE1 = "jit_shuffle_phase1"
 SHUFFLE_PHASE2 = "jit_shuffle_phase2"
 SHUFFLE_PHASE2_WIRE = "jit_shuffle_phase2_wire"
+# apps/terasort.py
+TERASORT_SAMPLE_KEYS = "jit_terasort_sample"        # a stride of each shard's keys
 # parallel/staging.py
 STAGE_RANK_GRAPH = "jit_stage_rank_graph"           # vertex table + edge ranks
 STAGE_TRIM_VERTS = "jit_stage_trim_verts"
@@ -60,7 +62,7 @@ PROGRAMS = (
     STAGE_TRIM_VERTS, PLACE_ROWS, CONCAT_ROWS, LEVEL_ROWS, REMAP_IDS,
     CC_LOOP, PAGERANK_LOOP, RMAT_EDGES, RMAT_EDGE_ROWS,
     TRI_ORIENT, TRI_WEDGES, TRI_APPEND, TRI_GROW, TRI_ROWS, LUBY_LOOP,
-    SSSP_LOOP, SSSP_WEIGHTS,
+    SSSP_LOOP, SSSP_WEIGHTS, TERASORT_SAMPLE_KEYS,
 )
 
 # parallel/devkernels.py's two generic mappers run one program per kernel
@@ -150,13 +152,15 @@ INGEST_RECORDS_H2D = "ingest.records.h2d"       # shard, bytes; the puts'
 #                             dispatch, the host block let go; the last
 #                             shard's also waits for every shard's blocks
 # apps/terasort.py
-TERASORT_SAMPLE = "terasort.sample"             # sampled, splitters
+TERASORT_SAMPLE = "terasort.sample"             # sampled, splitters,
+#                                                 d2h_bytes
 TERASORT_PULL = "terasort.pull"                 # shard, records, d2h_bytes
 TERASORT_WRITE = "terasort.write"               # shard, records, bytes
 
 # older spans that metrics quote by name
 SHUFFLE_EXCHANGE = "shuffle.exchange"           # ..., recv_rows_max, _mean,
-#                                                 cols_rode, cols_by_index
+#                                                 cols_rode, cols_by_index,
+#                                                 dest, phase1_built
 SHUFFLE_COUNT_SYNC = "shuffle.count_sync"
 OINK_RMAT = "oink.rmat"                         # rounds
 

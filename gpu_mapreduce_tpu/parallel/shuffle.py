@@ -292,7 +292,12 @@ def _dest_fn(dest, nprocs: int, mesh) -> Callable:
       (``searchsorted(ends, g, "right")``).  The redistribution
       schedule (offsets/ends, both hashable tuples) is computed
       host-side from the counts — the data itself moves only through
-      the collective, the 2112.01075 recipe."""
+      the collective, the 2112.01075 recipe;
+    * ("order",) — a total order (:class:`TotalOrder`): the number of
+      sorted splitter keys a row's key words are not below.  The one
+      spec whose function takes an operand, ``fn(keys, splitters)``:
+      the splitters are data of the job, so a job over other records
+      runs the same program."""
     kind = dest[0]
     if kind == "hash":
         fn = dest[1]
@@ -323,7 +328,52 @@ def _dest_fn(dest, nprocs: int, mesh) -> Callable:
                                     side="right", method="compare_all"
                                     ).astype(jnp.int32)
         return ranged
+    if kind == "order":
+        def ordered(keys, splitters):
+            if splitters.shape[0] >= nprocs:
+                raise ValueError(
+                    f"{splitters.shape[0]} splitters name "
+                    f"{splitters.shape[0] + 1} destinations, the mesh "
+                    f"has {nprocs}")
+            return order_dest(keys, splitters)
+        return ordered
     raise ValueError(dest)
+
+
+def order_dest(keys, splitters):
+    """``keys [n, w]`` (or ``[n]``) → the number of ``splitters [s, w]``
+    (sorted, the keys' dtype) each key is not below, uint32: destination
+    *i* takes the keys in ``[splitter i-1, splitter i)``, a key equal to
+    a splitter goes up.  Every key is compared with every splitter (a
+    ``searchsorted`` is a gather a round on the chip); a key's words
+    compare as numbers, the first the most significant."""
+    if keys.ndim == 1:
+        keys = keys[:, None]
+    k, s = keys[:, None, :], splitters.reshape(1, -1, keys.shape[1])
+    w = keys.shape[1]
+    ge = k[..., w - 1] >= s[..., w - 1]
+    for j in range(w - 2, -1, -1):
+        ge = (k[..., j] > s[..., j]) | ((k[..., j] == s[..., j]) & ge)
+    return jnp.sum(ge, axis=1, dtype=jnp.uint32)
+
+
+class TotalOrder:
+    """The user hash of a total-order ``aggregate``: ``mr.aggregate(
+    TotalOrder(splitters))`` sends every row to the shard that owns its
+    key range (:func:`order_dest`), so that shard *i*'s keys all precede
+    shard *i+1*'s — Hadoop's ``TotalOrderPartitioner``.  ``splitters
+    [s, w]``: sorted keys in the dataset's key dtype, at most one fewer
+    than the shards.
+
+    ``aggregate`` recognises it (as it does a ``host_hash``) and runs the
+    ``("order",)`` spec with the splitters as an operand of phase 1; a
+    caller that only calls it gets the same destinations."""
+
+    def __init__(self, splitters):
+        self.splitters = np.ascontiguousarray(splitters)
+
+    def __call__(self, keys):
+        return order_dest(keys, jnp.asarray(self.splitters))
 
 
 # bounded executable caches (ISSUE 2 satellite): the pre-plan caches
@@ -342,7 +392,8 @@ PHASE2_CACHE = LRUCache(env_knob("MRTPU_JIT_CACHE", int, 64),
 def _phase1_jit(mesh, dest, donate: bool = False, wire=None):
     """Cache the jitted phase1 only for stable dest specs — a per-call
     user hash lambda would defeat reuse (and one-shot entries would
-    churn the LRU), so those build uncached (old behavior).
+    churn the LRU), so those build uncached (old behavior).  The
+    ``("order",)`` program takes the splitters as a fourth operand.
 
     ``donate=True`` (exec/: MRTPU_DONATE) donates the key/value inputs —
     the dest-sorted outputs are same-shape/dtype, so XLA aliases the
@@ -364,22 +415,21 @@ def _phase1_jit(mesh, dest, donate: bool = False, wire=None):
 
 def _phase1_build(mesh, dest, donate: bool = False, wire=None):
     nprocs = mesh_axis_size(mesh)
-    dest_of = _dest_fn(dest, nprocs, mesh)
+    dest_fn = _dest_fn(dest, nprocs, mesh)
     spec = row_spec(mesh)
+    nouts = 3 if wire is None else 4
+    # the spec's operands (the splitters of a total order) reach every
+    # shard whole, behind the three row-sharded arguments
+    nargs = 1 if dest[0] == "order" else 0
 
-    if wire is None:
-        def body(k, v, c):
-            return phase1_shard_body(nprocs, dest_of, None, k, v, c)[:3]
-        nouts = 3
-    else:
-        def body(k, v, c):
-            return phase1_shard_body(nprocs, dest_of, wire, k, v, c)
-        nouts = 4
+    def body(k, v, c, *args):
+        return phase1_shard_body(
+            nprocs, lambda keys: dest_fn(keys, *args), wire, k, v, c)[:nouts]
 
-    def shuffle_phase1(key, value, count):
+    def shuffle_phase1(key, value, count, *args):
         return jax.shard_map(
-            body, mesh=mesh, in_specs=(spec, spec, spec),
-            out_specs=(spec,) * nouts)(key, value, count)
+            body, mesh=mesh, in_specs=(spec,) * 3 + (P(),) * nargs,
+            out_specs=(spec,) * nouts)(key, value, count, *args)
 
     # phase 1 is shape-preserving (dest-sorted rows), so donation always
     # aliases — the biggest win, on every aggregate/gather
@@ -629,10 +679,12 @@ def free_if_donated(kv, skv) -> bool:
 
 
 def exchange(skv: ShardedKV, dest, transport: int = 1,
-             counters=None) -> ShardedKV:
+             counters=None, dest_args: tuple = ()) -> ShardedKV:
     """Full ragged exchange: route every valid row to its dest shard.
-    ``dest`` is a hashable spec (see :func:`_dest_fn`).  The intern table
-    of byte-keyed datasets rides along (ids move, bytes stay put).
+    ``dest`` is a hashable spec (see :func:`_dest_fn`), ``dest_args`` the
+    arrays its function takes beside the keys (the splitters of
+    ``("order",)``).  The intern table of byte-keyed datasets rides along
+    (ids move, bytes stay put).
 
     Emits a ``shuffle.exchange`` child span (obs/) under the calling MR
     op carrying the flow-control telemetry (bucket/rounds/caps, useful
@@ -660,11 +712,12 @@ def exchange(skv: ShardedKV, dest, transport: int = 1,
         tr = get_tracer()
         if not tr.enabled:
             return _exchange_impl(skv, dest, transport, counters,
-                                  NULL_SPAN)
+                                  NULL_SPAN, dest_args)
         with tr.span("shuffle.exchange", cat="shuffle",
                      nprocs=mesh_axis_size(skv.mesh),
-                     transport=transport) as sp:
-            return _exchange_impl(skv, dest, transport, counters, sp)
+                     transport=transport, dest=_dest_kind(dest)) as sp:
+            return _exchange_impl(skv, dest, transport, counters, sp,
+                                  dest_args)
 
     def _retryable(e):
         try:
@@ -675,6 +728,13 @@ def exchange(skv: ShardedKV, dest, transport: int = 1,
     return retry_call("shuffle.exchange", _once,
                       detail=f"P={mesh_axis_size(skv.mesh)}",
                       retryable=_retryable)
+
+
+def _dest_kind(dest) -> str:
+    """What the ``shuffle.exchange`` span says of its spec: ``hash`` (the
+    lookup3 default), ``user`` (a device callable: phase 1 is built for
+    this exchange alone), ``order``, ``fixed_mod`` or ``range``."""
+    return "user" if dest[0] == "hash" and dest[1] is not None else dest[0]
 
 
 def _dispatch_phase2(plan, mesh, transport, donate2, skey, svalue,
@@ -693,7 +753,7 @@ def _dispatch_phase2(plan, mesh, transport, donate2, skey, svalue,
 
 
 def _exchange_impl(skv: ShardedKV, dest, transport: int,
-                   counters, sp) -> ShardedKV:
+                   counters, sp, dest_args: tuple = ()) -> ShardedKV:
     from . import wire as _wire
     mesh = skv.mesh
     nprocs = mesh_axis_size(mesh)
@@ -712,13 +772,14 @@ def _exchange_impl(skv: ShardedKV, dest, transport: int,
     counts_dev = jax.device_put(skv.counts.astype(np.int32),
                                 row_sharding(mesh))
     bump_dispatch()
-    stats_local = None
-    if wire_on:
-        skey, svalue, counts_local, stats_local = _phase1_jit(
-            mesh, dest, donate, wire=elig)(skv.key, skv.value, counts_dev)
-    else:
-        skey, svalue, counts_local = _phase1_jit(mesh, dest, donate)(
-            skv.key, skv.value, counts_dev)
+    phase1 = _phase1_jit(mesh, dest, donate, wire=elig)
+    traced = phase1._cache_size()
+    skey, svalue, counts_local, *stats = phase1(
+        skv.key, skv.value, counts_dev, *dest_args)
+    stats_local = stats[0] if stats else None
+    # whether this exchange traced and lowered a phase 1 of its own (a new
+    # spec or shape, or a user hash) or ran one the process already had
+    sp.set(phase1_built=phase1._cache_size() - traced)
     # speculative phase 2: enqueue with last time's plan BEFORE the
     # count-matrix pull, so the pull overlaps device work (async
     # dispatch) instead of gating it
@@ -912,11 +973,15 @@ def aggregate_kv(backend, mr, hash_fn: Optional[Callable]):
             _replace_kv_frames(kv, frame)
         return
     skv = _shard(frame) if isinstance(frame, KVFrame) else frame
+    # a total order is a spec of its own, its splitters an operand: one
+    # program for every job; any other device callable is ("hash", fn)
+    dest, dest_args = ("hash", hash_fn), ()
+    if isinstance(hash_fn, TotalOrder):
+        dest, dest_args = ("order",), (hash_fn.splitters,)
     t = Timer()
     try:
-        out = exchange(skv, ("hash", hash_fn),
-                       transport=mr.settings.all2all,
-                       counters=mr.counters)
+        out = exchange(skv, dest, transport=mr.settings.all2all,
+                       counters=mr.counters, dest_args=dest_args)
     except BaseException:
         free_if_donated(kv, skv)
         raise

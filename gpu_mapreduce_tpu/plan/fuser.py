@@ -116,9 +116,13 @@ def _reduce_stage_op(st: PlanStage) -> Optional[str]:
 
 def _agg_hash(st: PlanStage):
     """(ok, hash_fn) for an aggregate stage: host-evaluated hashes break
-    fusion (they need per-key python on the controller)."""
+    fusion (they need per-key python on the controller), and a total
+    order runs eagerly, where its splitters are an operand of a cached
+    phase 1 and not constants of a program a job."""
+    from ..parallel.shuffle import TotalOrder
     fn = st.args[0] if st.args else st.kw.get("hash_fn")
-    if fn is not None and getattr(fn, "host_hash", False):
+    if fn is not None and (getattr(fn, "host_hash", False)
+                           or isinstance(fn, TotalOrder)):
         return False, fn
     return True, fn
 

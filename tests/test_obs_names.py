@@ -31,7 +31,7 @@ def mesh():
 def _programs(mesh):
     """(declared name, lowered program) of every named program, at tiny
     shapes on the 8-device CPU mesh."""
-    from gpu_mapreduce_tpu.apps import invertedindex as app
+    from gpu_mapreduce_tpu.apps import invertedindex as app, terasort
     from gpu_mapreduce_tpu.models import cc, luby, pagerank, rmat, sssp, tri
     from gpu_mapreduce_tpu.parallel import (devkernels, group, sharded,
                                             shuffle, staging)
@@ -105,6 +105,8 @@ def _programs(mesh):
             SDS((), i32))),
         (names.SSSP_WEIGHTS, sssp.sssp_weights.lower(
             SDS((64,), jnp.float64), edges[2])),
+        (names.TERASORT_SAMPLE_KEYS, terasort._sample_jit(mesh, 4).lower(
+            SDS((64, 3), u32), cnt, cnt)),
     ]
 
 
@@ -472,6 +474,39 @@ def _terasort(mesh, out):
     return n, parts
 
 
+def test_terasort_on_four_devices_says_how_its_rows_were_sent(traced,
+                                                             tmp_path):
+    """ISSUE 39: what TeraSort has only when P > 1.  The sample says what
+    it pulled; the exchange says that its destinations are a total order
+    and whether it built a phase 1 of its own (the first job of a process
+    does, the second, over the same shapes, does not); every shard has a
+    pull and a write."""
+    from gpu_mapreduce_tpu.parallel import shuffle
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    shuffle.PHASE1_CACHE.clear()
+    mesh = make_mesh(4)
+    for job in range(2):
+        (tmp_path / str(job)).mkdir()
+        n, parts = _terasort(mesh, str(tmp_path / str(job)))
+        assert len(parts) == 4 and sum(map(len, parts)) == n * 100
+    tree = _tree(traced.events())
+    where, args = _where(tree), _attrs(tree)
+    assert where[names.TERASORT_SAMPLE] == {(names.HOST, names.TERASORT_RUN)}
+    for sample in args[names.TERASORT_SAMPLE]:
+        # every key is sampled (603 < SAMPLE), three words a key, and the
+        # fullest shard's share of slots is pulled from each of the four
+        assert (sample["sampled"], sample["splitters"]) == (n, 3)
+        assert n * 12 <= sample["d2h_bytes"] <= 4 * 202 * 12
+    ex = args[names.SHUFFLE_EXCHANGE]
+    assert [a["dest"] for a in ex] == ["order", "order"]
+    assert [a["phase1_built"] for a in ex] == [1, 0]
+    assert all(a["rows"] == n and a["nprocs"] == 4 for a in ex)
+    for name in (names.TERASORT_PULL, names.TERASORT_WRITE):
+        assert [a["shard"] for a in args[name]] == [0, 1, 2, 3] * 2
+        assert sum(a["records"] for a in args[name]) == 2 * n
+    assert sum(a["bytes"] for a in args[names.TERASORT_WRITE]) == 2 * n * 100
+
+
 def _wordfreq_script(mesh, corpus):
     from gpu_mapreduce_tpu.oink.script import OinkScript
     s = OinkScript(comm=mesh, screen=io.StringIO())
@@ -511,8 +546,13 @@ def test_wordfreq_emits_the_word_map_and_top_n_spans(traced, corpus):
     op = by_name["map_files"]
     assert all(op["ts"] <= e["ts"] and e["ts"] + e["dur"] <= op["ts"]
                + op["dur"] and e["trace"] == op["trace"] for e in spans)
-    assert 0 < op["args"]["intern_busy_s"] <= sum(
-        e["dur"] for e in spans) * 1e-6 + 1e-3
+    # a task's seconds are taken round its span, so they hold the span and
+    # whatever the thread waited to open and close it (under loaded cores
+    # more than a millisecond: the old upper bound of the spans' sum plus
+    # 1 ms failed under six workers), and each task runs inside map_files
+    busy = op["args"]["intern_busy_s"]
+    assert sum(e["dur"] for e in spans) * 1e-6 - 1e-3 <= busy \
+        <= len(spans) * op["dur"] * 1e-6 + 1e-3
     (conv,) = args[names.CONVERT_SPAN]
     assert conv[names.ATTR_ROWS] == nwords
     assert conv[names.ATTR_GROUPS] == nunique

@@ -25,7 +25,7 @@ from gpu_mapreduce_tpu.utils.io import RecordFormat
 
 @pytest.fixture(scope="module")
 def meshes():
-    return {1: make_mesh(1), 4: make_mesh(4)}
+    return {n: make_mesh(n) for n in (1, 2, 4, 8)}
 
 
 @pytest.fixture(autouse=True)
@@ -176,6 +176,256 @@ def test_the_oink_command_runs_the_application(meshes, tmp_path, rng):
         script.run_string(f"terasort -i mrs -o {out} NULL")
     with pytest.raises(MRError, match="Illegal terasort"):   # no argument
         script.run_string(f"terasort 64 -i v_files -o {out} NULL")
+
+
+# -- the total order: a spec of the exchange, its splitters an operand -------------
+
+def _key_bytes(rng, n):
+    return rng.integers(0, 256, (n, ref.KEY), dtype=np.uint8)
+
+
+def _random_keys(rng, nshards):
+    return _key_bytes(rng, 600), np.sort(ref.keys(_key_bytes(
+        rng, nshards - 1)))
+
+
+def _keys_on_a_splitter(rng, nshards):
+    """Half of the keys are a splitter's own bytes: ties go up."""
+    keys, splitters = _random_keys(rng, nshards)
+    own = splitters.view(np.uint8).reshape(-1, ref.KEY)
+    keys[::2] = own[rng.integers(0, len(own), len(keys[::2]))]
+    return keys, splitters
+
+
+def _repeated_splitters(rng, nshards):
+    """Every splitter the same key: the shards between the first and the
+    last get nothing."""
+    keys, splitters = _random_keys(rng, nshards)
+    splitters[:] = splitters[len(splitters) // 2]
+    keys[:50] = splitters[:1].view(np.uint8)
+    return keys, splitters
+
+
+def _all_below(rng, nshards):
+    """One shard's worth of keys, every one below every splitter."""
+    keys, splitters = _random_keys(rng, nshards)
+    keys[:, 0] = 0
+    own = splitters.view(np.uint8).reshape(-1, ref.KEY)
+    own[:, 0] = np.maximum(own[:, 0], 1)
+    return keys, np.sort(splitters)
+
+
+ORDER_CASES = {"random": _random_keys, "on_a_splitter": _keys_on_a_splitter,
+               "repeated_splitters": _repeated_splitters,
+               "all_below": _all_below}
+
+
+@pytest.mark.parametrize("nshards", [2, 4, 8])
+@pytest.mark.parametrize("case", ORDER_CASES)
+def test_the_total_order_sends_a_key_where_searchsorted_says(
+        case, nshards, meshes, rng):
+    """``aggregate(TotalOrder(splitters))``: every row on the shard that
+    ``np.searchsorted(splitters, keys, "right")`` names for its 10 key
+    bytes, its value still beside it, none lost."""
+    from gpu_mapreduce_tpu.parallel.shuffle import TotalOrder
+    keys, splitters = ORDER_CASES[case](rng, nshards)
+    want = np.searchsorted(splitters, ref.keys(keys), side="right")
+    words = fixed_key_words(keys)
+    value = np.arange(len(keys), dtype=np.uint32)[:, None] * np.ones(
+        23, np.uint32)
+    mr = MapReduce(meshes[nshards])
+    mr.map(1, lambda itask, kv, ptr: kv.add_batch(words, value))
+    order = TotalOrder(fixed_key_words(
+        splitters.view(np.uint8).reshape(-1, ref.KEY)))
+    assert np.array_equal(np.asarray(order(words)), want)   # a user hash
+    mr.aggregate(order)
+    assert mr.last_exchange.rows == len(keys)
+    fr = mr.kv.one_frame()
+    assert fr.counts.tolist() == np.bincount(
+        want, minlength=nshards).tolist()
+    for p in range(nshards):
+        host = fr.shard_to_host(p)
+        rows = np.asarray(host.value.data)[:, 0]
+        assert (want[rows] == p).all()
+        assert np.array_equal(np.asarray(host.key.data), words[rows])
+    if case == "repeated_splitters":
+        assert fr.counts[1:-1].sum() == 0
+    if case == "all_below":
+        assert fr.counts[0] == len(keys)
+
+
+def _even(rng):
+    """Four files of one size: with a sample of a third of the keys the
+    shards and their buckets come out even to a few rows in a hundred,
+    well inside the exchange plan's room, as at a real size (10^5 keys
+    of 2 x 10^7), so a job's plan holds for the next job's records."""
+    return [_records(rng, 720) for _ in range(4)]
+
+
+@pytest.fixture
+def a_sample_that_balances(monkeypatch):
+    monkeypatch.setattr(app, "SAMPLE", 1024)
+
+
+def _datasets(tmp_path, rng, n):
+    """The same file sizes ``n`` times, other records: other splitters."""
+    jobs = []
+    for j in range(n):
+        d = tmp_path / f"job{j}"
+        d.mkdir()
+        jobs.append(_write(d, _even(rng)))
+    return jobs
+
+
+def _traced_jobs(mesh, jobs, tmp_path):
+    """``TeraSort.run`` over each of ``jobs`` on a fresh application, the
+    tracer on: ``(applications, events)``."""
+    from gpu_mapreduce_tpu.obs import get_tracer
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.enable(ring=1 << 14)
+    try:
+        apps = []
+        for j, paths in enumerate(jobs):
+            apps.append(app.TeraSort(comm=mesh))
+            apps[-1].run(paths, outdir=str(tmp_path / f"out{j}"))
+        return apps, tracer.events()
+    finally:
+        tracer.clear()
+        tracer.disable()
+
+
+def test_a_second_job_over_other_records_builds_no_phase_1(
+        meshes, tmp_path, rng, a_sample_that_balances):
+    """The splitters are data: jobs over different records on one mesh
+    trace and lower phase 1 once, every exchange after the first runs
+    phase 2 ahead of the count sync, and every output is the reference's.
+    The second job lowers one program, the phase 2 that does not donate
+    its input (a speculation that fails runs phase 2 again on the same
+    rows; every exchange's first repeat builds it); the third lowers
+    nothing."""
+    from gpu_mapreduce_tpu import obs
+    from gpu_mapreduce_tpu.obs import names
+    from gpu_mapreduce_tpu.parallel import shuffle
+    shuffle.PHASE1_CACHE.clear()
+    shuffle._SPEC_CACHE.clear()
+    jobs = _datasets(tmp_path, rng, 3)
+    ran, events = _traced_jobs(meshes[4], jobs, tmp_path)
+    built = obs.programs()
+    assert not np.array_equal(ran[0].splitters, ran[1].splitters)
+    for ts, paths in zip(ran, jobs):
+        ref.validate(ts.parts, ref.summary(paths))
+    ex = [e["args"] for e in events if e["name"] == names.SHUFFLE_EXCHANGE]
+    assert [a["dest"] for a in ex] == ["order"] * 3
+    assert [a["phase1_built"] for a in ex] == [1, 0, 0]
+    assert [a["speculative"] for a in ex] == [False, True, True]
+    assert built[names.SHUFFLE_PHASE1]["lowerings"] == 1
+    assert built[names.TERASORT_SAMPLE_KEYS]["lowerings"] == 1
+    roots = [e["args"] for e in events if e["name"] == names.TERASORT_RUN]
+    assert roots[0][names.ATTR_JIT_LOWERINGS] > 0
+    assert roots[1][names.ATTR_JIT_LOWERINGS] <= 1
+    assert roots[2][names.ATTR_JIT_LOWERINGS] == 0
+    assert shuffle.PHASE1_CACHE.stats()["size"] == 1
+
+
+def test_the_caches_hold_as_much_after_five_jobs_as_after_two(
+        meshes, tmp_path, rng, a_sample_that_balances):
+    from gpu_mapreduce_tpu.parallel import shuffle
+    paths = _write(tmp_path, _even(rng))
+    sizes = []
+    for job in range(5):
+        recs = _even(rng)           # other records, other splitters, a job
+        for path, r in zip(paths, recs):
+            r.tofile(path)
+        app.TeraSort(comm=meshes[4]).run(paths)
+        sizes.append((len(shuffle._SPEC_CACHE), len(shuffle.PHASE1_CACHE),
+                      len(shuffle.PHASE2_CACHE),
+                      app._sample_jit.cache_info().currsize))
+    assert sizes[1] == sizes[4]
+
+
+def test_the_sample_reads_each_shards_own_rows(meshes):
+    """The sample's program: no collective and no gather over the sharded
+    column (an ``all-gather`` of it is 240 MB a chip at the cell's size);
+    a shard's rows at an even stride."""
+    import re
+    mesh = meshes[4]
+    sds = jax.ShapeDtypeStruct
+    lowered = app._sample_jit(mesh, 16).lower(
+        sds((4 * 1024, 3), np.uint32), sds((4,), np.int32),
+        sds((4,), np.int32))
+    from gpu_mapreduce_tpu.obs import names
+    assert re.search(r"module @(\w+)", lowered.as_text()).group(1) \
+        == names.TERASORT_SAMPLE_KEYS
+    text = lowered.compile().as_text()
+    assert not re.search(r"all-gather|all-reduce|all-to-all|"
+                         r"collective-permute", text), text
+    key = np.arange(4 * 1024 * 3, dtype=np.uint32).reshape(-1, 3)
+    counts = np.array([1000, 0, 7, 1024], np.int32)
+    take = np.array([16, 0, 7, 5], np.int32)
+    got = np.asarray(app._sample_jit(mesh, 16)(key, counts, take)).reshape(
+        4, 16, 3)
+    for p in range(4):
+        rows = p * 1024 + np.arange(take[p]) * counts[p] // max(take[p], 1)
+        assert np.array_equal(got[p, :take[p]], key[rows])
+
+
+def test_the_sample_is_the_constant_at_the_cells_size(meshes, monkeypatch):
+    """At 5 x 10^6 rows a shard the shares and the strides are past int32
+    (10^5 x 5 x 10^6; slot 24,999 x 5 x 10^6): the shares add up to the
+    application's 100,000 keys, and the device program's last slot is
+    the row the host's arithmetic names."""
+    from gpu_mapreduce_tpu.parallel.mesh import row_sharding
+    monkeypatch.setattr(app, "SAMPLE", 100_000)
+    counts = np.full(4, 5_000_000, np.int32)
+    take = app.sample_shares(counts)
+    assert take.tolist() == [25_000] * 4 and take.dtype == np.int64
+    uneven = app.sample_shares(np.array([7_000_000, 0, 12_999_999, 1],
+                                        np.int32))
+    assert 100_000 <= uneven.sum() <= 100_003 and uneven[1] == 0
+    assert app.sample_shares(np.zeros(4, np.int32)).sum() == 0
+    # the strides, at a real count over a small block: the rows asked for
+    # are clipped to the block, so the last slot reads its last row when
+    # the stride is computed in 64 bits and a low row when it wrapped
+    mesh = meshes[4]
+    key = np.arange(4 * 8 * 3, dtype=np.uint32).reshape(-1, 3)
+    on_mesh = lambda a: jax.device_put(a, row_sharding(mesh))
+    got = np.asarray(app._sample_jit(mesh, 25_000)(
+        on_mesh(key), on_mesh(counts), on_mesh(take.astype(np.int32))))
+    got = got.reshape(4, 25_000, 3)
+    for p in range(4):
+        rows = np.minimum(np.arange(25_000) * 5_000_000 // 25_000, 7)
+        assert np.array_equal(got[p], key[p * 8 + rows])
+
+
+def test_a_user_device_hash_still_runs_and_groups_as_the_reference(
+        meshes, rng):
+    """``aggregate(fn)`` with any other device callable keeps its
+    behaviour: ``fn(keys) % nprocs``, phase 1 built for the call."""
+    from gpu_mapreduce_tpu.obs import get_tracer, names
+    key = rng.integers(0, 1 << 20, 500).astype(np.uint64)
+    value = np.arange(500, dtype=np.uint64)
+    mr = MapReduce(meshes[4])
+    mr.map(1, lambda itask, kv, ptr: kv.add_batch(key, value))
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.enable(ring=1 << 12)
+    try:
+        mr.aggregate(lambda keys: (keys // 3).astype(np.uint32))
+        (ex,) = [e["args"] for e in tracer.events()
+                 if e["name"] == names.SHUFFLE_EXCHANGE]
+    finally:
+        tracer.clear()
+        tracer.disable()
+    assert (ex["dest"], ex["phase1_built"]) == ("user", 1)
+    fr = mr.kv.one_frame()
+    want = ((key // 3) % 4).astype(np.int64)
+    assert fr.counts.tolist() == np.bincount(want, minlength=4).tolist()
+    for p in range(4):
+        host = fr.shard_to_host(p)
+        rows = np.asarray(host.value.data)
+        assert np.array_equal(np.asarray(host.key.data), key[rows])
+        assert (want[rows] == p).all()
 
 
 # -- the record map ---------------------------------------------------------------
@@ -334,6 +584,9 @@ def test_the_spans_of_a_job(meshes, tmp_path, rng):
     (sample,) = by_name[names.TERASORT_SAMPLE]
     assert sample["args"]["splitters"] == 3
     assert 64 <= sample["args"]["sampled"] < 64 + 4     # a stride a shard
+    # the fullest shard's share of slots a shard, 3 words a key
+    assert sample["args"]["sampled"] * 12 <= sample["args"]["d2h_bytes"] \
+        <= 4 * 27 * 12
     (sort,) = by_name[names.SORT_KEYS_SPAN]
     assert sort["args"][names.ATTR_RECORDS] == 3151
     assert (sort["args"][names.ATTR_KEY_WORDS],
