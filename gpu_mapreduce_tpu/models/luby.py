@@ -4,24 +4,33 @@ The reference iterates {edge_winner, vert_winner, vert_loser,
 vert_emit} MapReduce stages until no edges remain
 (``oink/luby_find.cpp:53-95``); the composed twin lives in
 oink/commands/luby.py.  This model runs the whole thing in ONE jitted
-``lax.while_loop`` over a dense vertex state vector:
+program over a dense vertex state vector:
 
 * per-vertex priorities are the SAME splitmix64 stream as the composed
   engine (``vertex_rand(v, seed)`` on original ids), handed over as
   their int32 ranks in the order of (priority, id): distinct, so one
   comparison decides; exact, because the set depends on that order
-  alone; and integers, because the v5e's compiler lowers no float64
-  ``pmin``.  A vertex joins when its rank is smaller than every
-  UNDECIDED neighbour's.  With these shared priorities the two engines
-  produce identical sets on the golden script input, but only the MIS
-  property itself is contractual (the composed rounds cull edges in a
-  different order — see the LubyFind docstring);
-* one round = one masked segment-min (the smallest undecided
-  neighbour's rank) + neighbour-of-winner exclusion, all vectorised;
-  the mesh version pmin/pmax-combines over ICI.
+  alone.  A vertex joins when its rank is smaller than every UNDECIDED
+  neighbour's.  With these shared priorities the two engines produce
+  identical sets on the golden script input, but only the MIS property
+  itself is contractual (the composed rounds cull edges in a different
+  order — see the LubyFind docstring);
+* the ranks never change, so of an edge's two ends the same one beats the
+  other in every round.  Before the loop each edge becomes ONE row
+  ``(hi, lo)`` with ``prio[lo] < prio[hi]`` and each shard's rows are
+  sorted by ``hi`` once (self loops and padding behind every run), so
+  that the neighbours that beat a vertex are a run of rows with bounds
+  ``at[v] .. at[v + 1]``;
+* one round = two counts over those runs, each a gather by ``lo`` and a
+  prefix sum read at the bounds: an undecided vertex with no undecided
+  neighbour that beats it wins; a winner beats all its undecided
+  neighbours, so an undecided vertex with a winner among the neighbours
+  that beat it is excluded.  No scatter, and no sort inside the loop
+  (on the chip a scatter of these rows costs thirty sorts: PERF.md §6);
+  the mesh version adds the shards' counts over ICI (``lax.psum``).
 
 States: 0 undecided, 1 in MIS, 2 excluded.  A vertex whose undecided
-neighbourhood empties (everyone excluded) sees the largest int32 and
+neighbourhood empties (everyone excluded) counts nobody ahead of it and
 joins — the maximality guarantee."""
 
 from __future__ import annotations
@@ -38,42 +47,41 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..parallel.mesh import mesh_axes, mesh_axis_size, row_spec
 
 
-def _both_dirs(src, dst, x_by_src):
-    """Edge contributions in both directions: (values, targets) where
-    value i is x evaluated at the *other* endpoint."""
-    return (jnp.concatenate([x_by_src[src], x_by_src[dst]]),
-            jnp.concatenate([dst, src]))
+def _orient(src, dst, valid, prio, n):
+    """Each edge once, as the row (hi, lo) with ``prio[lo] < prio[hi]``,
+    the rows sorted by ``hi``: returns ``lo`` in that order and where
+    each vertex's run starts, ``at[0..n]``.  Self loops and invalid rows
+    go behind the last run.  A run's rows are counted, never read in
+    order, so the sort need not be stable: the stable one carries a third
+    operand and takes the chip's compiler three to four times as long."""
+    down = prio[src] < prio[dst]
+    hi = jnp.where(valid & (src != dst), jnp.where(down, dst, src), n)
+    hi, lo = lax.sort((hi, jnp.where(down, src, dst)), num_keys=1,
+                      is_stable=False)
+    at = jnp.searchsorted(hi, jnp.arange(n + 1, dtype=hi.dtype))
+    return lo, at.astype(jnp.int32)
 
 
-def _round(state, prio, src, dst, valid, n, axes=None):
+def _count(flag, lo, at, axes):
+    """Per vertex: how many of the neighbours that beat it carry ``flag``,
+    as a prefix sum over the rows read at the runs' bounds."""
+    c = jnp.cumsum(flag[lo], dtype=jnp.int32)
+    below = jnp.where(at > 0, c[jnp.maximum(at - 1, 0)], 0)
+    k = below[1:] - below[:-1]
+    return k if axes is None else lax.psum(k, axes)
+
+
+def _round(state, lo, at, axes):
     und = state == 0
-    active = valid & und[src] & und[dst]
-    act2 = jnp.concatenate([active, active])
-
-    pv, tgt = _both_dirs(src, dst, prio)
-    seg = jnp.where(act2, tgt, n)
-
-    # min neighbour priority among undecided neighbours; no two vertices
-    # share a priority, so the strict comparison decides alone
-    top = jnp.iinfo(prio.dtype).max
-    m1 = jax.ops.segment_min(jnp.where(act2, pv, top), seg,
-                             num_segments=n + 1)[:n]
-    if axes is not None:
-        m1 = lax.pmin(m1, axes)
-    winner = und & (prio < m1)
-
-    # neighbours of winners become excluded (only undecided ones change)
-    wv = jnp.concatenate([winner[src], winner[dst]]).astype(jnp.int32)
-    seg_all = jnp.where(jnp.concatenate([valid, valid]), tgt, n)
-    wn = jax.ops.segment_max(jnp.where(seg_all < n, wv, 0), seg_all,
-                             num_segments=n + 1)[:n]
-    if axes is not None:
-        wn = lax.pmax(wn, axes)
-    lose = und & ~winner & (wn > 0)
+    winner = und & (_count(und, lo, at, axes) == 0)
+    lose = und & (_count(winner, lo, at, axes) > 0)
     return jnp.where(winner, 1, jnp.where(lose, 2, state)).astype(jnp.int8)
 
 
-def _loop(step, n, maxiter):
+def _mis(src, dst, valid, prio, n, maxiter, axes=None):
+    """The whole command on one shard's rows: orient, then rounds until
+    no vertex is undecided."""
+    lo, at = _orient(src, dst, valid, prio, n)
     state0 = jnp.zeros(n, jnp.int8)
 
     def cond(s):
@@ -82,7 +90,7 @@ def _loop(step, n, maxiter):
 
     def body(s):
         state, it = s
-        return step(state), it + 1
+        return _round(state, lo, at, axes), it + 1
 
     return lax.while_loop(cond, body, (state0, jnp.int32(0)))
 
@@ -93,11 +101,8 @@ def luby_mis(src, dst, prio, n: int, maxiter: int = 0
     """Single device.  Returns (state[n] ∈ {1 MIS, 2 excluded}, rounds).
     ``prio``: each vertex's int32 rank in the order of (vertex_rand on
     original ids, id); no two alike."""
-    maxiter = maxiter or max(n, 1)
-    valid = jnp.ones(src.shape, bool)
-    s32, d32 = src.astype(jnp.int32), dst.astype(jnp.int32)
-    return _loop(lambda st: _round(st, prio, s32, d32, valid, n),
-                 n, maxiter)
+    return _mis(src.astype(jnp.int32), dst.astype(jnp.int32),
+                jnp.ones(src.shape, bool), prio, n, maxiter or max(n, 1))
 
 
 @functools.lru_cache(maxsize=None)
@@ -108,12 +113,10 @@ def _luby_sharded_fn(mesh: Mesh, n: int, maxiter: int):
 
     @functools.partial(jax.jit, out_shardings=(rep, rep))
     def luby_loop(src_d, dst_d, valid_d, prio):
-        body = jax.shard_map(
-            lambda st, pr, s, d, v: _round(st, pr, s, d, v, n, axes),
-            mesh=mesh, in_specs=(P(), P(), rspec, rspec, rspec),
-            out_specs=P())
-        return _loop(lambda st: body(st, prio, src_d, dst_d, valid_d),
-                     n, maxiter)
+        return jax.shard_map(
+            lambda s, d, v, pr: _mis(s, d, v, pr, n, maxiter, axes),
+            mesh=mesh, in_specs=(rspec, rspec, rspec, P()),
+            out_specs=P())(src_d, dst_d, valid_d, prio)
 
     return luby_loop
 

@@ -116,7 +116,8 @@ TRI_ENGINE = "tri.loop"                         # cat ENGINE: wedges, batches,
 #                                                 max_out_degree
 TRI_EMIT = "tri.emit"                           # triangles
 LUBY_STAGE = "luby.stage"                       # n, edges
-LUBY_ENGINE = "luby.loop"                       # cat ENGINE: iters, n, edges
+LUBY_ENGINE = "luby.loop"                       # cat ENGINE: iters, n, edges,
+#                                                 rows
 LUBY_EMIT = "luby.emit"                         # n
 SSSP_STAGE = "sssp.stage"                       # n, edges
 SSSP_ENGINE = "sssp.loop"                       # cat ENGINE: iters, source,

@@ -255,9 +255,13 @@ class LubyFind(Command):
     """luby_find seed: maximal independent set of an undirected edge list;
     output is one MIS vertex per line (oink/luby_find.cpp:53-115).
 
-    Engines: ``fused`` (default) — the whole round loop in one jitted
-    ``lax.while_loop`` over a dense state vector with the SAME splitmix64
-    per-vertex priorities as the composed engine (models/luby.py);
+    Engines: ``fused`` (default) — one jitted program over a dense state
+    vector with the SAME splitmix64 per-vertex priorities as the composed
+    engine (models/luby.py): each edge is kept once, pointing from the end
+    that loses to the end that beats it, the rows sorted by the losing
+    end before the loop; a round then counts, per vertex, the undecided
+    and the winning neighbours that beat it (two gathers and two prefix
+    sums over the rows, no scatter);
     ``composed`` — the reference's 5-stage MR round below
     (GPUMR_LUBY_ENGINE=composed).  Both are valid MIS constructions;
     selected sets can differ because the composed engine's winner rule is
@@ -312,27 +316,34 @@ class LubyFind(Command):
             prio = np.empty(n, np.int32)
             prio[np.lexsort((verts, vertex_rand(verts, self.seed)))] = \
                 np.arange(n, dtype=np.int32)
-            sp.set(n=n, edges=int(mre.kv.nkv) if mre.kv is not None else 0)
+            edges = int(mre.kv.nkv) if mre.kv is not None else 0
+            sp.set(n=n, edges=edges)
 
         with tr.span(names.LUBY_ENGINE, cat=names.ENGINE) as sp:
-            # the span ends at the pull of the state vector
+            # the span ends at the pull of the state vector; ``rows`` is
+            # what each of a round's two gathers and prefix sums runs over
             if n == 0:
                 # no edge but self loops (or none at all): nothing to decide
-                state, iters = np.zeros(0, np.int8), 0
+                state, iters, rows = np.zeros(0, np.int8), 0, 0
             elif sg is not None:
                 from ...models.luby import _luby_sharded_fn
                 state, iters = _luby_sharded_fn(mesh, n, max(n, 1))(
                     sg.src, sg.dst, sg.valid, jnp.asarray(prio))
+                rows = sg.src.shape[0]
             elif mesh is not None:
                 from ...models.luby import luby_mis_sharded
+                from ...parallel.mesh import mesh_axis_size
                 state, iters = luby_mis_sharded(mesh, src, dst, prio, n)
+                shards = mesh_axis_size(mesh)
+                rows = -(-len(src) // shards) * shards   # as it pads them
             else:
                 from ...models.luby import luby_mis
                 state, iters = luby_mis(src.astype(np.int32),
                                         dst.astype(np.int32),
                                         jnp.asarray(prio), n)
+                rows = len(src)
             state, iters = np.asarray(state), int(iters)
-            sp.set(iters=iters, n=n)
+            sp.set(iters=iters, n=n, edges=edges, rows=rows)
 
         mrv = obj.create_mr()
         with tr.span(names.LUBY_EMIT, cat=names.HOST) as sp:

@@ -397,7 +397,13 @@ def test_enumeration_commands_emit_their_spans(mesh, traced, tmp_path):
     assert walk["n"] == args[names.TRI_STAGE]["n"] > walk["max_out_degree"] > 1
     nset, rounds = map(int, re.search(
         r"Luby_find: (\d+) MIS vertices in (\d+) iterations", screen).groups())
-    assert args[names.LUBY_ENGINE]["iters"] == rounds >= 1
+    loop = args[names.LUBY_ENGINE]
+    assert loop["iters"] == rounds >= 1
+    # what names.py promises of the span: how much a round reads
+    assert loop["edges"] == args[names.LUBY_STAGE]["edges"] \
+        == s.obj.get_mr("mru").kv.nkv
+    assert loop["rows"] >= loop["edges"]
+    assert loop["n"] == args[names.LUBY_STAGE]["n"] > nset
     assert args[names.LUBY_EMIT]["n"] == nset > 0
     source, iters, labeled = map(int, re.search(
         r"SSSP: source (\d+): (\d+) iterations, (\d+) vertices labeled",

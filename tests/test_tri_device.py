@@ -9,6 +9,7 @@ rule (no scatter).  ``luby_find`` and ``sssp``, which run beside it in
 ``graph-tri-1chip``, are held to plain references of their own."""
 
 import collections
+import os
 import re
 
 import jax
@@ -18,6 +19,9 @@ import pytest
 
 from gpu_mapreduce_tpu.models import tri
 from gpu_mapreduce_tpu.oink import ObjectManager, run_command
+
+EXAMPLES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        os.pardir, "examples")
 
 
 # -- graphs ---------------------------------------------------------------------
@@ -412,3 +416,169 @@ def test_the_whole_weight_round_is_a_sort_and_no_scatter(backend):
         ops[dtype] = set(re.findall(r"stablehlo\.(\w+)", body))
     assert "sort" in ops[jnp.int32] and "scatter" not in ops[jnp.int32]
     assert "scatter" in ops[jnp.float64] and "sort" not in ops[jnp.float64]
+
+
+# -- luby's counting round ---------------------------------------------------------
+
+LUBY_N, LUBY_ROWS = 16, 64
+
+
+def _luby_graphs():
+    """name -> (edges, ranks): every graph over 16 vertices, so that one
+    compilation a caller and a round count serves them all."""
+    rng = np.random.default_rng(40)
+    ident = np.arange(LUBY_N, dtype=np.int32)
+    rand = [tuple(r) for r in rng.integers(0, LUBY_N, (40, 2)).tolist()]
+    return {
+        # every edge twice or three times, in both directions
+        "duplicates": (rand[:14] * 2 + [(b, a) for a, b in rand[:14]],
+                       rng.permutation(LUBY_N).astype(np.int32)),
+        "self-loops": (rand[14:30] + [(v, v) for v in range(0, LUBY_N, 2)],
+                       rng.permutation(LUBY_N).astype(np.int32)),
+        # 4 and 5 win at once, 2 and 3 go out, and 15, which lost to both,
+        # is left with no undecided neighbour: it joins in round 2
+        "isolated-after-exclusion": (
+            [(15, 2), (15, 3), (2, 4), (3, 5)],
+            np.argsort([4, 5, 2, 3, 0, 1] + list(range(6, LUBY_N))
+                       ).astype(np.int32)),
+        # the hub first (one round), and the hub last (every leaf at once)
+        "star-hub-wins": ([(0, v) for v in range(1, LUBY_N)], ident),
+        "star-hub-loses": ([(0, v) for v in range(1, LUBY_N)],
+                           ident[::-1].copy()),
+        # a path in rank order: one vertex a round can be decided by 0
+        "path": ([(v, v + 1) for v in range(LUBY_N - 1)], ident),
+        "self-loops-only": ([(v, v) for v in range(5)], ident),
+    }
+
+
+def _plain_luby_rounds(edges, prio, n):
+    """The definition: an undecided vertex whose rank is below every
+    undecided neighbour's joins, then the undecided neighbours of those
+    go out.  Self loops dropped first.  The state after each round."""
+    adj = collections.defaultdict(set)
+    for a, b in edges:
+        if a != b:
+            adj[a].add(b)
+            adj[b].add(a)
+    state, states = np.zeros(n, np.int8), []
+    while (state == 0).any():
+        und = state == 0
+        win = [v for v in range(n) if und[v] and all(
+            prio[v] < prio[u] for u in adj[v] if und[u])]
+        out = [u for v in win for u in adj[v] if und[u]]
+        state = state.copy()
+        state[out] = 2
+        state[win] = 1
+        states.append(state)
+    return states
+
+
+def _luby_caller(caller):
+    """(src, dst, prio, maxiter) -> (state, rounds) through one of the
+    module's three entries; the staged loop gets padding rows that name
+    real vertices and are not valid."""
+    from gpu_mapreduce_tpu.models import luby
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    kind, _, width = caller.partition("-mesh")
+
+    def run(src, dst, prio, maxiter):
+        if kind == "luby_mis":
+            return luby.luby_mis(src, dst, jnp.asarray(prio), LUBY_N,
+                                 maxiter=maxiter)
+        mesh = make_mesh(int(width))
+        if kind == "luby_mis_sharded":
+            return luby.luby_mis_sharded(mesh, src, dst, prio, LUBY_N,
+                                         maxiter=maxiter)
+        m = len(src)
+        rng = np.random.default_rng(m)
+        rows = rng.integers(0, LUBY_N, (2, LUBY_ROWS)).astype(np.int32)
+        at = rng.permutation(LUBY_ROWS)[:m]     # the real rows, scattered
+        rows[0, at], rows[1, at] = src, dst
+        valid = np.zeros(LUBY_ROWS, bool)
+        valid[at] = True
+        return luby._luby_sharded_fn(mesh, LUBY_N, maxiter)(
+            rows[0], rows[1], valid, jnp.asarray(prio))
+    return run
+
+
+@pytest.mark.parametrize("caller", [
+    "luby_mis", "luby_loop-mesh1", "luby_loop-mesh4", "luby_loop-mesh8",
+    "luby_mis_sharded-mesh4"])
+@pytest.mark.parametrize("name", list(_luby_graphs()))
+def test_luby_state_after_every_round_is_the_plain_rounds(name, caller):
+    edges, prio = _luby_graphs()[name]
+    src, dst = np.asarray(edges, np.int32).reshape(-1, 2).T
+    want = _plain_luby_rounds(edges, prio, LUBY_N)
+    assert 1 <= len(want) <= 8, len(want)
+    if name == "isolated-after-exclusion":
+        assert want[0][15] == 0 and want[1][15] == 1
+    run = _luby_caller(caller)
+    for k, state in enumerate(want, 1):
+        got, rounds = run(src, dst, prio, k)
+        assert np.asarray(got).tolist() == state.tolist(), k
+        assert int(rounds) == k
+    got, rounds = run(src, dst, prio, LUBY_N)       # and it stops there
+    assert np.asarray(got).tolist() == want[-1].tolist()
+    assert int(rounds) == len(want)
+
+
+@pytest.mark.parametrize("backend", ["serial", "mesh1", "mesh4"])
+def test_luby_rounds_on_the_golden_script_input(backend, tmp_path,
+                                                monkeypatch):
+    """``examples/in.luby``'s set and round count (tests/test_script.py
+    holds the serial run of the file itself to the same line)."""
+    import io
+    from gpu_mapreduce_tpu.oink import OinkScript
+    monkeypatch.chdir(tmp_path)
+    out = io.StringIO()
+    s = OinkScript(comm=_obj(backend).comm, screen=out)
+    s.run_file(os.path.join(EXAMPLES, "in.luby"))
+    assert "Luby_find: 1123 MIS vertices in 5 iterations" in out.getvalue()
+
+
+def _primitives(jaxpr, found):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("backend", ["serial", "mesh1", "mesh4"])
+def test_the_luby_round_is_two_gathers_two_prefix_sums_and_no_scatter(
+        backend):
+    """The chip's rule (PERF.md §6): a scatter costs thirty sorts there.
+    The program sorts once, before the loop; a round reads the rows by two
+    gathers and two prefix sums, and the runs' bounds by small gathers."""
+    from gpu_mapreduce_tpu.models import luby
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    SDS = jax.ShapeDtypeStruct
+    rows, n = 1 << 10, 48
+    e, p = SDS((rows,), jnp.int32), SDS((n,), jnp.int32)
+    if backend == "serial":
+        fn, args, static, shard = luby.luby_mis, (e, e, p, n), (3,), rows
+    else:
+        width = int(backend[4:])
+        fn = luby._luby_sharded_fn(make_mesh(width), n, n)
+        args, static = (e, e, SDS((rows,), jnp.bool_), p), ()
+        shard = rows // width
+    assert "scatter" not in fn.lower(*args).as_text()
+    eqns = _primitives(
+        jax.make_jaxpr(fn, static_argnums=static)(*args).jaxpr, [])
+    names = collections.Counter(q.primitive.name for q in eqns)
+    assert names["sort"] == 1 and not any("scatter" in k for k in names)
+    (loop,) = [q for q in eqns if q.primitive.name == "while"
+               and any(v.aval.dtype == jnp.int8 for v in q.outvars)]
+    body = _primitives(loop.params["body_jaxpr"].jaxpr, [])
+    inside = collections.Counter(q.primitive.name for q in body)
+    assert not inside.keys() & {"sort", "while"} and not any(
+        "scatter" in k or "segment" in k for k in inside), inside
+    assert inside["cumsum"] == 2, inside
+    big = [q for q in body if q.primitive.name == "gather"
+           and q.outvars[0].aval.shape == (shard,)]
+    assert len(big) == 2 and inside["gather"] == 4, inside
+    if backend != "serial":
+        sums = [k for k in inside.elements() if k.startswith("psum")]
+        assert len(sums) == 2 and not inside.keys() & {"pmin", "pmax"}
+
