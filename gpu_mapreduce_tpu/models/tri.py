@@ -9,42 +9,86 @@ composed twin lives in oink/commands/tri.py.
 This model keeps Cohen's core insight (orient edges from the
 lexicographically smaller (degree, id) endpoint, so every vertex's
 out-neighbourhood is O(√m) and the total wedge count is Σ k_v(k_v-1)/2
-≤ O(m^1.5)) and runs it as four jitted programs over the ranked edge
+≤ O(m^1.5)) and runs it as five jitted programs over the ranked edge
 arrays ``parallel/staging.stage_graph`` leaves on the device.  They are
 built from what the chip is good at (PERF.md §6: a scatter costs thirty
-sorts, a ``searchsorted`` a gather per round): sorts that carry
-payloads, prefix scans, copies, and one gather.
+sorts, a ``searchsorted`` a gather per round, a gathered element as much
+as two sorted rows): sorts that carry payloads, prefix scans, copies, and
+as few gathered elements as the enumeration allows.
+
+A wedge is a pair of positions of one out-list, and the lists lie sorted
+by centre in one array, so there are two ways to enumerate them, chosen
+list by list from the list's length:
+
+* **by tiles**, where a list has ``_TILED`` (K0) out-neighbours or more.
+  The list is cut into blocks of ``_BLOCK`` (B) positions, block a
+  starting at ``start + a·B`` (no row moves), and a *tile* is block a
+  against block b ≥ a of the same list: B² wedges from 2·B neighbour
+  reads, by broadcast.  A tile on the diagonal (a = b) keeps its pairs
+  i < j; a row that names a position past the list's end is masked.
+  Masked rows are padding, not wedges dropped.
+* **by index**, where it is shorter: wedge t belongs to the position p
+  with ``off[p] ≤ t < off[p+1]`` and pairs p with position p + 1 + (t −
+  off[p]).  A tile of a 3-element list would be 61 masked rows for 3
+  wedges (the median list of an R-MAT is 4 long), so short lists are
+  walked wedge by wedge; they hold 1.2 % of RMAT-20's wedges.
+
+The programs:
 
 * ``tri_orient`` (once a job): canonical edge keys by a sort (duplicates
   and self loops out), degrees as run lengths of the sorted endpoints
   carried back by a second sort, the (degree, id) orientation, the
-  out-neighbour lists by a third sort, and the prefix sum of the wedges
-  each list position owns (position p pairs with every later position
-  of its vertex).
-* ``tri_wedges`` (once a batch of ``_BATCH`` wedge indices, a static
-  cap): the owners' offsets are merged with the batch's indices by one
-  sort and their (position, neighbour) pairs filled forward by a prefix
-  max, a second sort brings the wedges back to index order, the partner
-  neighbour is gathered, the wedge keys are merged with the resident
-  edge keys by a third sort (a wedge closes when the key before it in
-  that order is its own edge), and a fourth sort brings the hits to the
-  front.
+  out-neighbour lists by a third sort, and two prefix sums over the list
+  positions: the index wedges a position of a short list owns (it pairs
+  with every later position of its vertex), and the tiles the first
+  position of a long list's block owns (block a of kb pairs with blocks
+  a … kb−1).
+* ``tri_tiles`` (once a job; once a table of ``_TILES`` where a graph has
+  more): the tile space expanded into a table of (first block's start,
+  second block's start, the list's end, the centre) by ``_owners``: the
+  owners' offsets merged with the tiles' indices by one sort, their
+  positions, ends and centres filled forward by int32 prefix maxima (all
+  three grow along the array; one u64 prefix max of packed pairs would
+  cost 0.04 s a run and 200 s to compile), a second sort back to index
+  order.
+* ``tri_wedges`` (once a batch of ``_BATCH`` wedge rows, a static cap), of
+  either kind.  A tile batch slices ``_BATCH / B²`` tiles off the table,
+  gathers both blocks of each (2·B elements a tile) and broadcasts them
+  into ``[B, B, tiles]`` keys, the tile on the minor axis.  An index
+  batch runs ``_owners`` over the wedge offsets and gathers each wedge's
+  two neighbours.  Both then merge the wedge keys with the resident edge keys by
+  a sort (a wedge closes when the key before it in that order is its own
+  edge) and bring the hits to the front by another.
 * ``tri_append`` copies a batch's hits behind those the buffer holds;
   ``tri_rows`` turns the buffer into (centre, u, w) rows of vertex ids.
   Ids below 2^31 name the vertices from ``tri_orient`` on, so that this
   is a copy; wider ids are gathered from the vertex table here.
 
+``_BLOCK`` = 8 and ``_TILED`` = 16 were chosen on the chip over RMAT-20
+(359 M wedges; PERF.md §6, PR 41, has the readings).  A batch costs what
+its 2^24 rows cost to join and compact whatever they hold (0.235 s), so
+the blocks' gather is all B can move: B = 8 generates 1.158 rows a wedge
+of the tiled lists, 25 tile batches of 0.2649 s (the gather 0.030 s of
+each); B = 16 generates 1.356, 29 batches of 0.2499 s (the gather
+0.015 s): 6.88 s of ``tri_wedges`` a job against 7.50.  K0 = 2·B is where
+a list's tiles stop being mostly padding: counted over the cell's graph,
+K0 = 12 … 20 give 24 or 25 tile batches and one index batch of 2^22 or
+2^23 (0.249 s at 2^22), and from K0 = 24 on the short lists fill an
+index batch of 2^24 (0.665 s) or two for one tile batch saved.
+
 Each triangle is found exactly once: the wedge (u, w) at centre v exists
 only in v's out-neighbourhood, and the edge (u, w) closes it.  The host
-reads one scalar after ``tri_orient`` (the wedge count) and one a batch
-(its hits, to keep room in the buffer); no edge, wedge or triangle row
-crosses to the host.  Vertices are int32 through the walk (``stage_graph``'s
-ranks, or ids that fit), so a packed pair of them leaves the low bit of a
-u64 free for the merge's tag."""
+reads five scalars in one array after ``tri_orient`` (wedges, index
+wedges, tiles, edges, the largest out-degree) and one a batch (its hits,
+to keep room in the buffer); no edge, wedge or triangle row crosses to
+the host.  Vertices are int32 through the walk (``stage_graph``'s ranks, or
+ids that fit), so a packed pair of them leaves the low bit of a u64 free
+for the merge's tag."""
 
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import NamedTuple, Optional
 
 import jax
@@ -56,7 +100,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..parallel.mesh import mesh_axis_size, row_sharding
 from ..parallel.sharded import round_cap
 
-_BATCH = 1 << 24        # wedges per tri_wedges execution (bounds peak memory)
+_BATCH = 1 << 24        # wedge rows per tri_wedges execution (bounds peak memory)
+_BLOCK = 8              # B: a tile pairs B positions of a list with B of the same
+_TILED = 16             # K0: a list of this many out-neighbours or more is tiled
+_TILES = 1 << 23        # tiles per tri_tiles execution (the table's static cap)
 _ROWS_STEP = 1 << 20    # a large result's capacity is a multiple of this
 
 _DEAD = np.int32(np.iinfo(np.int32).max)        # rank of a dropped edge row
@@ -81,13 +128,16 @@ def _runs(s):
     return start, end
 
 
-def tri_orient(src, dst, valid, verts, canonical: bool, by_id: bool):
+def tri_orient(src, dst, valid, verts, canonical: bool, by_id: bool,
+               block: int, tiled: int):
     """Ranked edge rows → (sorted canonical edge keys, centre and
     out-neighbour of every oriented edge sorted by centre, the exclusive
-    prefix sum of the wedges each such position owns, the wedge count, the
-    edge count, the largest out-degree).  ``by_id``: the vertices are
-    named by their ids from here on (two gathers of the edge rows, once),
-    which orders them as their ranks do."""
+    prefix sums of what each such position owns — index wedges, where its
+    list is shorter than ``tiled``, and tiles of ``block`` positions a side,
+    where it is not and the position starts a block — and five counts in
+    one array: wedges, index wedges, tiles, edges, the largest out-degree).
+    ``by_id``: the vertices are named by their ids from here on (two
+    gathers of the edge rows, once), which orders them as their ranks do."""
     ne = src.shape[0]
     if by_id:
         last = verts.shape[0] - 1
@@ -121,57 +171,127 @@ def tri_orient(src, dst, valid, verts, canonical: bool, by_id: bool):
         start, end = _runs(grp)
         alive = grp != _DEAD
         pos = lax.iota(jnp.int32, ne)
-        owns = jnp.where(alive, end - pos, 0).astype(jnp.int64)
-        off = jnp.cumsum(owns) - owns
-        maxk = jnp.max(jnp.where(alive, end - start + 1, 0))
-    return (ekey, grp, nbr, off, jnp.sum(owns), jnp.sum(live.astype(jnp.int32)),
-            maxk)
+        k = end - start + 1
+        pairs = jnp.where(alive, end - pos, 0).astype(jnp.int64)
+        short = k < tiled
+        off, nindex = _offsets(jnp.where(short, pairs, 0))
+    with jax.named_scope("tile_offsets"):
+        # block a of a list of kb blocks is paired with blocks a … kb-1; a
+        # last block of one position has no pair of its own
+        a, r = jnp.divmod(pos - start, block)
+        owns = jnp.where(alive & ~short & (r == 0) & (pos < end),
+                         -(-k // block) - a, 0)
+        toff, ntiles = _offsets(owns.astype(jnp.int64))
+    counts = jnp.stack([jnp.sum(pairs), nindex, ntiles,
+                        jnp.sum(live.astype(jnp.int64)),
+                        jnp.max(jnp.where(alive, k, 0)).astype(jnp.int64)])
+    return ekey, grp, nbr, off, toff, counts
 
 
-def tri_wedges(ekey, grp, nbr, off, t0, nwedges, batch: int):
-    """The wedges with indices ``t0 … t0 + batch`` joined against the edge
-    keys: (keys of those that close, their centres, their number), the
-    hits at the front of ``[batch]`` arrays."""
-    ne = ekey.shape[0]
+def _offsets(owns):
+    """(exclusive prefix sum, total) of what each position owns."""
+    total = jnp.cumsum(owns)
+    return total - owns, total[-1]
+
+
+def _owners(off, vals, t0, n: int):
+    """Entries ``t0 … t0 + n`` of the space ``off`` lays out (position p
+    owns the entries from ``off[p]`` up to the next position's offset): of
+    each entry (its owner's position, each of ``vals`` there, its place
+    among the owner's entries).  ``vals`` are int32 arrays, none negative,
+    that do not fall along the positions (a list's centre, its end).  Two
+    sorts and no search."""
+    ne = off.shape[0]
+    # merge the owners' offsets with the entries' indices: an owner sorts
+    # before the index equal to its offset, every owner at or before t0 at
+    # the very front, those past t0 + n at the back.  A prefix max then
+    # gives an entry its owner: positions grow with offsets, and of the
+    # positions at one offset the last is the owner.
+    rel = off - t0
+    okey = jnp.where(rel <= 0, 0, jnp.where(rel >= n, 2 * n,
+                                            2 * rel)).astype(jnp.int32)
+    tl = lax.iota(jnp.int32, n)
+    none = jnp.zeros(n, jnp.int32)
+    k, *pv = lax.sort(
+        (jnp.concatenate([okey, 2 * tl + 1]),
+         *(jnp.concatenate([x, none])
+           for x in (lax.iota(jnp.int32, ne), *vals))),
+        num_keys=1, is_stable=False)
+    # back to index order: the entries are the odd keys
+    _, *pv = lax.sort((jnp.where(k & 1 == 1, k, 2 * n + 1),
+                       *(lax.cummax(x) for x in pv)),
+                      num_keys=1, is_stable=False)
+    p, *vals = (x[:n] for x in pv)
+    # an entry's place among its owner's: the distance to the first entry
+    # of the same owner, which for the first owner lies off[p] - t0 before
+    # t0 (one element read)
+    new = jnp.concatenate([jnp.zeros(1, bool), p[1:] != p[:-1]])
+    seg = lax.cummax(jnp.where(new, tl, 0))
+    lead = (t0 - off[p[0]]).astype(jnp.int32)
+    return p, vals, tl - seg + jnp.where(seg == 0, lead, 0)
+
+
+def tri_tiles(grp, toff, t0, cap: int, block: int):
+    """Tiles ``t0 … t0 + cap`` of the tile space: of each (the position
+    its first block starts at, the position its second block starts at, the
+    last position of their list, the list's centre)."""
+    sa, (end, c), j = _owners(toff, (_runs(grp)[1], grp), t0, cap)
+    return sa, sa + j * block, end, c
+
+
+def _index_wedges(nbr, grp, off, t0, total, batch: int):
+    """Index wedges ``t0 … t0 + batch`` of ``total``: (key, centre)."""
+    ne = nbr.shape[0]
     with jax.named_scope("expand"):
-        # merge the owners' offsets with the batch's wedge indices: an
-        # owner sorts before the wedge index equal to its offset, every
-        # owner at or before t0 at the very front, those past the batch at
-        # the back.  The prefix max of (position, neighbour) then gives a
-        # wedge its owner: positions grow with offsets.
-        rel = off - t0
-        okey = jnp.where(rel <= 0, 0, jnp.where(rel >= batch, 2 * batch,
-                                                2 * rel)).astype(jnp.int32)
-        tl = lax.iota(jnp.int32, batch)
-        pos = lax.iota(jnp.uint64, ne)
-        k, pu, c = lax.sort(
-            (jnp.concatenate([okey, 2 * tl + 1]),
-             jnp.concatenate([(pos << 32) | nbr.astype(jnp.uint64),
-                              jnp.zeros(batch, jnp.uint64)]),
-             jnp.concatenate([grp, jnp.zeros(batch, jnp.int32)])),
-            num_keys=1, is_stable=False)
-        pu, c = lax.cummax(pu), lax.cummax(c)
-        # back to wedge order: the batch's entries are the odd keys
-        _, pu, c = lax.sort((jnp.where(k & 1 == 1, k, 2 * batch + 1), pu, c),
-                            num_keys=1, is_stable=False)
-        pu, c = pu[:batch], c[:batch]
-        p = (pu >> 32).astype(jnp.int32)
-        u = (pu & 0xFFFFFFFF).astype(jnp.int32)
+        p, (c,), j = _owners(off, (grp,), t0, batch)
     with jax.named_scope("partner"):
-        # a wedge's place among its owner's: the distance to the first
-        # wedge of the same owner, which for the batch's first owner lies
-        # off[p] - t0 before the batch (one element read)
-        new = jnp.concatenate([jnp.zeros(1, bool), p[1:] != p[:-1]])
-        seg = lax.cummax(jnp.where(new, tl, 0))
-        lead = (t0 - off[p[0]]).astype(jnp.int32)
-        j = tl - seg + jnp.where(seg == 0, lead, 0)
-        # the one gather over a batch: the partner's neighbour
-        w = jnp.take(nbr, jnp.minimum(p + 1 + j, ne - 1))
+        # the one gather over a batch: a wedge's two neighbours
+        uw = jnp.take(nbr, jnp.minimum(jnp.stack([p, p + 1 + j]), ne - 1))
+    inside = (t0 + lax.iota(jnp.int64, batch)) < total
+    # neighbours ascend along a list, so u < w
+    return jnp.where(inside, _pack(uw[0], uw[1]) | 1, _SENT), c
+
+
+def _tile_wedges(nbr, sa, sb, end, c, t0, total, batch: int, block: int):
+    """The wedges of tiles ``t0 … t0 + batch / block²`` of a table of
+    ``total``: (key, centre), ``[block, block, tiles]`` flattened, the tile
+    on the minor axis.  A row is masked where it names a position past its
+    list's end, or a pair i ≥ j of a tile on the diagonal."""
+    ne = nbr.shape[0]
+    nt = batch // (block * block)
+    sa, sb, end, c = (lax.dynamic_slice_in_dim(x, t0, nt)
+                      for x in (sa, sb, end, c))
+    i = lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+    with jax.named_scope("blocks"):
+        # the one gather over a batch: both blocks of every tile
+        at = jnp.expand_dims(jnp.stack([sa, sb]), 1) + i
+        uw = jnp.take(nbr, jnp.minimum(at, ne - 1))
+        ok = at <= end
+        real = (t0 + lax.iota(jnp.int64, nt)) < total
+    with jax.named_scope("pairs"):
+        # (neighbours ascend along a list, so u < w wherever the pair is one)
+        upper = jnp.expand_dims(i < i.T, 2)
+        pair = (jnp.expand_dims(ok[0] & real, 1) & jnp.expand_dims(ok[1], 0)
+                & (upper | (sa != sb)))
+        key = jnp.where(pair, _pack(jnp.expand_dims(uw[0], 1),
+                                    jnp.expand_dims(uw[1], 0)) | 1, _SENT)
+        c = jnp.broadcast_to(c, key.shape)
+    return key.reshape(batch), c.reshape(batch)
+
+
+def tri_wedges(ekey, nbr, lists, t0, total, batch: int, block: int):
+    """One batch of wedges joined against the edge keys: (keys of those
+    that close, their centres, their number), the hits at the front of
+    ``[batch]`` arrays.  ``block`` 0: the index wedges ``t0 … t0 + batch``
+    of the short lists, ``lists`` = (grp, off); else the wedges of
+    ``batch / block²`` tiles from ``t0`` on, ``lists`` = a table of
+    :func:`tri_tiles`."""
+    ne = ekey.shape[0]
+    if block:
+        wkey, c = _tile_wedges(nbr, *lists, t0, total, batch, block)
+    else:
+        wkey, c = _index_wedges(nbr, *lists, t0, total, batch)
     with jax.named_scope("join"):
-        inside = (t0 + tl.astype(jnp.int64)) < nwedges
-        wkey = jnp.where(inside,
-                         _pack(jnp.minimum(u, w), jnp.maximum(u, w)) | 1,
-                         _SENT)
         key, c = lax.sort((jnp.concatenate([ekey, wkey]),
                            jnp.concatenate([jnp.zeros(ne, jnp.int32), c])),
                           num_keys=1, is_stable=False)
@@ -228,6 +348,7 @@ def tri_rows(kbuf, cbuf, verts, rows: int, by_id: bool):
 
 class _Programs(NamedTuple):
     orient: object
+    tiles: object
     wedges: object
     append: object
     grow: object
@@ -236,7 +357,7 @@ class _Programs(NamedTuple):
 
 @functools.lru_cache(maxsize=None)
 def _programs(mesh: Optional[Mesh]) -> _Programs:
-    """The five programs.  On a mesh everything between the sharded edge
+    """The six programs.  On a mesh everything between the sharded edge
     rows and the result rows is replicated: every device walks the same
     wedges (the walk is one chip's work until it is sharded), and only a
     one-device mesh keeps the rows as a frame of its own."""
@@ -245,9 +366,12 @@ def _programs(mesh: Optional[Mesh]) -> _Programs:
         rep = NamedSharding(mesh, PartitionSpec())
         rows = row_sharding(mesh) if mesh_axis_size(mesh) == 1 else rep
     return _Programs(
-        jax.jit(tri_orient, static_argnames=("canonical", "by_id"),
+        jax.jit(tri_orient, out_shardings=rep,
+                static_argnames=("canonical", "by_id", "block", "tiled")),
+        jax.jit(tri_tiles, static_argnames=("cap", "block"),
                 out_shardings=rep),
-        jax.jit(tri_wedges, static_argnames="batch", out_shardings=rep),
+        jax.jit(tri_wedges, static_argnames=("batch", "block"),
+                out_shardings=rep),
         jax.jit(tri_append, donate_argnums=(0, 1), out_shardings=rep),
         jax.jit(tri_grow, out_shardings=rep),
         jax.jit(tri_rows, static_argnames=("rows", "by_id"),
@@ -269,13 +393,23 @@ class Walk(NamedTuple):
     verts: Optional[jax.Array]  # the rank → id table, on the device
     by_id: bool                 # the buffers name vertices by id, not rank
     ntri: int
-    wedges: int
-    batches: int
+    wedges: int                 # Σ k(k-1)/2 over the lists, of either kind
+    batches: int                # executions of tri_wedges, of either kind
     edges: int
     max_out_degree: int
+    tiles: int
+    index_wedges: int           # the wedges of the lists walked by index
+    tile_rows: int              # rows the tile batches generated
+
+    @property
+    def tile_fill(self) -> float:
+        """Wedges that came from tiles over the rows the tile batches
+        generated; 0 where no tile batch ran."""
+        return ((self.wedges - self.index_wedges) / self.tile_rows
+                if self.tile_rows else 0.0)
 
 
-NO_WALK = Walk(None, None, None, False, 0, 0, 0, 0, 0)
+NO_WALK = Walk(None, None, None, False, 0, 0, 0, 0, 0, 0, 0, 0)
 
 
 def walk(src, dst, valid, verts: np.ndarray, mesh: Optional[Mesh] = None,
@@ -291,34 +425,52 @@ def walk(src, dst, valid, verts: np.ndarray, mesh: Optional[Mesh] = None,
     table = np.full(round_cap(len(verts)), verts[-1], np.uint64)
     table[:len(verts)] = verts
     table = jnp.asarray(table)
-    ekey, grp, nbr, off, nw, ne, maxk = prog.orient(
-        src, dst, valid, table, canonical=canonical, by_id=by_id)
-    nw = int(nw)                        # the one read before the loop
-    batch = min(_BATCH, round_cap(nw))  # a small graph's walk is one small batch
-    nbatch = -(-nw // batch)
+    block = _BLOCK
+    ekey, grp, nbr, off, toff, counts = prog.orient(
+        src, dst, valid, table, canonical=canonical, by_id=by_id,
+        block=block, tiled=_TILED)
+    # the one read before the loop
+    nw, nindex, ntiles, ne, maxk = map(int, np.asarray(counts))
+    # a small graph's walk is one small batch, its tile table a small one
+    cap = min(_TILES, round_cap(ntiles))
+    per = min(cap, max(1, _BATCH // block ** 2))    # tiles a batch
+    ibatch = min(_BATCH, round_cap(nindex))
+
+    def batches():
+        """Every execution of ``tri_wedges``, dispatched when asked for:
+        the tiles table after table, then the short lists by index."""
+        for t0 in range(0, ntiles, cap):
+            tiles = prog.tiles(grp, toff, jnp.int64(t0), cap=cap, block=block)
+            n = min(cap, ntiles - t0)
+            for i in range(0, n, per):
+                yield prog.wedges(ekey, nbr, tiles, jnp.int64(i), jnp.int64(n),
+                                  batch=per * block ** 2, block=block)
+        for i in range(0, nindex, ibatch):
+            yield prog.wedges(ekey, nbr, (grp, off), jnp.int64(i),
+                              jnp.int64(nindex), batch=ibatch, block=0)
+
     kbuf = cbuf = None
-    count = 0
+    count = nbatch = 0
     ahead = None
-    for i in range(nbatch + 1):
+    for nxt in itertools.chain(batches(), [None]):
         # one batch is dispatched before the last one's count is read, so
         # the device does not wait for the host
-        nxt = (prog.wedges(ekey, grp, nbr, off, jnp.int64(i * batch),
-                           jnp.int64(nw), batch=batch)
-               if i < nbatch else None)
         if ahead is not None:
             key, c, nhit = ahead
+            nbatch += 1
             if kbuf is None:
                 # the first batch's hits are the buffer, at twice their room
                 kbuf, cbuf = prog.grow(key, c)
             else:
-                while count + batch > kbuf.shape[0]:
+                while count + key.shape[0] > kbuf.shape[0]:
                     kbuf, cbuf = prog.grow(kbuf, cbuf)
                 kbuf, cbuf = prog.append(kbuf, cbuf, key, c,
                                          jnp.int32(count))
             count += int(nhit)
         ahead = nxt
-    return Walk(kbuf, cbuf, table, by_id, count, nw, nbatch, int(ne),
-                int(maxk))
+    tile_batches = nbatch - -(-nindex // ibatch)
+    return Walk(kbuf, cbuf, table, by_id, count, nw, nbatch, ne, maxk,
+                ntiles, nindex, tile_batches * per * block ** 2)
 
 
 def rows(w: Walk, mesh: Optional[Mesh] = None):

@@ -47,7 +47,9 @@ PAGERANK_LOOP = "jit_pagerank_loop"
 RMAT_EDGES = "jit_rmat_edges"
 RMAT_EDGE_ROWS = "jit_rmat_edge_rows"               # [m, 2] keys + NULL values
 TRI_ORIENT = "jit_tri_orient"                       # keys, degrees, neighbour lists
-TRI_WEDGES = "jit_tri_wedges"                       # one batch: expand, join, compact
+TRI_TILES = "jit_tri_tiles"                         # a table of tiles, once a job
+TRI_WEDGES = "jit_tri_wedges"                       # one batch, of tiles or of wedge
+#                                                     indices: its wedges, join, compact
 TRI_APPEND = "jit_tri_append"                       # a batch's hits into the buffer
 TRI_GROW = "jit_tri_grow"
 TRI_ROWS = "jit_tri_rows"                           # (centre, u, w) id rows
@@ -61,8 +63,8 @@ PROGRAMS = (
     SHUFFLE_PHASE1, SHUFFLE_PHASE2, SHUFFLE_PHASE2_WIRE, STAGE_RANK_GRAPH,
     STAGE_TRIM_VERTS, PLACE_ROWS, CONCAT_ROWS, LEVEL_ROWS, REMAP_IDS,
     CC_LOOP, PAGERANK_LOOP, RMAT_EDGES, RMAT_EDGE_ROWS,
-    TRI_ORIENT, TRI_WEDGES, TRI_APPEND, TRI_GROW, TRI_ROWS, LUBY_LOOP,
-    SSSP_LOOP, SSSP_WEIGHTS, TERASORT_SAMPLE_KEYS,
+    TRI_ORIENT, TRI_TILES, TRI_WEDGES, TRI_APPEND, TRI_GROW, TRI_ROWS,
+    LUBY_LOOP, SSSP_LOOP, SSSP_WEIGHTS, TERASORT_SAMPLE_KEYS,
 )
 
 # parallel/devkernels.py's two generic mappers run one program per kernel
@@ -111,9 +113,13 @@ PAGERANK_EMIT = "pagerank.emit"
 PAGERANK_ENGINE = "pagerank.loop"               # cat ENGINE
 # oink/commands/{tri,luby,sssp}.py
 TRI_STAGE = "tri.stage"                         # n, edges
-TRI_ENGINE = "tri.loop"                         # cat ENGINE: wedges, batches,
-#                                                 triangles, edges, n,
-#                                                 max_out_degree
+TRI_ENGINE = "tri.loop"                         # cat ENGINE: wedges, batches
+#                                                 (executions of TRI_WEDGES, of
+#                                                 either kind), triangles,
+#                                                 edges, n, max_out_degree,
+#                                                 and ATTR_TILES,
+#                                                 ATTR_INDEX_WEDGES,
+#                                                 ATTR_TILE_FILL below
 TRI_EMIT = "tri.emit"                           # triangles
 LUBY_STAGE = "luby.stage"                       # n, edges
 LUBY_ENGINE = "luby.loop"                       # cat ENGINE: iters, n, edges,
@@ -197,6 +203,13 @@ ATTR_KEY_WORDS = "key_words"
 ATTR_RODE_WORDS = "rode_words"
 ATTR_TAKEN_WORDS = "taken_words"
 ATTR_HBM_ROW_BYTES = "hbm_row_bytes"
+# on the ``tri.loop`` span (oink/commands/tri.py, from models/tri.Walk): the
+# tiles the long out-lists were cut into, the wedges of the short lists
+# (walked index by index), and the wedges that came from tiles over the
+# rows the tile batches generated (0 where no tile batch ran)
+ATTR_TILES = "tiles"
+ATTR_INDEX_WEDGES = "index_wedges"
+ATTR_TILE_FILL = "tile_fill"
 # on every span (obs/tracer.Span): the thread's CPU seconds, and the rest of
 # the span's wall (blocked on the device, a file, a lock, or descheduled)
 ATTR_CPU_S = "cpu_s"
@@ -215,6 +228,7 @@ ATTR_JIT_CACHE_LOADS = "jit_cache_loads"
 SPAN_ATTRS = (
     ATTR_ROWS, ATTR_GROUPS, ATTR_GROUP_ROWS_MAX, ATTR_RECORDS, ATTR_KEY_WORDS,
     ATTR_RODE_WORDS, ATTR_TAKEN_WORDS, ATTR_HBM_ROW_BYTES,
+    ATTR_TILES, ATTR_INDEX_WEDGES, ATTR_TILE_FILL,
     ATTR_CPU_S, ATTR_OFF_CPU_S,
     ATTR_PROC_CPU_S, ATTR_SYS_CPU_S, ATTR_VOL_SWITCHES, ATTR_INVOL_SWITCHES,
     ATTR_JIT_LOWERINGS, ATTR_JIT_LOWER_S, ATTR_JIT_BACKEND_S,

@@ -206,8 +206,9 @@ class TriFind(Command):
     emitted the angle (oink/tri_find.cpp:43-81).
 
     Engines: ``fused`` (default) — the degree-ordered wedge walk as device
-    programs (models/tri.py: sorts that carry payloads, prefix scans, one
-    gather a batch of wedges; the triangles stay on the device as the
+    programs (models/tri.py: a long out-list paired block against block,
+    a short one wedge index by wedge index, each batch of wedges joined
+    with the edge keys by a sort; the triangles stay on the device as the
     output MR), the same programs on every backend; ``composed`` — the
     reference's 6-stage MR pipeline below (GPUMR_TRI_ENGINE=composed),
     whose ``nsq_angles`` holds every angle of a shard in one frame and so
@@ -266,7 +267,10 @@ class TriFind(Command):
             # the span ends at the pull of the last batch's hit count
             w = tri.walk(src, dst, valid, verts, mesh) if n else tri.NO_WALK
             sp.set(wedges=w.wedges, batches=w.batches, triangles=w.ntri,
-                   edges=w.edges, n=n, max_out_degree=w.max_out_degree)
+                   edges=w.edges, n=n, max_out_degree=w.max_out_degree,
+                   **{names.ATTR_TILES: w.tiles,
+                      names.ATTR_INDEX_WEDGES: w.index_wedges,
+                      names.ATTR_TILE_FILL: w.tile_fill})
 
         self.ntri = w.ntri
         mrt = obj.create_mr()

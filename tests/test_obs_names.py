@@ -89,10 +89,14 @@ def _programs(mesh):
             np.asarray([0.57, 0.19, 0.19, 0.05]), 0.0, noisy=False)),
         (names.RMAT_EDGE_ROWS, rmat.rmat_edge_rows.lower(col, col)),
         (names.TRI_ORIENT, tri._programs(mesh).orient.lower(
-            *edges, small, canonical=False, by_id=True)),
+            *edges, small, canonical=False, by_id=True, block=8, tiled=16)),
+        (names.TRI_TILES, tri._programs(mesh).tiles.lower(
+            cnt2, SDS((64,), jnp.int64), SDS((), jnp.int64), cap=8, block=8)),
+        # an index batch; a tile batch is the same function, held to the
+        # same name below
         (names.TRI_WEDGES, tri._programs(mesh).wedges.lower(
-            col, cnt2, cnt2, SDS((64,), jnp.int64), SDS((), jnp.int64),
-            SDS((), jnp.int64), batch=64)),
+            col, cnt2, (cnt2, SDS((64,), jnp.int64)), SDS((), jnp.int64),
+            SDS((), jnp.int64), batch=64, block=0)),
         (names.TRI_APPEND, tri._programs(mesh).append.lower(
             SDS((128,), u64), SDS((128,), i32), col, cnt2, SDS((), i32))),
         (names.TRI_GROW, tri._programs(mesh).grow.lower(col, cnt2)),
@@ -111,6 +115,7 @@ def _programs(mesh):
 
 
 def test_every_program_lowers_under_its_declared_name(mesh):
+    from gpu_mapreduce_tpu.models import tri
     seen = set()
     for want, lowered in _programs(mesh):
         got = re.search(r"module @(\w+)", lowered.as_text()).group(1)
@@ -141,11 +146,22 @@ def test_every_program_lowers_under_its_declared_name(mesh):
                 ops = re.findall(r"stablehlo\.(\w+)", text)
                 assert ops.count("sort") == 1 and not set(ops) & {
                     "scatter", "gather", "while"}, ops
-        if want in (names.TRI_ORIENT, names.TRI_WEDGES):
+        if want in (names.TRI_ORIENT, names.TRI_TILES, names.TRI_WEDGES):
             # the same rule for the wedge walk: sorts, no scatter, and no
             # ``while`` (a searchsorted is a gather a round)
-            ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
-            assert "sort" in ops and not ops & {"scatter", "while"}, ops
+            texts = [lowered.as_text()]
+            if want == names.TRI_WEDGES:
+                # both kinds of batch run under the one name the metrics
+                # sum (ISSUE 41): the tile batch too
+                tile = SDS((8,), jnp.int32)
+                texts.append(tri._programs(mesh).wedges.lower(
+                    SDS((64,), jnp.uint64), tile, (tile,) * 4,
+                    SDS((), jnp.int64), SDS((), jnp.int64), batch=128,
+                    block=8).as_text())
+                assert re.search(r"module @(\w+)", texts[1]).group(1) == want
+            for text in texts:
+                ops = set(re.findall(r"stablehlo\.(\w+)", text))
+                assert "sort" in ops and not ops & {"scatter", "while"}, ops
     assert set(names.PROGRAMS) <= seen
     assert len(set(names.PROGRAMS)) == len(names.PROGRAMS)
     assert len(set(names.SPANS)) == len(names.SPANS)
@@ -393,6 +409,11 @@ def test_enumeration_commands_emit_their_spans(mesh, traced, tmp_path):
     ntri = int(re.search(r"Tri_find: (\d+) triangles", screen).group(1))
     assert walk["triangles"] == ntri == args[names.TRI_EMIT]["triangles"] > 0
     assert walk["wedges"] >= ntri and walk["batches"] >= 1
+    # the two enumerations (ISSUE 41): RMAT-7's hubs are tiled, the rest of
+    # its lists walked by index
+    assert walk[names.ATTR_TILES] >= 1
+    assert 0 < walk[names.ATTR_INDEX_WEDGES] < walk["wedges"]
+    assert 0 < walk[names.ATTR_TILE_FILL] <= 1
     assert walk["edges"] == s.obj.get_mr("mru").kv.nkv
     assert walk["n"] == args[names.TRI_STAGE]["n"] > walk["max_out_degree"] > 1
     nset, rounds = map(int, re.search(

@@ -16,7 +16,10 @@ def dense_oracle(src, dst, n, damping=0.85, iters=200):
     for a, b in zip(src, dst):
         A[a, b] += 1.0
     deg = A.sum(1)
-    P = np.divide(A, deg[:, None], where=deg[:, None] > 0)
+    # rows of dangling vertices stay 0 (without ``out`` they are whatever
+    # the allocator left there: a NaN now and then)
+    P = np.divide(A, deg[:, None], out=np.zeros_like(A),
+                  where=deg[:, None] > 0)
     x = np.full(n, 1.0 / n)
     for _ in range(iters):
         dangling = x[deg == 0].sum()

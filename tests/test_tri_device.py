@@ -4,11 +4,14 @@ here, on the CPU, to a brute-force oracle and to the composed engine over
 graphs that have what R-MAT's hubs have (a vertex whose wedges span
 several batches, a hub with no triangle, hubs that share neighbours), with
 the batch cap forced below and above the wedge count, on the serial
-backend and on a four-device mesh; and the wedge program to the chip's
-rule (no scatter).  ``luby_find`` and ``sssp``, which run beside it in
+backend and on a four-device mesh; the two enumerations of ISSUE 41 (a
+list of ``_TILED`` out-neighbours or more paired block against block, a
+shorter one wedge index by wedge index) over lists on both sides of every
+boundary; and the walk's programs to the chip's rule (no scatter).  ``luby_find`` and ``sssp``, which run beside it in
 ``graph-tri-1chip``, are held to plain references of their own."""
 
 import collections
+import functools
 import os
 import re
 
@@ -107,6 +110,34 @@ def _wedges(e):
     return sum(n * (n - 1) // 2 for n in k.values())
 
 
+def _out_lists(e):
+    """Length of every vertex's (degree, id) out-list."""
+    deg = collections.Counter(e.reshape(-1).tolist())
+    return collections.Counter(min((a, b), key=lambda v: (deg[v], v))
+                               for a, b in e.tolist())
+
+
+def _enumeration(e, batch):
+    """What the walk must count, from the lengths of the out-lists alone:
+    (wedges, index wedges, tiles, batches, tile rows) under the module's
+    ``_BLOCK`` / ``_TILED`` / ``_TILES`` and a batch cap of ``batch``."""
+    from gpu_mapreduce_tpu.parallel.sharded import round_cap
+    B, ks = tri._BLOCK, list(_out_lists(e).values())
+    index = sum(k * (k - 1) // 2 for k in ks if k < tri._TILED)
+    tiles = 0
+    for k in ks:
+        if k >= tri._TILED:     # block a pairs with blocks a … kb-1; a last
+            kb = -(-k // B)     # block of one position has no diagonal tile
+            tiles += kb * (kb + 1) // 2 - (k % B == 1)
+    cap = min(tri._TILES, round_cap(tiles))
+    per = min(cap, max(1, batch // B ** 2))
+    tbatches = sum(-(-min(cap, tiles - t0) // per)
+                   for t0 in range(0, tiles, cap))
+    ibatches = -(-index // min(batch, round_cap(index)))
+    return (sum(k * (k - 1) // 2 for k in ks), index, tiles,
+            tbatches + ibatches, tbatches * per * B ** 2)
+
+
 def _rows_of(path):
     with open(path) as f:
         return np.array(f.read().split(), np.uint64).reshape(-1, 3)
@@ -187,20 +218,108 @@ def test_walk_counts_and_module_functions(cases, monkeypatch, name):
     assert np.array_equal(np.asarray(tri.rows(wide)[0])[:w.ntri],
                           np.asarray(tri.rows(w)[0])[:w.ntri]
                           + np.uint64(1 << 33))
-    nw = _wedges(e)
+    # ``batches`` counts the executions of the wedge program, of either
+    # kind: the tile batches of every table, then the index batches
+    nw, index, tiles, batches, tile_rows = _enumeration(e, 1024)
+    assert nw == _wedges(e)
+    if name == "rmat-10":       # both enumerations ran
+        assert 0 < index < nw and tiles > 0
+    else:                       # every list is short: the index walk alone
+        assert index == nw and tiles == 0
     assert (w.wedges, w.batches, w.ntri, w.edges) == (
-        nw, -(-nw // min(1024, 1 << (nw - 1).bit_length())), len(oracle),
-        len(e))
-    deg = collections.Counter(e.reshape(-1).tolist())
-    k = collections.Counter(min((a, b), key=lambda v: (deg[v], v))
-                            for a, b in e.tolist())
-    assert w.max_out_degree == max(k.values())
+        nw, batches, len(oracle), len(e))
+    assert (w.tiles, w.index_wedges, w.tile_rows) == (tiles, index, tile_rows)
+    assert w.tile_fill == ((nw - index) / tile_rows if tiles else 0.0)
+    assert w.tile_fill <= 1
+    assert w.max_out_degree == max(_out_lists(e).values())
     both = np.concatenate([e, e[:, ::-1], e[:5]])       # not canonical
     for rows in (tri.triangles(both),
                  tri.triangles_ranked(inv[:, 0], inv[:, 1], len(verts),
                                       verts, canonical=True)):
         assert rows.dtype == np.uint64 and rows.shape == (len(oracle), 3)
         assert {frozenset(map(int, r)) for r in rows} == oracle
+
+
+# -- the two enumerations (ISSUE 41) ----------------------------------------------
+
+_B, _K0 = tri._BLOCK, tri._TILED
+
+
+def _hub(k, among):
+    """A centre whose out-list is exactly ``k`` long: vertex 0 joined to
+    1 … k, each of which has k + 1 leaves of its own (so that its degree
+    passes the centre's and the edge points at it), and ``among`` them
+    every edge, none, or a chain with chords (some wedges close)."""
+    nb = list(range(1, k + 1))
+    edges = _star(0, nb)
+    for i, v in enumerate(nb):
+        first = k + 1 + i * (k + 1)
+        edges += _star(v, range(first, first + k + 1))
+    return edges + {
+        "all": _complete(nb), "none": [],
+        "some": ([(v, v + 1) for v in nb[:-1]]
+                 + [(v, v + 3) for v in nb[:-3:2]])}[among]
+
+
+TILE_GRAPHS = {
+    # a list on each side of the boundary between the enumerations, one
+    # that ends with a block and one that ends one position into a block
+    **{f"list-{k}": (lambda k=k: _hub(k, "some"))
+       for k in (_K0 - 1, _K0, _K0 + 1, 3 * _B, 3 * _B + 1)},
+    # a hub of 3B + 1 with every pair closed (K_n: lists of every length
+    # below n, by id), and with none
+    "hub-complete": lambda: _complete(range(3 * _B + 2)),
+    "hub-star": lambda: _hub(3 * _B + 1, "none"),
+    # every list shorter than K0: today's program and nothing else
+    "all-short": lambda: (_complete(range(_K0)) + _complete(range(100, 106))
+                          + _star(200, range(201, 240)) + [(5, 100), (5, 200)]),
+}
+
+
+@pytest.mark.parametrize("how", ["serial", "mesh4", "wide-ids", "small-batch"])
+@pytest.mark.parametrize("name", list(TILE_GRAPHS))
+def test_tile_and_index_walks_against_brute_force(monkeypatch, name, how):
+    """Every triangle once whichever enumeration its centre's list gets,
+    and the walk's counts what the lists' lengths say.  ``small-batch``:
+    two tiles a batch and eight a table, so that a list's tiles are cut
+    across batches and across tables."""
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    from gpu_mapreduce_tpu.parallel.sharded import round_cap
+    e = _upper(TILE_GRAPHS[name]())
+    oracle = brute_triangles(e)
+    batch = 1 << 24
+    if how == "small-batch":
+        batch = 2 * _B ** 2
+        monkeypatch.setattr(tri, "_BATCH", batch)
+        monkeypatch.setattr(tri, "_TILES", 8)
+    mesh = make_mesh(4) if how == "mesh4" else None
+    wide = np.uint64(1 << 33 if how == "wide-ids" else 0)
+    verts, inv = np.unique(e.reshape(-1), return_inverse=True)
+    inv = inv.reshape(-1, 2)
+    w = tri.walk(jnp.asarray(inv[:, 0], jnp.int32),
+                 jnp.asarray(inv[:, 1], jnp.int32),
+                 jnp.ones(len(inv), bool), verts + wide, mesh)
+    assert w.by_id == (how != "wide-ids")
+    rows = np.asarray(tri.rows(w, mesh)[0])[:w.ntri] - wide
+    got = [frozenset(map(int, r)) for r in rows]
+    assert len(got) == len(set(got)) == w.ntri and set(got) == oracle
+    nw, index, tiles, batches, tile_rows = _enumeration(e, batch)
+    assert nw == _wedges(e)
+    assert (w.wedges, w.index_wedges, w.tiles, w.batches, w.tile_rows) == (
+        nw, index, tiles, batches, tile_rows)
+    longest = max(_out_lists(e).values())
+    assert w.max_out_degree == longest
+    assert (tiles > 0) == (longest >= _K0)
+    if name.startswith("list-"):
+        assert longest == int(name[5:])
+    if name == "all-short":     # no tile batch ran; ``batches`` is the
+        assert w.tile_rows == 0 and w.tile_fill == 0.0  # rule before tiles
+        assert w.batches == -(-nw // min(batch, round_cap(nw)))
+    elif how == "small-batch" and longest >= 3 * _B:
+        # the longest list's six tiles or more, two a batch; K_n's fill
+        # more than one table
+        assert w.batches >= tiles // 2 >= 3
+        assert tiles > 8 or name != "hub-complete"
 
 
 def test_empty_inputs_give_no_rows():
@@ -249,22 +368,44 @@ def _lowered_ops(fn, *shapes, **static):
     return collections.Counter(re.findall(r"stablehlo\.(\w+)", text))
 
 
+def _gathers(fn, *shapes, **static):
+    """Result shape of every gather in a program's jaxpr."""
+    sds = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    jaxpr = jax.make_jaxpr(functools.partial(fn, **static))(*sds).jaxpr
+    return sorted(q.outvars[0].aval.shape for q in _primitives(jaxpr, [])
+                  if q.primitive.name == "gather")
+
+
 def test_the_wedge_program_holds_no_scatter():
     """The chip's rule (PERF.md §6, PRs 25 and 29): a scatter costs thirty
-    sorts there and looks cheap here.  The walk is four sorts, prefix
-    scans, and one gather over a batch (the partner's neighbour; the other
-    is one element)."""
-    e, b = 1 << 10, 1 << 12
-    ops = _lowered_ops(
-        tri.tri_wedges, ((e,), jnp.uint64), ((e,), jnp.int32),
-        ((e,), jnp.int32), ((e,), jnp.int64), ((), jnp.int64),
-        ((), jnp.int64), batch=b)
-    assert ops["sort"] == 4 and "scatter" not in ops, ops
-    assert ops["gather"] == 2, ops      # nbr[partner], and off[first owner]
-    assert "while" not in ops       # no searchsorted either
+    sorts there and looks cheap here.  A tile batch is two sorts (the join,
+    the compaction), prefix scans and one gather of both blocks of every
+    tile; an index batch is four sorts and one gather over the batch (a
+    wedge's two neighbours); the tile expansion, once a job, is two sorts and
+    no gather (the first owner's offset is one element sliced)."""
+    e, b, B = 1 << 10, 1 << 12, tri._BLOCK
+    col = lambda dtype, n=e: ((n,), dtype)      # noqa: E731
+    scalar = ((), jnp.int64)
+    tiles = tuple(col(jnp.int32, 256) for _ in range(4))
+    index = (col(jnp.int32), col(jnp.int64))
+    for lists, block, sorts, gathers in (
+            (tiles, B, 2, [(2, B, b // B ** 2)]),    # nbr[both blocks]
+            (index, 0, 4, [(2, b)])):               # nbr[both ends]
+        fn = lambda ekey, nbr, t0, total, *ls: tri.tri_wedges(  # noqa: E731
+            ekey, nbr, ls, t0, total, batch=b, block=block)
+        shapes = (col(jnp.uint64), col(jnp.int32), scalar, scalar) + lists
+        ops = _lowered_ops(fn, *shapes)
+        assert ops["sort"] == sorts and "scatter" not in ops, (block, ops)
+        assert "while" not in ops, (block, ops)     # no searchsorted either
+        assert _gathers(fn, *shapes) == gathers, block
+    shapes = (col(jnp.int32), col(jnp.int64), scalar)
+    ops = _lowered_ops(tri.tri_tiles, *shapes, cap=256, block=B)
+    assert ops["sort"] == 2 and not ops.keys() & {"scatter", "while"}, ops
+    assert _gathers(tri.tri_tiles, *shapes, cap=256, block=B) == []
     ops = _lowered_ops(
         tri.tri_orient, ((e,), jnp.int32), ((e,), jnp.int32),
-        ((e,), jnp.bool_), ((64,), jnp.uint64), canonical=False, by_id=False)
+        ((e,), jnp.bool_), ((64,), jnp.uint64), canonical=False, by_id=False,
+        block=B, tiled=tri._TILED)
     # (five sorts; the two ``jnp.sort`` of the keys lower as one function)
     assert ops["sort"] >= 4 and not ops.keys() & {
         "scatter", "gather", "while"}, ops
