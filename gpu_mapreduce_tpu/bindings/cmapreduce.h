@@ -10,8 +10,8 @@
  * callbacks (MR_kv_add them there, like the reference's KVptr).
  */
 
-#ifndef GPUMR_CMAPREDUCE_H
-#define GPUMR_CMAPREDUCE_H
+#ifndef MRTPU_CMAPREDUCE_H
+#define MRTPU_CMAPREDUCE_H
 
 #include <stdint.h>
 
@@ -158,4 +158,4 @@ void OINK_close(void *oink);
 }
 #endif
 
-#endif /* GPUMR_CMAPREDUCE_H */
+#endif /* MRTPU_CMAPREDUCE_H */

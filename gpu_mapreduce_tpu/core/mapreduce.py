@@ -1352,12 +1352,8 @@ class MapReduce:
                 return fr
             try:
                 if isinstance(fr, ShardedKV):
-                    return reshard_kv(fr, mesh,
-                                      transport=self.settings.all2all,
-                                      counters=self.counters)
-                return reshard_kmv(fr, mesh,
-                                   transport=self.settings.all2all,
-                                   counters=self.counters)
+                    return reshard_kv(fr, mesh, counters=self.counters)
+                return reshard_kmv(fr, mesh, counters=self.counters)
             except BaseException:
                 # donation may have consumed the frame mid-exchange:
                 # leave a clean empty dataset, not deleted buffers

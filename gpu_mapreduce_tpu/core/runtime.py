@@ -14,9 +14,10 @@ TPU reinterpretations (documented, not silently dropped):
 * ``keyalign``/``valuealign`` — byte alignment is meaningless for columnar
   arrays; accepted and ignored (validated like the reference,
   ``src/mapreduce.cpp:251-261``).
-* ``all2all`` — selects the shuffle transport: 1 = single fused all_to_all
-  collective, 0 = ppermute ring (the reference's MPI_Alltoallv vs.
-  Irecv/Send ring, ``src/irregular.cpp:254-363``).
+* ``all2all`` — the reference's choice of MPI_Alltoallv over its
+  Irecv/Send ring (``src/irregular.cpp:254-363``); accepted and ignored:
+  the exchange picks its collective from the mesh
+  (``parallel/shuffle._exchange_blocks``).
 * ``mapstyle`` — 0 chunk / 1 stride task assignment both reduce to "run
   all tasks here" under one controller; 2 (the reference's master-slave
   MPI work queue, src/mapreduce.cpp:1136-1213) is a dynamic thread-pool
@@ -69,7 +70,7 @@ class Error:
 @dataclass
 class Settings:
     mapstyle: int = 0       # 0 chunk, 1 stride, 2 master-slave work queue
-    all2all: int = 1        # shuffle transport (fused collective vs ring)
+    all2all: int = 1        # accepted for MR-MPI parity, selects nothing
     verbosity: int = 0      # 0 silent, 1 totals, 2 + per-shard histograms
     timer: int = 0          # 0 off, 1 totals, 2 + per-shard histograms
     # MB per frame (reference default 64, mapreduce.cpp:209); the env

@@ -13,7 +13,7 @@ callgraph (``callgraph.py``):
 * ``knob-registry`` — MRTPU_* knobs route through utils/env.py
   and match doc/settings.md (knobs.py);
 * ``metric-catalog`` — mrtpu_* metrics match doc/observability.md
-  (metrics_doc.py, formerly scripts/check_metrics_doc.py);
+  (metrics_doc.py);
 * ``net-timeout`` — outbound network calls in serve/router/client code
   must carry an explicit timeout (nettimeout.py).
 

@@ -12,8 +12,8 @@ reads.
 
 Scope: the package plus the Project's ``extra`` modules (the scripts
 scripts/mrlint.py names).  Only the reserved ``MRTPU_`` namespace is
-enforced; legacy ``MR_*``/``GPUMR_*`` app knobs predate the registry
-and stay out of it until renamed.
+enforced; legacy ``MR_*`` app knobs predate the registry and stay out
+of it until renamed.
 
 Rules:
 

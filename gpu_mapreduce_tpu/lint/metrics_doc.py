@@ -1,6 +1,4 @@
-"""metric-catalog: code and doc/observability.md must agree (the
-former scripts/check_metrics_doc.py, re-homed as an mrlint checker —
-the script remains as a thin shim).
+"""metric-catalog: code and doc/observability.md must agree.
 
 Every metric name registered in the package (any lowercase ``mrtpu_*``
 string literal — the reserved namespace for metric names) must appear

@@ -15,8 +15,6 @@ component with its minimum vertex id (deterministic across backends)."""
 
 from __future__ import annotations
 
-import os
-
 import numpy as np
 
 from ...core.runtime import MRError
@@ -242,12 +240,12 @@ class CCFind(Command):
       where each MR stage is a compiled program.
     * ``composed`` — the reference's 9-stage MapReduce composition
       (below), kept as the parity demonstration of the op algebra's
-      device tier; select with GPUMR_CC_ENGINE=composed (or by setting
-      ``CCFind.engine``)."""
+      device tier and the tests' reference; reached by setting
+      ``CCFind.engine``."""
 
     ninputs = 1
     noutputs = 1
-    engine: str | None = None   # None → GPUMR_CC_ENGINE env (or fused)
+    engine: str = "fused"
 
     def params(self, args):
         if len(args) != 1:
@@ -255,11 +253,10 @@ class CCFind(Command):
         self.nthresh = int(args[0])  # accepted for parity; see module doc
 
     def run(self):
-        engine = self.engine or os.environ.get("GPUMR_CC_ENGINE", "fused")
-        if engine not in ("fused", "composed"):
-            raise MRError(f"cc_find: unknown engine {engine!r} "
+        if self.engine not in ("fused", "composed"):
+            raise MRError(f"cc_find: unknown engine {self.engine!r} "
                           f"(use 'fused' or 'composed')")
-        if engine == "composed":
+        if self.engine == "composed":
             return self._run_composed()
         obj = self.obj
         mre = obj.input(1, read_edge)
@@ -270,25 +267,20 @@ class CCFind(Command):
         # vertices ON DEVICE — the O(E) edge columns never reach the
         # controller; only n and the [n] id table do
         from ...obs import get_tracer, names
-        from ...parallel.staging import stage_graph
+        from ...parallel.staging import stage_graph, stage_graph_host
         tr = get_tracer()
         with tr.span(names.CC_STAGE, cat=names.HOST) as sp:
             sg = stage_graph(mre, obj.comm)
             # (sg.n == 0 cannot happen here: empty datasets return None
             # and without drop_self every valid edge row has real
             # endpoints)
-            if sg is not None:
-                verts, n, nedges = sg.verts, sg.n, int(mre.kv.nkv)
+            on_device = sg is not None
+            if on_device:
+                nedges = int(mre.kv.nkv)
             else:
-                edges: list = []
-                mre.scan_kv(lambda fr, p: edges.append(kv_keys(fr)),
-                            batch=True)
-                e = (np.concatenate(edges) if edges
-                     else np.zeros((0, 2), np.uint64))
-                verts, inv = np.unique(e.reshape(-1), return_inverse=True)
-                n, nedges = len(verts), len(e)
-                src = inv.reshape(-1, 2)[:, 0]
-                dst = inv.reshape(-1, 2)[:, 1]
+                sg = stage_graph_host(mre)
+                nedges = len(sg.src)
+            verts, n = sg.verts, sg.n
             sp.set(n=n, edges=nedges)
         if n == 0:
             self.ncc, self.niterate = 0, 0
@@ -301,7 +293,7 @@ class CCFind(Command):
         # the fused loop, from dispatch to the pull that ends it
         with tr.span(names.CC_ENGINE, cat=names.ENGINE, n=n,
                      edges=nedges) as sp:
-            if sg is not None:
+            if on_device:
                 from ...models.cc import _cc_sharded_fn
                 labels_d, iters = _cc_sharded_fn(mesh, n, max(n, 1))(
                     sg.src, sg.dst, sg.valid)
@@ -309,10 +301,10 @@ class CCFind(Command):
             else:
                 from ...models.cc import cc, cc_sharded
                 if mesh is not None:
-                    labels, iters = cc_sharded(mesh, src, dst, n)
+                    labels, iters = cc_sharded(mesh, sg.src, sg.dst, n)
                 else:
-                    labels, iters = cc(src.astype(np.int32),
-                                       dst.astype(np.int32), n)
+                    labels, iters = cc(sg.src.astype(np.int32),
+                                       sg.dst.astype(np.int32), n)
                     labels, iters = np.asarray(labels), int(iters)
             sp.set(iters=iters)
 

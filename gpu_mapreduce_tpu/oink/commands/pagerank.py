@@ -21,7 +21,7 @@ import numpy as np
 
 from ...core.runtime import MRError
 from ..command import Command, command
-from ..kernels import kv_keys, read_edge, read_edge_weight
+from ..kernels import read_edge, read_edge_weight
 from ...models.pagerank import pagerank, pagerank_sharded
 
 
@@ -59,20 +59,16 @@ class PageRankCommand(Command):
         mre = obj.input(1, _read_edges_sniff)
 
         from ...obs import get_tracer, names
+        from ...parallel.staging import stage_graph_host
         tr = get_tracer()
         with tr.span(names.PAGERANK_STAGE, cat=names.HOST) as sp:
-            edges: list = []
-            mre.scan_kv(lambda fr, p: edges.append(kv_keys(fr)), batch=True)
-            e = (np.concatenate(edges) if edges
-                 else np.zeros((0, 2), np.uint64))
             # compact arbitrary u64 ids to dense 0..n-1 for the dense-rank
             # model
-            verts, inv = np.unique(e.reshape(-1), return_inverse=True)
-            n = len(verts)
-            sp.set(n=n, edges=len(e))
+            sg = stage_graph_host(mre)
+            verts, n, src, dst = sg.verts, sg.n, sg.src, sg.dst
+            sp.set(n=n, edges=len(src))
             if n == 0:
                 raise MRError("pagerank: empty edge list")
-            src, dst = inv.reshape(-1, 2)[:, 0], inv.reshape(-1, 2)[:, 1]
 
         from jax.sharding import Mesh
         mesh = obj.comm if isinstance(obj.comm, Mesh) else None

@@ -40,8 +40,6 @@ TPU-first redesigns vs the reference:
 
 from __future__ import annotations
 
-import os
-
 import numpy as np
 
 from ...core.runtime import MRError
@@ -276,11 +274,12 @@ class SSSPCommand(Command):
     ``lax.while_loop`` with the source as a traced operand, so every
     source of the ncnt experiment reuses ONE compiled program
     (models/sssp.py); ``composed`` — the reference's per-round MR
-    composition below (GPUMR_SSSP_ENGINE=composed)."""
+    composition below (the tests' reference, reached by setting
+    ``SSSPCommand.engine``)."""
 
     ninputs = 1
     noutputs = 1
-    engine: str | None = None   # None → GPUMR_SSSP_ENGINE env (or fused)
+    engine: str = "fused"
 
     def params(self, args):
         if len(args) != 2:
@@ -289,11 +288,10 @@ class SSSPCommand(Command):
         self.seed = int(args[1])
 
     def run(self):
-        engine = self.engine or os.environ.get("GPUMR_SSSP_ENGINE", "fused")
-        if engine not in ("fused", "composed"):
-            raise MRError(f"sssp: unknown engine {engine!r} "
+        if self.engine not in ("fused", "composed"):
+            raise MRError(f"sssp: unknown engine {self.engine!r} "
                           f"(use 'fused' or 'composed')")
-        if engine == "composed":
+        if self.engine == "composed":
             return self._run_composed()
         obj = self.obj
         mredge = obj.input(1, read_edge_weight)
@@ -305,7 +303,7 @@ class SSSPCommand(Command):
         # (need_weights guards against interned byte values, whose u64
         # ids are not numbers)
         from ...obs import get_tracer, names
-        from ...parallel.staging import stage_graph
+        from ...parallel.staging import stage_graph, stage_graph_host
         tr = get_tracer()
         with tr.span(names.SSSP_STAGE, cat=names.HOST) as sp:
             sg = stage_graph(mredge, obj.comm, need_weights=True)
@@ -317,22 +315,11 @@ class SSSPCommand(Command):
                 bf = runner(_bf_sharded_fn(mesh, n, max(n, 1)), sg.src,
                             sg.dst, sg.weights, sg.valid, n)
             else:
-                ecols: list = []
-                mredge.scan_kv(lambda fr, p: ecols.append(
-                    (kv_keys(fr), kv_values(fr))), batch=True)
-                if ecols:
-                    e = np.concatenate([c[0] for c in ecols]).astype(np.uint64)
-                    w = np.concatenate([c[1] for c in ecols]).astype(
-                        np.float64)
-                else:
-                    e = np.zeros((0, 2), np.uint64)
-                    w = np.zeros(0, np.float64)
-                verts, inv = np.unique(e.reshape(-1), return_inverse=True)
-                n = len(verts)
+                sg = stage_graph_host(mredge, need_weights=True)
+                verts, n, src, dst = sg.verts, sg.n, sg.src, sg.dst
                 if n == 0:
                     raise MRError("sssp: empty edge list")
-                src = inv.reshape(-1, 2)[:, 0]
-                dst = inv.reshape(-1, 2)[:, 1]
+                w = sg.weights.astype(np.float64)
 
                 from ...models.sssp import (bellman_ford,
                                             prepare_bellman_ford, runner)

@@ -41,8 +41,7 @@ def gather_kv(backend, mr, nprocs: int):
     # recv from hi procs with same ID % numprocs",
     # src/mapreduce.cpp:919-928)
     try:
-        out = exchange(skv, ("fixed_mod", n),
-                       transport=mr.settings.all2all, counters=mr.counters)
+        out = exchange(skv, ("fixed_mod", n), counters=mr.counters)
     except BaseException:
         # donation may have consumed an installed frame: leave a clean
         # empty dataset, not deleted buffers (shuffle.free_if_donated)

@@ -156,8 +156,7 @@ def _narrow(skv: ShardedKV, new_mesh: Mesh) -> ShardedKV:
 
 
 def _exchange_range(skv: ShardedKV, new_mesh: Mesh,
-                    ends: Tuple[int, ...], transport: int,
-                    counters) -> ShardedKV:
+                    ends: Tuple[int, ...], counters) -> ShardedKV:
     """The shared routing core: contiguous-global-order rows of ``skv``
     → target shards per the host-computed ``ends`` schedule, result on
     ``new_mesh``."""
@@ -166,14 +165,14 @@ def _exchange_range(skv: ShardedKV, new_mesh: Mesh,
     if M > N:
         skv = _widen(skv, new_mesh)
         out = exchange(skv, ("range", _offsets(skv.counts), ends),
-                       transport=transport, counters=counters)
+                       counters=counters)
         return out
     out = exchange(skv, ("range", _offsets(skv.counts), ends),
-                   transport=transport, counters=counters)
+                   counters=counters)
     return _narrow(out, new_mesh)
 
 
-def reshard_kv(skv: ShardedKV, new_mesh: Mesh, transport: int = 1,
+def reshard_kv(skv: ShardedKV, new_mesh: Mesh,
                counters=None) -> ShardedKV:
     """Redistribute a ShardedKV onto ``new_mesh`` (any width), global
     row order preserved exactly.  The id→bytes decode tables ride along
@@ -181,11 +180,11 @@ def reshard_kv(skv: ShardedKV, new_mesh: Mesh, transport: int = 1,
     OWN table count, independent of row placement."""
     tcounts = even_counts(len(skv), mesh_axis_size(new_mesh))
     ends = tuple(int(x) for x in np.cumsum(tcounts))
-    out = _exchange_range(skv, new_mesh, ends, transport, counters)
+    out = _exchange_range(skv, new_mesh, ends, counters)
     return out
 
 
-def reshard_kmv(skmv: ShardedKMV, new_mesh: Mesh, transport: int = 1,
+def reshard_kmv(skmv: ShardedKMV, new_mesh: Mesh,
                 counters=None) -> ShardedKMV:
     """Redistribute a ShardedKMV onto ``new_mesh`` at group
     granularity.  Two range exchanges (groups, then their value runs)
@@ -214,7 +213,7 @@ def reshard_kmv(skmv: ShardedKMV, new_mesh: Mesh, transport: int = 1,
                     skmv.gcounts.astype(np.int32),
                     key_decode=skmv.key_decode)
     gkv._shared = True      # buffers belong to the live KMV frame
-    gout = _exchange_range(gkv, new_mesh, gends, transport, counters)
+    gout = _exchange_range(gkv, new_mesh, gends, counters)
 
     # exchange 2: the value rows, routed by the SAME group-aligned cuts
     # (a 1-byte rider fills the KV-shaped exchange's second column)
@@ -224,7 +223,7 @@ def reshard_kmv(skmv: ShardedKMV, new_mesh: Mesh, transport: int = 1,
                     skmv.vcounts.astype(np.int32),
                     key_decode=skmv.value_decode)
     vkv._shared = True
-    vout = _exchange_range(vkv, new_mesh, vends, transport, counters)
+    vout = _exchange_range(vkv, new_mesh, vends, counters)
 
     # new shard-local value offsets from the same host schedule
     gcap_new = gout.cap

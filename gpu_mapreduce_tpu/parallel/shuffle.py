@@ -24,8 +24,8 @@ host: read the [P,P] count matrix, pick the padded bucket size B and the
 
 phase 2 (jitted, per shard): window the sorted rows into a [P,B] send
   buffer (each destination's rows are one contiguous run), exchange via
-  ``lax.all_to_all`` (``all2all=1``) or a ppermute ring (``all2all=0`` —
-  the reference's custom Irecv/Send transport, ``irregular.cpp:311-363``),
+  ``lax.all_to_all`` (on a two-axis mesh one all-to-all an axis,
+  :func:`_a2a_hier`; :func:`_exchange_blocks` chooses from the mesh),
   then append each source's block to its run of the packed output.  Runs
   move, not rows: no index in phase 2 is a per-row array.
 
@@ -142,11 +142,10 @@ def _send_windows(nprocs: int, B: int, start: int, rows, counts_local):
     column a round (the cells' ``P`` is 4, the tests' 8).  They move
     ``P * B`` rows in all — the send block itself — whatever ``P`` is,
     but at ``P = 64+`` and 16 rounds the trace is some thousands of
-    operations: the growth ``_ring_exchange`` was rewritten to avoid.
-    The forms to take there are one batched window (a ``gather`` of
-    ``P`` indices with ``[B, ...]`` slices) here and a ``fori_loop``
-    over the sources in :func:`_place_blocks`: for the v5e both compile
-    to a ``while`` of ``P`` steps of dynamic-slice +
+    operations.  The forms to take there are one batched window (a
+    ``gather`` of ``P`` indices with ``[B, ...]`` slices) here and a
+    ``fori_loop`` over the sources in :func:`_place_blocks`: for the v5e
+    both compile to a ``while`` of ``P`` steps of dynamic-slice +
     dynamic-update-slice (PERF.md §6, PR 31) — copies as well, but a
     loop where the unrolled form is straight-line code, so at the
     cells' ``P`` the unrolled form stands."""
@@ -211,36 +210,6 @@ def _build_send(nprocs: int, B: int, rows, counts_local, round_idx: int = 0):
     return _build_send_window(nprocs, B, round_idx * B, rows, counts_local)
 
 
-def _ring_exchange(send, mesh):
-    """Systolic shift-by-one ring: recv[j] = what shard j holds for me.
-
-    The reference's second transport is a hand-rolled Irecv/Send ring
-    (``irregular.cpp:311-363``).  Round 1 unrolled one ppermute per shift
-    distance k — O(P) collectives of O(P·B) state each, an O(P²) trace
-    that stops compiling at pod scale.  This version keeps the *same*
-    single shift-by-one permutation every step inside ``lax.fori_loop``
-    (ppermute's permutation must be trace-static, so a varying shift can't
-    live in the loop): after s shifts my buffer is shard (me-s)'s original
-    send array, and its row [me] is that shard's block for me."""
-    axes = mesh_axes(mesh)
-    nprocs = send.shape[0]
-    me = flat_axis_index(mesh)
-    perm = [(i, (i + 1) % nprocs) for i in range(nprocs)]
-    recv = jnp.zeros_like(send)
-    recv = recv.at[me].set(send[me])  # self-copy overlap (irregular.cpp:311)
-
-    def body(s, carry):
-        buf, recv = carry
-        # flat 1-axis mesh only: _exchange_blocks/_exchange_counts route
-        # every 2-axis mesh through _a2a_hier before reaching the ring
-        buf = lax.ppermute(buf, axes[0], perm)
-        recv = recv.at[(me - s) % nprocs].set(buf[me])
-        return buf, recv
-
-    _, recv = lax.fori_loop(1, nprocs, body, (send, recv))
-    return recv
-
-
 def _a2a_hier(send, mesh):
     """Hierarchical all-to-all for a (slice, chip) mesh: rows for
     (s', c') first move to the LOCAL chip c' over ICI (axis "c"), then
@@ -257,23 +226,20 @@ def _a2a_hier(send, mesh):
     return x.reshape(send.shape)
 
 
-def _exchange_counts(counts_local, transport: int, mesh):
-    """Exchange per-dest counts: counts_from[j] = rows shard j sends me.
-    Multi-slice meshes always take the hierarchical route (a flat ring
-    would cross DCN on most hops — the pattern the hierarchy avoids)."""
-    if transport == 1 or len(mesh_axes(mesh)) == 2:
-        return _exchange_blocks(counts_local[:, None], transport, mesh)[:, 0]
-    return _ring_exchange(counts_local[:, None], mesh)[:, 0]
+def _exchange_counts(counts_local, mesh):
+    """Exchange per-dest counts: counts_from[j] = rows shard j sends me."""
+    return _exchange_blocks(counts_local[:, None], mesh)[:, 0]
 
 
-def _exchange_blocks(send, transport: int, mesh):
-    """[P,B,...] send blocks → [P,B,...] recv blocks."""
+def _exchange_blocks(send, mesh):
+    """[P,B,...] send blocks → [P,B,...] recv blocks.  The one place that
+    chooses the collective, from the mesh: a (slice, chip) mesh goes
+    ICI-then-DCN (:func:`_a2a_hier`: a flat exchange would cross DCN on
+    most hops), a one-axis mesh is one ``lax.all_to_all``."""
     axes = mesh_axes(mesh)
     if len(axes) == 2:
-        return _a2a_hier(send, mesh)            # ICI-then-DCN (module doc)
-    if transport == 1:
-        return lax.all_to_all(send, axes[0], 0, 0)
-    return _ring_exchange(send, mesh)
+        return _a2a_hier(send, mesh)
+    return lax.all_to_all(send, axes[0], 0, 0)
 
 
 def _dest_fn(dest, nprocs: int, mesh) -> Callable:
@@ -437,8 +403,8 @@ def _phase1_build(mesh, dest, donate: bool = False, wire=None):
     return donated_jit(shuffle_phase1, (0, 1) if donate else ())
 
 
-def phase2_shard_body(nprocs: int, transport: int, mesh, B: int,
-                      nrounds: int, cap_out: int, k, v, cl):
+def phase2_shard_body(nprocs: int, mesh, B: int, nrounds: int,
+                      cap_out: int, k, v, cl):
     """Per-shard phase-2 body — the fusible stage builder the plan/
     fuser composes with convert/reduce inside ONE shard_map program.
     Returns ``(out_k, out_v, nrecv)``: received rows packed to the
@@ -455,21 +421,19 @@ def phase2_shard_body(nprocs: int, transport: int, mesh, B: int,
     send block is windows of the dest-sorted shard
     (:func:`_send_windows`): both sides move runs, indexed by ``P``
     offsets, not rows indexed one by one."""
-    counts_from = _exchange_counts(cl, transport, mesh)
+    counts_from = _exchange_counts(cl, mesh)
     base = _run_starts(counts_from)
     out_k = _recv_buffer(cap_out, B, k)
     out_v = _recv_buffer(cap_out, B, v)
     for r in range(nrounds):
-        recv_k = _exchange_blocks(
-            _build_send(nprocs, B, k, cl, r), transport, mesh)
-        recv_v = _exchange_blocks(
-            _build_send(nprocs, B, v, cl, r), transport, mesh)
+        recv_k = _exchange_blocks(_build_send(nprocs, B, k, cl, r), mesh)
+        recv_v = _exchange_blocks(_build_send(nprocs, B, v, cl, r), mesh)
         out_k = _place_blocks(out_k, recv_k, base, counts_from, r * B)
         out_v = _place_blocks(out_v, recv_v, base, counts_from, r * B)
     return out_k[:cap_out], out_v[:cap_out], jnp.sum(counts_from)
 
 
-def _phase2_jit(mesh, transport: int, B: int, nrounds: int, cap_out: int,
+def _phase2_jit(mesh, B: int, nrounds: int, cap_out: int,
                 donate: bool = False):
     """``donate=True`` donates the dest-sorted skey/svalue (dead after
     the exchange has placed them in the output blocks).  NEVER used for
@@ -479,12 +443,11 @@ def _phase2_jit(mesh, transport: int, B: int, nrounds: int, cap_out: int,
     case the donation is byte-aliasable, so it never degrades to a
     warned no-op."""
     return PHASE2_CACHE.get_or_build(
-        (mesh, transport, B, nrounds, cap_out, donate),
-        lambda: _phase2_build(mesh, transport, B, nrounds, cap_out,
-                              donate))
+        (mesh, B, nrounds, cap_out, donate),
+        lambda: _phase2_build(mesh, B, nrounds, cap_out, donate))
 
 
-def _phase2_build(mesh, transport: int, B: int, nrounds: int, cap_out: int,
+def _phase2_build(mesh, B: int, nrounds: int, cap_out: int,
                   donate: bool = False):
     nprocs = mesh_axis_size(mesh)
     spec = row_spec(mesh)
@@ -492,7 +455,7 @@ def _phase2_build(mesh, transport: int, B: int, nrounds: int, cap_out: int,
     def shuffle_phase2(skey, svalue, counts_local):
         def body(k, v, cl):
             out_k, out_v, _ = phase2_shard_body(
-                nprocs, transport, mesh, B, nrounds, cap_out, k, v, cl)
+                nprocs, mesh, B, nrounds, cap_out, k, v, cl)
             return out_k, out_v
         return jax.shard_map(
             body, mesh=mesh, in_specs=(spec, spec, spec),
@@ -502,21 +465,21 @@ def _phase2_build(mesh, transport: int, B: int, nrounds: int, cap_out: int,
     return donated_jit(shuffle_phase2, (0, 1) if donate else ())
 
 
-def _phase2_wire_jit(mesh, transport: int, tiers, cap_out: int, kpack,
-                     vpack, donate: bool = False):
+def _phase2_wire_jit(mesh, tiers, cap_out: int, kpack, vpack,
+                     donate: bool = False):
     """The wire-codec phase 2 (parallel/wire.py): same packed output as
     :func:`_phase2_jit` byte for byte, but rows cross the interconnect
     delta-packed at the planned widths with tiered round caps.  The
     plan's every static knob keys the executable cache — the "wire in
     the jit key" contract of doc/perf.md."""
     return PHASE2_CACHE.get_or_build(
-        (mesh, transport, "wire", tiers, cap_out, kpack, vpack, donate),
-        lambda: _phase2_wire_build(mesh, transport, tiers, cap_out,
-                                   kpack, vpack, donate))
+        (mesh, "wire", tiers, cap_out, kpack, vpack, donate),
+        lambda: _phase2_wire_build(mesh, tiers, cap_out, kpack, vpack,
+                                   donate))
 
 
-def _phase2_wire_build(mesh, transport: int, tiers, cap_out: int, kpack,
-                       vpack, donate: bool = False):
+def _phase2_wire_build(mesh, tiers, cap_out: int, kpack, vpack,
+                       donate: bool = False):
     from .wire import phase2_wire_shard_body
     nprocs = mesh_axis_size(mesh)
     spec = row_spec(mesh)
@@ -524,8 +487,7 @@ def _phase2_wire_build(mesh, transport: int, tiers, cap_out: int, kpack,
     def shuffle_phase2_wire(skey, svalue, counts_local, stats_local):
         def body(k, v, cl, st):
             out_k, out_v, _ = phase2_wire_shard_body(
-                nprocs, transport, mesh, tiers, cap_out, kpack, vpack,
-                k, v, cl, st)
+                nprocs, mesh, tiers, cap_out, kpack, vpack, k, v, cl, st)
             return out_k, out_v
         return jax.shard_map(
             body, mesh=mesh, in_specs=(spec,) * 4,
@@ -539,7 +501,7 @@ def _phase2_wire_build(mesh, transport: int, tiers, cap_out: int, kpack,
 # speculative capacity cache (round 4, VERDICT r3 weak #5): composed
 # iterative commands pay the exchange's ONE host sync — the count-matrix
 # pull that sizes the bucket/round/output shapes — once per op, a full
-# device round-trip.  Keyed by (mesh, transport,
+# device round-trip.  Keyed by (mesh, dest spec,
 # operand shapes/dtypes), the caps that worked last time are assumed
 # again: phase 2 is ENQUEUED immediately with the cached shapes and the
 # count matrix is pulled while it runs.  The pull then verifies the
@@ -576,30 +538,14 @@ def _plan_caps(counts_mat: np.ndarray):
     return B, nrounds, cap_out, Bmax, new_counts
 
 
-class _ExchangeStatsMeta(type):
-    """Class-level assignment to the legacy names would silently
-    REPLACE their read-through descriptors and freeze the value (the
-    pre-r5 reset idiom `ExchangeStats.last_nrounds = 0` did exactly
-    this) — intercept it with a clear error (r5 review)."""
-
-    def __setattr__(cls, name, value):
-        if name in ("last_nrounds", "last_bucket"):
-            raise AttributeError(
-                f"{name} is a read-only view of ExchangeStats.last — "
-                f"assign ExchangeStats.last = (nrounds, bucket) instead")
-        super().__setattr__(name, value)
-
-
 @dataclass
 class ExchangeCallStats:
-    """Flow-control telemetry of ONE exchange() call (ISSUE 2
-    satellite): the class-level ExchangeStats records only the LAST
-    exchange process-wide, so two concurrent MapReduce objects
-    (mapstyle-2 threads, -partition worlds, fused plans running
-    interleaved segments) silently clobber each other.  This per-call
-    object is attached to the returned ShardedKV (``.exchange_stats``)
-    and surfaced as ``MapReduce.last_exchange`` after aggregate(); the
-    same numbers land on the obs ``shuffle.exchange`` span."""
+    """Flow-control telemetry of ONE exchange() call: attached to the
+    returned ShardedKV (``.exchange_stats``) and surfaced as
+    ``MapReduce.last_exchange`` after aggregate(), so two concurrent
+    MapReduce objects (mapstyle-2 threads, -partition worlds, fused
+    plans running interleaved segments) each read their own.  The same
+    numbers land on the obs ``shuffle.exchange`` span."""
 
     nrounds: int
     bucket: int
@@ -638,27 +584,6 @@ def exchange_volume(skv: ShardedKV, counts_mat, slots: int,
     return moved, pad, rowbytes
 
 
-class ExchangeStats(metaclass=_ExchangeStatsMeta):
-    """DEPRECATED process-global telemetry of the LAST exchange's flow
-    control — kept as a read-only shim for existing callers; new code
-    reads the per-call :class:`ExchangeCallStats` on the exchange
-    result (or ``mr.last_exchange``), which concurrent MapReduce
-    objects cannot clobber.  ``last`` is ONE (nrounds, bucket) tuple so
-    a reader under -partition threading never sees a torn pair; the
-    legacy attribute names read through it."""
-    last = (0, 0)
-
-    class _Attr:
-        def __init__(self, i):
-            self.i = i
-
-        def __get__(self, obj, owner):
-            return owner.last[self.i]
-
-    last_nrounds = _Attr(0)
-    last_bucket = _Attr(1)
-
-
 def free_if_donated(kv, skv) -> bool:
     """After a FAILED exchange: if donation already consumed ``skv``'s
     buffers and ``skv`` is an installed frame of ``kv``, free the
@@ -678,8 +603,8 @@ def free_if_donated(kv, skv) -> bool:
     return False
 
 
-def exchange(skv: ShardedKV, dest, transport: int = 1,
-             counters=None, dest_args: tuple = ()) -> ShardedKV:
+def exchange(skv: ShardedKV, dest, counters=None,
+             dest_args: tuple = ()) -> ShardedKV:
     """Full ragged exchange: route every valid row to its dest shard.
     ``dest`` is a hashable spec (see :func:`_dest_fn`), ``dest_args`` the
     arrays its function takes beside the keys (the splitters of
@@ -711,13 +636,12 @@ def exchange(skv: ShardedKV, dest, transport: int = 1,
         fault_point("shuffle.exchange")
         tr = get_tracer()
         if not tr.enabled:
-            return _exchange_impl(skv, dest, transport, counters,
-                                  NULL_SPAN, dest_args)
+            return _exchange_impl(skv, dest, counters, NULL_SPAN,
+                                  dest_args)
         with tr.span("shuffle.exchange", cat="shuffle",
                      nprocs=mesh_axis_size(skv.mesh),
-                     transport=transport, dest=_dest_kind(dest)) as sp:
-            return _exchange_impl(skv, dest, transport, counters, sp,
-                                  dest_args)
+                     dest=_dest_kind(dest)) as sp:
+            return _exchange_impl(skv, dest, counters, sp, dest_args)
 
     def _retryable(e):
         try:
@@ -737,23 +661,23 @@ def _dest_kind(dest) -> str:
     return "user" if dest[0] == "hash" and dest[1] is not None else dest[0]
 
 
-def _dispatch_phase2(plan, mesh, transport, donate2, skey, svalue,
-                     counts_local, stats_local):
+def _dispatch_phase2(plan, mesh, donate2, skey, svalue, counts_local,
+                     stats_local):
     """Run one exchange plan (the tagged tuple of parallel/wire.py):
     raw plans take the original counts-only program, wire plans the
     codec program (which additionally consumes the phase-1 stats)."""
     if plan[0] == "wire":
         _tag, tiers, cap_out, kpack, vpack = plan
-        return _phase2_wire_jit(mesh, transport, tiers, cap_out, kpack,
-                                vpack, donate=donate2)(
+        return _phase2_wire_jit(mesh, tiers, cap_out, kpack, vpack,
+                                donate=donate2)(
             skey, svalue, counts_local, stats_local)
     _tag, B, nrounds, cap_out = plan
-    return _phase2_jit(mesh, transport, B, nrounds, cap_out,
-                       donate=donate2)(skey, svalue, counts_local)
+    return _phase2_jit(mesh, B, nrounds, cap_out, donate=donate2)(
+        skey, svalue, counts_local)
 
 
-def _exchange_impl(skv: ShardedKV, dest, transport: int,
-                   counters, sp, dest_args: tuple = ()) -> ShardedKV:
+def _exchange_impl(skv: ShardedKV, dest, counters, sp,
+                   dest_args: tuple = ()) -> ShardedKV:
     from . import wire as _wire
     mesh = skv.mesh
     nprocs = mesh_axis_size(mesh)
@@ -788,16 +712,15 @@ def _exchange_impl(skv: ShardedKV, dest, transport: int,
     # different bucket profiles — sharing one slot would cross-
     # contaminate caps and waste speculative dispatches (r4 review).
     # wire_on too: raw and wire plans are different executables
-    spec_key = (mesh, transport, dest, skv.key.shape, skv.key.dtype.str,
+    spec_key = (mesh, dest, skv.key.shape, skv.key.dtype.str,
                 skv.value.shape, skv.value.dtype.str, wire_on)
     with _SPEC_LOCK:
         spec = _SPEC_CACHE.get(spec_key)
     out_spec = None
     if spec is not None:
         bump_dispatch()
-        out_spec = _dispatch_phase2(spec, mesh, transport, False,
-                                    skey, svalue, counts_local,
-                                    stats_local)
+        out_spec = _dispatch_phase2(spec, mesh, False, skey, svalue,
+                                    counts_local, stats_local)
     SyncStats.bump()   # the op's ONE round-trip: the count matrix
     from ..obs import get_tracer
     with get_tracer().span("shuffle.count_sync", cat="shuffle"):
@@ -860,20 +783,14 @@ def _exchange_impl(skv: ShardedKV, dest, transport: int,
         donate2 = (donate
                    and _wire.plan_cap_out(plan)
                    == skey.shape[0] // max(nprocs, 1))
-        out_k, out_v = _dispatch_phase2(plan, mesh, transport, donate2,
-                                        skey, svalue, counts_local,
-                                        stats_local)
+        out_k, out_v = _dispatch_phase2(plan, mesh, donate2, skey, svalue,
+                                        counts_local, stats_local)
         with _SPEC_LOCK:
             _SPEC_CACHE[spec_key] = plan
         ran = plan
 
     B_eff, nrounds_eff = _wire.plan_rounds(ran)
     cap_out_eff = _wire.plan_cap_out(ran)
-    # one tuple assignment: a concurrent world's exchange can interleave
-    # here, but a reader then sees ONE exchange's (nrounds, bucket) pair,
-    # never a torn mix (VERDICT r4 weak #7) — deprecated shim; the
-    # per-call truth is the ExchangeCallStats built below
-    ExchangeStats.last = (nrounds_eff, B_eff)
     stats = ExchangeCallStats(nrounds=nrounds_eff, bucket=B_eff,
                               cap_out=cap_out_eff,
                               rows=int(counts_mat.sum()),
@@ -980,14 +897,13 @@ def aggregate_kv(backend, mr, hash_fn: Optional[Callable]):
         dest, dest_args = ("order",), (hash_fn.splitters,)
     t = Timer()
     try:
-        out = exchange(skv, dest, transport=mr.settings.all2all,
-                       counters=mr.counters, dest_args=dest_args)
+        out = exchange(skv, dest, counters=mr.counters,
+                       dest_args=dest_args)
     except BaseException:
         free_if_donated(kv, skv)
         raise
     mr.counters.add(commtime=t.elapsed())
-    # per-call stats (not the deprecated class attrs): concurrent MRs
-    # each keep their own last_exchange
+    # per-call stats: concurrent MRs each keep their own last_exchange
     mr.last_exchange = getattr(out, "exchange_stats", None)
     _replace_kv_frames(kv, out)
 

@@ -39,6 +39,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ..core.frame import KVFrame
 from .mesh import mesh_axis_size, row_spec
 from .sharded import ShardedKV, round_cap
 
@@ -70,7 +71,8 @@ def staged_frame(mr) -> Optional[ShardedKV]:
 
 class StagedGraph:
     """Result of :func:`stage_graph`: ranked sharded edge arrays plus the
-    host-side [n] vertex-id table (pulled once, for output)."""
+    host-side [n] vertex-id table (pulled once, for output).  From
+    :func:`stage_graph_host` the same fields, every one a numpy array."""
 
     __slots__ = ("verts", "n", "src", "dst", "valid", "weights")
 
@@ -86,8 +88,9 @@ def stage_graph(mr, comm, drop_self: bool = False,
     rank vertices/edges on device.  Returns None when mesh staging does
     not apply (no mesh comm, empty dataset, or — with ``need_weights`` —
     interned byte values, whose ids are not numbers); the caller then
-    takes its host path.  An n==0 result carries empty arrays so callers
-    can emit their empty output without re-pulling the edge list."""
+    stages on the host (:func:`stage_graph_host`).  An n==0 result
+    carries empty arrays so callers can emit their empty output without
+    re-pulling the edge list."""
     from jax.sharding import Mesh
     if not isinstance(comm, Mesh):
         return None
@@ -102,6 +105,41 @@ def stage_graph(mr, comm, drop_self: bool = False,
                            None)
     return StagedGraph(np.asarray(verts_d)[:n], n, src_d, dst_d, valid_d,
                        fr.value if need_weights else None)
+
+
+def stage_graph_host(mr, drop_self: bool = False,
+                     need_weights: bool = False) -> StagedGraph:
+    """The ranking on the host, for where :func:`stage_graph` does not
+    apply (it returned None) and for ``pagerank``: the edge KV scanned
+    into numpy and ranked by ``np.unique``.  ``src``/``dst`` are each
+    endpoint's rank as ``np.unique`` counts it (int64; a caller whose
+    program takes int32 casts), every row is valid — with ``drop_self``
+    the self-loop rows are gone, not masked — and ``weights`` is the
+    value column as it was read, row for row.  An empty edge list gives
+    ``n == 0`` and empty arrays."""
+    keys, values = [], []
+
+    def read(fr, _ptr):
+        fr = fr if isinstance(fr, KVFrame) else fr.to_host()
+        keys.append(np.asarray(fr.key.to_host().data))
+        if need_weights:
+            values.append(np.asarray(fr.value.to_host().data))
+
+    mr.scan_kv(read, batch=True)
+    e = (np.concatenate(keys).astype(np.uint64, copy=False) if keys
+         else np.zeros((0, 2), np.uint64))
+    w = None
+    if need_weights:
+        w = np.concatenate(values) if values else np.zeros(0, np.float64)
+    if drop_self:
+        keep = e[:, 0] != e[:, 1]
+        e = e[keep]
+        if need_weights:
+            w = w[keep]
+    verts, inv = np.unique(e.reshape(-1), return_inverse=True)
+    inv = inv.reshape(-1, 2)
+    return StagedGraph(verts, len(verts), inv[:, 0], inv[:, 1],
+                       np.ones(len(inv), bool), w)
 
 
 def _valid_rows(nrows: int, nprocs: int, counts):

@@ -332,9 +332,9 @@ def _encode_windows(wins, valid, base_bits, pack: Optional[str]):
     return jnp.where(valid, wins, jnp.zeros((), wins.dtype))
 
 
-def phase2_wire_shard_body(nprocs: int, transport: int, mesh, tiers,
-                           cap_out: int, kpack: Optional[str],
-                           vpack: Optional[str], k, v, cl, stats):
+def phase2_wire_shard_body(nprocs: int, mesh, tiers, cap_out: int,
+                           kpack: Optional[str], vpack: Optional[str],
+                           k, v, cl, stats):
     """The wire twin of ``shuffle.phase2_shard_body``: same multi-round
     bounded exchange and same packed output layout (row positions are
     identical, so output is byte-identical), but rows cross the
@@ -353,8 +353,7 @@ def phase2_wire_shard_body(nprocs: int, transport: int, mesh, tiers,
 
     meta_local = jnp.stack([cl.astype(jnp.uint64), stats[:, 0],
                             stats[:, 2]], axis=1)          # [P, 3]
-    meta_from = _exchange_blocks(meta_local[:, None, :], transport,
-                                 mesh)[:, 0, :]
+    meta_from = _exchange_blocks(meta_local[:, None, :], mesh)[:, 0, :]
     counts_from = meta_from[:, 0].astype(jnp.int32)
     kbase = _base_in(meta_from[:, 1], k.dtype) if kpack else None
     vbase = _base_in(meta_from[:, 2], v.dtype) if vpack else None
@@ -368,8 +367,8 @@ def phase2_wire_shard_body(nprocs: int, transport: int, mesh, tiers,
                                  stats[:, 0], kpack)
         send_v = _encode_windows(*_send_windows(nprocs, B, start, v, cl),
                                  stats[:, 2], vpack)
-        recv_k = _exchange_blocks(send_k, transport, mesh)
-        recv_v = _exchange_blocks(send_v, transport, mesh)
+        recv_k = _exchange_blocks(send_k, mesh)
+        recv_v = _exchange_blocks(send_v, mesh)
         out_k = _place_blocks(out_k, recv_k, base, counts_from, start,
                               rebase=kbase)
         out_v = _place_blocks(out_v, recv_v, base, counts_from, start,

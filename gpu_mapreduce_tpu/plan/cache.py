@@ -17,7 +17,7 @@ counters that ``MapReduce.stats()`` and the obs spans report.
 
 Key discipline: every knob that changes a compiled program's BYTES must
 be in its cache key — the plan cache keys (fingerprint, frame
-signature, backend, transport, outofcore, ``MRTPU_WIRE``), and the
+signature, backend, outofcore, ``MRTPU_WIRE``), and the
 shuffle/fused executable caches additionally key the wire codec's full
 plan tuple (tier ladder + pack dtypes; ``parallel/wire.py``), so
 flipping a knob can never replay a stale executable.
@@ -109,8 +109,8 @@ class LRUCache:
 
 
 # ---------------------------------------------------------------------------
-# the plan cache: (stage-chain fingerprint, frame shapes/dtypes, mesh,
-# transport) → executable plan (see fuser.CompiledPlan)
+# the plan cache: (stage-chain fingerprint, frame shapes/dtypes, mesh)
+# → executable plan (see fuser.CompiledPlan)
 # ---------------------------------------------------------------------------
 
 _PLAN_CACHE: Optional[LRUCache] = None

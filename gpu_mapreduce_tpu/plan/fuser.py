@@ -35,7 +35,7 @@ those stages replay through the ordinary eager methods, so every
 pipeline still runs, fused or not.
 
 Compiled plans live in the plan cache (``plan.cache``) keyed on
-(stage-chain fingerprint, frame shapes/dtypes, mesh, transport); a hit
+(stage-chain fingerprint, frame shapes/dtypes, mesh); a hit
 reuses the previous run's exchange caps (validated against the fresh
 count matrix, like the shuffle's speculative-cap cache) so repeated
 pipelines reuse compiled programs instead of re-deriving shapes.
@@ -233,21 +233,20 @@ def _donate_argnums(donate: bool, aliasable_dim0: bool, out_kind: str,
     return (0,)
 
 
-def _fused_exchange_jit(mesh, transport: int, plan, out_kind: str,
+def _fused_exchange_jit(mesh, plan, out_kind: str,
                         reduce_op: Optional[str], donate_argnums=()):
     """``plan`` is the tagged exchange plan (parallel/wire.py): raw
     plans compose the original phase-2 body, wire plans the codec body —
     either way every static knob of the plan keys the executable cache."""
-    key = ("exchange", mesh, transport, plan, out_kind,
-           reduce_op, tuple(donate_argnums))
+    key = ("exchange", mesh, plan, out_kind, reduce_op,
+           tuple(donate_argnums))
     return FUSED_CACHE.get_or_build(
-        key, lambda: _fused_exchange_build(mesh, transport, plan,
-                                           out_kind, reduce_op,
-                                           donate_argnums))
+        key, lambda: _fused_exchange_build(mesh, plan, out_kind,
+                                           reduce_op, donate_argnums))
 
 
-def _fused_exchange_build(mesh, transport, plan, out_kind,
-                          reduce_op, donate_argnums=()):
+def _fused_exchange_build(mesh, plan, out_kind, reduce_op,
+                          donate_argnums=()):
     import jax
     from ..exec import donated_jit
     from ..parallel.group import fused_group_body
@@ -265,8 +264,8 @@ def _fused_exchange_build(mesh, transport, plan, out_kind,
         def run(skey, svalue, counts_local, stats_local):
             def body(k, v, cl, st):
                 out_k, out_v, nrecv = phase2_wire_shard_body(
-                    nprocs, transport, mesh, tiers, cap_out, kpack,
-                    vpack, k, v, cl, st)
+                    nprocs, mesh, tiers, cap_out, kpack, vpack, k, v,
+                    cl, st)
                 return fused_group_body(out_k, out_v, nrecv, cap_out,
                                         out_kind, reduce_op)
             return jax.shard_map(
@@ -279,8 +278,7 @@ def _fused_exchange_build(mesh, transport, plan, out_kind,
         def run(skey, svalue, counts_local):
             def body(k, v, cl):
                 out_k, out_v, nrecv = phase2_shard_body(
-                    nprocs, transport, mesh, B, nrounds, cap_out, k, v,
-                    cl)
+                    nprocs, mesh, B, nrounds, cap_out, k, v, cl)
                 return fused_group_body(out_k, out_v, nrecv, cap_out,
                                         out_kind, reduce_op)
             return jax.shard_map(
@@ -292,23 +290,21 @@ def _fused_exchange_build(mesh, transport, plan, out_kind,
     return donated_jit(run, donate_argnums)
 
 
-def _mega_jit(mesh, transport: int, dest, plan, gcap: int,
-              out_kind: str, reduce_op, elig):
+def _mega_jit(mesh, dest, plan, gcap: int, out_kind: str, reduce_op,
+              elig):
     """The fusion-v2 single-dispatch program: phase-1 dest-sort (+wire
     stats) + exchange (+wire encode/decode) + group/segment-reduce in
     ONE jit/shard_map, with the count/stats/meta matrices as extra
     outputs the host pulls AFTER dispatch (the speculation check).
     Every static knob — the exchange plan, the group capacity — keys
     the executable cache."""
-    key = ("mega", mesh, transport, dest, plan, gcap, out_kind,
-           reduce_op, elig)
+    key = ("mega", mesh, dest, plan, gcap, out_kind, reduce_op, elig)
     return FUSED_CACHE.get_or_build(
-        key, lambda: _mega_build(mesh, transport, dest, plan, gcap,
-                                 out_kind, reduce_op, elig))
+        key, lambda: _mega_build(mesh, dest, plan, gcap, out_kind,
+                                 reduce_op, elig))
 
 
-def _mega_build(mesh, transport, dest, plan, gcap, out_kind, reduce_op,
-                elig):
+def _mega_build(mesh, dest, plan, gcap, out_kind, reduce_op, elig):
     import jax
     from ..parallel.group import fused_group_body
     from ..parallel.mesh import mesh_axis_size, row_spec
@@ -327,12 +323,12 @@ def _mega_build(mesh, transport, dest, plan, gcap, out_kind, reduce_op,
         if plan[0] == "wire":
             _tag, tiers, _cap, kpack, vpack = plan
             out_k, out_v, nrecv = phase2_wire_shard_body(
-                nprocs, transport, mesh, tiers, cap_out, kpack, vpack,
-                sk, sv, cl, st)
+                nprocs, mesh, tiers, cap_out, kpack, vpack, sk, sv, cl,
+                st)
         else:
             _tag, B, nrounds, _cap = plan
             out_k, out_v, nrecv = phase2_shard_body(
-                nprocs, transport, mesh, B, nrounds, cap_out, sk, sv, cl)
+                nprocs, mesh, B, nrounds, cap_out, sk, sv, cl)
         gouts = fused_group_body(out_k, out_v, nrecv, gcap, out_kind,
                                  reduce_op)
         return (*gouts, cl) if st is None else (*gouts, cl, st)
@@ -508,7 +504,6 @@ def _exchange_group_impl(mr, stages, reduce_op, compiled, gidx, sp,
 
     mesh = mr.backend.mesh
     nprocs = mesh_axis_size(mesh)
-    transport = mr.settings.all2all
     out_kind = "kv" if reduce_op is not None else "kmv"
     _ok, hash_fn = _agg_hash(stages[0])
     dest = ("hash", hash_fn)
@@ -565,8 +560,8 @@ def _exchange_group_impl(mr, stages, reduce_op, compiled, gidx, sp,
     argnums = _donate_argnums(
         donate, cap_out == skey.shape[0] // max(nprocs, 1), out_kind,
         reduce_op, svalue)
-    fused = _fused_exchange_jit(mesh, transport, plan, out_kind,
-                                reduce_op, donate_argnums=argnums)
+    fused = _fused_exchange_jit(mesh, plan, out_kind, reduce_op,
+                                donate_argnums=argnums)
     if plan[0] == "wire":
         out = fused(skey, svalue, counts_local, stats_local)
     else:
@@ -597,11 +592,9 @@ def _exec_mega_exchange(mr, stages, reduce_op, compiled, gidx, sp, skv,
 
     mesh = mr.backend.mesh
     nprocs = mesh_axis_size(mesh)
-    transport = mr.settings.all2all
     _tag, plan, gcap = entry
     bump_dispatch()   # THE one dispatch of the warm group
-    prog = _mega_jit(mesh, transport, dest, plan, gcap, out_kind,
-                     reduce_op, elig)
+    prog = _mega_jit(mesh, dest, plan, gcap, out_kind, reduce_op, elig)
     out = prog(skv.key, skv.value, counts_dev)
     SyncStats.bump()   # still ONE host round-trip — now after dispatch
     ngout = 5 if out_kind == "kmv" else 3
@@ -646,7 +639,7 @@ def _finish_exchange_group(mr, stages, sp, skv, out_kind, reduce_op,
     ONE copy so the two tiers' telemetry can never diverge."""
     from ..parallel import wire as _wire
     from ..parallel.sharded import ShardedKMV, ShardedKV
-    from ..parallel.shuffle import ExchangeCallStats, ExchangeStats
+    from ..parallel.shuffle import ExchangeCallStats
 
     mr.counters.add(commtime=t.elapsed())
     nrows = int(counts_mat.sum())
@@ -657,7 +650,6 @@ def _finish_exchange_group(mr, stages, sp, skv, out_kind, reduce_op,
                               cap_out=cap_out, rows=nrows,
                               speculative=mega)
     _account_exchange(mr, skv, counts_mat, plan, nprocs, stats)
-    ExchangeStats.last = (nrounds_eff, B_eff)   # deprecated shim
     mr.last_exchange = stats
     sp.set(bucket=B_eff, nrounds=nrounds_eff, cap_out=cap_out,
            rows=nrows, groups=ngroups, wire_bytes=stats.wire_bytes,
@@ -807,8 +799,8 @@ def execute_plan(mr, plan: Plan) -> None:
         # versa), so the two knob states never share an entry
         from ..parallel.wire import wire_enabled
         key = (plan.fingerprint(), frame_signature(frame),
-               _backend_signature(mr), mr.settings.all2all,
-               mr.settings.outofcore, wire_enabled())
+               _backend_signature(mr), mr.settings.outofcore,
+               wire_enabled())
         compiled = plan_cache().get(key)
     except TypeError:       # unhashable stage arg: run uncached
         key = None
@@ -943,7 +935,7 @@ def _backend_signature(mr):
 def _key_brief(key) -> Optional[str]:
     if key is None:
         return None
-    fp, frame_sig, backend, transport, ooc, wire = key
+    fp, frame_sig, backend, ooc, wire = key
     ops = "→".join(s[0] for s in fp)
     return (f"ops[{ops}] frame{frame_sig!r} backend={backend[0]} "
-            f"all2all={transport} outofcore={ooc} wire={int(wire)}")
+            f"outofcore={ooc} wire={int(wire)}")

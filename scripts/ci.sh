@@ -62,8 +62,7 @@ run_context_subset() {
 }
 
 # mrlint (doc/lint.md): trace purity, lock discipline, cache-key
-# completeness, knob registry + the metric catalog (the former
-# check_metrics_doc call is folded in — metric-catalog is rule 5).
+# completeness, knob registry + the metric catalog (rule 5).
 # quick: report only files changed vs HEAD/HEAD~1 (analysis still sees
 # the whole package, so cross-module rules stay sound); full: whole
 # package, findings and counts as JSON in mrlint.json.

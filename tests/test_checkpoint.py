@@ -2,6 +2,8 @@
 improvement over the reference, which has no restartable persistence:
 SURVEY.md §5, page files deleted on destruction)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -144,7 +146,8 @@ def test_example_in_checkpoint(tmp_path, monkeypatch):
     from gpu_mapreduce_tpu.oink.script import OinkScript
 
     s = OinkScript(screen=False, logfile=None)
-    s.run_file("/root/repo/examples/in.checkpoint")
+    s.run_file(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "examples", "in.checkpoint"))
     a = sorted((tmp_path / "deg.original").read_text().split())
     b = sorted((tmp_path / "deg.restored").read_text().split())
     assert a == b and len(a) > 0

@@ -31,11 +31,16 @@ _PATH = re.compile(r"^[\w./-]+\.(?:py|sh|md)$")
 _TAIL = re.compile(r"(?::[\d,-]+|#[\w-]*)$")      # :line, :a-b, #anchor
 
 
+# a record, not a guide: what accepted PRs wrote into PERF.md, kept word
+# for word, so it names files that later PRs deleted
+RECORDS = {"doc/perf-history.md"}
+
+
 def _documents():
     docs = ["README.md", ".claude/skills/verify/SKILL.md"]
     docs += sorted("doc/" + f for f in os.listdir(os.path.join(REPO, "doc"))
                    if f.endswith(".md"))
-    return docs
+    return [d for d in docs if d not in RECORDS]
 
 
 def _tree():

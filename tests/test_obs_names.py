@@ -64,9 +64,9 @@ def _programs(mesh):
          shuffle._phase1_jit(mesh, ("hash", None), False).lower(
              key, val, cnt)),
         (names.SHUFFLE_PHASE2,
-         shuffle._phase2_jit(mesh, 1, 8, 1, 8).lower(key, val, cnt2)),
+         shuffle._phase2_jit(mesh, 8, 1, 8).lower(key, val, cnt2)),
         (names.SHUFFLE_PHASE2_WIRE,
-         shuffle._phase2_wire_jit(mesh, 1, (8,), 8, None, None).lower(
+         shuffle._phase2_wire_jit(mesh, (8,), 8, None, None).lower(
              key, val, cnt2, SDS((64, 4), u64))),
         (names.STAGE_RANK_GRAPH,
          staging._rank_fn(mesh, 64, False).lower(key, cnt)),

@@ -469,8 +469,7 @@ def test_shuffle_jit_caches_bounded():
 # ---------------------------------------------------------------------------
 
 def test_exchange_call_stats_per_object():
-    """Two MapReduce objects keep their OWN exchange telemetry — the
-    deprecated class attrs record only the last one process-wide."""
+    """Two MapReduce objects keep their OWN exchange telemetry."""
     k1, v1 = intcount_keys(512, card=7)
     k2, v2 = intcount_keys(2048, card=300)
     mr1 = MapReduce(make_mesh(4))
@@ -485,9 +484,9 @@ def test_exchange_call_stats_per_object():
     # the stats object also rides the sharded frame itself
     fr = mr2.kv.one_frame()
     assert getattr(fr, "exchange_stats", None) is s2
-    # deprecated shim still readable (last exchange process-wide)
-    from gpu_mapreduce_tpu.parallel.shuffle import ExchangeStats
-    assert ExchangeStats.last == (s2.nrounds, s2.bucket)
+    # and mr1's still reads its own exchange after mr2's ran
+    assert mr1.last_exchange is s1
+    assert mr1.kv.one_frame().exchange_stats is s1
 
 
 def test_fused_chain_sets_last_exchange():

@@ -1,6 +1,5 @@
 """Pod-scale compile sanity: the exchange must trace/compile fast at
-P=32 for both transports (VERDICT r1 #8 — the unrolled ppermute ring grew
-an O(P²) trace that would not compile at pod scale).
+P=32 (VERDICT r1 #8: its trace grows with P — ``shuffle._send_windows``).
 
 Runs in a subprocess because the virtual device count is fixed at jax
 init (conftest pins 8 for everything else).
@@ -34,14 +33,13 @@ keys = rng.integers(0, 997, size=4096).astype(np.uint64)
 vals = np.arange(len(keys), dtype=np.uint64)
 import collections
 oracle = collections.Counter(zip(keys.tolist(), vals.tolist()))
-for transport in (1, 0):
-    t0 = time.time()
-    skv = shard_frame(KVFrame(DenseColumn(keys), DenseColumn(vals)), mesh)
-    out = shuffle.exchange(skv, ("hash", None), transport=transport)
-    got = collections.Counter((int(k), int(v))
-                              for k, v in out.to_host().pairs())
-    assert got == oracle, f"transport {transport}: pair multiset mismatch"
-    print(f"transport {transport}: {time.time()-t0:.1f}s", flush=True)
+t0 = time.time()
+skv = shard_frame(KVFrame(DenseColumn(keys), DenseColumn(vals)), mesh)
+out = shuffle.exchange(skv, ("hash", None))
+got = collections.Counter((int(k), int(v))
+                          for k, v in out.to_host().pairs())
+assert got == oracle, "pair multiset mismatch"
+print(f"P=32 exchange: {time.time()-t0:.1f}s", flush=True)
 print("OK")
 """
 
@@ -205,7 +203,7 @@ from gpu_mapreduce_tpu.parallel.mesh import make_mesh, make_mesh2
 from gpu_mapreduce_tpu.parallel.sharded import shard_frame
 from gpu_mapreduce_tpu.parallel import shuffle
 
-# (a) both transports at P=64 — beyond the r1 P=32 compile-sanity bar
+# (a) the flat exchange at P=64 — beyond the r1 P=32 compile-sanity bar
 mesh = make_mesh()
 P = shuffle.mesh_axis_size(mesh)
 assert P == 64
@@ -213,14 +211,13 @@ rng = np.random.default_rng(11)
 keys = rng.integers(0, 1499, size=8192).astype(np.uint64)
 vals = np.arange(len(keys), dtype=np.uint64)
 oracle = collections.Counter(zip(keys.tolist(), vals.tolist()))
-for transport in (1, 0):
-    t0 = time.time()
-    skv = shard_frame(KVFrame(DenseColumn(keys), DenseColumn(vals)), mesh)
-    out = shuffle.exchange(skv, ("hash", None), transport=transport)
-    got = collections.Counter((int(k), int(v))
-                              for k, v in out.to_host().pairs())
-    assert got == oracle, f"transport {transport}: mismatch"
-    print(f"P=64 transport {transport}: {time.time()-t0:.1f}s", flush=True)
+t0 = time.time()
+skv = shard_frame(KVFrame(DenseColumn(keys), DenseColumn(vals)), mesh)
+out = shuffle.exchange(skv, ("hash", None))
+got = collections.Counter((int(k), int(v))
+                          for k, v in out.to_host().pairs())
+assert got == oracle, "P=64 flat: mismatch"
+print(f"P=64 flat exchange: {time.time()-t0:.1f}s", flush=True)
 
 # (b) 8x8 hierarchical DCN route at P=64
 mrh = MapReduce(make_mesh2(8, 8))
@@ -250,7 +247,7 @@ print("OK")
 
 
 def test_round5_paths_compile_at_p64():
-    """r5 paths beyond P=32 (VERDICT r4 #9): both exchange transports,
+    """r5 paths beyond P=32 (VERDICT r4 #9): the flat exchange,
     the 8×8 hierarchical route, and the generic per-shard file ingest
     trace/compile and run at P=64."""
     env = dict(os.environ)

@@ -4,12 +4,17 @@ the examples/in.* integration scripts with golden invariants."""
 
 import io
 import math
+import os
 
 import numpy as np
 import pytest
 
 from gpu_mapreduce_tpu.core.runtime import MRError
 from gpu_mapreduce_tpu.oink import OinkScript, Variables
+
+# this checkout's scripts, wherever it is
+EXAMPLES = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "examples")
 
 
 def run(text, **kw):
@@ -240,7 +245,7 @@ def test_example_in_cc_golden(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     out = io.StringIO()
     s = OinkScript(screen=out)
-    s.run_file("/root/repo/examples/in.cc")
+    s.run_file(os.path.join(EXAMPLES, "in.cc"))
     text = out.getvalue()
     assert "RMAT: 65536 rows, 131072 non-zeroes" in text
     # fused engine: 9 pointer-jumping rounds (the composed MR engine's
@@ -254,7 +259,7 @@ def test_example_in_luby_golden(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     out = io.StringIO()
     s = OinkScript(screen=out)
-    s.run_file("/root/repo/examples/in.luby")
+    s.run_file(os.path.join(EXAMPLES, "in.luby"))
     text = out.getvalue()
     assert "RMAT: 4096 rows, 16384 non-zeroes" in text
     # fused engine: 5 rounds (composed counted 4 edge-winner rounds)
@@ -265,7 +270,7 @@ def test_example_in_tri_golden(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     out = io.StringIO()
     s = OinkScript(screen=out)
-    s.run_file("/root/repo/examples/in.tri")
+    s.run_file(os.path.join(EXAMPLES, "in.tri"))
     text = out.getvalue()
     assert "RMAT: 65536 rows, 524288 non-zeroes" in text
     assert "Tri_find: 670 triangles" in text
@@ -277,7 +282,7 @@ def test_example_in_pagerank_golden(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     out = io.StringIO()
     s = OinkScript(screen=out)
-    s.run_file("/root/repo/examples/in.pagerank")
+    s.run_file(os.path.join(EXAMPLES, "in.pagerank"))
     text = out.getvalue()
     assert "RMAT: 16384 rows, 131072 non-zeroes" in text
     assert "PageRank: 11227 vertices, 131072 edges, 7 iterations" in text
@@ -291,7 +296,7 @@ def test_example_in_rmat_golden(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     out = io.StringIO()
     s = OinkScript(screen=out)
-    s.run_file("/root/repo/examples/in.rmat")
+    s.run_file(os.path.join(EXAMPLES, "in.rmat"))
     text = out.getvalue()
     assert "RMAT: 65536 rows, 524288 non-zeroes" in text
     assert "DegreeStats: 65536 vertices, 524288 edges" in text
@@ -305,7 +310,7 @@ def test_example_in_wordfreq_via_var(tmp_path, monkeypatch):
     out = io.StringIO()
     s = OinkScript(screen=out)
     s.variables.set(["files", "index", str(corpus)])
-    s.run_file("/root/repo/examples/in.wordfreq")
+    s.run_file(os.path.join(EXAMPLES, "in.wordfreq"))
     text = out.getvalue()
     assert "1 files, 15 words, 9 unique" in text
     assert "4 to" in text and "3 be" in text
@@ -316,7 +321,7 @@ def test_example_in_sssp_named_mr_weighting(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     out = io.StringIO()
     s = OinkScript(screen=out)
-    s.run_file("/root/repo/examples/in.sssp")
+    s.run_file(os.path.join(EXAMPLES, "in.sssp"))
     text = out.getvalue()
     assert text.count("SSSP: source") == 10
     assert (tmp_path / "tmp.sssp.0").exists()

@@ -19,8 +19,8 @@ import numpy as np
 import pytest
 
 from gpu_mapreduce_tpu.parallel import shuffle, wire
-from gpu_mapreduce_tpu.parallel.mesh import (make_mesh, mesh_axis_size,
-                                             row_spec)
+from gpu_mapreduce_tpu.parallel.mesh import (make_mesh, make_mesh2,
+                                             mesh_axis_size, row_spec)
 
 
 # ---------------------------------------------------------------------------
@@ -58,24 +58,23 @@ def _oracle_base(counts_from):
                                  cum[:-1].astype(jnp.int32)])
 
 
-def oracle_phase2_body(nprocs, transport, mesh, B, nrounds, cap_out,
-                       k, v, cl):
-    counts_from = shuffle._exchange_counts(cl, transport, mesh)
+def oracle_phase2_body(nprocs, mesh, B, nrounds, cap_out, k, v, cl):
+    counts_from = shuffle._exchange_counts(cl, mesh)
     _, base = _oracle_base(counts_from)
     out_k = jnp.zeros((cap_out,) + k.shape[1:], k.dtype)
     out_v = jnp.zeros((cap_out,) + v.shape[1:], v.dtype)
     for r in range(nrounds):
         recv_k = shuffle._exchange_blocks(
-            oracle_send_window(nprocs, B, r * B, k, cl), transport, mesh)
+            oracle_send_window(nprocs, B, r * B, k, cl), mesh)
         recv_v = shuffle._exchange_blocks(
-            oracle_send_window(nprocs, B, r * B, v, cl), transport, mesh)
+            oracle_send_window(nprocs, B, r * B, v, cl), mesh)
         out_k = oracle_place(out_k, recv_k, base, counts_from, r * B)
         out_v = oracle_place(out_v, recv_v, base, counts_from, r * B)
     return out_k, out_v, jnp.sum(counts_from)
 
 
-def oracle_phase2_wire_body(nprocs, transport, mesh, tiers, cap_out,
-                            kpack, vpack, k, v, cl, stats):
+def oracle_phase2_wire_body(nprocs, mesh, tiers, cap_out, kpack, vpack,
+                            k, v, cl, stats):
     def encode(col, base_bits, dest, pack):
         base = wire._base_in(base_bits, col.dtype)
         return (col - jnp.take(base, dest)).astype(jnp.dtype(pack))
@@ -88,7 +87,7 @@ def oracle_phase2_wire_body(nprocs, transport, mesh, tiers, cap_out,
     meta_local = jnp.stack([cl.astype(jnp.uint64), stats[:, 0],
                             stats[:, 2]], axis=1)
     meta_from = shuffle._exchange_blocks(meta_local[:, None, :],
-                                         transport, mesh)[:, 0, :]
+                                         mesh)[:, 0, :]
     counts_from = meta_from[:, 0].astype(jnp.int32)
     cap = k.shape[0]
     denc = jnp.minimum(
@@ -102,9 +101,9 @@ def oracle_phase2_wire_body(nprocs, transport, mesh, tiers, cap_out,
     start = 0
     for B in tiers:
         recv_k = shuffle._exchange_blocks(
-            oracle_send_window(nprocs, B, start, ke, cl), transport, mesh)
+            oracle_send_window(nprocs, B, start, ke, cl), mesh)
         recv_v = shuffle._exchange_blocks(
-            oracle_send_window(nprocs, B, start, ve, cl), transport, mesh)
+            oracle_send_window(nprocs, B, start, ve, cl), mesh)
         out_k = oracle_place(out_k, recv_k, base, counts_from, start)
         out_v = oracle_place(out_v, recv_v, base, counts_from, start)
         start += B
@@ -128,6 +127,11 @@ def oracle_phase2_wire_body(nprocs, transport, mesh, tiers, cap_out,
 @pytest.fixture(scope="module")
 def mesh():
     return make_mesh(4)
+
+
+# the one choice the exchange makes, from the mesh: one all_to_all on a
+# one-axis mesh, one an axis (shuffle._a2a_hier) on a (slice, chip) mesh
+MESH_SHAPES = {"flat": lambda: make_mesh(4), "2x2": lambda: make_mesh2(2, 2)}
 
 
 def _column(rng, cap, dtype, width, lo=1, hi=200):
@@ -222,15 +226,16 @@ WIRE_CASES = [
 ]
 
 
-@pytest.mark.parametrize("transport", [1, 0])
+@pytest.mark.parametrize("mesh_shape", list(MESH_SHAPES))
 @pytest.mark.parametrize("case", RAW_CASES, ids=[c[0] for c in RAW_CASES])
-def test_phase2_equals_the_scatter_form(mesh, case, transport):
+def test_phase2_equals_the_scatter_form(case, mesh_shape):
+    mesh = MESH_SHAPES[mesh_shape]()
     _, counts, cap, B, nrounds, cap_out, (kdt, kw), (vdt, vw) = case
     assert B * nrounds >= counts.max() and cap_out >= counts.sum(0).max()
     P = mesh_axis_size(mesh)
     k, v, cl = _shards(np.random.default_rng(31), counts, cap, kdt, kw,
                        vdt, vw)
-    args = (P, transport, mesh, B, nrounds, cap_out)
+    args = (P, mesh, B, nrounds, cap_out)
     new = _on_mesh(mesh, lambda *a: shuffle.phase2_shard_body(*args, *a), 3)
     old = _on_mesh(mesh, lambda *a: oracle_phase2_body(*args, *a), 3)
     got, want = new(k, v, cl), old(k, v, cl)
@@ -258,10 +263,11 @@ def _bucket_bases(col, counts_mat, cap, signed):
     return mins.view(np.uint64)
 
 
-@pytest.mark.parametrize("transport", [1, 0])
+@pytest.mark.parametrize("mesh_shape", list(MESH_SHAPES))
 @pytest.mark.parametrize("case", WIRE_CASES,
                          ids=[c[0] for c in WIRE_CASES])
-def test_phase2_wire_equals_the_scatter_form(mesh, case, transport):
+def test_phase2_wire_equals_the_scatter_form(case, mesh_shape):
+    mesh = MESH_SHAPES[mesh_shape]()
     (_, counts, cap, tiers, cap_out, (kdt, kw), (vdt, vw), kpack,
      vpack) = case
     assert sum(tiers) >= counts.max() and cap_out >= counts.sum(0).max()
@@ -279,7 +285,7 @@ def test_phase2_wire_equals_the_scatter_form(mesh, case, transport):
     if vw is None:
         stats[:, 2] = _bucket_bases(v, counts, cap,
                                     np.dtype(vdt).kind == "i").reshape(-1)
-    args = (P, transport, mesh, tiers, cap_out, kpack, vpack)
+    args = (P, mesh, tiers, cap_out, kpack, vpack)
     new = _on_mesh(mesh,
                    lambda *a: wire.phase2_wire_shard_body(*args, *a), 4)
     old = _on_mesh(mesh, lambda *a: oracle_phase2_wire_body(*args, *a), 4)
@@ -350,17 +356,17 @@ def test_phase2_lowers_to_windows(mesh, form, cap, B, tiers, cap_out):
     wire_plan = ("wire", tiers, cap_out, "uint16", None)
     want_name = None
     if form == "raw":
-        text = shuffle._phase2_build(mesh, 1, B, len(tiers), cap_out
+        text = shuffle._phase2_build(mesh, B, len(tiers), cap_out
                                      ).lower(key2, val, cl).as_text()
         want_name = names.SHUFFLE_PHASE2
     elif form == "wire":
         text = shuffle._phase2_wire_build(
-            mesh, 1, tiers, cap_out, None, None
+            mesh, tiers, cap_out, None, None
         ).lower(key2, val, cl, st).as_text()
         want_name = names.SHUFFLE_PHASE2_WIRE
     elif form == "wire_packs":
         text = shuffle._phase2_wire_build(
-            mesh, 1, tiers, cap_out, "uint16", "uint8"
+            mesh, tiers, cap_out, "uint16", "uint8"
         ).lower(key1, _sds(P, cap, jnp.int64), cl, st).as_text()
         want_name = names.SHUFFLE_PHASE2_WIRE
     else:
@@ -369,15 +375,15 @@ def test_phase2_lowers_to_windows(mesh, form, cap, B, tiers, cap_out):
         # text is held to the rule: everything up to the first sort
         if form == "fused_raw":
             text = fuser._fused_exchange_build(
-                mesh, 1, raw_plan, "kmv", None).lower(
+                mesh, raw_plan, "kmv", None).lower(
                 key1, val, cl).as_text()
         elif form == "fused_wire":
             text = fuser._fused_exchange_build(
-                mesh, 1, wire_plan, "kmv", None).lower(
+                mesh, wire_plan, "kmv", None).lower(
                 key1, val, cl, st).as_text()
         else:
             text = fuser._mega_build(
-                mesh, 1, ("fixed_mod", P), wire_plan, cap_out, "kmv",
+                mesh, ("fixed_mod", P), wire_plan, cap_out, "kmv",
                 None, (True, False)).lower(
                 key1, val, jax.ShapeDtypeStruct((P,), i32)).as_text()
             # phase 1 (its argsort, its takes, the stats' scatter-min)
@@ -387,7 +393,7 @@ def test_phase2_lowers_to_windows(mesh, form, cap, B, tiers, cap_out):
     if want_name:
         assert re.search(r"module @(\w+)", text).group(1) == want_name
     assert "dynamic_slice" in text and "dynamic_update_slice" in text
-    assert "all_to_all" in text or "collective_permute" in text
+    assert "all_to_all" in text
     assert _bad_ops(text, P) == []
 
 
@@ -397,7 +403,7 @@ def test_the_scatter_form_would_be_caught(mesh):
     spec = row_spec(mesh)
     cap, B, cap_out = 64, 16, 128
     old = jax.jit(jax.shard_map(
-        lambda k, v, cl: oracle_phase2_body(P, 1, mesh, B, 2, cap_out,
+        lambda k, v, cl: oracle_phase2_body(P, mesh, B, 2, cap_out,
                                             k, v, cl)[:2],
         mesh=mesh, in_specs=(spec,) * 3, out_specs=(spec,) * 2))
     text = old.lower(_sds(P, cap, jnp.uint64, 2), _sds(P, cap, jnp.uint8),

@@ -3,8 +3,9 @@
 payload-carrying sort, over mesh sizes, ``drop_self`` and the inputs
 that have bitten before (padding rows in every shard, ids around 2^32
 and above 2^63, a single edge); the sentinel refusal and the n == 0
-result; and the staging programs hold no loop (the binary search of
-``searchsorted``, a ``while`` of gathers, is gone and stays gone)."""
+result; the staging programs hold no loop (the binary search of
+``searchsorted``, a ``while`` of gathers, is gone and stays gone); and
+the host's ranking (``stage_graph_host``) against ``rank_graph``."""
 
 import jax
 import jax.numpy as jnp
@@ -126,6 +127,95 @@ def test_no_vertices_left(nprocs):
     mr.map(1, lambda i, kv, p: kv.add_batch(e, np.zeros(len(e), np.uint8)))
     sg = staging.stage_graph(mr, mesh, drop_self=True)
     assert sg.n == 0 and sg.verts.shape == (0,) and sg.src is None
+
+
+def _unranked(verts, src, dst, valid, weights=None):
+    """The edge list a staging result stands for, sorted: its valid rows
+    with the ranks turned back into ids (and each row's weight)."""
+    src, dst, valid = (np.asarray(a) for a in (src, dst, valid))
+    cols = [np.asarray(verts)[src[valid]], np.asarray(verts)[dst[valid]]]
+    if weights is not None:
+        cols.append(np.asarray(weights)[valid].astype(np.float64)
+                    .view(U64))
+    rows = np.stack(cols, 1) if valid.any() else np.zeros((0, len(cols)),
+                                                          U64)
+    return rows[np.lexsort(rows.T[::-1])]
+
+
+def _identity(e):
+    """An edge list as a staging result of its own: np.unique's."""
+    verts, inv = np.unique(e.reshape(-1), return_inverse=True)
+    inv = inv.reshape(-1, 2)
+    return verts, inv[:, 0], inv[:, 1], np.ones(len(inv), bool)
+
+
+_LOOPS = np.asarray([[4, 4], [9, 9], [4, 4]], U64)
+HOST_CASES = {
+    # id: (edges, drop_self, weighted)
+    "plain": (lambda: _random(np.random.default_rng(3), 4)[0], False, False),
+    "drop_self": (lambda: _random(np.random.default_rng(4), 4)[0], True,
+                  False),
+    "weighted": (lambda: _wide(np.random.default_rng(5), 4)[0], False, True),
+    "weighted_drop_self": (lambda: _random(np.random.default_rng(6), 4)[0],
+                           True, True),
+    "self_loops_only": (lambda: _LOOPS, True, False),
+    "self_loops_kept": (lambda: _LOOPS, False, False),
+}
+
+
+@pytest.mark.parametrize("case", list(HOST_CASES))
+def test_host_ranking_matches_rank_graph(case):
+    """The two ways a command's edges are ranked give one graph: the same
+    vertex table, the same ``n``, the same edges once the ranks are turned
+    back into ids (the device keeps invalid rows behind a mask, the host
+    drops them) — and the same weight on each."""
+    make, drop_self, weighted = HOST_CASES[case]
+    e = make()
+    w = (np.arange(len(e), dtype=np.float64) * 0.5 + 1.0 if weighted
+         else np.zeros(len(e), np.uint8))
+    mesh = make_mesh(4)
+    mrs = []
+    for comm in (None, mesh):
+        mr = MapReduce(comm)
+        mr.map(1, lambda i, kv, p: kv.add_batch(e, w))
+        mrs.append(mr)
+    host = staging.stage_graph_host(mrs[0], drop_self=drop_self,
+                                    need_weights=weighted)
+    dev = staging.stage_graph(mrs[1], mesh, drop_self=drop_self,
+                              need_weights=weighted)
+    assert host.n == dev.n == len(host.verts)
+    assert host.verts.dtype == U64
+    np.testing.assert_array_equal(host.verts, dev.verts)
+    for a in (host.src, host.dst, host.valid):
+        assert isinstance(a, np.ndarray) and a.shape == host.src.shape
+    assert host.valid.all()
+    assert (host.weights is None) == (not weighted)
+    if host.n == 0:
+        assert len(host.src) == 0 and dev.src is None
+        return
+    np.testing.assert_array_equal(
+        _unranked(host.verts, host.src, host.dst, host.valid, host.weights),
+        _unranked(dev.verts, dev.src, dev.dst, dev.valid, dev.weights))
+    # and both are the input's own rows
+    keep = e[:, 0] != e[:, 1] if drop_self else np.ones(len(e), bool)
+    np.testing.assert_array_equal(
+        _unranked(host.verts, host.src, host.dst, host.valid, host.weights),
+        _unranked(*_identity(e[keep]), w[keep] if weighted else None))
+
+
+@pytest.mark.parametrize("comm", [None, 4], ids=["serial", "mesh"])
+def test_host_ranking_of_an_empty_edge_list(comm):
+    """No edges at all: ``stage_graph`` does not apply (None) and the
+    host's ranking is the empty graph, whatever the backend."""
+    mesh = make_mesh(comm) if comm else None
+    mr = MapReduce(mesh)
+    mr.map(1, lambda i, kv, p: kv.add_batch(
+        np.zeros((0, 2), U64), np.zeros(0, np.float64)))
+    assert staging.stage_graph(mr, mesh, need_weights=True) is None
+    sg = staging.stage_graph_host(mr, need_weights=True)
+    assert sg.n == 0 and sg.verts.shape == (0,) and sg.verts.dtype == U64
+    assert sg.src.shape == sg.dst.shape == sg.valid.shape == (0,)
+    assert sg.weights.shape == (0,)
 
 
 @pytest.mark.parametrize("nprocs", [1, 8])

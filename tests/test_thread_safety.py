@@ -2,11 +2,11 @@
 
 ``-partition`` runs each world's interpreter in a thread
 (oink/universe.py), so the parallel tier's shared state — the
-speculative-cap cache, SyncStats/ToHostStats counters, ExchangeStats —
-sees concurrent exchanges.  Two worlds hammer disjoint sub-meshes (the
-MPI_Comm_split layout the universe actually builds) and the shared
-telemetry must stay consistent: no lost counter bumps, no torn
-ExchangeStats pair, correct per-world results."""
+speculative-cap cache, SyncStats/ToHostStats counters — sees concurrent
+exchanges.  Two worlds hammer disjoint sub-meshes (the MPI_Comm_split
+layout the universe actually builds) and the telemetry must stay
+consistent: no lost counter bumps, each world's ``last_exchange`` its
+own exchange's and never the other world's, correct per-world results."""
 
 import threading
 
@@ -36,8 +36,9 @@ def _world(mesh, seed, iters, results, idx, barrier):
             for k in keys.tolist():
                 expect[k] = expect.get(k, 0) + 1
             assert got == expect, "world result corrupted"
-            r = shuffle.ExchangeStats.last
-            assert isinstance(r, tuple) and len(r) == 2
+            r = mr.last_exchange       # this world's own, never torn
+            assert r.rows == len(keys)
+            assert r.nrounds >= 1 and r.bucket >= 1
         results[idx] = "ok"
     except Exception as e:  # noqa: BLE001 - surface in the main thread
         results[idx] = repr(e)

@@ -16,7 +16,7 @@ from gpu_mapreduce_tpu import MapReduce
 from gpu_mapreduce_tpu.core.column import DenseColumn
 from gpu_mapreduce_tpu.core.frame import KVFrame
 from gpu_mapreduce_tpu.parallel import shuffle, wire
-from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+from gpu_mapreduce_tpu.parallel.mesh import make_mesh, make_mesh2
 from gpu_mapreduce_tpu.parallel.sharded import shard_frame
 
 
@@ -33,13 +33,12 @@ def zipf_keys(n=20000, seed=7, lim=1 << 22):
     return np.minimum(rng.zipf(1.3, n), lim).astype(np.uint64)
 
 
-def run_exchange(mesh, keys, vals, wire_flag, dest=("hash", None),
-                 transport=1):
+def run_exchange(mesh, keys, vals, wire_flag, dest=("hash", None)):
     os.environ["MRTPU_WIRE"] = wire_flag
     shuffle._SPEC_CACHE.clear()
     skv = shard_frame(KVFrame(DenseColumn(keys.copy()),
                               DenseColumn(vals.copy())), mesh)
-    out = shuffle.exchange(skv, dest, transport=transport)
+    out = shuffle.exchange(skv, dest)
     return (np.asarray(out.key), np.asarray(out.value),
             out.counts.copy(), out.exchange_stats)
 
@@ -117,14 +116,16 @@ def test_codec_signed_value_roundtrip(mesh, monkeypatch):
 # goldens: compressed == raw, byte for byte
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("transport", [1, 0])
-def test_golden_zipf_exchange_byte_identical(mesh, transport):
+@pytest.mark.parametrize("mesh_shape", ["flat", "2x4"])
+def test_golden_zipf_exchange_byte_identical(mesh, mesh_shape):
+    """On both collectives the exchange chooses between (from the mesh:
+    ``shuffle._exchange_blocks``)."""
+    if mesh_shape == "2x4":
+        mesh = make_mesh2(2, 4)
     keys = zipf_keys()
     vals = np.arange(len(keys), dtype=np.uint64)
-    k0, v0, c0, s0 = run_exchange(mesh, keys, vals, "0",
-                                  transport=transport)
-    k1, v1, c1, s1 = run_exchange(mesh, keys, vals, "1",
-                                  transport=transport)
+    k0, v0, c0, s0 = run_exchange(mesh, keys, vals, "0")
+    k1, v1, c1, s1 = run_exchange(mesh, keys, vals, "1")
     assert np.array_equal(k0, k1), "compressed keys differ from raw"
     assert np.array_equal(v0, v1), "compressed values differ from raw"
     assert (c0 == c1).all()
@@ -357,10 +358,9 @@ def test_wire_speculative_plan_reuse_and_overflow(mesh, monkeypatch):
     calls = []
     orig = shuffle._phase2_wire_jit
 
-    def spy(mesh_, transport, tiers, cap_out, kpack, vpack, **kw):
+    def spy(mesh_, tiers, cap_out, kpack, vpack, **kw):
         calls.append((tiers, cap_out, kpack, vpack))
-        return orig(mesh_, transport, tiers, cap_out, kpack, vpack,
-                    **kw)
+        return orig(mesh_, tiers, cap_out, kpack, vpack, **kw)
 
     monkeypatch.setattr(shuffle, "_phase2_wire_jit", spy)
     shuffle._SPEC_CACHE.clear()
