@@ -1202,6 +1202,94 @@ class MapReduce:
         self.kv.add_kv(src)
         return self._finish_kv("add")
 
+    @_traced
+    def join(self, build: "MapReduce") -> int:
+        """Inner join of my KV (the probe side) with ``build``'s KV on
+        equal keys: afterwards my KV holds, for every pair of mine whose
+        key occurs in ``build``, ``key -> (my value's words ++ build's
+        value's words)``; pairs without a partner are dropped, pairs of
+        one key keep their order, ``build`` is left as it was.  ``build``'s
+        keys must be unique (a primary key): one that occurs twice is an
+        error raised at the op's one sync, never a silently chosen row.
+        Keys and values are fixed-width numbers, the keys of one shape and
+        dtype, the values of one dtype.
+
+        MR-MPI's idiom for this is tag / ``add`` / ``collate`` / a reduce
+        that reads the tags; here it is an op.  On a mesh both sides are
+        first placed by the default hash of the key through the exchange
+        (on one shard nothing moves), then joined shard by shard
+        (``parallel/group.join_sharded``: the keys' sort, the op's one
+        sync, the joined rows' values taken); host frames join in numpy
+        (``ops/join.py``).  Never deferred into a plan."""
+        t = self._begin_op()
+        mine = self._require_kv("join")
+        other = build._require_kv("join with")
+        pf, bf = mine.one_frame(), other.one_frame()
+        if not len(pf) or not len(bf):      # nothing can have a partner
+            mine.free()
+            self._join_note(len(pf), len(bf), 0)
+            return self._finish_kv("join")
+        for side, fr in (("probe", pf), ("build", bf)):
+            dense = (fr.is_dense() if isinstance(fr, KVFrame) else
+                     fr.key_decode is None and fr.value_decode is None)
+            if not dense:
+                self.error.all(f"join: the {side} side's keys and values "
+                               f"must be fixed-width numbers")
+        kshape = lambda fr: (_data(fr.key).shape[1:], _data(fr.key).dtype)
+        if kshape(pf) != kshape(bf):
+            self.error.all(f"join: the two sides' keys differ in shape or "
+                           f"type ({kshape(pf)} and {kshape(bf)})")
+        if _data(pf.value).dtype != _data(bf.value).dtype:
+            self.error.all("join: the two sides' values differ in type")
+        nin = pf.nbytes() + bf.nbytes()
+        if isinstance(pf, KVFrame) and isinstance(bf, KVFrame) \
+                and not hasattr(self.backend, "mesh"):
+            from ..ops.join import join_frames
+            out, twice = join_frames(pf, bf)
+        else:
+            out, twice = self._join_mesh(pf, bf)
+        if twice:
+            self.error.all(f"join: {twice} key(s) of the build side occur "
+                           f"more than once; its keys must be unique")
+        mine.free()
+        mine.add_frame(out)
+        n = mine.complete()
+        self.counters.add(jisize=nin, josize=out.nbytes())
+        self._join_note(len(pf), len(bf), n)
+        self._op_stats("join", nkv=n)
+        self._time("join", t)
+        return int(self.backend.allreduce_sum(n))
+
+    def _join_note(self, probe: int, build: int, matched: int) -> None:
+        if self.tracer.enabled:     # on the ``join`` op span
+            from ..obs import names
+            self.tracer.annotate(**{names.ATTR_PROBE_ROWS: probe,
+                                    names.ATTR_BUILD_ROWS: build,
+                                    names.ATTR_MATCHED_ROWS: matched})
+
+    def _join_mesh(self, pf, bf):
+        """Both sides on the mesh with equal keys on one shard, joined
+        there.  The frames handed to the exchange are views marked shared:
+        it donates neither side's arrays (``build`` stays as it was, and
+        so do I until the join has succeeded)."""
+        import dataclasses
+        from ..parallel.group import join_sharded
+        from ..parallel.sharded import shard_frame
+        from ..parallel.shuffle import exchange
+        mesh = getattr(self.backend, "mesh", None)
+        if mesh is None:        # a serial object handed a mesh frame
+            mesh = (bf if isinstance(pf, KVFrame) else pf).mesh
+
+        def placed(fr):
+            if isinstance(fr, KVFrame):
+                fr = shard_frame(fr.to_host(), mesh)
+            if fr.nprocs == 1:
+                return fr
+            view = dataclasses.replace(fr)
+            view._shared = True
+            return exchange(view, ("hash", None), counters=self.counters)
+        return join_sharded(placed(pf), placed(bf))
+
     def copy(self) -> "MapReduce":
         """Deep copy: new MR with copied settings and data (reference
         src/mapreduce.cpp:269-342)."""
@@ -1456,6 +1544,12 @@ class MapReduce:
 
 
 # ---------------------------------------------------------------------------
+
+def _data(col):
+    """The array behind a frame's key or value: a host column's ``data``,
+    a mesh frame's device array itself."""
+    return getattr(col, "data", col)
+
 
 def _to_bytes(s) -> bytes:
     return s.encode() if isinstance(s, str) else bytes(s)

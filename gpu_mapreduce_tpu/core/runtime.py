@@ -132,6 +132,8 @@ class Counters:
     #                         slack: [P,B]-buckets minus real rows —
     #                         the weak-scaling "network volume" diagnosis)
     commtime: float = 0.0   # seconds in collectives
+    jisize: int = 0         # bytes of the two sides that went into joins
+    josize: int = 0         # bytes of the joined rows that came out
     ndispatch: int = 0      # compiled-program launches (jitted shuffle/
     #                         convert/reduce/sort programs, fused plans,
     #                         AND eager pallas_call kernel launches —

@@ -26,6 +26,8 @@ GROUP_FIRST = "jit_group_first"
 SORT_MULTIVALUES = "jit_sort_multivalues"
 SORT_ROWS = "jit_sort_rows"
 SORT_INTERNED = "jit_sort_interned"
+JOIN_ROWS = "jit_join_rows"                         # both sides' keys in one sort
+JOIN_TAKE = "jit_join_take"                         # the joined rows' values
 # parallel/shuffle.py
 SHUFFLE_PHASE1 = "jit_shuffle_phase1"
 SHUFFLE_PHASE2 = "jit_shuffle_phase2"
@@ -39,6 +41,7 @@ STAGE_TRIM_VERTS = "jit_stage_trim_verts"
 PLACE_ROWS = "jit_place_rows"                       # device rows → shard blocks
 # parallel/devkernels.py
 CONCAT_ROWS = "jit_concat_rows"                     # append, per shard: a copy
+TAKE_ROWS = "jit_take_rows"                         # a scan's kept rows, by index
 LEVEL_ROWS = "jit_level_rows"                       # excess rows → short shards
 REMAP_IDS = "jit_remap_ids"
 # models/
@@ -60,6 +63,7 @@ SSSP_WEIGHTS = "jit_sssp_weights"                   # int32 where exact
 PROGRAMS = (
     INVINDEX_EXTRACT, INVINDEX_COLLISIONS, CONVERT_SORT, CONVERT_LAYOUT,
     REDUCE_SEGMENTS, GROUP_FIRST, SORT_MULTIVALUES, SORT_ROWS, SORT_INTERNED,
+    JOIN_ROWS, JOIN_TAKE, TAKE_ROWS,
     SHUFFLE_PHASE1, SHUFFLE_PHASE2, SHUFFLE_PHASE2_WIRE, STAGE_RANK_GRAPH,
     STAGE_TRIM_VERTS, PLACE_ROWS, CONCAT_ROWS, LEVEL_ROWS, REMAP_IDS,
     CC_LOOP, PAGERANK_LOOP, RMAT_EDGES, RMAT_EDGE_ROWS,
@@ -68,10 +72,12 @@ PROGRAMS = (
 )
 
 # parallel/devkernels.py's two generic mappers run one program per kernel
-# body: ``jit_kv_map_<body>`` / ``jit_kmv_map_<body>``
+# body: ``jit_kv_map_<body>`` / ``jit_kmv_map_<body>``; the scan of a
+# resident table (``skv_scan``: packed by a sort) ``jit_kv_scan_<body>``
 KV_MAP_PREFIX = "jit_kv_map_"
 KMV_MAP_PREFIX = "jit_kmv_map_"
-PROGRAM_PREFIXES = (KV_MAP_PREFIX, KMV_MAP_PREFIX)
+KV_SCAN_PREFIX = "jit_kv_scan_"
+PROGRAM_PREFIXES = (KV_MAP_PREFIX, KMV_MAP_PREFIX, KV_SCAN_PREFIX)
 
 
 def declared_program(module: str) -> bool:
@@ -90,6 +96,7 @@ ENTRY = "entry"         # one root span a job: the call into an entry point
 INVINDEX_RUN = "invindex.run"       # apps/invertedindex.InvertedIndex.run
 OINK_SCRIPT = "oink.script"         # oink/script: the outermost script run
 TERASORT_RUN = "terasort.run"       # apps/terasort.TeraSort.run
+TPCH_Q3 = "tpch.q3"                 # apps/tpch.q3, inside the oink.script root
 
 # -- host-phase spans (cat HOST unless said) ----------------------------------
 # parallel/shuffle.aggregate_kv, before the exchange / the one-chip early-out
@@ -163,6 +170,13 @@ TERASORT_SAMPLE = "terasort.sample"             # sampled, splitters,
 #                                                 d2h_bytes
 TERASORT_PULL = "terasort.pull"                 # shard, records, d2h_bytes
 TERASORT_WRITE = "terasort.write"               # shard, records, bytes
+# apps/tpch.py
+TPCH_LOAD = "tpch.load"                         # table, files, rows, bytes
+TPCH_SCAN = "tpch.scan"                         # table, and ATTR_ROWS_IN,
+#                                                 ATTR_ROWS_OUT,
+#                                                 ATTR_ROW_WORDS_IN below
+TPCH_TOPN = "tpch.topn"                         # rows
+TPCH_EMIT = "tpch.emit"                         # rows, bytes
 
 # older spans that metrics quote by name
 SHUFFLE_EXCHANGE = "shuffle.exchange"           # ..., recv_rows_max, _mean,
@@ -183,6 +197,7 @@ SPANS = (
     INVINDEX_RUN, OINK_SCRIPT,
     INGEST_RECORDS_PLAN, INGEST_RECORDS_READ, INGEST_RECORDS_H2D,
     TERASORT_RUN, TERASORT_SAMPLE, TERASORT_PULL, TERASORT_WRITE,
+    TPCH_Q3, TPCH_LOAD, TPCH_SCAN, TPCH_TOPN, TPCH_EMIT,
 )
 
 # -- attrs that metrics quote by name -----------------------------------------
@@ -203,6 +218,20 @@ ATTR_KEY_WORDS = "key_words"
 ATTR_RODE_WORDS = "rode_words"
 ATTR_TAKEN_WORDS = "taken_words"
 ATTR_HBM_ROW_BYTES = "hbm_row_bytes"
+# on the ``join`` op span (core/mapreduce.join): the rows of the two sides,
+# the probe rows that found a partner, and the four attrs of the sorts above
+# (the key's operands, the payload words both sides' values share, the bytes
+# a probe row occupies in HBM); its Counters deltas are ``join_in_bytes`` and
+# ``join_out_bytes``
+JOIN_SPAN = "join"
+ATTR_PROBE_ROWS = "probe_rows"
+ATTR_BUILD_ROWS = "build_rows"
+ATTR_MATCHED_ROWS = "matched_rows"
+# on the ``tpch.scan`` spans (apps/tpch.py): the table's rows, the rows the
+# predicate kept, and the 32-bit words of a table row (key and value)
+ATTR_ROWS_IN = "rows_in"
+ATTR_ROWS_OUT = "rows_out"
+ATTR_ROW_WORDS_IN = "row_words_in"
 # on the ``tri.loop`` span (oink/commands/tri.py, from models/tri.Walk): the
 # tiles the long out-lists were cut into, the wedges of the short lists
 # (walked index by index), and the wedges that came from tiles over the
@@ -228,6 +257,8 @@ ATTR_JIT_CACHE_LOADS = "jit_cache_loads"
 SPAN_ATTRS = (
     ATTR_ROWS, ATTR_GROUPS, ATTR_GROUP_ROWS_MAX, ATTR_RECORDS, ATTR_KEY_WORDS,
     ATTR_RODE_WORDS, ATTR_TAKEN_WORDS, ATTR_HBM_ROW_BYTES,
+    ATTR_PROBE_ROWS, ATTR_BUILD_ROWS, ATTR_MATCHED_ROWS,
+    ATTR_ROWS_IN, ATTR_ROWS_OUT, ATTR_ROW_WORDS_IN,
     ATTR_TILES, ATTR_INDEX_WEDGES, ATTR_TILE_FILL,
     ATTR_CPU_S, ATTR_OFF_CPU_S,
     ATTR_PROC_CPU_S, ATTR_SYS_CPU_S, ATTR_VOL_SWITCHES, ATTR_INVOL_SWITCHES,

@@ -56,6 +56,8 @@ _DELTA_FIELDS = (
     ("wsize", "spill_write_bytes"),
     ("rsize", "spill_read_bytes"),
     ("ndispatch", "dispatches"),
+    ("jisize", "join_in_bytes"),
+    ("josize", "join_out_bytes"),
     ("jit_lowerings", names.ATTR_JIT_LOWERINGS),
     ("jit_lower_s", names.ATTR_JIT_LOWER_S),
     ("jit_backend_s", names.ATTR_JIT_BACKEND_S),

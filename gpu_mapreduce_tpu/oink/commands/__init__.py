@@ -3,4 +3,4 @@ COMMANDS registry (the generated style_command.h of the reference)."""
 
 from . import (cc, degree, dump_metrics, dump_plan, dump_trace,  # noqa: F401
                edges, histo, invertedindex, luby, pagerank, rmat, sssp,
-               stream, terasort, tri, wordfreq)
+               stream, terasort, tpch, tri, wordfreq)

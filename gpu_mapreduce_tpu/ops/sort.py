@@ -115,6 +115,39 @@ def sort_carrying(keys, carry=(), stable: bool = True):
     return tuple(out[:len(keys)]), scarry
 
 
+def front_order(keep):
+    """Inside a program: ``(order, count)`` of the rows flagged in
+    ``keep``: ``order[j]`` is the index of the j-th flagged row for j
+    below ``count``, and past the block's end after.  ONE sort of one
+    int32 operand, the row index or a fill: no payload rides it, so it
+    compiles in seconds where a sort that carries the rows takes minutes
+    (7 s against 176 s at 6 x 10^7 rows of five operands on the v5e's
+    compiler, PERF.md §6, PR 43), and no scatter (``devkernels._pack``'s costs 0.115 us
+    a row there).  The rows come by ``order`` afterwards, as many as are
+    wanted (``parallel/devkernels.skv_scan``, ``parallel/group.join_sharded``)."""
+    n = keep.shape[0]
+    row = jnp.arange(n, dtype=jnp.int32)
+    order, = lax.sort((jnp.where(keep, row, row + n),), num_keys=1,
+                      is_stable=False)
+    return order, jnp.sum(keep, dtype=jnp.int32)
+
+
+def take_together(at, *blocks):
+    """Inside a program: the rows ``at`` of each of ``blocks``.  Blocks
+    that are all ``[n, w]`` of one dtype are taken as ONE block of their
+    words side by side and cut apart again: on the v5e a gather costs about 20
+    ns a row whatever the row holds up to eight words (3.3 x 10^7 rows of
+    a 6 x 10^7-row block: 0.66 s for ``[n, 4]``, 0.69 s for ``[n, 8]``,
+    1.22 s for two ``[n, 2]``, 3.8 s for four columns apart: PERF.md §6,
+    PR 43), so the index is paid once."""
+    if (len(blocks) > 1 and all(b.ndim == 2 for b in blocks)
+            and len({b.dtype for b in blocks}) == 1):
+        rows = jnp.take(jnp.concatenate(blocks, axis=1), at, axis=0)
+        cuts = np.cumsum([b.shape[1] for b in blocks])[:-1].tolist()
+        return tuple(jnp.split(rows, cuts, axis=1))
+    return tuple(jnp.take(b, at, axis=0) for b in blocks)
+
+
 def argsort_column(col: Column, descending: bool = False,
                    cmp: Optional[Callable] = None) -> np.ndarray:
     """Stable argsort of a column; lexicographic over trailing width dim."""

@@ -36,6 +36,12 @@ def gather_kv(backend, mr, nprocs: int):
     skv = _ensure_sharded(backend, mr)
     if skv is None:
         return  # host-resident data is already "gathered"
+    if backend.nprocs == 1:
+        # one shard holds every row already: the reference returns at
+        # once (src/mapreduce.cpp:903); an exchange here would be a sort
+        # and a copy of every row to where it lies
+        _replace_kv_frames(mr.kv, skv)
+        return
     n = min(nprocs, backend.nprocs)
     # shard i → i % n: the reference's exact funnel layout ("lo procs
     # recv from hi procs with same ID % numprocs",

@@ -31,8 +31,9 @@ from jax.sharding import PartitionSpec as P
 
 from .group import _local_segment_ids
 from .mesh import mesh_axes, mesh_axis_size, row_sharding, row_spec
+from ..ops.sort import front_order, take_together
 from .sharded import (ShardedKMV, ShardedKV, SyncStats, fill_counts,
-                      round_cap, rows_below, window_rows)
+                      front_cap, round_cap, rows_below, window_rows)
 
 U64MAX = np.uint64(0xFFFFFFFFFFFFFFFF)
 
@@ -85,6 +86,58 @@ def _skv_map_jit(mesh, fn, static, nextra):
     return jax.jit(run)
 
 
+@functools.lru_cache(maxsize=None)
+def _skv_rows_jit(mesh, fn, static, nextra, scan: bool):
+    """The program of :func:`skv_each` (``scan`` False: the body's rows as
+    they lie) or :func:`skv_scan` (True: beside them the order of the rows
+    the body keeps, and their count)."""
+    spec = row_spec(mesh)
+
+    def run(key, value, count, *extra):
+        def body(k, v, c, *ex):
+            out = fn(k, v, c[0], *ex, *static)
+            if not scan:
+                return out
+            order, kept = front_order(out[2])
+            return out[0], out[1], order, kept[None]
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(spec, spec, spec) + (P(),) * nextra,
+            out_specs=(spec,) * (4 if scan else 2))(key, value, count, *extra)
+
+    # one program per kernel body (obs/names.KV_MAP_PREFIX, KV_SCAN_PREFIX)
+    run.__name__ = ("kv_scan_" if scan else "kv_map_") + _body_name(fn)
+    return jax.jit(run)
+
+
+def skv_each(skv: ShardedKV, fn, static=(), extra=()) -> ShardedKV:
+    """:func:`skv_map` for a body that keeps every row, ``fn(key, value,
+    count, *extra, *static) -> (okey, ovalue)``: a re-keying, a
+    projection.  The rows stay where they are and the counts are the
+    source's, so nothing is packed and nothing is pulled.  Plain numeric
+    frames only."""
+    _check_decodes(skv, False, "skv_each")
+    counts = jax.device_put(skv.counts.astype(np.int32),
+                            row_sharding(skv.mesh))
+    k, v = _skv_rows_jit(skv.mesh, fn, tuple(static), len(extra), False)(
+        skv.key, skv.value, counts, *extra)
+    return ShardedKV(skv.mesh, k, v, skv.counts.copy())
+
+
+@functools.lru_cache(maxsize=None)
+def _take_rows_jit(mesh, cap: int):
+    spec = row_spec(mesh)
+
+    def take_rows(key, value, order):
+        def body(k, v, o):
+            at = jnp.minimum(o[:cap], k.shape[0] - 1)
+            return take_together(at, k, v)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+                             out_specs=(spec, spec))(key, value, order)
+
+    rows = row_sharding(mesh)
+    return jax.jit(take_rows, out_shardings=(rows, rows))
+
+
 def _check_decodes(fr, preserve_decodes: bool, what: str):
     """Interned byte/object ids look like plain numbers inside a kernel
     body; silently doing arithmetic on them is the bug reduce_sharded
@@ -122,6 +175,29 @@ def skv_map(skv: ShardedKV, fn, static=(), extra=(),
     SyncStats.bump()
     return ShardedKV(skv.mesh, k, v, np.asarray(c).astype(np.int32),
                      key_decode=kd, value_decode=vd)
+
+
+def skv_scan(skv: ShardedKV, fn, static=(), extra=()) -> ShardedKV:
+    """:func:`skv_map` for a filter over a large resident frame: the same
+    kernel-body convention (``fn -> (okey, ovalue, keep)``), but the rows
+    that pass are not packed inside the body's program.  That program
+    (``jit_kv_scan_<body>``) writes the body's rows where they lie and
+    the order of the kept ones (:func:`front_order`); behind the sync that
+    brings their count, ``jit_take_rows`` takes just them, into a frame of
+    the capacity they need.  So a scan that keeps a hundredth of a table
+    hands the next op a hundredth of its block, and one that keeps half
+    gathers half.  The source frame is read, never donated.  Plain numeric
+    frames only."""
+    _check_decodes(skv, False, "skv_scan")
+    counts = jax.device_put(skv.counts.astype(np.int32),
+                            row_sharding(skv.mesh))
+    k, v, order, c = _skv_rows_jit(
+        skv.mesh, fn, tuple(static), len(extra), True)(
+        skv.key, skv.value, counts, *extra)
+    SyncStats.bump()
+    kept = np.asarray(c).astype(np.int32)
+    k, v = _take_rows_jit(skv.mesh, front_cap(kept, skv.cap))(k, v, order)
+    return ShardedKV(skv.mesh, k, v, kept)
 
 
 @functools.lru_cache(maxsize=None)

@@ -26,10 +26,11 @@ from jax.sharding import NamedSharding
 
 from ..core.runtime import bump_dispatch
 from ..obs import get_tracer, names
-from ..ops.sort import columns, riding, sort_carrying, sort_operands
+from ..ops.sort import (columns, front_order, riding, sort_carrying,
+                        sort_operands, take_together)
 from .mesh import mesh_axis_size, row_sharding, row_spec
 from .sharded import (ShardedKMV, ShardedKV, SyncStats, _decode_col,
-                      round_cap)
+                      front_cap, round_cap)
 
 
 def _local_sort(key, value, count):
@@ -527,3 +528,140 @@ def sort_interned_sharded(skv: ShardedKV, by: str = "key",
     return ShardedKV(skv.mesh, k, v, new_counts,
                      key_decode=skv.key_decode,
                      value_decode=skv.value_decode)
+
+
+# ---------------------------------------------------------------------------
+# keyed join of two datasets (MapReduce.join)
+# ---------------------------------------------------------------------------
+
+def value_width(v) -> int:
+    """Words a value row holds: ``[n]`` is one."""
+    return 1 if v.ndim == 1 else v.shape[1]
+
+
+def join_rows_body(pk, pc, bk, bc):
+    """A shard's inner join, up to the rows' values: ``(order, where,
+    [joined rows, duplicate build keys])`` over the ``bcap + pcap`` rows
+    of both sides in key order (``where``: `join_take_body`).
+
+    ONE sort orders both sides' KEYS together, and nothing rides it: its
+    operands are the key's columns and, as the last key, a row's tag,
+    which is its own index in (build block ++ probe block), or that plus
+    the blocks' length for a row past its count.  Tags are unique, so the
+    sort need not be stable; in a run of equal keys the build row (the
+    lowest tag) comes first, the probe rows follow in their order, and
+    rows past a count come last in their run and are nobody's partner.
+    Two int32 prefix maxima over the sorted position say where a row's
+    run starts and where the last build row at or before it lies: a probe
+    row has a partner when the second is not before the first.  A build
+    key that occurs twice is a build row whose predecessor is a build row
+    of the same key: counted, never chosen.  ``order`` brings the joined
+    probe rows' positions to the front (ops/sort.front_order: a second
+    sort, of one operand).  Values are taken afterwards, for the joined
+    rows alone (`join_take_body`): a sort that carried them compiled in
+    486 s at 3.6 x 10^7 rows where this one takes about 100 (PERF.md §6,
+    PR 43), and its gather of the partners' values ran over every row."""
+    pcap, bcap = pk.shape[0], bk.shape[0]
+    n = bcap + pcap
+    row = jnp.arange(n, dtype=jnp.int32)
+    valid = jnp.where(row >= bcap, row - bcap < pc, row < bc)
+    with jax.named_scope("sort_sides"):
+        (*scols, tag), _ = sort_carrying(
+            (*columns(jnp.concatenate([bk, pk])),
+             jnp.where(valid, row, row + n)), stable=False)
+    with jax.named_scope("partners"):
+        isb = tag < bcap
+        isp = (tag >= bcap) & (tag < n)
+        same = jnp.ones(n - 1, bool)
+        for c in scols:
+            same = same & (c[1:] == c[:-1])
+        same = jnp.concatenate([jnp.zeros(1, bool), same])
+        run = jax.lax.cummax(jnp.where(same, 0, row))
+        lastb = jax.lax.cummax(jnp.where(isb, row, -1))
+        matched = isp & (lastb >= run)
+        twice = isb & same & jnp.concatenate([jnp.zeros(1, bool), isb[:-1]])
+    with jax.named_scope("joined_rows_first"):
+        order, joined = front_order(matched)
+    return order, jnp.stack([tag, lastb], axis=1), jnp.stack(
+        [joined, jnp.sum(twice, dtype=jnp.int32)])
+
+
+def join_take_body(cap: int, order, where, pk, pv, bv):
+    """The first ``cap`` joined rows of a shard: ``(key, probe value ++
+    build value)``.  A joined row's sorted position (``order``) gives, in
+    ``where`` (a row's tag and the position of the last build row at or
+    before it, side by side), its probe row and its partner's position,
+    and that position the partner's build row; key and values are taken
+    from the blocks they came in, the probe's key and value together
+    (ops/sort.take_together).  Four gathers of ``cap`` rows, whatever the
+    blocks hold."""
+    pcap, bcap = pk.shape[0], bv.shape[0]
+    words = lambda v: v[:, None] if v.ndim == 1 else v
+    here = jnp.take(where, jnp.minimum(order[:cap], where.shape[0] - 1),
+                    axis=0)
+    src = jnp.clip(here[:, 0] - bcap, 0, pcap - 1)
+    partner = jnp.clip(
+        jnp.take(where[:, 0], jnp.maximum(here[:, 1], 0)), 0, bcap - 1)
+    key, pvalue = take_together(src, pk, words(pv))
+    return key, jnp.concatenate(
+        [pvalue, jnp.take(words(bv), partner, axis=0)], axis=1)
+
+
+@functools.lru_cache(maxsize=None)
+def _join_jit(mesh):
+    spec = row_spec(mesh)
+
+    def join_rows(pkey, pcount, bkey, bcount):
+        def body(pk, pc, bk, bc):
+            return join_rows_body(pk, pc[0], bk, bc[0])
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 4,
+                             out_specs=(spec,) * 3)(
+            pkey, pcount, bkey, bcount)
+
+    return jax.jit(join_rows)
+
+
+@functools.lru_cache(maxsize=None)
+def _join_take_jit(mesh, cap: int):
+    spec = row_spec(mesh)
+
+    def join_take(order, where, pkey, pvalue, bvalue):
+        return jax.shard_map(functools.partial(join_take_body, cap),
+                             mesh=mesh, in_specs=(spec,) * 5,
+                             out_specs=(spec, spec))(
+            order, where, pkey, pvalue, bvalue)
+
+    rows = row_sharding(mesh)
+    return jax.jit(join_take, out_shardings=(rows, rows))
+
+
+def join_sharded(probe: ShardedKV, build: ShardedKV):
+    """Inner join of two mesh frames whose equal keys share a shard:
+    ``(frame of the joined rows, duplicate build keys)``; no frame when
+    there is a duplicate.  ``jit_join_rows`` a shard, then ONE controller
+    round-trip, the pull of every shard's two counts, then
+    ``jit_join_take`` at the capacity the joined rows need."""
+    mesh = probe.mesh
+    put = lambda c: jax.device_put(c.astype(np.int32), row_sharding(mesh))
+    tracer = get_tracer()
+    if tracer.enabled:          # on the ``join`` op span
+        taken = value_width(probe.value) + value_width(build.value)
+        tracer.annotate(**{
+            names.ATTR_KEY_WORDS: sort_operands(probe.key) + 1,
+            names.ATTR_RODE_WORDS: 0, names.ATTR_TAKEN_WORDS: taken,
+            names.ATTR_HBM_ROW_BYTES: round(
+                (probe.key.on_device_size_in_bytes()
+                 + probe.value.on_device_size_in_bytes())
+                / max(1, probe.key.shape[0]), 3)})
+    bump_dispatch()
+    order, where, meta = _join_jit(mesh)(
+        probe.key, put(probe.counts), build.key, put(build.counts))
+    SyncStats.bump()
+    meta = np.asarray(meta).reshape(-1, 2)
+    counts, twice = meta[:, 0].astype(np.int32), int(meta[:, 1].sum())
+    if twice:
+        return None, twice
+    bump_dispatch()
+    key, value = _join_take_jit(mesh, front_cap(counts, probe.cap))(
+        order, where, probe.key, probe.value, build.value)
+    return ShardedKV(mesh, key, value, counts), 0

@@ -118,6 +118,13 @@ def round_cap(n: int) -> int:
     return cap
 
 
+def front_cap(counts, cap: int) -> int:
+    """The capacity a frame of ``counts`` rows a shard is given when it is
+    cut out of a block of ``cap``: `round_cap` of the fullest shard's,
+    and no more than the block."""
+    return min(round_cap(int(np.max(counts, initial=0))), cap)
+
+
 def narrowest_uint(maxval: int):
     """(dtype name, itemsize) of the narrowest unsigned dtype holding
     ``maxval`` — the wire codec's width rule (parallel/wire.py), kept

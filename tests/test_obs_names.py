@@ -111,6 +111,13 @@ def _programs(mesh):
             SDS((64,), jnp.float64), edges[2])),
         (names.TERASORT_SAMPLE_KEYS, terasort._sample_jit(mesh, 4).lower(
             SDS((64, 3), u32), cnt, cnt)),
+        (names.JOIN_ROWS, group._join_jit(mesh).lower(
+            SDS((64, 2), u32), cnt, SDS((64, 2), u32), cnt)),
+        (names.JOIN_TAKE, group._join_take_jit(mesh, 4).lower(
+            SDS((128,), i32), SDS((128, 2), i32),
+            SDS((64, 2), u32), SDS((64, 4), u32), SDS((64, 1), u32))),
+        (names.TAKE_ROWS, devkernels._take_rows_jit(mesh, 4).lower(
+            key, val, cnt2)),
     ]
 
 
@@ -146,6 +153,12 @@ def test_every_program_lowers_under_its_declared_name(mesh):
                 ops = re.findall(r"stablehlo\.(\w+)", text)
                 assert ops.count("sort") == 1 and not set(ops) & {
                     "scatter", "gather", "while"}, ops
+        if want == names.JOIN_ROWS:
+            # and for the join (PR 43): two sorts that carry nothing, the
+            # values taken afterwards; tests/test_join.py pins the counts
+            ops = re.findall(r"stablehlo\.(\w+)", lowered.as_text())
+            assert ops.count("sort") == 2 and not set(ops) & {
+                "scatter", "gather", "while"}, ops
         if want in (names.TRI_ORIENT, names.TRI_TILES, names.TRI_WEDGES):
             # the same rule for the wedge walk: sorts, no scatter, and no
             # ``while`` (a searchsorted is a gather a round)
@@ -501,6 +514,24 @@ def _terasort(mesh, out):
     return n, parts
 
 
+def _tpch(mesh, out):
+    """TPC-H Query 3 (ISSUE 43) over tiny seeded tables through the two
+    OINK commands: the ten lines and what the command said."""
+    import io
+    from benchmark.gen import tpch as gen
+    from gpu_mapreduce_tpu.oink.script import OinkScript
+    paths = gen.make_tables(os.path.join(out, "tables"), 0.002, 43)
+    script = OinkScript(comm=mesh, screen=io.StringIO())
+    for t, files in paths.items():
+        script.run_string(f"variable f{t} index {' '.join(files)}")
+    script.run_string("tpch_load -i v_fcustomer v_forders v_flineitem "
+                      "-o NULL customer -o NULL orders -o NULL lineitem")
+    script.run_string(f"tpch_q3 BUILDING 1995-03-15 -i customer orders "
+                      f"lineitem -o {out}/q3.txt mrq3")
+    with open(os.path.join(out, "q3.txt")) as f:
+        return f.read(), script.screen.getvalue()
+
+
 def test_terasort_on_four_devices_says_how_its_rows_were_sent(traced,
                                                              tmp_path):
     """ISSUE 39: what TeraSort has only when P > 1.  The sample says what
@@ -711,6 +742,8 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
     off_enum = _enum_script(mesh, str(tmp_path / "e0"))[1:]
     (tmp_path / "t0").mkdir(), (tmp_path / "t1").mkdir()
     off_sorted = _terasort(mesh, str(tmp_path / "t0"))
+    (tmp_path / "q0").mkdir(), (tmp_path / "q1").mkdir()
+    off_q3 = _tpch(mesh, str(tmp_path / "q0"))
     assert built == []          # every site returned NULL_SPAN
 
     tr.enable(ring=1 << 16)
@@ -723,6 +756,7 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
         on_words = _wordfreq_script(mesh, corpus)
         on_enum = _enum_script(mesh, str(tmp_path / "e1"))[1:]
         on_sorted = _terasort(mesh, str(tmp_path / "t1"))
+        on_q3 = _tpch(mesh, str(tmp_path / "q1"))
     finally:
         tr.clear()
         tr.disable()
@@ -730,6 +764,7 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
     assert set(names.SPANS) <= set(built)
     assert on_graph == off_graph and on_enum == off_enum
     assert on_sorted == off_sorted and on_sorted[0] == 603
+    assert on_q3 == off_q3 and len(on_q3[0].splitlines()) == 10
     assert (on_counts, on_parts, on_rows, on_words) == (
         off_counts, off_parts, off_rows, off_words)
 
@@ -773,3 +808,53 @@ def test_named_scopes_are_metadata_only(mesh, monkeypatch, no_compile_cache):
     assert "flagged_rows" not in bare and "shard_map/layout" not in bare
     assert "sort(" in _strip(bare)           # the code is what is compared
     assert _strip(bare) == _strip(with_scopes)
+
+
+def test_the_scan_programs_are_named_for_their_bodies(mesh):
+    """``skv_scan`` runs one program a kernel body, ``jit_kv_scan_<body>``,
+    which orders the kept rows by a sort of one operand where ``skv_map``'s
+    packs by a scatter; Q3's scans are the three the benchmark's
+    ``scan_dev_s`` names."""
+    import gpu_mapreduce_tpu.apps.tpch as tpch
+    from gpu_mapreduce_tpu.parallel import devkernels
+    cnt, date = SDS((8,), jnp.int32), SDS((), jnp.uint32)
+    seen = set()
+    for table, words in (("customer", 4), ("orders", 9), ("lineitem", 15)):
+        cells = tpch.SCAN[table].__closure__
+        (dev,) = [c.cell_contents for c in cells
+                  if getattr(c.cell_contents, "__name__", "") ==
+                  "tpch_" + table]
+        text = devkernels._skv_rows_jit(mesh, dev, (), 1, True).lower(
+            SDS((64, 2), jnp.uint32), SDS((64, words), jnp.uint32), cnt,
+            date).as_text()
+        got = re.search(r"module @(\w+)", text).group(1)
+        assert got == names.KV_SCAN_PREFIX + "tpch_" + table
+        assert names.declared_program(got) and got in tpch.SCAN_PROGRAMS
+        ops = re.findall(r"stablehlo\.(\w+)", text)
+        assert ops.count("sort") == 1 and not set(ops) & {
+            "scatter", "gather", "while"}, ops
+        seen.add(got)
+    assert seen == set(tpch.SCAN_PROGRAMS)
+    # a map that keeps every row is a plain map of the rows where they lie
+    (dev,) = [c.cell_contents for c in tpch._BY_ORDERKEY.__closure__
+              if getattr(c.cell_contents, "__name__", "") ==
+              "tpch_by_orderkey"]
+    text = devkernels._skv_rows_jit(mesh, dev, (), 0, False).lower(
+        SDS((64, 2), jnp.uint32), SDS((64, 5), jnp.uint32), cnt).as_text()
+    assert re.search(r"module @(\w+)", text).group(1) == (
+        names.KV_MAP_PREFIX + "tpch_by_orderkey")
+    assert not set(re.findall(r"stablehlo\.(\w+)", text)) & {
+        "sort", "scatter", "gather", "while"}
+
+
+def test_the_new_spans_and_attrs_are_declared():
+    for span in (names.TPCH_Q3, names.TPCH_LOAD, names.TPCH_SCAN,
+                 names.TPCH_TOPN, names.TPCH_EMIT):
+        assert span in names.SPANS and span.startswith("tpch.")
+    assert names.JOIN_SPAN == "join"
+    for attr in (names.ATTR_PROBE_ROWS, names.ATTR_BUILD_ROWS,
+                 names.ATTR_MATCHED_ROWS, names.ATTR_ROWS_IN,
+                 names.ATTR_ROWS_OUT, names.ATTR_ROW_WORDS_IN):
+        assert attr in names.SPAN_ATTRS
+    assert names.JOIN_ROWS in names.PROGRAMS
+    assert names.KV_SCAN_PREFIX in names.PROGRAM_PREFIXES

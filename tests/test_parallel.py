@@ -179,6 +179,27 @@ def test_sort_multivalues_sharded(mesh):
         assert list(vals) == sorted(vals, reverse=True)
 
 
+def test_gather_on_one_shard_moves_nothing():
+    """One shard holds every row already: like the reference's, ``gather``
+    there returns at once: the frame's arrays are the same arrays, in the
+    same order, and no exchange ran (a host frame is placed)."""
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    from gpu_mapreduce_tpu.parallel.sharded import ShardedKV, SyncStats
+    mr = MapReduce(make_mesh(1))
+    mr.map(6, emit)
+    mr.aggregate()
+    frame = mr.kv.one_frame()
+    pulls = SyncStats.snapshot()
+    assert mr.gather(1) == 3000 and mr.gather(4) == 3000
+    assert mr.kv.one_frame() is frame and SyncStats.delta(pulls) == 0
+    host = MapReduce(make_mesh(1))
+    host.map(6, emit)
+    assert host.gather(1) == 3000
+    assert isinstance(host.kv.one_frame(), ShardedKV)
+    assert multiset(host.kv.one_frame().to_host().pairs()) == multiset(
+        frame.to_host().pairs())
+
+
 def test_gather_and_broadcast(mesh):
     mr = MapReduce(mesh)
     mr.map(6, emit)
