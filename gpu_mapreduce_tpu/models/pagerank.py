@@ -171,20 +171,32 @@ def _sharded_run_fn(mesh: Mesh, n: int, tol: float, maxiter: int,
     return pagerank_loop
 
 
+def pagerank_staged(mesh: Mesh, src_d: jax.Array, dst_d: jax.Array,
+                    valid_d: jax.Array, n: int, tol: float = 1e-6,
+                    maxiter: int = 100, damping: float = 0.85
+                    ) -> Tuple[np.ndarray, int]:
+    """The sharded loop over edge columns that are ALREADY on the mesh,
+    row-sharded: what ``parallel/staging.stage_graph`` leaves there (int32
+    ranks and the frame's row mask; a masked row may carry any rank in
+    [0, n], it adds nothing to a degree or an inflow).  Only the [n]
+    ranks and the iteration count come to the host."""
+    ranks, iters = _sharded_run_fn(mesh, n, tol, maxiter, damping)(
+        src_d, dst_d, valid_d)
+    return np.asarray(ranks), int(iters)
+
+
 def pagerank_sharded(mesh: Mesh, src: np.ndarray, dst: np.ndarray, n: int,
                      tol: float = 1e-6, maxiter: int = 100,
                      damping: float = 0.85) -> Tuple[np.ndarray, int]:
-    """Edge-parallel PageRank over a device mesh (flat or multi-slice).
-    Edges are block-sharded over all mesh axes; ranks replicated; one
-    psum per iteration rides ICI (+DCN across slices)."""
+    """Edge-parallel PageRank over a device mesh (flat or multi-slice),
+    from host arrays.  Edges are block-sharded over all mesh axes; ranks
+    replicated; one psum per iteration rides ICI (+DCN across slices)."""
     nprocs = mesh_axis_size(mesh)
     src_p, dst_p, valid_p = pad_edges_for_mesh(src, dst, nprocs)
     edge_shard = NamedSharding(mesh, row_spec(mesh))
     # bounded per-device messages (a scale-22 edge column is ~134 MB)
     from ..parallel.mesh import device_put_chunked
-    src_d = device_put_chunked(src_p, edge_shard)
-    dst_d = device_put_chunked(dst_p, edge_shard)
-    valid_d = device_put_chunked(valid_p, edge_shard)
-    run = _sharded_run_fn(mesh, n, tol, maxiter, damping)
-    ranks, iters = run(src_d, dst_d, valid_d)
-    return np.asarray(ranks), int(iters)
+    return pagerank_staged(
+        mesh, device_put_chunked(src_p, edge_shard),
+        device_put_chunked(dst_p, edge_shard),
+        device_put_chunked(valid_p, edge_shard), n, tol, maxiter, damping)

@@ -112,7 +112,9 @@ RMAT_GENERATE = "rmat.generate"                 # rows, d2h_bytes
 OINK_INPUT = "oink.input"                       # source, rows, bytes
 OINK_OUTPUT = "oink.output"                     # path, rows, bytes
 # oink/commands/{cc,pagerank}.py
-CC_STAGE = "cc.stage"                           # n, edges
+CC_STAGE = "cc.stage"                           # n, edges, on_device (1:
+#                                                 ranked by stage_graph, 0: on
+#                                                 the host); pagerank.stage too
 CC_EMIT = "cc.emit"                             # n
 CC_ENGINE = "cc.loop"                           # cat ENGINE: iters, n, edges
 PAGERANK_STAGE = "pagerank.stage"

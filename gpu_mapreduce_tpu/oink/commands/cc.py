@@ -281,7 +281,7 @@ class CCFind(Command):
                 sg = stage_graph_host(mre)
                 nedges = len(sg.src)
             verts, n = sg.verts, sg.n
-            sp.set(n=n, edges=nedges)
+            sp.set(n=n, edges=nedges, on_device=int(on_device))
         if n == 0:
             self.ncc, self.niterate = 0, 0
             mrv = obj.create_mr()

@@ -9,6 +9,15 @@ composition pattern, backed by the flagship TPU model
 ``lax.while_loop`` convergence, mesh-sharded edges + one ICI psum per
 iteration when the ObjectManager carries a mesh.
 
+Where the vertex ranking runs: on a mesh the edge KV is ranked on the
+device where it lies (``parallel/staging.stage_graph``, the program
+``jit_stage_rank_graph``, as ``cc_find``) and its ranked int32 columns go
+straight into the loop; the O(E) columns never reach the host, which
+pulls ``n``, the [n] vertex table and the [n] ranks.  On the serial
+backend (or where ``stage_graph`` does not apply) the host ranks them
+(``stage_graph_host``: ``scan_kv`` + ``np.unique``).  Vertex id 2^64-1 is
+the device staging's sentinel: on a mesh it is refused by name.
+
 Script syntax (reference ``PageRank::params``): ``pagerank tol maxiter
 alpha``.  Edge weights are accepted in the input ('vi vj [wt]') for
 script parity but rank follows link structure only (classic PageRank).
@@ -22,7 +31,8 @@ import numpy as np
 from ...core.runtime import MRError
 from ..command import Command, command
 from ..kernels import read_edge, read_edge_weight
-from ...models.pagerank import pagerank, pagerank_sharded
+from ...models.pagerank import (pagerank, pagerank_sharded,
+                                pagerank_staged)
 
 
 def _read_edges_sniff(itask, filename, kv, ptr):
@@ -58,31 +68,45 @@ class PageRankCommand(Command):
         obj = self.obj
         mre = obj.input(1, _read_edges_sniff)
 
+        from jax.sharding import Mesh
+        mesh = obj.comm if isinstance(obj.comm, Mesh) else None
+        # device staging, as cc_find's: the edge KV is ranked where it
+        # lies and the ranked columns stay there for the loop; only n and
+        # the [n] id table come to the host.  The values (weights, or
+        # interned bytes) are not read, so they never decide the path.
         from ...obs import get_tracer, names
-        from ...parallel.staging import stage_graph_host
+        from ...parallel.staging import stage_graph, stage_graph_host
         tr = get_tracer()
         with tr.span(names.PAGERANK_STAGE, cat=names.HOST) as sp:
-            # compact arbitrary u64 ids to dense 0..n-1 for the dense-rank
-            # model
-            sg = stage_graph_host(mre)
-            verts, n, src, dst = sg.verts, sg.n, sg.src, sg.dst
-            sp.set(n=n, edges=len(src))
+            try:
+                sg = stage_graph(mre, obj.comm)
+            except ValueError as e:     # the reserved vertex id 2^64-1
+                raise MRError(f"pagerank: {e}") from e
+            on_device = sg is not None
+            if on_device:
+                nedges = int(mre.kv.nkv)
+            else:
+                sg = stage_graph_host(mre)
+                nedges = len(sg.src)
+            verts, n = sg.verts, sg.n
+            sp.set(n=n, edges=nedges, on_device=int(on_device))
             if n == 0:
                 raise MRError("pagerank: empty edge list")
 
-        from jax.sharding import Mesh
-        mesh = obj.comm if isinstance(obj.comm, Mesh) else None
-        # the fused loop, from the edges' transfer to the pull that ends it
+        # the fused loop, from dispatch (on the host path: from the
+        # edges' transfer) to the pull that ends it
         with tr.span(names.PAGERANK_ENGINE, cat=names.ENGINE, n=n,
-                     edges=len(src)) as sp:
-            if mesh is not None:
-                ranks, iters = pagerank_sharded(
-                    mesh, src, dst, n, tol=self.tolerance,
-                    maxiter=self.maxiter, damping=self.alpha)
+                     edges=nedges) as sp:
+            params = dict(tol=self.tolerance, maxiter=self.maxiter,
+                          damping=self.alpha)
+            if on_device:
+                ranks, iters = pagerank_staged(mesh, sg.src, sg.dst,
+                                               sg.valid, n, **params)
+            elif mesh is not None:
+                ranks, iters = pagerank_sharded(mesh, sg.src, sg.dst, n,
+                                                **params)
             else:
-                ranks, iters = pagerank(src, dst, n, tol=self.tolerance,
-                                        maxiter=self.maxiter,
-                                        damping=self.alpha)
+                ranks, iters = pagerank(sg.src, sg.dst, n, **params)
                 ranks, iters = np.asarray(ranks), int(iters)
             sp.set(iters=iters)
 
@@ -94,6 +118,6 @@ class PageRankCommand(Command):
             mrr.map(1, lambda i, kv, p: kv.add_batch(
                 verts, ranks.astype(np.float64)))
         obj.output(1, mrr, lambda k, v, fp: fp.write(f"{k} {v:.8g}\n"))
-        self.message(f"PageRank: {n} vertices, {len(src)} edges, "
+        self.message(f"PageRank: {n} vertices, {nedges} edges, "
                      f"{iters} iterations")
         obj.cleanup()

@@ -1,9 +1,10 @@
 """Device-side staging for the fused graph engines (VERDICT r2 #2).
 
-The fused cc/sssp/luby/tri engines need compact vertex ranks 0..n-1
-(their labels/state live in dense replicated vectors).  Round 2 staged
-this on the controller — ``scan_kv`` pulled the whole edge list to host
-numpy and ``np.unique`` ranked it — a funnel the mesh cannot outgrow
+The fused cc/pagerank/sssp/luby/tri engines need compact vertex ranks
+0..n-1 (their labels/ranks/state live in dense replicated vectors).
+Round 2 staged this on the controller — ``scan_kv`` pulled the whole edge
+list to host numpy and ``np.unique`` ranked it — a funnel the mesh cannot
+outgrow
 (the reference gives every rank its own slice and never funnels,
 ``cuda/InvertedIndex.cu:284-312``).
 
@@ -110,9 +111,10 @@ def stage_graph(mr, comm, drop_self: bool = False,
 def stage_graph_host(mr, drop_self: bool = False,
                      need_weights: bool = False) -> StagedGraph:
     """The ranking on the host, for where :func:`stage_graph` does not
-    apply (it returned None) and for ``pagerank``: the edge KV scanned
-    into numpy and ranked by ``np.unique``.  ``src``/``dst`` are each
-    endpoint's rank as ``np.unique`` counts it (int64; a caller whose
+    apply (it returned None: the serial backend, an empty dataset,
+    interned weights), the fallback of all five graph commands: the edge
+    KV scanned into numpy and ranked by ``np.unique``.  ``src``/``dst``
+    are each endpoint's rank as ``np.unique`` counts it (int64; a caller whose
     program takes int32 casts), every row is valid — with ``drop_self``
     the self-loop rows are gone, not masked — and ``weights`` is the
     value column as it was read, row for row.  An empty edge list gives

@@ -400,6 +400,34 @@ def test_graph_commands_emit_the_host_and_engine_spans(mesh, traced,
     assert args[names.CC_STAGE]["n"] == args[names.CC_EMIT]["n"] > 0
 
 
+@pytest.mark.parametrize("nprocs", [1, 4, None],
+                         ids=["mesh1", "mesh4", "serial"])
+def test_stage_spans_say_where_the_ranking_ran(nprocs, traced, tmp_path):
+    """``cc.stage`` and ``pagerank.stage`` carry ``on_device`` beside ``n``
+    and ``edges`` (ISSUE 44): 1 on a mesh, where no ``scan_kv`` opens
+    under either command, 0 on the serial backend, where the host ranks
+    through one."""
+    from gpu_mapreduce_tpu.oink.script import OinkScript
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    s = OinkScript(comm=make_mesh(nprocs) if nprocs else None,
+                   screen=io.StringIO())
+    for line in ("rmat 7 8 0.57 0.19 0.19 0.05 0.0 1 -o NULL mre",
+                 "edge_upper -i mre -o NULL mru",
+                 "cc_find 0 -i mru -o NULL NULL",
+                 f"pagerank 1e-6 100 0.85 -i mre -o {tmp_path}/pr NULL"):
+        s.run_string(line)
+    events = traced.events()
+    by_id = {e["id"]: e for e in events}
+    for stage, cmd in ((names.CC_STAGE, "oink.cc_find"),
+                       (names.PAGERANK_STAGE, "oink.pagerank")):
+        (a,) = [e["args"] for e in events if e["name"] == stage]
+        assert a["on_device"] == (1 if nprocs else 0), stage
+        assert a["n"] > 0 and a["edges"] > 0
+        scans = [e for e in events if e["name"] == "scan_kv"
+                 and by_id[e["parent"]]["name"] == stage]
+        assert len(scans) == (0 if nprocs else 1), (stage, cmd)
+
+
 def test_enumeration_commands_emit_their_spans(mesh, traced, tmp_path):
     """``tri_find``, ``luby_find`` and ``sssp`` (ISSUE 32): stage, engine
     and emit spans under their commands, the counts on the engine spans

@@ -104,6 +104,33 @@ def test_rank_graph_matches_np_unique(nprocs, kind, drop_self):
     assert got.min() >= 0 and got.max() <= n
 
 
+@pytest.mark.parametrize("kind", list(INPUTS))
+@pytest.mark.parametrize("nprocs", [1, 4])
+def test_ranked_columns_feed_the_pagerank_loop(nprocs, kind):
+    """``pagerank`` takes ``src``/``dst``/``valid`` as ``rank_graph``
+    leaves them (ISSUE 44): self loops and duplicates kept, the padding
+    rows of every shard behind the mask with whatever rank they carry.
+    The ranks are those of the same edges ranked by ``np.unique``."""
+    from gpu_mapreduce_tpu.models.pagerank import pagerank, pagerank_staged
+    mesh = make_mesh(nprocs)
+    e, counts = INPUTS[kind](np.random.default_rng(11), nprocs)
+    fr = _frame(mesh, e, counts)
+    # ("wide" fills its shards to the row, as the benchmark's 2^23 edges
+    # do; the others leave padding rows)
+    assert _row_mask(fr).all() == (kind == "wide")
+    _, n, src, dst, valid = staging.rank_graph(fr)
+    assert src.dtype == dst.dtype == jnp.int32
+    got, iters = pagerank_staged(mesh, src, dst, valid, n, tol=1e-7,
+                                 maxiter=200)
+    verts, inv = np.unique(e.reshape(-1), return_inverse=True)
+    inv = inv.reshape(-1, 2)
+    want, want_iters = pagerank(inv[:, 0], inv[:, 1], len(verts), tol=1e-7,
+                                maxiter=200)
+    assert n == len(verts) and iters == int(want_iters)
+    assert np.abs(got - np.asarray(want)).sum() < 1e-6
+    assert abs(got.sum() - 1.0) < 1e-5
+
+
 def test_sentinel_vertex_is_refused():
     mesh = make_mesh(4)
     e = np.asarray([[1, 2], [3, staging.SENTINEL], [2, 5]], U64)
