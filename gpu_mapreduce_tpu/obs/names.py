@@ -143,7 +143,12 @@ MAP_PLAN = "map.plan"                           # files, bytes, rounds
 MAP_PAD = "map.pad"                             # bytes, shard_bytes
 MAP_COLLISIONS = "map.collisions"               # rows, rounds, shards
 PARTS_PULL = "parts.pull"                       # groups, bytes
-PARTS_WRITE = "parts.write"                     # groups, bytes
+PARTS_WRITE = "parts.write"                     # groups, bytes, pieces
+#                                                 (byte ranges gathered:
+#                                                 2 a group + 1 a distinct
+#                                                 url-file pair), recoded
+#                                                 (urls not ASCII, spelt
+#                                                 again as UTF-8)
 
 # utils/io.word_ranges / parallel/ingest._intern_shard: the word map of a
 # file map, by ranges (cat HOST; ``shard`` when the map runs shard by shard).

@@ -493,3 +493,191 @@ def test_puma_shapes_on_a_mesh_match_a_regex_scan_exactly(
             assert url not in got                     # in ONE part file
             got[url] = set(names.decode().split(" "))
     assert got == want
+
+
+# -- the part files from arrays (PR 45): `_part_file` plans a shard's lines
+# with numpy and copies them by one range gather; the per-group loop the
+# program ran until then is kept here as its oracle, byte for byte ------------
+
+def _loop_part_file(path, hf, lookup, docs):
+    """The loop `_write_parts_sharded` was (text mode, UTF-8)."""
+    with open(path, "w", encoding="utf-8") as out:
+        for k, vals in hf.groups():
+            url = lookup[int(k)].decode(errors="replace")
+            names = " ".join(docs[int(v)] for v in sorted(set(vals)))
+            out.write(f"{url}\t{names}\n")
+
+
+def _shard(groups, urls=None, ndocs=4, docs=None):
+    """A host shard as `shard_to_host` returns it (`groups`: one list of
+    file indices a group, keys random u64 in ascending order), its url
+    dict and the file names; the dict holds a few urls of other shards."""
+    from gpu_mapreduce_tpu.core.column import DenseColumn
+    from gpu_mapreduce_tpu.core.frame import KMVFrame
+
+    rng = np.random.default_rng(len(groups))
+    ids = np.unique(rng.integers(0, 2 ** 64, len(groups) + 3, dtype=np.uint64))
+    assert len(ids) == len(groups) + 3
+    keys, other = ids[1:-2], ids[[0, -2, -1]]     # below, above, the u64 top
+    if urls is None:
+        urls = [b"http://example.org/wiki/page-%06d" % i
+                for i in range(len(groups))]
+    lookup = dict(zip(keys.tolist(), urls))
+    lookup.update((k, b"http://other.shard/%d" % k) for k in other.tolist())
+    items = list(lookup.items())                  # a dict in no order
+    lookup = dict(items[i] for i in rng.permutation(len(items)))
+    nv = np.asarray([len(g) for g in groups], np.int64)
+    offsets = np.concatenate([[0], np.cumsum(nv)]).astype(np.int64)
+    values = np.asarray([v for g in groups for v in g], np.int32)
+    if docs is None:
+        docs = [f"/corpus/dir-{i % 3}/part-{i:05d}.html" for i in range(ndocs)]
+    return KMVFrame(DenseColumn(keys), nv, offsets, DenseColumn(values)), \
+        lookup, docs
+
+
+def _case_unsorted_repeated():
+    return _shard([[3, 0, 3, 1, 0, 0], [2, 2], [1, 3, 1, 2], [0]]), 0
+
+
+def _case_hot_group_beside_groups_of_one():
+    rng = np.random.default_rng(7)
+    hot = rng.integers(0, 8, 5000).tolist()
+    ones = [[int(v)] for v in rng.integers(0, 8, 700)]
+    return _shard(ones[:300] + [hot] + ones[300:] + [hot[:2048]],
+                  ndocs=8), 0
+
+
+def _case_a_group_naming_every_file():
+    return _shard([[1], list(range(16))[::-1] * 2, [15, 0]], ndocs=16), 0
+
+
+def _case_one_group():
+    return _shard([[2, 1]]), 0
+
+
+def _case_no_group():
+    return _shard([]), 0
+
+
+def _case_urls_of_0_1_and_max_bytes():
+    from gpu_mapreduce_tpu.apps.invertedindex import MAX_URL
+    urls = [b"", b"/", b"h" * (MAX_URL - 1), b"x", b"y" * (MAX_URL - 1), b"z"]
+    return _shard([[0], [1, 0], [3], [2, 2], [0, 1, 2, 3], [1]], urls), 0
+
+
+def _case_valid_utf8_in_a_url():
+    urls = [b"http://a/plain", "http://b/café/中".encode(),
+            b"http://c/plain", "ü".encode()]
+    return _shard([[0], [1, 0], [2], [3, 1]], urls), 2
+
+
+def _case_invalid_utf8_in_a_url():
+    urls = [b"", b"http://a/\xff\xfe/x", b"http://b/plain",
+            b"http://c/\xe4\xb8", "http://d/é".encode(), b"\x80"]
+    return _shard([[0], [1, 0], [2], [3, 1], [2, 2], [1]], urls), 4
+
+
+def _case_a_file_name_that_is_not_ascii():
+    docs = ["/corpus/part-0.html", "/corpus/süd/été 1.html",
+            "/corpus/中文.html"]
+    return _shard([[2, 1], [0], [1], [2, 0, 1]], docs=docs), 0
+
+
+_PART_CASES = [f for n, f in sorted(globals().items())
+               if n.startswith("_case_")]
+
+
+@pytest.mark.parametrize("native_lib", [True, False],
+                         ids=["native", "numpy"])
+@pytest.mark.parametrize("case", _PART_CASES,
+                         ids=[f.__name__[6:] for f in _PART_CASES])
+def test_part_file_from_arrays_equals_the_per_group_loop(
+        case, native_lib, tmp_path, monkeypatch):
+    from gpu_mapreduce_tpu import native
+    from gpu_mapreduce_tpu.apps.invertedindex import _part_file
+
+    if native_lib:
+        assert native.available(), native.build_error()
+    else:
+        monkeypatch.setattr(native, "available", lambda: False)
+    (hf, lookup, docs), want_recoded = case()
+    _loop_part_file(tmp_path / "loop", hf, lookup, docs)
+    want = (tmp_path / "loop").read_bytes()
+    assert want.count(b"\n") == len(hf)
+
+    before = dict(lookup)
+    lines, pieces, recoded = _part_file(hf, lookup, docs)
+    assert lines.dtype == np.uint8 and lines.tobytes() == want
+    pairs = sum(len(set(vals)) for _, vals in hf.groups())
+    assert pieces == 2 * len(hf) + pairs
+    assert recoded == want_recoded
+    assert (b"\xef\xbf\xbd" in want) == ("invalid" in case.__name__)
+    assert lookup == before                     # the dict is read, not recoded
+
+
+@pytest.mark.parametrize("table", ["without_the_key", "empty"])
+def test_a_group_key_missing_from_the_table_raises_and_writes_nothing(
+        table, tmp_path):
+    """`lookup[int(k)]` raised KeyError; a searchsorted that is not tested
+    for equality would write the neighbour's URL."""
+    import os
+    import types
+
+    (hf, lookup, docs), _ = _case_unsorted_repeated()
+    gone = int(hf.key.data[2])
+    lookup = {} if table == "empty" else {
+        k: u for k, u in lookup.items() if k != gone}
+    idx = InvertedIndex()
+    idx.docs, idx.shard_urls = docs, [lookup]
+    fr = types.SimpleNamespace(nprocs=1, shard_to_host=lambda p: hf)
+    with pytest.raises(KeyError) as err:
+        idx._write_parts_sharded(str(tmp_path), fr)
+    assert err.value.args == ((int(hf.key.data[0]) if table == "empty"
+                               else gone),)
+    assert os.listdir(tmp_path) == []
+
+
+def test_a_value_that_is_no_file_index_raises():
+    from gpu_mapreduce_tpu.apps.invertedindex import _part_file
+
+    for bad in (4, -1):
+        hf, lookup, docs = _shard([[0, 1], [bad, 2]])
+        with pytest.raises(IndexError):
+            _part_file(hf, lookup, docs)
+
+
+def test_part_write_spans_say_pieces_and_recoded(tmp_path):
+    """The words of `parts.write` (doc/observability.md): `pieces` ranges
+    gathered, `recoded` URLs spelt again, beside `groups` and `bytes`."""
+    import types
+
+    from gpu_mapreduce_tpu.obs import get_tracer, names
+
+    shards = [_case_invalid_utf8_in_a_url()[0], _case_no_group()[0],
+              _case_hot_group_beside_groups_of_one()[0]]
+    docs = shards[2][2]
+    idx = InvertedIndex()
+    idx.docs, idx.shard_urls = docs, [s[1] for s in shards]
+    fr = types.SimpleNamespace(nprocs=3,
+                               shard_to_host=lambda p: shards[p][0])
+    tr = get_tracer()
+    was = tr.enabled
+    tr.enable(ring=1 << 10)
+    tr.clear()
+    try:
+        idx._write_parts_sharded(str(tmp_path), fr)
+        spans = [e["args"] for e in tr.events()
+                 if e["name"] == names.PARTS_WRITE]
+    finally:
+        tr.clear()
+        if not was:
+            tr.disable()
+    assert [a["shard"] for a in spans] == [0, 1, 2]
+    assert [a["recoded"] for a in spans] == [4, 0, 0]
+    for p, (a, (hf, lookup, _)) in enumerate(zip(spans, shards)):
+        _loop_part_file(tmp_path / "loop", hf, lookup, docs)
+        want = (tmp_path / "loop").read_bytes()
+        assert (tmp_path / f"part-{p:05d}").read_bytes() == want
+        assert a["groups"] == len(hf) and a["bytes"] == len(want)
+        assert a["pieces"] == 2 * len(hf) + sum(
+            len(set(v)) for _, v in hf.groups())

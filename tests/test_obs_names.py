@@ -495,6 +495,11 @@ def test_invertedindex_emits_the_map_and_part_file_spans(mesh, traced,
     assert sum(a["groups"] for a in args[names.PARTS_WRITE]) == nunique
     assert sum(a["bytes"] for a in args[names.PARTS_WRITE]) == sum(
         len(b) for b in parts.values())
+    # a line is its url, its files and its newline, each one range gathered
+    assert sum(a["pieces"] for a in args[names.PARTS_WRITE]) == sum(
+        2 + len(line.split(b"\t")[1].split(b" "))
+        for b in parts.values() for line in b.splitlines())
+    assert {a["recoded"] for a in args[names.PARTS_WRITE]} == {0}
 
 
 def test_invertedindex_on_four_devices_says_which_shard(traced, corpus,
