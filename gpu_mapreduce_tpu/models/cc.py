@@ -70,6 +70,11 @@ def cc(src: jax.Array, dst: jax.Array, n: int, maxiter: int = 0
     return lab, iters
 
 
+# merges of the replicated [n] int32 labels a round of the sharded loop:
+# the one ``pmin`` below (the ``cc.loop`` span's ``allreduce_bytes``)
+PMINS_PER_ROUND = 1
+
+
 @functools.lru_cache(maxsize=None)
 def _cc_sharded_fn(mesh: Mesh, n: int, maxiter: int):
     axes = mesh_axes(mesh)

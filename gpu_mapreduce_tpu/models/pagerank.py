@@ -104,6 +104,13 @@ def pagerank(src: jax.Array, dst: jax.Array, n: int, tol: float = 1e-6,
 # sharded (multi-chip) path
 # ---------------------------------------------------------------------------
 
+# merges of a replicated [n] float32 vector an iteration of the sharded
+# loop: the one ``psum`` of the inflows in ``_sharded_step`` (the
+# ``pagerank.loop`` span's ``allreduce_bytes``; the degrees' ``psum``
+# runs once, ahead of the loop, and is not an iteration's)
+PSUMS_PER_ITERATION = 1
+
+
 def _sharded_step(ranks, src, dst, inv_outdeg, valid, damping, axes):
     """shard_map body: local segment-sum of the shard's edges, then one
     psum (over every mesh axis — ICI within a slice, DCN across for a

@@ -114,12 +114,19 @@ OINK_OUTPUT = "oink.output"                     # path, rows, bytes
 # oink/commands/{cc,pagerank}.py
 CC_STAGE = "cc.stage"                           # n, edges, on_device (1:
 #                                                 ranked by stage_graph, 0: on
-#                                                 the host); pagerank.stage too
+#                                                 the host), shards (the
+#                                                 mesh's size, 1 without a
+#                                                 mesh); pagerank.stage too
 CC_EMIT = "cc.emit"                             # n
-CC_ENGINE = "cc.loop"                           # cat ENGINE: iters, n, edges
+CC_ENGINE = "cc.loop"                           # cat ENGINE: iters, n, edges,
+#                                                 shards, allreduce_bytes (the
+#                                                 replicated [n] vector merged
+#                                                 over the mesh: n * 4 * the
+#                                                 all-reduces an iteration *
+#                                                 iters; 0 on one device)
 PAGERANK_STAGE = "pagerank.stage"
 PAGERANK_EMIT = "pagerank.emit"
-PAGERANK_ENGINE = "pagerank.loop"               # cat ENGINE
+PAGERANK_ENGINE = "pagerank.loop"               # cat ENGINE, as cc.loop
 # oink/commands/{tri,luby,sssp}.py
 TRI_STAGE = "tri.stage"                         # n, edges
 TRI_ENGINE = "tri.loop"                         # cat ENGINE: wedges, batches

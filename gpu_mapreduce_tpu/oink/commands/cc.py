@@ -266,7 +266,9 @@ class CCFind(Command):
         # device staging (VERDICT r2 #2): shard the edge KV once, rank
         # vertices ON DEVICE — the O(E) edge columns never reach the
         # controller; only n and the [n] id table do
+        from ...models.cc import PMINS_PER_ROUND
         from ...obs import get_tracer, names
+        from ...parallel.mesh import allreduce_bytes, mesh_axis_size
         from ...parallel.staging import stage_graph, stage_graph_host
         tr = get_tracer()
         with tr.span(names.CC_STAGE, cat=names.HOST) as sp:
@@ -281,7 +283,9 @@ class CCFind(Command):
                 sg = stage_graph_host(mre)
                 nedges = len(sg.src)
             verts, n = sg.verts, sg.n
-            sp.set(n=n, edges=nedges, on_device=int(on_device))
+            shards = mesh_axis_size(mesh) if mesh is not None else 1
+            sp.set(n=n, edges=nedges, on_device=int(on_device),
+                   shards=shards)
         if n == 0:
             self.ncc, self.niterate = 0, 0
             mrv = obj.create_mr()
@@ -306,7 +310,9 @@ class CCFind(Command):
                     labels, iters = cc(sg.src.astype(np.int32),
                                        sg.dst.astype(np.int32), n)
                     labels, iters = np.asarray(labels), int(iters)
-            sp.set(iters=iters)
+            sp.set(iters=iters, shards=shards,
+                   allreduce_bytes=allreduce_bytes(
+                       shards, n, iters * PMINS_PER_ROUND))
 
         with tr.span(names.CC_EMIT, cat=names.HOST, n=n):
             zones = verts[labels]           # min vertex id per component

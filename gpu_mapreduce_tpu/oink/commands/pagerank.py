@@ -31,8 +31,8 @@ import numpy as np
 from ...core.runtime import MRError
 from ..command import Command, command
 from ..kernels import read_edge, read_edge_weight
-from ...models.pagerank import (pagerank, pagerank_sharded,
-                                pagerank_staged)
+from ...models.pagerank import (PSUMS_PER_ITERATION, pagerank,
+                                pagerank_sharded, pagerank_staged)
 
 
 def _read_edges_sniff(itask, filename, kv, ptr):
@@ -75,6 +75,7 @@ class PageRankCommand(Command):
         # the [n] id table come to the host.  The values (weights, or
         # interned bytes) are not read, so they never decide the path.
         from ...obs import get_tracer, names
+        from ...parallel.mesh import allreduce_bytes, mesh_axis_size
         from ...parallel.staging import stage_graph, stage_graph_host
         tr = get_tracer()
         with tr.span(names.PAGERANK_STAGE, cat=names.HOST) as sp:
@@ -89,7 +90,9 @@ class PageRankCommand(Command):
                 sg = stage_graph_host(mre)
                 nedges = len(sg.src)
             verts, n = sg.verts, sg.n
-            sp.set(n=n, edges=nedges, on_device=int(on_device))
+            shards = mesh_axis_size(mesh) if mesh is not None else 1
+            sp.set(n=n, edges=nedges, on_device=int(on_device),
+                   shards=shards)
             if n == 0:
                 raise MRError("pagerank: empty edge list")
 
@@ -108,7 +111,9 @@ class PageRankCommand(Command):
             else:
                 ranks, iters = pagerank(sg.src, sg.dst, n, **params)
                 ranks, iters = np.asarray(ranks), int(iters)
-            sp.set(iters=iters)
+            sp.set(iters=iters, shards=shards,
+                   allreduce_bytes=allreduce_bytes(
+                       shards, n, iters * PSUMS_PER_ITERATION))
 
         with tr.span(names.PAGERANK_EMIT, cat=names.HOST, n=n):
             self.ranks = {int(v): float(r) for v, r in zip(verts, ranks)}

@@ -58,6 +58,15 @@ def mesh_axis_size(mesh: Mesh) -> int:
     return n
 
 
+def allreduce_bytes(shards: int, n: int, count: int) -> int:
+    """Bytes of a replicated ``[n]`` vector of 4-byte elements (int32
+    labels, float32 ranks) that ``count`` all-reduces (``psum`` /
+    ``pmin``) merge over a mesh of ``shards`` devices: what a fused loop's
+    spans record as ``allreduce_bytes``.  0 on one device (or without a
+    mesh: ``shards`` 1), where nothing is merged."""
+    return int(n) * 4 * int(count) if shards > 1 else 0
+
+
 def row_spec(mesh: Mesh) -> PartitionSpec:
     """PartitionSpec sharding dim 0 over ALL mesh axes (flat proc id =
     row-major (slice, chip) index)."""

@@ -428,6 +428,40 @@ def test_stage_spans_say_where_the_ranking_ran(nprocs, traced, tmp_path):
         assert len(scans) == (0 if nprocs else 1), (stage, cmd)
 
 
+@pytest.mark.parametrize("nprocs", [1, 4, None],
+                         ids=["mesh1", "mesh4", "serial"])
+def test_loop_spans_say_what_the_mesh_merged(nprocs, traced, tmp_path):
+    """``cc.loop`` and ``pagerank.loop`` carry ``shards`` (the mesh's size,
+    1 without a mesh) and ``allreduce_bytes`` beside ``iters``, ``n`` and
+    ``edges`` (ISSUE 46): the replicated [n] vector merged over the mesh,
+    ``n`` * 4 * one all-reduce a round * ``iters``, and 0 on one device,
+    where nothing is merged; the stage spans carry ``shards`` too."""
+    from gpu_mapreduce_tpu.models.cc import PMINS_PER_ROUND
+    from gpu_mapreduce_tpu.models.pagerank import PSUMS_PER_ITERATION
+    from gpu_mapreduce_tpu.oink.script import OinkScript
+    from gpu_mapreduce_tpu.parallel.mesh import allreduce_bytes, make_mesh
+    mesh = make_mesh(nprocs) if nprocs else None
+    s = OinkScript(comm=mesh, screen=io.StringIO())
+    for line in ("rmat 7 8 0.57 0.19 0.19 0.05 0.0 1 -o NULL mre",
+                 "edge_upper -i mre -o NULL mru",
+                 "cc_find 0 -i mru -o NULL NULL",
+                 f"pagerank 1e-6 100 0.85 -i mre -o {tmp_path}/pr NULL"):
+        s.run_string(line)
+    args = {e["name"]: e["args"] for e in traced.events()}
+    shards = nprocs or 1
+    for stage in (names.CC_STAGE, names.PAGERANK_STAGE):
+        assert args[stage]["shards"] == shards, stage
+    assert (PMINS_PER_ROUND, PSUMS_PER_ITERATION) == (1, 1)
+    for loop in (names.CC_ENGINE, names.PAGERANK_ENGINE):
+        a = args[loop]
+        assert a["shards"] == shards and a["iters"] >= 1 and a["n"] > 0
+        want = a["n"] * 4 * a["iters"] if shards > 1 else 0
+        assert a["allreduce_bytes"] == want, loop
+    # the helper by itself: bytes of ``count`` merges of an [n] vector
+    assert allreduce_bytes(1, 10, 3) == 0
+    assert allreduce_bytes(4, 10, 3) == 120
+
+
 def test_enumeration_commands_emit_their_spans(mesh, traced, tmp_path):
     """``tri_find``, ``luby_find`` and ``sssp`` (ISSUE 32): stage, engine
     and emit spans under their commands, the counts on the engine spans
