@@ -26,6 +26,8 @@ from __future__ import annotations
 
 import collections.abc
 import contextlib
+import functools
+import re
 import threading
 from typing import Dict, List, Optional, Sequence, Union
 
@@ -315,6 +317,88 @@ def _gather_ranges(buf: np.ndarray, starts: np.ndarray, lens: np.ndarray):
         return native.gather_ranges(buf, starts, lens, total), offs
     at = np.repeat(starts - offs[:-1], lens) + np.arange(total)
     return buf[at], offs
+
+
+FORMAT_BLOCK = 65536    # rows a block: format_rows' callers', its Python form's
+
+
+@functools.lru_cache(maxsize=None)
+def row_fields(template: str) -> tuple:
+    """A row template's fields: ``%d``, ``%g`` or ``%.Ng``, single
+    spaces between (``"%d %.8g"``).  One entry a field: -1 for ``%d``,
+    else the ``g`` precision (6 for a bare ``%g``)."""
+    fields = []
+    for f in template.split(" "):
+        m = re.fullmatch(r"%(?:(d)|(?:\.(\d{1,2}))?g)", f)
+        if m is None:
+            raise ValueError(f"row template {template!r}: field {f!r} is "
+                             f"not %d, %g or %.Ng")
+        fields.append(-1 if m.group(1) else int(m.group(2) or 6))
+    return tuple(fields)
+
+
+def row_columns(template: str, arrays) -> Optional[list]:
+    """One contiguous column a field of ``template`` out of ``arrays``
+    (each ``[n]``, or ``[n, w]`` for ``w`` fields in a row), or None
+    where they are not what the template names: as many columns as
+    fields, integers (as u64 / i64) under ``%d``, floats (as f64, a
+    float32 widened as ``float()`` widens it) under ``%g``.  What
+    :func:`format_rows` takes."""
+    spread = [a if a.ndim == 1 else a[:, j] for a in arrays
+              for j in range(1 if a.ndim == 1 else a.shape[1])]
+    fields = row_fields(template)
+    if len(spread) != len(fields):
+        return None
+    cols = []
+    for a, prec in zip(spread, fields):
+        kind = a.dtype.kind
+        if prec < 0 and kind in "ui":
+            dtype = np.uint64 if kind == "u" else np.int64
+        elif prec >= 0 and kind == "f" and a.dtype.itemsize <= 8:
+            dtype = np.float64
+        else:
+            return None
+        cols.append(np.ascontiguousarray(a, dtype))
+    return cols
+
+
+def format_rows(template: str, cols: list, start: int = 0,
+                stop: Optional[int] = None) -> np.ndarray:
+    """Rows ``[start, stop)`` of :func:`row_columns`' columns as the u8
+    bytes of their text lines, one line a row: byte for byte
+    ``"".join((template + "\\n") % row for row in zip(*cols))``, with no
+    Python object made for a row.  Natively where the library has the
+    formatter (``native.has_format_rows``; the call drops the GIL, so
+    blocks run side by side on a thread pool), else ``%`` over a
+    template repeated ``FORMAT_BLOCK`` rows at a time."""
+    from .. import native
+    stop = len(cols[0]) if stop is None else stop
+    if native.has_format_rows():
+        return native.format_rows(row_fields(template), cols, start, stop)
+    nf, line = len(cols), template + "\n"
+    out = []
+    for s in range(start, stop, FORMAT_BLOCK):
+        e = min(s + FORMAT_BLOCK, stop)
+        flat = [None] * ((e - s) * nf)
+        for f, c in enumerate(cols):
+            flat[f::nf] = c[s:e].tolist()
+        out.append((line * (e - s) % tuple(flat)).encode("ascii"))
+    return np.frombuffer(b"".join(out), np.uint8)
+
+
+def write_rows(fp, template: str, cols: list, pool=None) -> None:
+    """The lines of :func:`row_columns`' columns onto the binary file
+    ``fp``: blocks of ``FORMAT_BLOCK`` rows formatted on ``pool`` (in the
+    calling thread without one) and written in order, at most 64 blocks
+    formatted ahead of the write."""
+    n = len(cols[0])
+    run = pool.map if pool is not None and n > FORMAT_BLOCK else map
+    wave = 64 * FORMAT_BLOCK    # blocks in flight: what memory may hold
+    for lo in range(0, n, wave):
+        for block in run(lambda s: format_rows(template, cols, s,
+                                               min(s + FORMAT_BLOCK, n)),
+                         range(lo, min(lo + wave, n), FORMAT_BLOCK)):
+            fp.write(block)
 
 
 def _differ_ranges(a: np.ndarray, astarts: np.ndarray, b: np.ndarray,

@@ -115,6 +115,11 @@ def _load() -> Optional[ctypes.CDLL]:
     lib.mr_find_hrefs.argtypes = [u8p, i64, p(i64), p(i64), i64]
     lib.mr_tokenize.restype = i64
     lib.mr_tokenize.argtypes = [u8p, i64, p(i64), p(i64), i64]
+    if hasattr(lib, "mr_format_rows"):  # g++ 11 or newer: see the source
+        i32 = ctypes.c_int32
+        lib.mr_format_rows.restype = i64
+        lib.mr_format_rows.argtypes = [i32, p(i32), p(i32),
+                                       p(ctypes.c_void_p), i64, u8p, i64]
     return lib
 
 
@@ -253,6 +258,40 @@ def differ_ranges(a: np.ndarray, astarts: np.ndarray, b: np.ndarray,
         _arr(a, ctypes.c_uint8), _arr(astarts, ctypes.c_int64),
         _arr(b, ctypes.c_uint8), _arr(bstarts, ctypes.c_int64),
         _arr(lens, ctypes.c_int64), len(lens)))
+
+
+def has_format_rows() -> bool:
+    """Whether :func:`format_rows` is there: the library built, and with
+    a ``<charconv>`` that writes doubles (``std::to_chars`` for floating
+    types: g++ 11 or newer)."""
+    return _lib is not None and hasattr(_lib, "mr_format_rows")
+
+
+_ROW_KINDS = {np.dtype(np.uint64): 0, np.dtype(np.int64): 1,
+              np.dtype(np.float64): 2}
+
+
+def format_rows(precisions, cols, start: int, stop: int) -> np.ndarray:
+    """The text lines of rows ``[start, stop)`` as u8: field f of a row
+    is ``cols[f][row]`` (contiguous u64 / i64 written as ``%d``, f64 as
+    ``%.<precisions[f]>g``), single spaces between, a newline after.
+    ctypes drops the GIL for the call, so blocks format side by side on
+    a thread pool.  Callers check :func:`has_format_rows` first; a
+    buffer under the library's own bound raises."""
+    nf, n = len(cols), stop - start
+    kinds = np.array([_ROW_KINDS[c.dtype] for c in cols], np.int32)
+    precs = np.asarray(precisions, np.int32)
+    ptrs = (ctypes.c_void_p * nf)(
+        *[c.ctypes.data + start * 8 for c in cols])
+    args = (nf, _arr(kinds, ctypes.c_int32), _arr(precs, ctypes.c_int32),
+            ptrs, n)
+    cap = _lib.mr_format_rows(*args, None, 0)   # the most n rows can take
+    out = np.empty(cap, np.uint8)
+    nbytes = _lib.mr_format_rows(*args, _arr(out, ctypes.c_uint8), cap)
+    if nbytes < 0:
+        raise RuntimeError(f"mr_format_rows: {cap} bytes are under its "
+                           f"bound for {n} rows")
+    return out[:nbytes]
 
 
 def intern64_batch(buf: bytes, offsets: np.ndarray) -> np.ndarray:

@@ -15,6 +15,10 @@
 #include <cstdint>
 #include <cstring>
 #include <cstdlib>
+#include <cmath>
+#if __has_include(<charconv>)
+#include <charconv>     // defines __cpp_lib_to_chars where doubles are done
+#endif
 
 namespace {
 
@@ -362,5 +366,51 @@ int64_t mr_find_hrefs(const uint8_t *buf, int64_t len, int64_t *starts,
   }
   return n <= max ? n : -n;
 }
+
+#ifdef __cpp_lib_to_chars
+// n text lines from nf numeric columns (core/column.format_rows): field f
+// of row r is cols[f][r], a u64 (kind 0) or an i64 (kind 1) written as
+// printf's %d, or a double (kind 2) as %.<precs[f]>g; single spaces
+// between the fields, a newline after the last.  Byte for byte what
+// Python's `%` gives (nan and inf spelt its way, no sign on a nan).
+// std::to_chars, not snprintf: glibc's %.8g is slower than the
+// interpreter.  Left out where <charconv> has no floating to_chars
+// (g++ before 11); the loader then keeps the Python formatter.  Returns
+// the bytes written; with no `out`, the bytes n rows can take at most
+// (the `cap` to call again with); -1 when `cap` is under that bound.
+int64_t mr_format_rows(int32_t nf, const int32_t *kinds,
+                       const int32_t *precs, const void *const *cols,
+                       int64_t n, uint8_t *out, int64_t cap) {
+  int64_t rowmax = nf;                    // the spaces and the newline
+  for (int32_t f = 0; f < nf; f++)
+    rowmax += kinds[f] == 2 ? (precs[f] > 0 ? precs[f] : 1) + 8 : 20;
+  if (out == nullptr) return n * rowmax;
+  if (cap < n * rowmax) return -1;
+  char *p = (char *)out, *end = p + cap;
+  for (int64_t r = 0; r < n; r++) {
+    for (int32_t f = 0; f < nf; f++) {
+      if (f) *p++ = ' ';
+      if (kinds[f] == 0) {
+        p = std::to_chars(p, end, ((const uint64_t *)cols[f])[r]).ptr;
+      } else if (kinds[f] == 1) {
+        p = std::to_chars(p, end, ((const int64_t *)cols[f])[r]).ptr;
+      } else {
+        double v = ((const double *)cols[f])[r];
+        if (std::isnan(v)) {
+          memcpy(p, "nan", 3); p += 3;
+        } else if (std::isinf(v)) {
+          if (v < 0) *p++ = '-';
+          memcpy(p, "inf", 3); p += 3;
+        } else {
+          p = std::to_chars(p, end, v, std::chars_format::general,
+                            precs[f]).ptr;
+        }
+      }
+    }
+    *p++ = '\n';
+  }
+  return p - (char *)out;
+}
+#endif
 
 }  // extern "C"

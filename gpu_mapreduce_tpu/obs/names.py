@@ -110,7 +110,14 @@ CONVERT_COUNT_SYNC = "convert.count_sync"       # groups
 RMAT_GENERATE = "rmat.generate"                 # rows, d2h_bytes
 # oink/objects.py
 OINK_INPUT = "oink.input"                       # source, rows, bytes
-OINK_OUTPUT = "oink.output"                     # path, rows, bytes
+OINK_OUTPUT = "oink.output"                     # path, rows, bytes,
+#                                                 block_rows (rows whose
+#                                                 lines were formatted from
+#                                                 columns a block at a time,
+#                                                 core/column.format_rows;
+#                                                 0: a printer call a row),
+#                                                 native (1: the native
+#                                                 formatter wrote them)
 # oink/commands/{cc,pagerank}.py
 CC_STAGE = "cc.stage"                           # n, edges, on_device (1:
 #                                                 ranked by stage_graph, 0: on
@@ -144,7 +151,10 @@ LUBY_EMIT = "luby.emit"                         # n
 SSSP_STAGE = "sssp.stage"                       # n, edges
 SSSP_ENGINE = "sssp.loop"                       # cat ENGINE: iters, source,
 #                                                 labeled, n
-SSSP_EMIT = "sssp.emit"                         # n, source
+SSSP_EMIT = "sssp.emit"                         # n, source, rows,
+#                                                 block_rows, native (as
+#                                                 oink.output; 0 rows as
+#                                                 blocks with no -o path)
 # apps/invertedindex.py
 MAP_PLAN = "map.plan"                           # files, bytes, rounds
 MAP_PAD = "map.pad"                             # bytes, shard_bytes

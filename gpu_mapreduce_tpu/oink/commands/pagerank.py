@@ -21,16 +21,20 @@ the device staging's sentinel: on a mesh it is refused by name.
 Script syntax (reference ``PageRank::params``): ``pagerank tol maxiter
 alpha``.  Edge weights are accepted in the input ('vi vj [wt]') for
 script parity but rank follows link structure only (classic PageRank).
-Output: 'v rank' per vertex; self.ranks = {v: rank}.
+Output: 'v rank' per vertex (``print_vertex_rank``, ``%d %.8g``: a
+declared template, so the file is formatted from the two columns);
+self.ranks = {v: rank}, a dict made from those columns when first read.
 """
 
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 
 from ...core.runtime import MRError
 from ..command import Command, command
-from ..kernels import read_edge, read_edge_weight
+from ..kernels import print_vertex_rank, read_edge, read_edge_weight
 from ...models.pagerank import (PSUMS_PER_ITERATION, pagerank,
                                 pagerank_sharded, pagerank_staged)
 
@@ -63,6 +67,11 @@ class PageRankCommand(Command):
         self.tolerance = float(args[0])
         self.maxiter = int(args[1])
         self.alpha = float(args[2])
+
+    @functools.cached_property
+    def ranks(self) -> dict:
+        """{vertex: rank} of the last run, made when first read."""
+        return dict(zip(self._verts.tolist(), self._ranks.tolist()))
 
     def run(self):
         obj = self.obj
@@ -116,13 +125,13 @@ class PageRankCommand(Command):
                        shards, n, iters * PSUMS_PER_ITERATION))
 
         with tr.span(names.PAGERANK_EMIT, cat=names.HOST, n=n):
-            self.ranks = {int(v): float(r) for v, r in zip(verts, ranks)}
+            self._verts, self._ranks = verts, ranks.astype(np.float64)
+            vars(self).pop("ranks", None)
             self.niterate = iters
             self.nvert = n
             mrr = obj.create_mr()
-            mrr.map(1, lambda i, kv, p: kv.add_batch(
-                verts, ranks.astype(np.float64)))
-        obj.output(1, mrr, lambda k, v, fp: fp.write(f"{k} {v:.8g}\n"))
+            mrr.map(1, lambda i, kv, p: kv.add_batch(verts, self._ranks))
+        obj.output(1, mrr, print_vertex_rank)
         self.message(f"PageRank: {n} vertices, {nedges} edges, "
                      f"{iters} iterations")
         obj.cleanup()

@@ -40,15 +40,20 @@ TPU-first redesigns vs the reference:
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
+from ...core.column import row_columns, write_rows
 from ...core.runtime import MRError
 from ..command import Command, command
+from ..objects import block_attrs
 from ..kernels import (cull, edge_to_vertices, group_min_rows, host_kmv,
                        kmv_keys, kmv_values, kv_keys, kv_values,
                        read_edge_weight, seg_ids)
 from .luby import vertex_rand
 
+RESULT_LINE = "%d %g %d"         # 'v dist pred', a line a vertex
 TAG_EDGE, TAG_DIST = 0.0, 1.0
 NO_PRED = -1.0                   # see module docstring: sentinel, not id 0
 
@@ -267,7 +272,9 @@ class SSSPCommand(Command):
     """sssp ncnt seed: shortest paths from ncnt deterministic-random
     sources over a directed weighted edge list (oink/sssp.cpp).  Output
     per source: 'v dist pred' lines (path suffixed .<i> when ncnt > 1);
-    self.results[source] = {v: (dist, pred)}.
+    self.results[source] = {v: (dist, pred)} (the fused engine's made
+    from its arrays when first read, its file formatted from them:
+    ``%d %g %d`` through ``core/column.format_rows``).
 
     Engines (same contract — any pred realising the shortest distance):
     ``fused`` (default) — whole Bellman-Ford relaxation in one jitted
@@ -286,6 +293,15 @@ class SSSPCommand(Command):
             raise MRError("Illegal sssp command")
         self.ncnt = int(args[0])
         self.seed = int(args[1])
+
+    @functools.cached_property
+    def results(self) -> dict:
+        """{source: {v: (dist, pred)}} of the fused engine's last run,
+        made from its arrays when first read (the composed engine sets
+        the dict it fills)."""
+        return {source: dict(zip(verts.tolist(),
+                                 zip(dist.tolist(), predv.tolist())))
+                for source, (verts, dist, predv) in self._arrays.items()}
 
     def run(self):
         if self.engine not in ("fused", "composed"):
@@ -338,7 +354,8 @@ class SSSPCommand(Command):
             sources = verts[order][:self.ncnt].tolist()
             sp.set(n=n, edges=int(mredge.kv.nkv))
 
-        self.results = {}
+        self._arrays = {}
+        vars(self).pop("results", None)
         self.niters = {}
         outd = obj.outputs[0] if obj.outputs else None
         dist = np.full(n, np.inf)
@@ -352,24 +369,27 @@ class SSSPCommand(Command):
                 sp.set(iters=niter, source=int(source), labeled=nlabeled,
                        n=n)
             with tr.span(names.SSSP_EMIT, cat=names.HOST, n=n,
-                         source=int(source)):
+                         source=int(source)) as sp:
                 # dict/file view: -1 (source/unreachable) renders as 0 like
                 # the composed output path (np.maximum(..., 0))
                 predv = np.where(pred >= 0, verts[np.maximum(pred, 0)],
                                  np.uint64(0))
-                res = {int(v): (float(d), int(p))
-                       for v, d, p in zip(verts, dist, predv)}
-                self.results[source] = res
+                self._arrays[source] = (verts, dist, predv)
                 self.niters[source] = niter
                 self.message(f"SSSP: source {source}: {niter} iterations, "
                              f"{nlabeled} vertices labeled")
+                block_rows = 0
                 if outd is not None and outd.path is not None:
                     path = (f"{outd.path}.{cnt}" if self.ncnt > 1
                             else outd.path)
-                    with open(path, "w") as fp:
-                        for v in sorted(res):
-                            d, p = res[v]
-                            fp.write(f"{v} {d:g} {p}\n")
+                    # verts is ascending (np.unique's, the ranking's):
+                    # the order of the composed engine's sorted(res)
+                    cols = row_columns(RESULT_LINE, (verts, dist, predv))
+                    with open(path, "wb") as fp:
+                        write_rows(fp, RESULT_LINE, cols,
+                                   mredge._ingest_pool())
+                    block_rows = n
+                sp.set(rows=n, **block_attrs(block_rows))
         if outd is not None and outd.mr_name is not None:
             # named-MR rows keep the composed engine's persisted shape:
             # [TAG_DIST, pred (original id, NO_PRED sentinel intact),

@@ -19,8 +19,8 @@ import numpy as np
 from ...core.runtime import MRError
 from ..command import Command, command
 from ..kernels import (_parse_cols, edge_both_directions, host_kmv, kmv_keys,
-                       kmv_values, kv_keys, kv_values, read_edge, seg_ids,
-                       sum_values)
+                       kmv_values, kv_keys, kv_values, read_edge,
+                       row_template, seg_ids, sum_values)
 
 
 import jax
@@ -195,6 +195,7 @@ def emit_triangles(fr, kv, ptr):
                  np.zeros(len(center), np.uint8))
 
 
+@row_template("%d %d %d", key_fields=3)
 def print_tri(k, v, fp):
     fp.write(f"{k[0]} {k[1]} {k[2]}\n")
 

@@ -322,17 +322,40 @@ HASH_KERNELS = {
 }
 
 
+def row_template(template: str, key_fields: int = 1):
+    """Declare a printer's line: ``template`` (``core/column.row_fields``:
+    ``%d``, ``%g``, ``%.Ng``) over the key's ``key_fields`` words, then
+    the value's.  The printer stays the ``printer(k, v, fp)`` callable it
+    was; ``Object.output`` formats a dense KV frame whose columns are of
+    the template's kinds a block at a time from the declaration, and
+    calls the printer a row for everything else (a float under ``%d``
+    keeps its ``repr``)."""
+    def declare(printer):
+        printer.template, printer.key_fields = template, key_fields
+        return printer
+    return declare
+
+
+@row_template("%d %d", key_fields=2)
 def print_edge(k, v, fp):
     fp.write(f"{k[0]} {k[1]}\n")
 
 
+@row_template("%d")
 def print_vertex(k, v, fp):
     fp.write(f"{k}\n")
 
 
+@row_template("%d %d")
 def print_vertex_value(k, v, fp):
     fp.write(f"{k} {v}\n")
 
 
+@row_template("%d %d %d", key_fields=2)
 def print_edge_value(k, v, fp):
     fp.write(f"{k[0]} {k[1]} {v}\n")
+
+
+@row_template("%d %.8g")
+def print_vertex_rank(k, v, fp):
+    fp.write(f"{k} {v:.8g}\n")

@@ -23,6 +23,11 @@ import os
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
+import numpy as np
+
+from .. import native
+from ..core.column import row_columns, row_fields, write_rows
+from ..core.frame import KMVFrame, KVFrame
 from ..core.mapreduce import MapReduce
 from ..core.runtime import MRError
 from ..obs import get_tracer, names
@@ -198,7 +203,23 @@ class ObjectManager:
         output never funnels the dataset through the controller.  Host
         datasets (and P==1) keep the exact single path: our serial tier
         intentionally omits the reference's ``.0`` suffix so script
-        goldens address one file."""
+        goldens address one file.
+
+        Lines come from columns where the output can see that they may
+        (:func:`_block_columns`): the printer declares its line
+        (``kernels.row_template``: ``print_edge``, ``print_vertex``,
+        ``print_vertex_value``, ``print_edge_value``,
+        ``print_vertex_rank``, ``tri.print_tri``), the frame is a dense
+        KV frame and its columns are of the kinds the template names.
+        Such a frame becomes text a block at a time
+        (``core/column.write_rows``: the blocks formatted on the MR's
+        ingest pool and written in order; on a mesh one shard after
+        another, as before), and no Python object is made for a row.  Every
+        other frame (byte keys, KMV groups, a float under ``%d``, a
+        printer with no template, no printer) is printed a row at a
+        time, as ever; the files hold the same bytes either way.  The
+        span says how it went: ``block_rows`` of its ``rows`` left as
+        blocks, ``native`` 1 when the native formatter wrote them."""
         mr._flush_plan()   # a pending fused plan must land before we read
         if index > len(self.outputs):
             return
@@ -207,38 +228,81 @@ class ObjectManager:
             with get_tracer().span(names.OINK_OUTPUT, cat=names.HOST,
                                    path=d.path) as sp:
                 _ensure_parent(d.path)
-                nbytes = 0
+                pool = mr._ingest_pool()
                 fr = _mesh_frame(mr)
                 if fr is not None and fr.nprocs > 1:
-                    for p in range(fr.nprocs):
-                        if "%" in d.path:
-                            path = d.path.replace("%", str(p), 1)
-                        else:
-                            path = f"{d.path}.{p}"
-                        host = fr.shard_to_host(p)
+                    if "%" in d.path:
+                        paths = [d.path.replace("%", str(p), 1)
+                                 for p in range(fr.nprocs)]
+                    else:
+                        paths = [f"{d.path}.{p}" for p in range(fr.nprocs)]
+                    block_rows = 0
+                    for p, path in enumerate(paths):    # a shard at a time
                         with open(path, "w") as fp:
-                            rows = (host.pairs() if hasattr(host, "pairs")
-                                    else host.groups())
-                            if printer is None:
-                                for k, v in rows:
-                                    fp.write(f"{k} {v}\n")
-                            else:
-                                for k, v in rows:
-                                    printer(k, v, fp)
-                        nbytes += os.path.getsize(path)
+                            block_rows += _write_frame(
+                                fp, fr.shard_to_host(p), printer, pool)
                 else:
+                    paths, block_rows = [d.path], 0
                     with open(d.path, "w") as fp:
-                        if printer is None:
-                            mr_dump(mr, fp)
-                        else:
-                            for k, v in _iter_pairs(mr):
-                                printer(k, v, fp)
-                    nbytes = os.path.getsize(d.path)
+                        ds = mr.kv if mr.kv is not None else mr.kmv
+                        for f in (ds.frames() if ds is not None else ()):
+                            block_rows += _write_frame(fp, f, printer, pool)
                 sp.set(rows=(mr.kv.nkv if mr.kv is not None
                              else mr.kmv.nkmv if mr.kmv is not None else 0),
-                       bytes=nbytes)
+                       bytes=sum(os.path.getsize(p) for p in paths),
+                       **block_attrs(block_rows))
         if d.mr_name is not None:
             self.name_mr(d.mr_name, mr)
+
+
+def block_attrs(block_rows: int) -> dict:
+    """What a span that writes text says of how: ``block_rows``, the rows
+    formatted from columns a block at a time, and ``native``, 1 when the
+    native formatter wrote them."""
+    return dict(block_rows=block_rows,
+                native=int(block_rows > 0 and native.has_format_rows()))
+
+
+def _block_columns(printer, fr) -> Optional[list]:
+    """The columns of a host frame's lines, one a field of the printer's
+    template, where the lines may be formatted from them: the printer
+    declares a template, the frame is a dense KV frame, the key holds
+    the template's first ``key_fields`` fields and the value the rest
+    (none: the printer does not print it), a part of one field is
+    ``[n]`` and one of several ``[n, w]`` (a row of ``[n, 1]`` prints as
+    a tuple), and the kinds are the template's.  Else None."""
+    template = getattr(printer, "template", None)
+    if template is None or not isinstance(fr, KVFrame) or not fr.is_dense():
+        return None
+    nkey = printer.key_fields
+    nvalue = len(row_fields(template)) - nkey
+    parts = [(np.asarray(fr.key.data), nkey)]
+    if nvalue:
+        parts.append((np.asarray(fr.value.data), nvalue))
+    if any(a.shape[1:] != ((w,) if w > 1 else ()) for a, w in parts):
+        return None
+    return row_columns(template, [a for a, _ in parts])
+
+
+def _write_frame(fp, fr, printer, pool) -> int:
+    """One frame's lines onto the text file ``fp``, from its columns
+    where :func:`_block_columns` gives them, else a printer call a row;
+    returns the rows that left as blocks."""
+    if not isinstance(fr, (KVFrame, KMVFrame)):
+        fr = fr.to_host()
+    cols = _block_columns(printer, fr)
+    if cols is not None:
+        fp.flush()      # what the text layer holds goes first
+        write_rows(fp.buffer, printer.template, cols, pool)
+        return len(fr)
+    rows = fr.pairs() if isinstance(fr, KVFrame) else fr.groups()
+    if printer is None:
+        for k, v in rows:
+            fp.write(f"{k} {v}\n")
+    else:
+        for k, v in rows:
+            printer(k, v, fp)
+    return 0
 
 
 def _ensure_parent(path: str) -> None:
@@ -262,19 +326,3 @@ def _mesh_frame(mr: MapReduce):
         return None
     fr = next(iter(ds.frames()))
     return fr if isinstance(fr, (ShardedKV, ShardedKMV)) else None
-
-
-def _iter_pairs(mr: MapReduce):
-    """Yield (key, value) per KV pair, or (key, [values]) per KMV group when
-    the MR holds a KMV (e.g. neighbor's adjacency lists)."""
-    if mr.kv is not None:
-        for fr in mr.kv.frames():
-            yield from fr.pairs()
-    elif mr.kmv is not None:
-        for fr in mr.kmv.frames():
-            yield from fr.groups()
-
-
-def mr_dump(mr: MapReduce, fp):
-    for k, v in _iter_pairs(mr):
-        fp.write(f"{k} {v}\n")

@@ -41,3 +41,23 @@ def library(request, monkeypatch):
     elif not native.available():
         pytest.skip(f"no native library: {native.build_error()}")
     return request.param
+
+
+@pytest.fixture
+def traced():
+    """``traced(run)``: run() with the obs tracer on; returns (its
+    result, the spans it left)."""
+    from gpu_mapreduce_tpu.obs import get_tracer
+
+    def call(run):
+        tr = get_tracer()
+        was = tr.enabled
+        tr.enable(ring=1 << 14)
+        tr.clear()
+        try:
+            return run(), tr.events()
+        finally:
+            tr.clear()
+            if not was:
+                tr.disable()
+    return call

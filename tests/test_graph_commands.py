@@ -670,3 +670,96 @@ def test_luby_self_loop_only_mesh(tmp_path):
                       screen=False)
     assert (cmd.nset, cmd.niterate) == (0, 0)
     assert ToHostStats.delta(snap) == (0, 0)
+
+
+# ---------------------------------------------------------------------------
+# text output from columns (Object.output's block path, sssp's own files)
+
+def _files(prefix) -> dict:
+    import glob
+    return {f[len(str(prefix)):]: open(f, "rb").read()
+            for f in sorted(glob.glob(str(prefix) + "*"))}
+
+
+def _comm(nprocs):
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    return make_mesh(nprocs) if nprocs else None
+
+
+@pytest.mark.parametrize("nprocs", [0, 1, 4], ids=["serial", "mesh1", "mesh4"])
+@pytest.mark.parametrize("command,params", [
+    ("cc_find", ["0"]), ("pagerank", ["1e-9", "200", "0.85"]),
+    ("luby_find", ["7"]), ("tri_find", [])])
+def test_output_from_columns_is_the_printers_bytes(
+        graph_file, tmp_path, monkeypatch, traced, command, params, nprocs):
+    """A command whose printer declares a template writes its file(s)
+    from columns, byte for byte what the printer writes a row at a time
+    (the loop every other output still takes)."""
+    from gpu_mapreduce_tpu import native
+    from gpu_mapreduce_tpu.oink import objects
+
+    path, _e = graph_file
+
+    def run(out):
+        run_command(command, params, obj=ObjectManager(comm=_comm(nprocs)),
+                    inputs=[path], outputs=[str(out)], screen=False)
+
+    _, spans = traced(lambda: run(tmp_path / "block"))
+    monkeypatch.setattr(objects, "_block_columns", lambda printer, fr: None)
+    _, rowspans = traced(lambda: run(tmp_path / "rows"))
+    got, want = _files(tmp_path / "block"), _files(tmp_path / "rows")
+    assert got == want and got and all(got.values())
+    (sp,) = [e for e in spans if e["name"] == "oink.output"]
+    assert sp["args"]["rows"] > 0
+    assert sp["args"]["block_rows"] == sp["args"]["rows"]
+    assert sp["args"]["native"] == int(native.has_format_rows())
+    assert sp["args"]["bytes"] == sum(map(len, got.values()))
+    (sp,) = [e for e in rowspans if e["name"] == "oink.output"]
+    assert (sp["args"]["block_rows"], sp["args"]["native"]) == (0, 0)
+
+
+@pytest.mark.parametrize("nprocs", [0, 4], ids=["serial", "mesh4"])
+def test_sssp_files_from_columns_and_results_when_read(
+        weighted_graph_file, tmp_path, traced, nprocs):
+    path, _ew = weighted_graph_file
+    out = tmp_path / "sssp"
+    cmd, spans = traced(lambda: run_command(
+        "sssp", ["2", "17"], obj=ObjectManager(comm=_comm(nprocs)),
+        inputs=[path], outputs=[str(out)], screen=False))
+    emits = [e["args"] for e in spans if e["name"] == "sssp.emit"]
+    assert len(emits) == 2
+    assert all(a["block_rows"] == a["rows"] == a["n"] > 0 for a in emits)
+    # nobody has read a result yet: no dict was made
+    assert "results" not in vars(cmd)
+    assert cmd.results is vars(cmd)["results"] and len(cmd.results) == 2
+    for cnt, (source, res) in enumerate(cmd.results.items()):
+        # the file is the dict's rows in vertex order, as the composed
+        # engine writes it
+        want = "".join(f"{v} {d:g} {p}\n" for v, (d, p) in sorted(res.items()))
+        assert (tmp_path / f"sssp.{cnt}").read_text() == want
+        assert all(type(v) is int and type(d) is float and type(p) is int
+                   for v, (d, p) in res.items())
+
+
+def test_sssp_without_a_path_formats_nothing(weighted_graph_file, traced):
+    path, _ew = weighted_graph_file
+    cmd, spans = traced(lambda: run_command(
+        "sssp", ["1", "17"], inputs=[path], screen=False))
+    (emit,) = [e["args"] for e in spans if e["name"] == "sssp.emit"]
+    assert emit["rows"] > 0 and (emit["block_rows"], emit["native"]) == (0, 0)
+    assert "results" not in vars(cmd)
+
+
+def test_pagerank_ranks_are_made_when_read(weighted_graph_file, tmp_path):
+    path, _ew = weighted_graph_file
+    out = tmp_path / "pr.out"
+    cmd = run_command("pagerank", ["1e-9", "200", "0.85"], inputs=[path],
+                      outputs=[str(out)], screen=False)
+    assert "ranks" not in vars(cmd)             # nobody asked yet
+    ranks = cmd.ranks
+    assert vars(cmd)["ranks"] is ranks is cmd.ranks
+    assert len(ranks) == cmd.nvert
+    assert all(type(v) is int and type(r) is float for v, r in ranks.items())
+    # the file is the dict: same vertices, the same floats at %.8g
+    assert out.read_text() == "".join(f"{v} {r:.8g}\n"
+                                      for v, r in ranks.items())

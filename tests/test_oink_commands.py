@@ -226,3 +226,84 @@ def test_generate_unique_helper():
     # deterministic under the same seed
     edges2, _ = generate_unique(3, 5, 2)
     np.testing.assert_array_equal(edges, edges2)
+
+
+# ---------------------------------------------------------------------------
+# Object.output: blocks where the printer declares its line and the columns
+# are of its kinds, a printer call a row everywhere else
+
+def _output_spans(traced, run) -> list:
+    return [e["args"] for e in traced(run)[1] if e["name"] == "oink.output"]
+
+
+@pytest.mark.parametrize("command,params", [
+    ("degree", ["0"]), ("edge_upper", []), ("vertex_extract", [])])
+def test_mesh_shard_files_from_columns_are_the_printers_bytes(
+        edge_file, tmp_path, monkeypatch, traced, command, params):
+    """On a mesh of four every shard's path.<p> is formatted from its own
+    block (a shard after another) and holds what the per-row loop writes."""
+    from gpu_mapreduce_tpu.oink import objects
+    from gpu_mapreduce_tpu.parallel.mesh import make_mesh
+    path, _e = edge_file
+
+    def run(out):
+        run_command(command, params, obj=ObjectManager(comm=make_mesh(4)),
+                    inputs=[path], outputs=[str(out)], screen=False)
+
+    (sp,) = _output_spans(traced, lambda: run(tmp_path / "block"))
+    monkeypatch.setattr(objects, "_block_columns", lambda printer, fr: None)
+    (rowsp,) = _output_spans(traced, lambda: run(tmp_path / "rows"))
+    for p in range(4):
+        got = (tmp_path / f"block.{p}").read_bytes()
+        assert got == (tmp_path / f"rows.{p}").read_bytes()
+    assert sp["block_rows"] == sp["rows"] > 0
+    assert rowsp["block_rows"] == 0 and rowsp["rows"] == sp["rows"]
+    assert sp["bytes"] == rowsp["bytes"] > 0
+
+
+def test_byte_keys_groups_and_plain_callbacks_print_a_row_at_a_time(
+        edge_file, tmp_path, traced):
+    path, e = edge_file
+    words = tmp_path / "words.txt"
+    words.write_text("pear fig pear plum fig pear\n")
+    obj = ObjectManager()
+    mr = obj.create_mr()
+    mr.map(1, lambda i, kv, p: kv.add_batch(
+        np.arange(5, dtype=np.uint64), np.linspace(0.0, 1.0, 5)))
+    obj.add_output(path=str(tmp_path / "cb.out"))
+
+    spans = _output_spans(traced, lambda: [
+        run_command("wordfreq", ["2"], inputs=[str(words)],
+                    outputs=[str(tmp_path / "wf.out")], screen=False),
+        run_command("neighbor", [], inputs=[path],
+                    outputs=[str(tmp_path / "nb.out")], screen=False),
+        obj.output(1, mr, lambda k, v, fp: fp.write(f"{k}:{v}\n")),
+        obj.output(1, mr)])
+    assert len(spans) == 4
+    assert all(sp["rows"] > 0 and (sp["block_rows"], sp["native"]) == (0, 0)
+               for sp in spans)
+    assert sorted((tmp_path / "wf.out").read_text().split("\n")[:-1]) \
+        == ["fig 2", "pear 3", "plum 1"]
+    assert (tmp_path / "cb.out").read_text() \
+        == "0 0.0\n1 0.25\n2 0.5\n3 0.75\n4 1.0\n"
+
+
+def test_frames_of_both_kinds_share_one_file(tmp_path, traced):
+    """The decision is a frame's: integer rows leave as a block, float
+    rows beside them by their repr, in the dataset's order."""
+    from gpu_mapreduce_tpu.oink.kernels import print_vertex_value
+    obj = ObjectManager()
+    mr = obj.create_mr()
+    k = np.arange(3, dtype=np.uint64)
+    mr.map(1, lambda i, kv, p: kv.add_batch(k, np.array([0.5, 1.5, 2.0])))
+    mr.map(1, lambda i, kv, p: kv.add_batch(k + 10, k * 7), addflag=1)
+    mr.map(1, lambda i, kv, p: kv.add_batch(k + 20, np.array([1e-7] * 3)),
+           addflag=1)
+    if mr.kv.nframes != 3:
+        pytest.skip("the maps' batches were merged into one frame")
+    obj.add_output(path=str(tmp_path / "mixed.out"))
+    (sp,) = _output_spans(traced, lambda: obj.output(1, mr, print_vertex_value))
+    assert (sp["rows"], sp["block_rows"]) == (9, 3)
+    assert (tmp_path / "mixed.out").read_text() == (
+        "0 0.5\n1 1.5\n2 2.0\n10 0\n11 7\n12 14\n"
+        "20 1e-07\n21 1e-07\n22 1e-07\n")
