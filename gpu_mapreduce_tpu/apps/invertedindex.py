@@ -200,30 +200,31 @@ def _extract_core(words, file_starts, *, cap: int, use_pallas: bool,
         # skipped branch saves cap/4 rows x 65-word random gathers — the
         # skip is exact because the nlong==0 regather was a no-op anyway
         # (every lidx == cap scatters with mode="drop").
-        is_long = (lengths < 0) & (starts < nbytes)
-        nlong = jnp.sum(is_long.astype(jnp.int32))
+        with jax.named_scope("long_tail"):
+            is_long = (lengths < 0) & (starts < nbytes)
+            nlong = jnp.sum(is_long.astype(jnp.int32))
 
-        def _regather(ids, alts, lengths):
-            pos = jnp.cumsum(is_long.astype(jnp.int32)) - 1
-            tgt = jnp.where(is_long & (pos < cap_long), pos, cap_long)
-            lidx = jnp.full(cap_long, cap, jnp.int32).at[tgt].set(
-                jnp.arange(cap, dtype=jnp.int32), mode="drop")
-            lst = jnp.where(lidx < cap,
-                            jnp.take(ustarts, jnp.minimum(lidx, cap - 1)),
-                            jnp.int32(nbytes))
-            with jax.named_scope("gather"):
-                lwin = unaligned_words(words, lst, nw)
-                lln = first_byte_pos(lwin, QUOTE)
-                lln = jnp.where(lln >= _W_SHORT * 4, lln, jnp.int32(-1))
-            with jax.named_scope("hash"):
-                lids, lalt = _hash2(lwin, lln)
-            return (ids.at[lidx].set(lids, mode="drop"),
-                    alts.at[lidx].set(lalt, mode="drop"),
-                    lengths.at[lidx].set(lln, mode="drop"))
+            def _regather(ids, alts, lengths):
+                pos = jnp.cumsum(is_long.astype(jnp.int32)) - 1
+                tgt = jnp.where(is_long & (pos < cap_long), pos, cap_long)
+                lidx = jnp.full(cap_long, cap, jnp.int32).at[tgt].set(
+                    jnp.arange(cap, dtype=jnp.int32), mode="drop")
+                lst = jnp.where(lidx < cap,
+                                jnp.take(ustarts, jnp.minimum(lidx, cap - 1)),
+                                jnp.int32(nbytes))
+                with jax.named_scope("gather"):
+                    lwin = unaligned_words(words, lst, nw)
+                    lln = first_byte_pos(lwin, QUOTE)
+                    lln = jnp.where(lln >= _W_SHORT * 4, lln, jnp.int32(-1))
+                with jax.named_scope("hash"):
+                    lids, lalt = _hash2(lwin, lln)
+                return (ids.at[lidx].set(lids, mode="drop"),
+                        alts.at[lidx].set(lalt, mode="drop"),
+                        lengths.at[lidx].set(lln, mode="drop"))
 
-        ids, alts, lengths = lax.cond(
-            nlong > 0, _regather, lambda i, a, l: (i, a, l),
-            ids, alts, lengths)
+            ids, alts, lengths = lax.cond(
+                nlong > 0, _regather, lambda i, a, l: (i, a, l),
+                ids, alts, lengths)
         # nlong returns RAW (callers compare against cap_long): the
         # stats must show the second gather ran even below the
         # wide-retry threshold
@@ -284,11 +285,13 @@ def _extract_mesh_build(mesh, cap: int, use_pallas: bool, interpret: bool,
                                 interpret=interpret, wide=wide,
                                 compact=compact, bs=bs,
                                 page_words=page_words)
-        docs = docs + base[0].astype(jnp.uint32)
-        # ONE [4] stats vector per shard: the cap-retry loop pulls it with
-        # a single device_get instead of four per-array transfers — each
-        # round-trip sits inside the TIMED map stage
-        stats = jnp.stack([nhits, npairs, ncoll, nlong]).astype(jnp.int32)
+        with jax.named_scope("stats"):
+            docs = docs + base[0].astype(jnp.uint32)
+            # ONE [4] stats vector per shard: the cap-retry loop pulls it
+            # with a single device_get instead of four per-array transfers
+            # — each round-trip sits inside the TIMED map stage
+            stats = jnp.stack([nhits, npairs, ncoll,
+                               nlong]).astype(jnp.int32)
         return (ids, alts, docs, ustarts, lengths, stats)
 
     # check_vma=False: pallas_call's out_shape carries no varying-mesh-axes
@@ -387,8 +390,10 @@ def _shard_blocks(arr, P: int):
 def invindex_collision_count(ids, alts, valids):
     """The program of :func:`_mesh_collision_count`, under a name of its
     own (obs/names.INVINDEX_COLLISIONS)."""
-    return _count_collisions(jnp.concatenate(ids), jnp.concatenate(alts),
-                             jnp.concatenate(valids))
+    with jax.named_scope("collisions"):
+        return _count_collisions(jnp.concatenate(ids),
+                                 jnp.concatenate(alts),
+                                 jnp.concatenate(valids))
 
 
 # ONE jitted object for every job, at module level: jit keys its cache by

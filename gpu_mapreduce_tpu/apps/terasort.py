@@ -74,9 +74,10 @@ def _sample_jit(mesh, slots: int):
     @jax.jit
     def terasort_sample(key, count, take):
         def body(k, c, t):
-            at = jnp.arange(slots, dtype=jnp.int64) * c[0] // jnp.maximum(
-                t[0], 1)
-            return jnp.take(k, at, axis=0, mode="clip")
+            with jax.named_scope("sample"):
+                at = jnp.arange(slots, dtype=jnp.int64) * c[0] \
+                    // jnp.maximum(t[0], 1)
+                return jnp.take(k, at, axis=0, mode="clip")
         return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
                              out_specs=spec)(key, count, take)
 

@@ -37,10 +37,14 @@ def _propagate(lab, src, dst, valid, n):
     dropped segment n."""
     with jax.named_scope("segment_min_dst"):
         seg_dst = jnp.where(valid, dst, n)
-        m1 = jax.ops.segment_min(lab[src], seg_dst, num_segments=n + 1)[:n]
+        with jax.named_scope("gather_src"):
+            pulled = lab[src]
+        m1 = jax.ops.segment_min(pulled, seg_dst, num_segments=n + 1)[:n]
     with jax.named_scope("segment_min_src"):
         seg_src = jnp.where(valid, src, n)
-        m2 = jax.ops.segment_min(lab[dst], seg_src, num_segments=n + 1)[:n]
+        with jax.named_scope("gather_dst"):
+            pulled = lab[dst]
+        m2 = jax.ops.segment_min(pulled, seg_src, num_segments=n + 1)[:n]
     with jax.named_scope("pointer_jump"):
         nl = jnp.minimum(lab, jnp.minimum(m1, m2))
         return jnp.minimum(nl, nl[nl])
@@ -83,12 +87,17 @@ def _cc_sharded_fn(mesh: Mesh, n: int, maxiter: int):
 
     @functools.partial(jax.jit, out_shardings=(rep, rep))
     def cc_loop(src_d, dst_d, valid_d):
-        lab0 = jnp.arange(n, dtype=jnp.int32)
+        with jax.named_scope("init"):
+            lab0 = jnp.arange(n, dtype=jnp.int32)
+
+        def round_(lab, s, d, v):
+            nl = _propagate(lab, s, d, v, n)
+            with jax.named_scope("merge"):
+                return lax.pmin(nl, axes)
 
         step = jax.shard_map(
-            lambda lab, s, d, v: lax.pmin(
-                _propagate(lab, s, d, v, n), axes),
-            mesh=mesh, in_specs=(P(), rspec, rspec, rspec), out_specs=P())
+            round_, mesh=mesh, in_specs=(P(), rspec, rspec, rspec),
+            out_specs=P())
 
         def cond(state):
             _, changed, it = state
@@ -97,7 +106,8 @@ def _cc_sharded_fn(mesh: Mesh, n: int, maxiter: int):
         def body(state):
             lab, _, it = state
             nl = step(lab, src_d, dst_d, valid_d)
-            return nl, jnp.any(nl != lab), it + 1
+            with jax.named_scope("changed"):
+                return nl, jnp.any(nl != lab), it + 1
 
         return lax.while_loop(
             cond, body, (lab0, jnp.bool_(n > 0), jnp.int32(0)))[::2]

@@ -65,28 +65,38 @@ def _orient(src, dst, valid, prio, n):
 def _count(flag, lo, at, axes):
     """Per vertex: how many of the neighbours that beat it carry ``flag``,
     as a prefix sum over the rows read at the runs' bounds."""
-    c = jnp.cumsum(flag[lo], dtype=jnp.int32)
-    below = jnp.where(at > 0, c[jnp.maximum(at - 1, 0)], 0)
-    k = below[1:] - below[:-1]
-    return k if axes is None else lax.psum(k, axes)
+    with jax.named_scope("gather"):
+        beaten = flag[lo]
+    with jax.named_scope("prefix"):
+        c = jnp.cumsum(beaten, dtype=jnp.int32)
+        below = jnp.where(at > 0, c[jnp.maximum(at - 1, 0)], 0)
+        k = below[1:] - below[:-1]
+    if axes is None:
+        return k
+    with jax.named_scope("merge"):
+        return lax.psum(k, axes)
 
 
 def _round(state, lo, at, axes):
-    und = state == 0
-    winner = und & (_count(und, lo, at, axes) == 0)
-    lose = und & (_count(winner, lo, at, axes) > 0)
-    return jnp.where(winner, 1, jnp.where(lose, 2, state)).astype(jnp.int8)
+    with jax.named_scope("select"):
+        und = state == 0
+        winner = und & (_count(und, lo, at, axes) == 0)
+        lose = und & (_count(winner, lo, at, axes) > 0)
+        return jnp.where(winner, 1,
+                         jnp.where(lose, 2, state)).astype(jnp.int8)
 
 
 def _mis(src, dst, valid, prio, n, maxiter, axes=None):
     """The whole command on one shard's rows: orient, then rounds until
     no vertex is undecided."""
-    lo, at = _orient(src, dst, valid, prio, n)
-    state0 = jnp.zeros(n, jnp.int8)
+    with jax.named_scope("orient"):
+        lo, at = _orient(src, dst, valid, prio, n)
+        state0 = jnp.zeros(n, jnp.int8)
 
     def cond(s):
         state, it = s
-        return jnp.logical_and(jnp.any(state == 0), it < maxiter)
+        with jax.named_scope("undecided"):
+            return jnp.logical_and(jnp.any(state == 0), it < maxiter)
 
     def body(s):
         state, it = s

@@ -117,10 +117,15 @@ def _sharded_step(ranks, src, dst, inv_outdeg, valid, damping, axes):
     multi-slice mesh) merges per-shard inflows (replicated ranks in,
     replicated ranks out)."""
     n = ranks.shape[0]
+    with jax.named_scope("gather_ranks"):
+        pulled = ranks[src]
+    with jax.named_scope("gather_inv_outdeg"):
+        share = inv_outdeg[src]
     with jax.named_scope("scatter_add"):
-        contrib = jnp.where(valid, ranks[src] * inv_outdeg[src], 0.0)
-        inflow = lax.psum(
-            jax.ops.segment_sum(contrib, dst, num_segments=n), axes)
+        contrib = jnp.where(valid, pulled * share, 0.0)
+        local = jax.ops.segment_sum(contrib, dst, num_segments=n)
+    with jax.named_scope("merge"):
+        inflow = lax.psum(local, axes)
     with jax.named_scope("normalise"):
         return ((1.0 - damping) / n +
                 damping * (inflow + _dangling_mass(ranks, inv_outdeg)))
@@ -149,12 +154,13 @@ def _sharded_run_fn(mesh: Mesh, n: int, tol: float, maxiter: int,
 
     @functools.partial(jax.jit, out_shardings=(rep, rep))
     def pagerank_loop(src_d, dst_d, valid_d):
-        deg = jax.shard_map(
-            lambda s, v: lax.psum(out_degrees(s, n, valid=v), axes),
-            mesh=mesh, in_specs=(rspec, rspec), out_specs=P())(
-                src_d, valid_d)
-        inv = inv_outdegrees(deg)
-        r0 = jnp.full((n,), 1.0 / n, jnp.float32)
+        with jax.named_scope("out_degrees"):
+            deg = jax.shard_map(
+                lambda s, v: lax.psum(out_degrees(s, n, valid=v), axes),
+                mesh=mesh, in_specs=(rspec, rspec), out_specs=P())(
+                    src_d, valid_d)
+            inv = inv_outdegrees(deg)
+            r0 = jnp.full((n,), 1.0 / n, jnp.float32)
 
         step = jax.shard_map(
             functools.partial(_sharded_step, damping=damping, axes=axes),
@@ -169,7 +175,8 @@ def _sharded_run_fn(mesh: Mesh, n: int, tol: float, maxiter: int,
         def body(state):
             r, _, it = state
             r2 = step(r, src_d, dst_d, inv, valid_d)
-            return r2, jnp.max(jnp.abs(r2 - r)), it + 1
+            with jax.named_scope("delta"):
+                return r2, jnp.max(jnp.abs(r2 - r)), it + 1
 
         ranks, _, iters = lax.while_loop(
             cond, body, (r0, jnp.float32(jnp.inf), jnp.int32(0)))

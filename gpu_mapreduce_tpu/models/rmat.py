@@ -33,31 +33,32 @@ def rmat_edges(key, m: int, nlevels: int, abcd, frac: float, noisy: bool
     Returns (vi[m], vj[m]) uint64.  ``abcd`` is a length-4 array of
     quadrant probabilities; ``noisy`` statically gates the per-level
     fraction perturbation (frac == 0 ⇒ pass noisy=False)."""
-    abcd = jnp.asarray(abcd, jnp.float32)
-    probs0 = jnp.broadcast_to(abcd, (m, 4)) if noisy else abcd[None, :]
+    with jax.named_scope("generate"):
+        abcd = jnp.asarray(abcd, jnp.float32)
+        probs0 = jnp.broadcast_to(abcd, (m, 4)) if noisy else abcd[None, :]
 
-    def level(carry, lkey):
-        i, j, probs = carry
-        ku, kn = jax.random.split(lkey)
-        u = jax.random.uniform(ku, (m,), jnp.float32)
-        t = jnp.cumsum(probs, axis=1)          # [*,4]: a, a+b, a+b+c, 1
-        t = jnp.broadcast_to(t, (m, 4))
-        # quadrant: 0=a (i0,j0)  1=b (j1)  2=c (i1)  3=d (i1,j1)
-        jbit = ((u >= t[:, 0]) & (u < t[:, 1])) | (u >= t[:, 2])
-        ibit = u >= t[:, 1]
-        i = (i << np.uint64(1)) | ibit.astype(jnp.uint64)
-        j = (j << np.uint64(1)) | jbit.astype(jnp.uint64)
-        if noisy:
-            nz = jax.random.uniform(kn, (m, 4), jnp.float32,
-                                    minval=-0.5, maxval=0.5)
-            probs = probs * (1.0 + frac * nz)
-            probs = probs / jnp.sum(probs, axis=1, keepdims=True)
-        return (i, j, probs), None
+        def level(carry, lkey):
+            i, j, probs = carry
+            ku, kn = jax.random.split(lkey)
+            u = jax.random.uniform(ku, (m,), jnp.float32)
+            t = jnp.cumsum(probs, axis=1)          # [*,4]: a, a+b, a+b+c, 1
+            t = jnp.broadcast_to(t, (m, 4))
+            # quadrant: 0=a (i0,j0)  1=b (j1)  2=c (i1)  3=d (i1,j1)
+            jbit = ((u >= t[:, 0]) & (u < t[:, 1])) | (u >= t[:, 2])
+            ibit = u >= t[:, 1]
+            i = (i << np.uint64(1)) | ibit.astype(jnp.uint64)
+            j = (j << np.uint64(1)) | jbit.astype(jnp.uint64)
+            if noisy:
+                nz = jax.random.uniform(kn, (m, 4), jnp.float32,
+                                        minval=-0.5, maxval=0.5)
+                probs = probs * (1.0 + frac * nz)
+                probs = probs / jnp.sum(probs, axis=1, keepdims=True)
+            return (i, j, probs), None
 
-    zeros = jnp.zeros((m,), jnp.uint64)
-    keys = jax.random.split(key, nlevels)
-    (vi, vj, _), _ = lax.scan(level, (zeros, zeros, probs0), keys)
-    return vi, vj
+        zeros = jnp.zeros((m,), jnp.uint64)
+        keys = jax.random.split(key, nlevels)
+        (vi, vj, _), _ = lax.scan(level, (zeros, zeros, probs0), keys)
+        return vi, vj
 
 
 @jax.jit
@@ -65,7 +66,8 @@ def rmat_edge_rows(vi, vj):
     """One generated batch as KV rows, where the columns are: the [m, 2]
     edge keys and their NULL (one zero byte) values — what the ``rmat``
     commands add to a dataset that lives on a mesh."""
-    return jnp.stack([vi, vj], axis=1), jnp.zeros(vi.shape, jnp.uint8)
+    with jax.named_scope("rows"):
+        return jnp.stack([vi, vj], axis=1), jnp.zeros(vi.shape, jnp.uint8)
 
 
 def generate_unique(seed: int, nlevels: int, nnonzero: int,

@@ -75,33 +75,39 @@ def _round(dist, pred, src, dst, w, valid, n, segs=None, axes=None):
     else by two ``segment_min``; with ``axes`` the shards' partial
     answers combine across the mesh."""
     inf = _inf(dist.dtype)
-    seg = jnp.where(valid, dst, n)
-    d = dist[src]
-    relax = jnp.where(valid & (d < inf), d + w, inf)
+    with jax.named_scope("gather"):
+        seg = jnp.where(valid, dst, n)
+        d = dist[src]
+    with jax.named_scope("relax"):
+        relax = jnp.where(valid & (d < inf), d + w, inf)
     # per vertex: the least relaxed distance over this shard's in-edges,
     # and the smallest source that achieves it
-    if segs is None:
-        m = jax.ops.segment_min(relax, seg, num_segments=n + 1)
-        cand = jnp.where(relax == m[seg], src, n)
-        pm = jax.ops.segment_min(cand, seg, num_segments=n + 1)[:n]
-        m = m[:n]
-    else:
-        first, any_in = segs
-        _, r, s = lax.sort((seg, relax, src), num_keys=3)
-        m = jnp.where(any_in, r[first], inf)
-        pm = jnp.where(any_in, s[first], n)
+    with jax.named_scope("select"):
+        if segs is None:
+            m = jax.ops.segment_min(relax, seg, num_segments=n + 1)
+            cand = jnp.where(relax == m[seg], src, n)
+            pm = jax.ops.segment_min(cand, seg, num_segments=n + 1)[:n]
+            m = m[:n]
+        else:
+            first, any_in = segs
+            _, r, s = lax.sort((seg, relax, src), num_keys=3)
+            m = jnp.where(any_in, r[first], inf)
+            pm = jnp.where(any_in, s[first], n)
     if axes is not None:
-        least = _pmin(m, axes)
-        pm = lax.pmin(jnp.where(m == least, pm, n), axes)
-        m = least
-    nd = jnp.minimum(dist, m)
-    improved = nd < dist
-    return nd, jnp.where(improved, pm, pred), jnp.any(improved)
+        with jax.named_scope("merge"):
+            least = _pmin(m, axes)
+            pm = lax.pmin(jnp.where(m == least, pm, n), axes)
+            m = least
+    with jax.named_scope("update"):
+        nd = jnp.minimum(dist, m)
+        improved = nd < dist
+        return nd, jnp.where(improved, pm, pred), jnp.any(improved)
 
 
 def _loop(step, n, maxiter, source, dtype):
-    dist0 = jnp.full((n,), _inf(dtype), dtype).at[source].set(0)
-    pred0 = jnp.full((n,), -1, jnp.int32)
+    with jax.named_scope("init"):
+        dist0 = jnp.full((n,), _inf(dtype), dtype).at[source].set(0)
+        pred0 = jnp.full((n,), -1, jnp.int32)
 
     def cond(state):
         return jnp.logical_and(state[2], state[3] < maxiter)
@@ -141,10 +147,11 @@ def _bf_sharded_fn(mesh: Mesh, n: int, maxiter: int):
     def sssp_loop(src_d, dst_d, w_d, valid_d, source):
         segs = ()
         if _whole(w_d.dtype):       # each shard's own rows, sorted once
-            segs = jax.shard_map(
-                lambda d, v: _segments(d, v, n), mesh=mesh,
-                in_specs=(rspec, rspec), out_specs=(rspec, rspec)
-            )(dst_d, valid_d)
+            with jax.named_scope("prologue"):
+                segs = jax.shard_map(
+                    lambda d, v: _segments(d, v, n), mesh=mesh,
+                    in_specs=(rspec, rspec), out_specs=(rspec, rspec)
+                )(dst_d, valid_d)
         body = jax.shard_map(
             lambda dist, pred, s, d, w, v, *sg: _round(
                 dist, pred, s, d, w, v, n, sg or None, axes),
@@ -167,9 +174,10 @@ def _bf_sharded_fn(mesh: Mesh, n: int, maxiter: int):
 def sssp_weights(w, valid):
     """The weights as int32, whether that lost nothing (whole and not
     negative), and the largest."""
-    wi = w.astype(jnp.int32)
-    same = (wi >= 0) & (wi.astype(w.dtype) == w)
-    return wi, jnp.all(same | ~valid), jnp.max(jnp.where(valid, wi, 0))
+    with jax.named_scope("weights"):
+        wi = w.astype(jnp.int32)
+        same = (wi >= 0) & (wi.astype(w.dtype) == w)
+        return wi, jnp.all(same | ~valid), jnp.max(jnp.where(valid, wi, 0))
 
 
 def exact_weights(w, valid, n: int):

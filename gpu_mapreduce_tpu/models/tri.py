@@ -139,19 +139,22 @@ def tri_orient(src, dst, valid, verts, canonical: bool, by_id: bool,
     ``by_id``: the vertices are named by their ids from here on (two
     gathers of the edge rows, once), which orders them as their ranks do."""
     ne = src.shape[0]
-    if by_id:
-        last = verts.shape[0] - 1
-        src, dst = (jnp.take(verts, jnp.minimum(x, last)).astype(jnp.int32)
-                    for x in (src, dst))
-    a, b = jnp.minimum(src, dst), jnp.maximum(src, dst)
+    with jax.named_scope("ids"):
+        if by_id:
+            last = verts.shape[0] - 1
+            src, dst = (
+                jnp.take(verts, jnp.minimum(x, last)).astype(jnp.int32)
+                for x in (src, dst))
+        a, b = jnp.minimum(src, dst), jnp.maximum(src, dst)
     with jax.named_scope("edge_keys"):
         ekey = jnp.sort(jnp.where(valid & (a != b), _pack(a, b), _SENT))
         if not canonical:       # duplicates to the back, by one more sort
             dup = jnp.concatenate([jnp.zeros(1, bool), ekey[1:] == ekey[:-1]])
             ekey = jnp.sort(jnp.where(dup, _SENT, ekey))
-    live = ekey != _SENT
-    a = jnp.where(live, (ekey >> 33).astype(jnp.int32), _DEAD)
-    b = jnp.where(live, ((ekey >> 1) & 0xFFFFFFFF).astype(jnp.int32), _DEAD)
+        live = ekey != _SENT
+        a = jnp.where(live, (ekey >> 33).astype(jnp.int32), _DEAD)
+        b = jnp.where(live, ((ekey >> 1) & 0xFFFFFFFF).astype(jnp.int32),
+                      _DEAD)
     with jax.named_scope("degrees"):
         # a vertex's degree is the length of its run among the sorted
         # endpoints; each endpoint's position rides the sort and brings the
@@ -182,9 +185,10 @@ def tri_orient(src, dst, valid, verts, canonical: bool, by_id: bool,
         owns = jnp.where(alive & ~short & (r == 0) & (pos < end),
                          -(-k // block) - a, 0)
         toff, ntiles = _offsets(owns.astype(jnp.int64))
-    counts = jnp.stack([jnp.sum(pairs), nindex, ntiles,
-                        jnp.sum(live.astype(jnp.int64)),
-                        jnp.max(jnp.where(alive, k, 0)).astype(jnp.int64)])
+    with jax.named_scope("counts"):
+        counts = jnp.stack([
+            jnp.sum(pairs), nindex, ntiles, jnp.sum(live.astype(jnp.int64)),
+            jnp.max(jnp.where(alive, k, 0)).astype(jnp.int64)])
     return ekey, grp, nbr, off, toff, counts
 
 
@@ -235,8 +239,9 @@ def tri_tiles(grp, toff, t0, cap: int, block: int):
     """Tiles ``t0 … t0 + cap`` of the tile space: of each (the position
     its first block starts at, the position its second block starts at, the
     last position of their list, the list's centre)."""
-    sa, (end, c), j = _owners(toff, (_runs(grp)[1], grp), t0, cap)
-    return sa, sa + j * block, end, c
+    with jax.named_scope("tiles"):
+        sa, (end, c), j = _owners(toff, (_runs(grp)[1], grp), t0, cap)
+        return sa, sa + j * block, end, c
 
 
 def _index_wedges(nbr, grp, off, t0, total, batch: int):
@@ -247,9 +252,9 @@ def _index_wedges(nbr, grp, off, t0, total, batch: int):
     with jax.named_scope("partner"):
         # the one gather over a batch: a wedge's two neighbours
         uw = jnp.take(nbr, jnp.minimum(jnp.stack([p, p + 1 + j]), ne - 1))
-    inside = (t0 + lax.iota(jnp.int64, batch)) < total
-    # neighbours ascend along a list, so u < w
-    return jnp.where(inside, _pack(uw[0], uw[1]) | 1, _SENT), c
+        inside = (t0 + lax.iota(jnp.int64, batch)) < total
+        # neighbours ascend along a list, so u < w
+        return jnp.where(inside, _pack(uw[0], uw[1]) | 1, _SENT), c
 
 
 def _tile_wedges(nbr, sa, sb, end, c, t0, total, batch: int, block: int):
@@ -259,10 +264,10 @@ def _tile_wedges(nbr, sa, sb, end, c, t0, total, batch: int, block: int):
     list's end, or a pair i ≥ j of a tile on the diagonal."""
     ne = nbr.shape[0]
     nt = batch // (block * block)
-    sa, sb, end, c = (lax.dynamic_slice_in_dim(x, t0, nt)
-                      for x in (sa, sb, end, c))
-    i = lax.broadcasted_iota(jnp.int32, (block, 1), 0)
     with jax.named_scope("blocks"):
+        sa, sb, end, c = (lax.dynamic_slice_in_dim(x, t0, nt)
+                          for x in (sa, sb, end, c))
+        i = lax.broadcasted_iota(jnp.int32, (block, 1), 0)
         # the one gather over a batch: both blocks of every tile
         at = jnp.expand_dims(jnp.stack([sa, sb]), 1) + i
         uw = jnp.take(nbr, jnp.minimum(at, ne - 1))
@@ -276,7 +281,7 @@ def _tile_wedges(nbr, sa, sb, end, c, t0, total, batch: int, block: int):
         key = jnp.where(pair, _pack(jnp.expand_dims(uw[0], 1),
                                     jnp.expand_dims(uw[1], 0)) | 1, _SENT)
         c = jnp.broadcast_to(c, key.shape)
-    return key.reshape(batch), c.reshape(batch)
+        return key.reshape(batch), c.reshape(batch)
 
 
 def tri_wedges(ekey, nbr, lists, t0, total, batch: int, block: int):
@@ -297,25 +302,28 @@ def tri_wedges(ekey, nbr, lists, t0, total, batch: int, block: int):
                           num_keys=1, is_stable=False)
         # the last edge key at or before each entry; a wedge's own edge
         # sorts directly before it (same pair, low bit 0)
-        edge = lax.cummax(jnp.where(key & 1 == 0, key, 0))
+        with jax.named_scope("join_scan"):      # the u64 prefix max
+            edge = lax.cummax(jnp.where(key & 1 == 0, key, 0))
         hit = (key & 1 == 1) & (key != _SENT) & (edge == key - 1)
     with jax.named_scope("compact"):
         _, key, c = lax.sort((1 - hit.astype(jnp.int32), key, c),
                              num_keys=1, is_stable=False)
-    return key[:batch], c[:batch], jnp.sum(hit.astype(jnp.int32))
+        return key[:batch], c[:batch], jnp.sum(hit.astype(jnp.int32))
 
 
 def tri_append(kbuf, cbuf, key, c, count):
     """A batch's hits behind the ``count`` the buffer holds: two copies.
     The caller keeps ``count + len(key) <= len(kbuf)``."""
-    return (lax.dynamic_update_slice_in_dim(kbuf, key, count, 0),
-            lax.dynamic_update_slice_in_dim(cbuf, c, count, 0))
+    with jax.named_scope("append"):
+        return (lax.dynamic_update_slice_in_dim(kbuf, key, count, 0),
+                lax.dynamic_update_slice_in_dim(cbuf, c, count, 0))
 
 
 def tri_grow(kbuf, cbuf):
     """The buffer at twice its capacity."""
-    return (jnp.concatenate([kbuf, jnp.zeros_like(kbuf)]),
-            jnp.concatenate([cbuf, jnp.zeros_like(cbuf)]))
+    with jax.named_scope("grow"):
+        return (jnp.concatenate([kbuf, jnp.zeros_like(kbuf)]),
+                jnp.concatenate([cbuf, jnp.zeros_like(cbuf)]))
 
 
 def tri_rows(kbuf, cbuf, verts, rows: int, by_id: bool):
@@ -324,26 +332,27 @@ def tri_rows(kbuf, cbuf, verts, rows: int, by_id: bool):
     by rank, the ids are gathered from the vertex table a block of rows at
     a time, so that the three id columns of a hundred million triangles
     never stand beside the result."""
-    null = jnp.zeros(rows, jnp.uint8)
-    if by_id:
-        k = kbuf[:rows]
-        return jnp.stack([cbuf[:rows].astype(jnp.uint64), k >> 33,
-                          (k >> 1) & 0xFFFFFFFF], 1), null
-    block = min(rows, _ROWS_STEP)
-    last = verts.shape[0] - 1
+    with jax.named_scope("rows"):
+        null = jnp.zeros(rows, jnp.uint8)
+        if by_id:
+            k = kbuf[:rows]
+            return jnp.stack([cbuf[:rows].astype(jnp.uint64), k >> 33,
+                              (k >> 1) & 0xFFFFFFFF], 1), null
+        block = min(rows, _ROWS_STEP)
+        last = verts.shape[0] - 1
 
-    def ids(ranks):
-        return jnp.take(verts, jnp.minimum(ranks.astype(jnp.int32), last))
+        def ids(ranks):
+            return jnp.take(verts, jnp.minimum(ranks.astype(jnp.int32), last))
 
-    def body(i, key):
-        k = lax.dynamic_slice_in_dim(kbuf, i * block, block)
-        c = lax.dynamic_slice_in_dim(cbuf, i * block, block)
-        part = jnp.stack([ids(c), ids(k >> 33), ids((k >> 1) & 0xFFFFFFFF)],
-                         1)
-        return lax.dynamic_update_slice_in_dim(key, part, i * block, 0)
+        def body(i, key):
+            k = lax.dynamic_slice_in_dim(kbuf, i * block, block)
+            c = lax.dynamic_slice_in_dim(cbuf, i * block, block)
+            part = jnp.stack(
+                [ids(c), ids(k >> 33), ids((k >> 1) & 0xFFFFFFFF)], 1)
+            return lax.dynamic_update_slice_in_dim(key, part, i * block, 0)
 
-    return lax.fori_loop(0, rows // block, body,
-                         jnp.zeros((rows, 3), jnp.uint64)), null
+        return lax.fori_loop(0, rows // block, body,
+                             jnp.zeros((rows, 3), jnp.uint64)), null
 
 
 class _Programs(NamedTuple):

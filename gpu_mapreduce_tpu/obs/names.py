@@ -85,6 +85,94 @@ def declared_program(module: str) -> bool:
     return module in PROGRAMS or module.startswith(PROGRAM_PREFIXES)
 
 
+# -- steps inside the programs ------------------------------------------------
+# A step is a ``jax.named_scope`` inside a program: one component of the
+# ``op_name`` path every HLO instruction carries into the profiler's trace
+# (``jit(cc_loop)/while/body/shard_map/segment_min_dst/gather_src/gather``),
+# which is how a device second gets a name below the program's
+# (benchmark/xsteps.py reads the table out of the trace's own HLO).  Every
+# operation of a declared program lies under one of its steps; where steps
+# nest, the innermost is the operation's.  A step is lower_snake, unique
+# inside its program, and none of JAX's own path words (STEP_RESERVED: a
+# loop's ``body`` would claim every operation of every loop).  A rename
+# here is a rename in ``benchmark/layer_metrics/*.json`` and in
+# ``doc/observability.md``; tests/test_obs_names.py holds source, lowered
+# programs and this table to one another.
+STEP_RESERVED = ("while", "body", "cond", "shard_map", "scan", "pjit",
+                 "branch", "checkpoint", "remat", "vmap", "jvp", "transpose")
+STEPS = {
+    INVINDEX_EXTRACT: ("mark", "compact", "gather", "hash", "long_tail",
+                       "pack", "collisions", "stats"),
+    INVINDEX_COLLISIONS: ("collisions",),
+    CONVERT_SORT: ("sort", "take", "boundary"),
+    CONVERT_LAYOUT: ("layout", "segment_ids", "flagged_rows_first",
+                     "group_sizes", "unique_keys", "group_offsets",
+                     "largest_group"),
+    REDUCE_SEGMENTS: ("segment_ids", "reduce"),
+    GROUP_FIRST: ("gather",),
+    SORT_MULTIVALUES: ("segment_ids", "sort", "take"),
+    # ops/sort.sort_carrying's two steps (the sort; the take of the words
+    # that did not ride, by the sorted row index), under the words around
+    SORT_ROWS: ("keys", "sort", "take"),
+    SORT_INTERNED: ("rank", "sort", "take"),
+    JOIN_ROWS: ("sort_sides", "partners", "joined_rows_first"),
+    JOIN_TAKE: ("positions", "take"),
+    TAKE_ROWS: ("take",),
+    SHUFFLE_PHASE1: ("dest", "dest_sort", "dest_counts", "wire_stats"),
+    # the exchange's helpers carry them (parallel/shuffle._send_windows,
+    # _exchange_blocks, _place_blocks): a round's send block cut from the
+    # dest-sorted shard, the collective (the counts' too), the received
+    # blocks placed in the packed output
+    SHUFFLE_PHASE2: ("windows", "exchange", "unpack"),
+    SHUFFLE_PHASE2_WIRE: ("windows", "exchange", "unpack"),
+    TERASORT_SAMPLE_KEYS: ("sample",),
+    STAGE_RANK_GRAPH: ("endpoints", "sort", "rank", "table", "return"),
+    STAGE_TRIM_VERTS: ("trim",),
+    PLACE_ROWS: ("window",),
+    CONCAT_ROWS: ("append",),
+    LEVEL_ROWS: ("level",),
+    REMAP_IDS: ("remap",),
+    CC_LOOP: ("init", "segment_min_dst", "gather_src", "segment_min_src",
+              "gather_dst", "pointer_jump", "merge", "changed"),
+    PAGERANK_LOOP: ("out_degrees", "gather_ranks", "gather_inv_outdeg",
+                    "scatter_add", "merge", "normalise", "delta"),
+    RMAT_EDGES: ("generate",),
+    RMAT_EDGE_ROWS: ("rows",),
+    TRI_ORIENT: ("ids", "edge_keys", "degrees", "orient", "wedge_offsets",
+                 "tile_offsets", "counts"),
+    TRI_TILES: ("tiles",),
+    # an index batch runs expand + partner, a tile batch blocks + pairs
+    TRI_WEDGES: ("expand", "partner", "blocks", "pairs", "join",
+                 "join_scan", "compact"),
+    TRI_APPEND: ("append",),
+    TRI_GROW: ("grow",),
+    TRI_ROWS: ("rows",),
+    LUBY_LOOP: ("orient", "undecided", "select", "gather", "prefix",
+                "merge"),
+    SSSP_LOOP: ("prologue", "init", "gather", "relax", "select", "merge",
+                "update"),
+    SSSP_WEIGHTS: ("weights",),
+    # the generic mappers, by prefix: the kernel body's own operations,
+    # then what brings the rows it keeps to the front (a prefix sum and a
+    # scatter in ``jit_kv_map_*`` / ``jit_kmv_map_*``, a one-operand sort
+    # in ``jit_kv_scan_*``; a map that keeps every row has no ``pack``)
+    KV_MAP_PREFIX: ("kernel", "pack"),
+    KMV_MAP_PREFIX: ("kernel", "pack"),
+    KV_SCAN_PREFIX: ("kernel", "pack"),
+}
+
+
+def steps_of(module: str) -> tuple:
+    """The steps declared for a trace's module name; none for a program
+    that is not declared."""
+    if module in STEPS:
+        return STEPS[module]
+    for prefix in PROGRAM_PREFIXES:
+        if module.startswith(prefix):
+            return STEPS[prefix]
+    return ()
+
+
 # -- span categories ----------------------------------------------------------
 HOST = "host"           # host work inside an op, stage or command
 ENGINE = "engine"       # a device loop, from dispatch to the pull that ends it
@@ -123,9 +211,11 @@ CC_STAGE = "cc.stage"                           # n, edges, on_device (1:
 #                                                 ranked by stage_graph, 0: on
 #                                                 the host), shards (the
 #                                                 mesh's size, 1 without a
-#                                                 mesh); pagerank.stage too
+#                                                 mesh), ATTR_EDGE_ROWS;
+#                                                 pagerank.stage too
 CC_EMIT = "cc.emit"                             # n
 CC_ENGINE = "cc.loop"                           # cat ENGINE: iters, n, edges,
+#                                                 ATTR_EDGE_ROWS,
 #                                                 shards, allreduce_bytes (the
 #                                                 replicated [n] vector merged
 #                                                 over the mesh: n * 4 * the
@@ -144,13 +234,15 @@ TRI_ENGINE = "tri.loop"                         # cat ENGINE: wedges, batches
 #                                                 ATTR_INDEX_WEDGES,
 #                                                 ATTR_TILE_FILL below
 TRI_EMIT = "tri.emit"                           # triangles
-LUBY_STAGE = "luby.stage"                       # n, edges
+LUBY_STAGE = "luby.stage"                       # n, edges, ATTR_EDGE_ROWS
 LUBY_ENGINE = "luby.loop"                       # cat ENGINE: iters, n, edges,
-#                                                 rows
+#                                                 rows, ATTR_EDGE_ROWS (the
+#                                                 same number)
 LUBY_EMIT = "luby.emit"                         # n
-SSSP_STAGE = "sssp.stage"                       # n, edges
+SSSP_STAGE = "sssp.stage"                       # n, edges, ATTR_EDGE_ROWS
 SSSP_ENGINE = "sssp.loop"                       # cat ENGINE: iters, source,
-#                                                 labeled, n
+#                                                 labeled, n, edges,
+#                                                 ATTR_EDGE_ROWS
 SSSP_EMIT = "sssp.emit"                         # n, source, rows,
 #                                                 block_rows, native (as
 #                                                 oink.output; 0 rows as
@@ -263,6 +355,13 @@ ATTR_ROW_WORDS_IN = "row_words_in"
 ATTR_TILES = "tiles"
 ATTR_INDEX_WEDGES = "index_wedges"
 ATTR_TILE_FILL = "tile_fill"
+# on the four graph loops' ``*.loop`` and ``*.stage`` spans: the rows the
+# program iterates or ranks (the staged columns' length: on a mesh, shards
+# times the per-shard capacity ``round_cap`` gave the frame), beside
+# ``edges``, the valid ones: ``edges`` / ``edge_rows`` is how full a loop's
+# rows are (a masked row is gathered and scattered like a real one)
+ATTR_EDGES = "edges"
+ATTR_EDGE_ROWS = "edge_rows"
 # on every span (obs/tracer.Span): the thread's CPU seconds, and the rest of
 # the span's wall (blocked on the device, a file, a lock, or descheduled)
 ATTR_CPU_S = "cpu_s"
@@ -284,6 +383,7 @@ SPAN_ATTRS = (
     ATTR_PROBE_ROWS, ATTR_BUILD_ROWS, ATTR_MATCHED_ROWS,
     ATTR_ROWS_IN, ATTR_ROWS_OUT, ATTR_ROW_WORDS_IN,
     ATTR_TILES, ATTR_INDEX_WEDGES, ATTR_TILE_FILL,
+    ATTR_EDGES, ATTR_EDGE_ROWS,
     ATTR_CPU_S, ATTR_OFF_CPU_S,
     ATTR_PROC_CPU_S, ATTR_SYS_CPU_S, ATTR_VOL_SWITCHES, ATTR_INVOL_SWITCHES,
     ATTR_JIT_LOWERINGS, ATTR_JIT_LOWER_S, ATTR_JIT_BACKEND_S,

@@ -284,8 +284,9 @@ class CCFind(Command):
                 nedges = len(sg.src)
             verts, n = sg.verts, sg.n
             shards = mesh_axis_size(mesh) if mesh is not None else 1
+            edge_rows = sg.rows
             sp.set(n=n, edges=nedges, on_device=int(on_device),
-                   shards=shards)
+                   shards=shards, edge_rows=edge_rows)
         if n == 0:
             self.ncc, self.niterate = 0, 0
             mrv = obj.create_mr()
@@ -296,7 +297,7 @@ class CCFind(Command):
 
         # the fused loop, from dispatch to the pull that ends it
         with tr.span(names.CC_ENGINE, cat=names.ENGINE, n=n,
-                     edges=nedges) as sp:
+                     edges=nedges, edge_rows=edge_rows) as sp:
             if on_device:
                 from ...models.cc import _cc_sharded_fn
                 labels_d, iters = _cc_sharded_fn(mesh, n, max(n, 1))(

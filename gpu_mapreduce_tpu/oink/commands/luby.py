@@ -306,7 +306,7 @@ class LubyFind(Command):
             prio[np.lexsort((verts, vertex_rand(verts, self.seed)))] = \
                 np.arange(n, dtype=np.int32)
             edges = int(mre.kv.nkv) if mre.kv is not None else 0
-            sp.set(n=n, edges=edges)
+            sp.set(n=n, edges=edges, edge_rows=sg.rows)
 
         with tr.span(names.LUBY_ENGINE, cat=names.ENGINE) as sp:
             # the span ends at the pull of the state vector; ``rows`` is
@@ -333,7 +333,8 @@ class LubyFind(Command):
                                         jnp.asarray(prio), n)
                 rows = len(sg.src)
             state, iters = np.asarray(state), int(iters)
-            sp.set(iters=iters, n=n, edges=edges, rows=rows)
+            sp.set(iters=iters, n=n, edges=edges, rows=rows,
+                   edge_rows=int(rows))
 
         mrv = obj.create_mr()
         with tr.span(names.LUBY_EMIT, cat=names.HOST) as sp:

@@ -100,15 +100,16 @@ class PageRankCommand(Command):
                 nedges = len(sg.src)
             verts, n = sg.verts, sg.n
             shards = mesh_axis_size(mesh) if mesh is not None else 1
+            edge_rows = sg.rows
             sp.set(n=n, edges=nedges, on_device=int(on_device),
-                   shards=shards)
+                   shards=shards, edge_rows=edge_rows)
             if n == 0:
                 raise MRError("pagerank: empty edge list")
 
         # the fused loop, from dispatch (on the host path: from the
         # edges' transfer) to the pull that ends it
         with tr.span(names.PAGERANK_ENGINE, cat=names.ENGINE, n=n,
-                     edges=nedges) as sp:
+                     edges=nedges, edge_rows=edge_rows) as sp:
             params = dict(tol=self.tolerance, maxiter=self.maxiter,
                           damping=self.alpha)
             if on_device:

@@ -352,7 +352,8 @@ class SSSPCommand(Command):
             # deterministic-random source list (same ranking as composed)
             order = np.lexsort((verts, vertex_rand(verts, self.seed)))
             sources = verts[order][:self.ncnt].tolist()
-            sp.set(n=n, edges=int(mredge.kv.nkv))
+            edges, edge_rows = int(mredge.kv.nkv), sg.rows
+            sp.set(n=n, edges=edges, edge_rows=edge_rows)
 
         self._arrays = {}
         vars(self).pop("results", None)
@@ -367,7 +368,7 @@ class SSSPCommand(Command):
                 dist, pred, niter = bf(sidx)
                 nlabeled = int(np.isfinite(dist).sum())
                 sp.set(iters=niter, source=int(source), labeled=nlabeled,
-                       n=n)
+                       n=n, edges=edges, edge_rows=edge_rows)
             with tr.span(names.SSSP_EMIT, cat=names.HOST, n=n,
                          source=int(source)) as sp:
                 # dict/file view: -1 (source/unreachable) renders as 0 like

@@ -101,12 +101,15 @@ def sort_carrying(keys, carry=(), stable: bool = True):
     if not all(rides):
         operands.append(jnp.arange(
             n, dtype=jnp.int32 if n < 2 ** 31 else jnp.int64))
-    out = lax.sort(tuple(operands), num_keys=len(keys), is_stable=stable)
+    with jax.named_scope("sort"):
+        out = lax.sort(tuple(operands), num_keys=len(keys),
+                       is_stable=stable)
     rest = iter(out[len(keys):])
     scarry = []
     for c, r in zip(carry, rides):
         if not r:
-            scarry.append(jnp.take(c, out[-1], axis=0))   # the row index
+            with jax.named_scope("take"):
+                scarry.append(jnp.take(c, out[-1], axis=0))  # the row index
         elif c.ndim == 1:
             scarry.append(next(rest))
         else:

@@ -76,7 +76,10 @@ def _skv_map_jit(mesh, fn, static, nextra):
 
     def run(key, value, count, *extra):
         def body(k, v, c, *ex):
-            return _pack(*fn(k, v, c[0], *ex, *static))
+            with jax.named_scope("kernel"):
+                out = fn(k, v, c[0], *ex, *static)
+            with jax.named_scope("pack"):
+                return _pack(*out)
         return jax.shard_map(
             body, mesh=mesh, in_specs=(spec, spec, spec) + (P(),) * nextra,
             out_specs=(spec, spec, spec))(key, value, count, *extra)
@@ -95,11 +98,13 @@ def _skv_rows_jit(mesh, fn, static, nextra, scan: bool):
 
     def run(key, value, count, *extra):
         def body(k, v, c, *ex):
-            out = fn(k, v, c[0], *ex, *static)
+            with jax.named_scope("kernel"):
+                out = fn(k, v, c[0], *ex, *static)
             if not scan:
                 return out
-            order, kept = front_order(out[2])
-            return out[0], out[1], order, kept[None]
+            with jax.named_scope("pack"):
+                order, kept = front_order(out[2])
+                return out[0], out[1], order, kept[None]
         return jax.shard_map(
             body, mesh=mesh, in_specs=(spec, spec, spec) + (P(),) * nextra,
             out_specs=(spec,) * (4 if scan else 2))(key, value, count, *extra)
@@ -129,8 +134,9 @@ def _take_rows_jit(mesh, cap: int):
 
     def take_rows(key, value, order):
         def body(k, v, o):
-            at = jnp.minimum(o[:cap], k.shape[0] - 1)
-            return take_together(at, k, v)
+            with jax.named_scope("take"):
+                at = jnp.minimum(o[:cap], k.shape[0] - 1)
+                return take_together(at, k, v)
         return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
                              out_specs=(spec, spec))(key, value, order)
 
@@ -206,7 +212,10 @@ def _skmv_map_jit(mesh, fn, static, nextra):
 
     def run(ukey, nval, voff, values, gcount, vcount, *extra):
         def body(uk, nv, vo, vals, gc, vc, *ex):
-            return _pack(*fn(uk, nv, vo, vals, gc[0], vc[0], *ex, *static))
+            with jax.named_scope("kernel"):
+                out = fn(uk, nv, vo, vals, gc[0], vc[0], *ex, *static)
+            with jax.named_scope("pack"):
+                return _pack(*out)
         return jax.shard_map(
             body, mesh=mesh, in_specs=(spec,) * 6 + (P(),) * nextra,
             out_specs=(spec, spec, spec))(ukey, nval, voff, values,
@@ -256,8 +265,9 @@ def _concat_jit(mesh, cap: int):
 
     def concat_rows(k1, v1, c1, k2, v2, c2):
         def body(ka, va, ca, kb, vb, cb):
-            return (_append(ka, kb, ca[0], cb[0], cap),
-                    _append(va, vb, ca[0], cb[0], cap))
+            with jax.named_scope("append"):
+                return (_append(ka, kb, ca[0], cb[0], cap),
+                        _append(va, vb, ca[0], cb[0], cap))
         return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 6,
                              out_specs=(spec, spec))(k1, v1, c1,
                                                      k2, v2, c2)
@@ -299,9 +309,10 @@ def _remap_ids_jit(mesh, m: int):
     @functools.partial(jax.jit,
                        out_shardings=NamedSharding(mesh, row_spec(mesh)))
     def remap_ids(col, old_sorted, new_by_old):
-        pos = jnp.clip(jnp.searchsorted(old_sorted, col), 0, m - 1)
-        hit = old_sorted[pos] == col
-        return jnp.where(hit, new_by_old[pos], col)
+        with jax.named_scope("remap"):
+            pos = jnp.clip(jnp.searchsorted(old_sorted, col), 0, m - 1)
+            hit = old_sorted[pos] == col
+            return jnp.where(hit, new_by_old[pos], col)
 
     return remap_ids
 
@@ -428,7 +439,8 @@ def _level_jit(mesh, cap: int, xcap: int, ycap: int):
                     got = got + tk[s]
                 return _append(x, inc, kp[0], got, cap)
 
-            return levelled(k), levelled(v)
+            with jax.named_scope("level"):
+                return levelled(k), levelled(v)
         return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 6,
                              out_specs=(spec, spec))(key, value, keep, over,
                                                      start, take)

@@ -172,7 +172,8 @@ def _convert_phase2_jit(mesh, gcap: int):
                 ukey, sizes, voff, _seg, _g = grouped_layout(sk, m, c[0],
                                                              gcap)
             # the largest group's rows: read only by a traced run
-            return ukey, sizes, voff, jnp.max(sizes)[None]
+            with jax.named_scope("largest_group"):
+                return ukey, sizes, voff, jnp.max(sizes)[None]
         return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=(spec,) * 4)(skey, mask, count)
 
@@ -271,12 +272,16 @@ def _reduce_build(mesh, gcap: int, op: str, values_transform):
     def reduce_segments(ukey, nval, voff, values, vcount):
         def body(uk, nv, vo, vals, vc):
             if op == "count":
-                return uk, nv.astype(jnp.int64)
+                with jax.named_scope("reduce"):
+                    return uk, nv.astype(jnp.int64)
             vcap = vals.shape[0]
-            seg = _local_segment_ids(vo, nv, vcap)
-            valid = jnp.arange(vcap) < vc
-            x = vals if values_transform is None else values_transform(vals)
-            return uk, segment_reduce_rows(x, seg, valid, gcap, op)
+            with jax.named_scope("segment_ids"):
+                seg = _local_segment_ids(vo, nv, vcap)
+                valid = jnp.arange(vcap) < vc
+            with jax.named_scope("reduce"):
+                x = (vals if values_transform is None
+                     else values_transform(vals))
+                return uk, segment_reduce_rows(x, seg, valid, gcap, op)
         return jax.shard_map(body, mesh=mesh,
                              in_specs=(spec, spec, spec, spec, spec),
                              out_specs=(spec, spec))(ukey, nval, voff, values,
@@ -327,8 +332,9 @@ def _first_jit(mesh):
     @jax.jit
     def group_first(ukey, voff, values):
         def body(uk, vo, vals):
-            idx = jnp.minimum(vo, vals.shape[0] - 1)
-            return uk, jnp.take(vals, idx, axis=0)
+            with jax.named_scope("gather"):
+                idx = jnp.minimum(vo, vals.shape[0] - 1)
+                return uk, jnp.take(vals, idx, axis=0)
         return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=(spec, spec))(ukey, voff, values)
 
@@ -352,12 +358,15 @@ def _sortmv_jit(mesh, descending: bool):
     def sort_multivalues(voff, nval, values, vcount):
         def body(vo, nv, vals, vc):
             vcap = vals.shape[0]
-            seg = _local_segment_ids(vo, nv, vcap)
-            valid = jnp.arange(vcap) < vc
-            v = vals if vals.ndim == 1 else vals[:, 0]
-            keyv = _desc_key(v) if descending else v
-            order = jnp.lexsort((keyv, seg, ~valid))
-            return jnp.take(vals, order, axis=0)
+            with jax.named_scope("segment_ids"):
+                seg = _local_segment_ids(vo, nv, vcap)
+                valid = jnp.arange(vcap) < vc
+            with jax.named_scope("sort"):
+                v = vals if vals.ndim == 1 else vals[:, 0]
+                keyv = _desc_key(v) if descending else v
+                order = jnp.lexsort((keyv, seg, ~valid))
+            with jax.named_scope("take"):
+                return jnp.take(vals, order, axis=0)
         return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 4,
                              out_specs=spec)(voff, nval, values, vcount)
 
@@ -408,16 +417,21 @@ def _sort_jit(mesh, by: str, descending: bool):
             # or comes by the row index, as its width says.  Descending
             # is the same sort on complemented words: a reversal by
             # scatter is what the chip does worst (PERF.md §6, PR 25)
+            # (the sort and the take by the row index are steps of their
+            # own inside sort_carrying; ``keys`` is the words around them)
             col, other = (k, v) if by == "key" else (v, k)
-            past = (jnp.arange(col.shape[0], dtype=jnp.int32)
-                    >= c[0]).astype(jnp.uint8)
-            cols = columns(col)
-            if descending:
-                cols = [_desc_key(x) for x in cols]
-            (_, *scols), (sother,) = sort_carrying((past, *cols), (other,))
-            if descending:
-                scols = [_desc_key(x) for x in scols]
-            scol = scols[0] if col.ndim == 1 else jnp.stack(scols, axis=1)
+            with jax.named_scope("keys"):
+                past = (jnp.arange(col.shape[0], dtype=jnp.int32)
+                        >= c[0]).astype(jnp.uint8)
+                cols = columns(col)
+                if descending:
+                    cols = [_desc_key(x) for x in cols]
+                (_, *scols), (sother,) = sort_carrying((past, *cols),
+                                                       (other,))
+                if descending:
+                    scols = [_desc_key(x) for x in scols]
+                scol = (scols[0] if col.ndim == 1
+                        else jnp.stack(scols, axis=1))
             return (scol, sother) if by == "key" else (sother, scol)
         return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=(spec, spec))(key, value, count)
@@ -465,20 +479,24 @@ def _sort_interned_jit(mesh, nrows: int, by: str, descending: bool):
     @functools.partial(jax.jit, out_shardings=(shard, shard))
     def sort_interned(key, value, counts, ids_by_id, rank_of):
         col = key if by == "key" else value
-        idx = jnp.arange(nrows)
-        valid = (idx % cap) < counts[idx // cap]
-        pos = jnp.clip(jnp.searchsorted(ids_by_id, col), 0,
-                       ids_by_id.shape[0] - 1)
-        rank = jnp.take(rank_of, pos)
-        order = jnp.lexsort((rank, ~valid))   # valid first, GLOBAL order
-        if descending:
-            total = jnp.sum(counts)
-            r = jnp.arange(nrows)
-            ppos = jnp.where(r < total, total - 1 - r, r)
-            inv = jnp.zeros(nrows, order.dtype).at[ppos].set(r,
-                                                             mode="drop")
-            order = jnp.take(order, inv)
-        return jnp.take(key, order, axis=0), jnp.take(value, order, axis=0)
+        with jax.named_scope("rank"):
+            idx = jnp.arange(nrows)
+            valid = (idx % cap) < counts[idx // cap]
+            pos = jnp.clip(jnp.searchsorted(ids_by_id, col), 0,
+                           ids_by_id.shape[0] - 1)
+            rank = jnp.take(rank_of, pos)
+        with jax.named_scope("sort"):
+            order = jnp.lexsort((rank, ~valid))   # valid first, GLOBAL order
+            if descending:
+                total = jnp.sum(counts)
+                r = jnp.arange(nrows)
+                ppos = jnp.where(r < total, total - 1 - r, r)
+                inv = jnp.zeros(nrows, order.dtype).at[ppos].set(
+                    r, mode="drop")
+                order = jnp.take(order, inv)
+        with jax.named_scope("take"):
+            return (jnp.take(key, order, axis=0),
+                    jnp.take(value, order, axis=0))
 
     return sort_interned
 
@@ -563,9 +581,9 @@ def join_rows_body(pk, pc, bk, bc):
     PR 43), and its gather of the partners' values ran over every row."""
     pcap, bcap = pk.shape[0], bk.shape[0]
     n = bcap + pcap
-    row = jnp.arange(n, dtype=jnp.int32)
-    valid = jnp.where(row >= bcap, row - bcap < pc, row < bc)
     with jax.named_scope("sort_sides"):
+        row = jnp.arange(n, dtype=jnp.int32)
+        valid = jnp.where(row >= bcap, row - bcap < pc, row < bc)
         (*scols, tag), _ = sort_carrying(
             (*columns(jnp.concatenate([bk, pk])),
              jnp.where(valid, row, row + n)), stable=False)
@@ -582,8 +600,8 @@ def join_rows_body(pk, pc, bk, bc):
         twice = isb & same & jnp.concatenate([jnp.zeros(1, bool), isb[:-1]])
     with jax.named_scope("joined_rows_first"):
         order, joined = front_order(matched)
-    return order, jnp.stack([tag, lastb], axis=1), jnp.stack(
-        [joined, jnp.sum(twice, dtype=jnp.int32)])
+        return order, jnp.stack([tag, lastb], axis=1), jnp.stack(
+            [joined, jnp.sum(twice, dtype=jnp.int32)])
 
 
 def join_take_body(cap: int, order, where, pk, pv, bv):
@@ -597,14 +615,16 @@ def join_take_body(cap: int, order, where, pk, pv, bv):
     blocks hold."""
     pcap, bcap = pk.shape[0], bv.shape[0]
     words = lambda v: v[:, None] if v.ndim == 1 else v
-    here = jnp.take(where, jnp.minimum(order[:cap], where.shape[0] - 1),
-                    axis=0)
-    src = jnp.clip(here[:, 0] - bcap, 0, pcap - 1)
-    partner = jnp.clip(
-        jnp.take(where[:, 0], jnp.maximum(here[:, 1], 0)), 0, bcap - 1)
-    key, pvalue = take_together(src, pk, words(pv))
-    return key, jnp.concatenate(
-        [pvalue, jnp.take(words(bv), partner, axis=0)], axis=1)
+    with jax.named_scope("positions"):
+        here = jnp.take(where, jnp.minimum(order[:cap], where.shape[0] - 1),
+                        axis=0)
+        src = jnp.clip(here[:, 0] - bcap, 0, pcap - 1)
+        partner = jnp.clip(
+            jnp.take(where[:, 0], jnp.maximum(here[:, 1], 0)), 0, bcap - 1)
+    with jax.named_scope("take"):
+        key, pvalue = take_together(src, pk, words(pv))
+        return key, jnp.concatenate(
+            [pvalue, jnp.take(words(bv), partner, axis=0)], axis=1)
 
 
 @functools.lru_cache(maxsize=None)

@@ -439,8 +439,9 @@ def _place_rows_jit(mesh, cap: int):
 
     def place_rows(key, value, start, count):
         def body(k, v, s, c):
-            return (window_rows(k, s[0], c[0], cap),
-                    window_rows(v, s[0], c[0], cap))
+            with jax.named_scope("window"):
+                return (window_rows(k, s[0], c[0], cap),
+                        window_rows(v, s[0], c[0], cap))
         return jax.shard_map(
             body, mesh=mesh,
             in_specs=(PartitionSpec(), PartitionSpec(), spec, spec),

@@ -108,15 +108,19 @@ def phase1_shard_body(nprocs: int, dest_of: Callable, wire_elig, k, v, c):
     padding rows get ``dest = nprocs`` and sort last."""
     from ..ops.sort import sort_carrying
     cap = k.shape[0]
-    valid = jnp.arange(cap) < c
-    dest = jnp.where(valid, dest_of(k).astype(jnp.int32), nprocs)
-    _, (sk, sv) = sort_carrying((dest,), (k, v))
-    cl = dest_counts(nprocs, dest)
+    with jax.named_scope("dest"):
+        valid = jnp.arange(cap) < c
+        dest = jnp.where(valid, dest_of(k).astype(jnp.int32), nprocs)
+    with jax.named_scope("dest_sort"):
+        _, (sk, sv) = sort_carrying((dest,), (k, v))
+    with jax.named_scope("dest_counts"):
+        cl = dest_counts(nprocs, dest)
     if wire_elig is None:
         return sk, sv, cl, None
     from .wire import bucket_stats
     k_elig, v_elig = wire_elig
-    return sk, sv, cl, bucket_stats(nprocs, k, v, dest, k_elig, v_elig)
+    with jax.named_scope("wire_stats"):
+        return sk, sv, cl, bucket_stats(nprocs, k, v, dest, k_elig, v_elig)
 
 
 def _run_starts(counts):
@@ -149,12 +153,13 @@ def _send_windows(nprocs: int, B: int, start: int, rows, counts_local):
     dynamic-update-slice (PERF.md §6, PR 31) — copies as well, but a
     loop where the unrolled form is straight-line code, so at the
     cells' ``P`` the unrolled form stands."""
-    off = _run_starts(counts_local)
-    wins = jnp.stack([slice_rows(rows, off[d] + start, B)
-                      for d in range(nprocs)])
-    valid = jnp.stack([rows_below(counts_local[d] - start, B, rows.ndim)
-                       for d in range(nprocs)])
-    return wins, valid
+    with jax.named_scope("windows"):
+        off = _run_starts(counts_local)
+        wins = jnp.stack([slice_rows(rows, off[d] + start, B)
+                          for d in range(nprocs)])
+        valid = jnp.stack([rows_below(counts_local[d] - start, B, rows.ndim)
+                           for d in range(nprocs)])
+        return wins, valid
 
 
 def _build_send_window(nprocs: int, B: int, start: int, rows,
@@ -165,7 +170,8 @@ def _build_send_window(nprocs: int, B: int, start: int, rows,
     (uniform rounds use ``start = r * B``; the wire codec's tiered caps
     use the running tier offset).  Built by :func:`_send_windows`."""
     wins, valid = _send_windows(nprocs, B, start, rows, counts_local)
-    return jnp.where(valid, wins, jnp.zeros((), rows.dtype))
+    with jax.named_scope("windows"):
+        return jnp.where(valid, wins, jnp.zeros((), rows.dtype))
 
 
 def _recv_buffer(cap_out: int, pad: int, like):
@@ -174,7 +180,8 @@ def _recv_buffer(cap_out: int, pad: int, like):
     that holds a valid row can run past the end and be shifted
     (``dynamic_update_slice`` clamps its start); the caller trims the
     extension off again."""
-    return jnp.zeros((cap_out + pad,) + like.shape[1:], like.dtype)
+    with jax.named_scope("unpack"):
+        return jnp.zeros((cap_out + pad,) + like.shape[1:], like.dtype)
 
 
 def _place_blocks(out, recv, base, counts_from, start: int, rebase=None):
@@ -193,15 +200,16 @@ def _place_blocks(out, recv, base, counts_from, start: int, rebase=None):
     what it read); one with a valid row starts below ``cap_out`` and the
     buffer is ``B`` rows longer (:func:`_recv_buffer`)."""
     B = recv.shape[1]
-    for j in range(recv.shape[0]):
-        pos = base[j] + start
-        block = recv[j]
-        if rebase is not None:
-            block = block.astype(out.dtype) + rebase[j]
-        keep = rows_below(counts_from[j] - start, B, block.ndim)
-        here = lax.dynamic_slice_in_dim(out, pos, B)
-        out = lax.dynamic_update_slice_in_dim(
-            out, jnp.where(keep, block, here), pos, 0)
+    with jax.named_scope("unpack"):
+        for j in range(recv.shape[0]):
+            pos = base[j] + start
+            block = recv[j]
+            if rebase is not None:
+                block = block.astype(out.dtype) + rebase[j]
+            keep = rows_below(counts_from[j] - start, B, block.ndim)
+            here = lax.dynamic_slice_in_dim(out, pos, B)
+            out = lax.dynamic_update_slice_in_dim(
+                out, jnp.where(keep, block, here), pos, 0)
     return out
 
 
@@ -228,7 +236,8 @@ def _a2a_hier(send, mesh):
 
 def _exchange_counts(counts_local, mesh):
     """Exchange per-dest counts: counts_from[j] = rows shard j sends me."""
-    return _exchange_blocks(counts_local[:, None], mesh)[:, 0]
+    with jax.named_scope("exchange"):
+        return _exchange_blocks(counts_local[:, None], mesh)[:, 0]
 
 
 def _exchange_blocks(send, mesh):
@@ -237,9 +246,10 @@ def _exchange_blocks(send, mesh):
     ICI-then-DCN (:func:`_a2a_hier`: a flat exchange would cross DCN on
     most hops), a one-axis mesh is one ``lax.all_to_all``."""
     axes = mesh_axes(mesh)
-    if len(axes) == 2:
-        return _a2a_hier(send, mesh)
-    return lax.all_to_all(send, axes[0], 0, 0)
+    with jax.named_scope("exchange"):
+        if len(axes) == 2:
+            return _a2a_hier(send, mesh)
+        return lax.all_to_all(send, axes[0], 0, 0)
 
 
 def _dest_fn(dest, nprocs: int, mesh) -> Callable:
@@ -421,8 +431,10 @@ def phase2_shard_body(nprocs: int, mesh, B: int, nrounds: int,
     send block is windows of the dest-sorted shard
     (:func:`_send_windows`): both sides move runs, indexed by ``P``
     offsets, not rows indexed one by one."""
+    # (the steps are the helpers': ``windows``, ``exchange``, ``unpack``)
     counts_from = _exchange_counts(cl, mesh)
-    base = _run_starts(counts_from)
+    with jax.named_scope("unpack"):
+        base = _run_starts(counts_from)
     out_k = _recv_buffer(cap_out, B, k)
     out_v = _recv_buffer(cap_out, B, v)
     for r in range(nrounds):
@@ -430,7 +442,8 @@ def phase2_shard_body(nprocs: int, mesh, B: int, nrounds: int,
         recv_v = _exchange_blocks(_build_send(nprocs, B, v, cl, r), mesh)
         out_k = _place_blocks(out_k, recv_k, base, counts_from, r * B)
         out_v = _place_blocks(out_v, recv_v, base, counts_from, r * B)
-    return out_k[:cap_out], out_v[:cap_out], jnp.sum(counts_from)
+    with jax.named_scope("unpack"):
+        return out_k[:cap_out], out_v[:cap_out], jnp.sum(counts_from)
 
 
 def _phase2_jit(mesh, B: int, nrounds: int, cap_out: int,

@@ -82,6 +82,13 @@ class StagedGraph:
         self.src, self.dst, self.valid = src, dst, valid
         self.weights = weights
 
+    @property
+    def rows(self) -> int:
+        """The rows of the staged columns, valid or not (on a mesh the
+        shards times the frame's per-shard capacity): what a loop over
+        them iterates, the spans' ``edge_rows``.  0 for an empty graph."""
+        return 0 if self.src is None else int(self.src.shape[0])
+
 
 def stage_graph(mr, comm, drop_self: bool = False,
                 need_weights: bool = False) -> Optional[StagedGraph]:
@@ -167,19 +174,20 @@ def _rank_fn(mesh, nrows: int, drop_self: bool):
     @functools.partial(jax.jit,
                        out_shardings=(shard, rep, rep, shard, shard, shard))
     def stage_rank_graph(key, counts):
-        valid = _valid_rows(nrows, nprocs, counts)
-        if drop_self:
-            valid = valid & (key[:, 0] != key[:, 1])
-        # vertex id 2^64-1 IS the padding sentinel — count real
-        # occurrences so the host wrapper can refuse instead of
-        # silently dropping the vertex
-        nbad = jnp.sum((valid[:, None] & (key == SENTINEL))
-                       .astype(jnp.int32))
-        # the two columns end to end, not interleaved: position p is
-        # row p % nrows, and no [2E] <-> [E, 2] relayout is needed (on
-        # the chip that one is a 64x tile-padded copy)
-        flat = jnp.concatenate([jnp.where(valid, key[:, 0], SENTINEL),
-                                jnp.where(valid, key[:, 1], SENTINEL)])
+        with jax.named_scope("endpoints"):
+            valid = _valid_rows(nrows, nprocs, counts)
+            if drop_self:
+                valid = valid & (key[:, 0] != key[:, 1])
+            # vertex id 2^64-1 IS the padding sentinel — count real
+            # occurrences so the host wrapper can refuse instead of
+            # silently dropping the vertex
+            nbad = jnp.sum((valid[:, None] & (key == SENTINEL))
+                           .astype(jnp.int32))
+            # the two columns end to end, not interleaved: position p is
+            # row p % nrows, and no [2E] <-> [E, 2] relayout is needed
+            # (on the chip that one is a 64x tile-padded copy)
+            flat = jnp.concatenate([jnp.where(valid, key[:, 0], SENTINEL),
+                                    jnp.where(valid, key[:, 1], SENTINEL)])
         with jax.named_scope("sort"):
             s, origin = lax.sort((flat, lax.iota(pos_t, m)), num_keys=1)
         with jax.named_scope("rank"):
@@ -198,7 +206,7 @@ def _rank_fn(mesh, nrows: int, drop_self: bool):
             # the ranks go back to edge order by the carried positions
             _, back = lax.sort((origin, rank.astype(jnp.int32)),
                                num_keys=1)
-        return verts, n, nbad, back[:nrows], back[nrows:], valid
+            return verts, n, nbad, back[:nrows], back[nrows:], valid
 
     return stage_rank_graph
 
@@ -209,7 +217,8 @@ def _trim_fn(mesh, nout: int):
 
     @functools.partial(jax.jit, out_shardings=rep)
     def stage_trim_verts(x):
-        return x[:nout]
+        with jax.named_scope("trim"):
+            return x[:nout]
 
     return stage_trim_verts
 

@@ -41,6 +41,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -326,10 +327,11 @@ def _encode_windows(wins, valid, base_bits, pack: Optional[str]):
     fit the pack width by construction (the planner checked the
     ranges); the rest of a window is other buckets' rows or padding and
     is masked, as the raw send block's is."""
-    if pack is not None:
-        base = _base_in(base_bits, wins.dtype)
-        wins = (wins - base[:, None]).astype(jnp.dtype(pack))
-    return jnp.where(valid, wins, jnp.zeros((), wins.dtype))
+    with jax.named_scope("windows"):
+        if pack is not None:
+            base = _base_in(base_bits, wins.dtype)
+            wins = (wins - base[:, None]).astype(jnp.dtype(pack))
+        return jnp.where(valid, wins, jnp.zeros((), wins.dtype))
 
 
 def phase2_wire_shard_body(nprocs: int, mesh, tiers, cap_out: int,
@@ -351,14 +353,16 @@ def phase2_wire_shard_body(nprocs: int, mesh, tiers, cap_out: int,
     from .shuffle import (_exchange_blocks, _place_blocks, _recv_buffer,
                           _run_starts, _send_windows)
 
-    meta_local = jnp.stack([cl.astype(jnp.uint64), stats[:, 0],
-                            stats[:, 2]], axis=1)          # [P, 3]
-    meta_from = _exchange_blocks(meta_local[:, None, :], mesh)[:, 0, :]
-    counts_from = meta_from[:, 0].astype(jnp.int32)
-    kbase = _base_in(meta_from[:, 1], k.dtype) if kpack else None
-    vbase = _base_in(meta_from[:, 2], v.dtype) if vpack else None
-
-    base = _run_starts(counts_from)
+    # (the steps are the helpers': ``windows``, ``exchange``, ``unpack``)
+    with jax.named_scope("exchange"):
+        meta_local = jnp.stack([cl.astype(jnp.uint64), stats[:, 0],
+                                stats[:, 2]], axis=1)          # [P, 3]
+        meta_from = _exchange_blocks(meta_local[:, None, :], mesh)[:, 0, :]
+    with jax.named_scope("unpack"):
+        counts_from = meta_from[:, 0].astype(jnp.int32)
+        kbase = _base_in(meta_from[:, 1], k.dtype) if kpack else None
+        vbase = _base_in(meta_from[:, 2], v.dtype) if vpack else None
+        base = _run_starts(counts_from)
     out_k = _recv_buffer(cap_out, max(tiers), k)
     out_v = _recv_buffer(cap_out, max(tiers), v)
     start = 0
@@ -374,7 +378,8 @@ def phase2_wire_shard_body(nprocs: int, mesh, tiers, cap_out: int,
         out_v = _place_blocks(out_v, recv_v, base, counts_from, start,
                               rebase=vbase)
         start += B
-    return out_k[:cap_out], out_v[:cap_out], jnp.sum(counts_from)
+    with jax.named_scope("unpack"):
+        return out_k[:cap_out], out_v[:cap_out], jnp.sum(counts_from)
 
 
 # ---------------------------------------------------------------------------

@@ -418,11 +418,19 @@ def test_stage_spans_say_where_the_ranking_ran(nprocs, traced, tmp_path):
         s.run_string(line)
     events = traced.events()
     by_id = {e["id"]: e for e in events}
-    for stage, cmd in ((names.CC_STAGE, "oink.cc_find"),
-                       (names.PAGERANK_STAGE, "oink.pagerank")):
+    from gpu_mapreduce_tpu.parallel.staging import mesh_kv_frame
+    for stage, cmd, mr in ((names.CC_STAGE, "oink.cc_find", "mru"),
+                           (names.PAGERANK_STAGE, "oink.pagerank", "mre")):
         (a,) = [e["args"] for e in events if e["name"] == stage]
         assert a["on_device"] == (1 if nprocs else 0), stage
         assert a["n"] > 0 and a["edges"] > 0
+        # the rows the ranking ran over (ISSUE 48): the frame's, padding
+        # and all, on a mesh; the edges themselves on the host
+        frame = mesh_kv_frame(s.obj.get_mr(mr)) if nprocs else None
+        rows = frame.key.shape[0] if nprocs else a["edges"]
+        assert a[names.ATTR_EDGE_ROWS] == rows >= a[names.ATTR_EDGES], stage
+        if nprocs:
+            assert rows % nprocs == 0
         scans = [e for e in events if e["name"] == "scan_kv"
                  and by_id[e["parent"]]["name"] == stage]
         assert len(scans) == (0 if nprocs else 1), (stage, cmd)
@@ -457,6 +465,12 @@ def test_loop_spans_say_what_the_mesh_merged(nprocs, traced, tmp_path):
         assert a["shards"] == shards and a["iters"] >= 1 and a["n"] > 0
         want = a["n"] * 4 * a["iters"] if shards > 1 else 0
         assert a["allreduce_bytes"] == want, loop
+        # what the loop iterates, beside the valid edges (ISSUE 48): the
+        # staged columns' rows, which its stage span says as well
+        stage = args[loop.replace(".loop", ".stage")]
+        assert a[names.ATTR_EDGE_ROWS] == stage[names.ATTR_EDGE_ROWS] \
+            >= a[names.ATTR_EDGES] == stage[names.ATTR_EDGES] > 0, loop
+        assert a[names.ATTR_EDGE_ROWS] % shards == 0
     # the helper by itself: bytes of ``count`` merges of an [n] vector
     assert allreduce_bytes(1, 10, 3) == 0
     assert allreduce_bytes(4, 10, 3) == 120
@@ -495,6 +509,12 @@ def test_enumeration_commands_emit_their_spans(mesh, traced, tmp_path):
         r"Luby_find: (\d+) MIS vertices in (\d+) iterations", screen).groups())
     loop = args[names.LUBY_ENGINE]
     assert loop["iters"] == rounds >= 1
+    # every loop and stage span of the suite says its rows (ISSUE 48)
+    for span in (names.LUBY_STAGE, names.LUBY_ENGINE, names.SSSP_STAGE,
+                 names.SSSP_ENGINE):
+        a = args[span]
+        assert a[names.ATTR_EDGE_ROWS] >= a[names.ATTR_EDGES] > 0, span
+    assert loop[names.ATTR_EDGE_ROWS] == loop["rows"]
     # what names.py promises of the span: how much a round reads
     assert loop["edges"] == args[names.LUBY_STAGE]["edges"] \
         == s.obj.get_mr("mru").kv.nkv
@@ -925,3 +945,135 @@ def test_the_new_spans_and_attrs_are_declared():
         assert attr in names.SPAN_ATTRS
     assert names.JOIN_ROWS in names.PROGRAMS
     assert names.KV_SCAN_PREFIX in names.PROGRAM_PREFIXES
+
+
+# -- steps (ISSUE 48) -----------------------------------------------------------
+
+def _count_dev(uk, nv, vo, vals, gc, vc):
+    return uk, nv, nv > 0
+
+
+def _keep_even_dev(k, v, c):
+    return k, v, k[:, 0] % 2 == 0
+
+
+def _step_programs(mesh):
+    """declared name (or prefix) -> lowered programs that between them
+    run every step ``names.STEPS`` declares for it: ``_programs``, with
+    other arguments where the tiny ones take a branch that skips a step."""
+    from gpu_mapreduce_tpu.models import sssp, tri
+    from gpu_mapreduce_tpu.parallel import devkernels, group, shuffle
+    u64, i32 = jnp.uint64, jnp.int32
+    key, col, cnt = SDS((64, 2), u64), SDS((64,), u64), SDS((8,), i32)
+    f64 = SDS((64,), jnp.float64)       # a value that never rides a sort
+    edges = (SDS((64,), i32), SDS((64,), i32), SDS((64,), jnp.bool_))
+    out = {name: [lowered] for name, lowered in _programs(mesh)}
+    out[names.KV_MAP_PREFIX] = out.pop(names.KV_MAP_PREFIX + "edge_upper")
+    out[names.KMV_MAP_PREFIX] = [devkernels._skmv_map_jit(
+        mesh, _count_dev, (), 0).lower(
+            col, SDS((64,), i32), SDS((64,), i32), col, cnt, cnt)]
+    out[names.KV_SCAN_PREFIX] = [devkernels._skv_rows_jit(
+        mesh, _keep_even_dev, (), 0, True).lower(key, col, cnt)]
+    out[names.CONVERT_SORT] = [
+        group._convert_phase1_jit(mesh).lower(key, f64, cnt)]
+    out[names.SORT_ROWS] = [
+        group._sort_jit(mesh, "key", False).lower(col, f64, cnt)]
+    # groups cut to fewer than the rows: the offsets are a slice of them
+    out[names.CONVERT_LAYOUT] = [group._convert_phase2_jit(mesh, 4).lower(
+        key, SDS((64,), jnp.bool_), cnt)]
+    out[names.SHUFFLE_PHASE1] = [shuffle._phase1_jit(
+        mesh, ("hash", None), False, wire=(True, True)).lower(col, col, cnt)]
+    tile = SDS((8,), i32)
+    out[names.TRI_WEDGES].append(tri._programs(mesh).wedges.lower(
+        col, tile, (tile,) * 4, SDS((), jnp.int64), SDS((), jnp.int64),
+        batch=128, block=8))
+    # whole weights: each shard's rows sorted once, ahead of the loop
+    out[names.SSSP_LOOP] = [sssp._bf_sharded_fn(mesh, 16, 16).lower(
+        edges[0], edges[1], SDS((64,), i32), edges[2], SDS((), i32))]
+    return out
+
+
+@pytest.fixture(scope="module")
+def step_programs(mesh):
+    return _step_programs(mesh)
+
+
+def _scope_components(lowered) -> set:
+    """Every component but the last (the primitive's own name) of every
+    operation's name-stack path in the lowered program's debug info."""
+    out = set()
+    for path in re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)):
+        out.update(path.split("/")[:-1])
+    return out
+
+
+@pytest.mark.parametrize("program", sorted(names.STEPS))
+def test_every_declared_step_is_a_scope_of_its_program(step_programs,
+                                                       program):
+    """What a metric file quotes is in the program: each step of
+    ``names.STEPS[program]`` is a component of some operation's scope path
+    in the program as lowered (which is what the compiler copies into every
+    instruction's ``op_name``, and the profiler into the trace)."""
+    steps = names.STEPS[program]
+    assert len(set(steps)) == len(steps) > 0
+    for step in steps:
+        assert re.fullmatch(r"[a-z][a-z0-9_]*", step), step
+        assert step not in names.STEP_RESERVED, step
+    found = set().union(*map(_scope_components, step_programs[program]))
+    assert set(steps) <= found, (program, set(steps) - found)
+    assert names.steps_of(program + ("x" if program.endswith("_") else "")) \
+        == steps
+
+
+def test_steps_cover_the_declared_programs_and_the_source():
+    """``STEPS`` has every declared program and prefix and nothing else;
+    every ``jax.named_scope("...")`` literal of the package is a declared
+    step of some program, and every declared step is such a literal: a
+    scope renamed in the source alone, or in ``names.py`` alone, fails
+    here before it empties a metric."""
+    assert set(names.STEPS) == set(names.PROGRAMS) | set(
+        names.PROGRAM_PREFIXES)
+    assert names.steps_of("jit_run") == ()
+    root = os.path.dirname(os.path.abspath(names.__file__))
+    root = os.path.dirname(root)            # gpu_mapreduce_tpu/
+    literals = set()
+    for d, _dirs, files in os.walk(root):
+        for fn in files:
+            if fn.endswith(".py"):
+                with open(os.path.join(d, fn)) as f:
+                    literals.update(re.findall(
+                        r'jax\.named_scope\(\s*"([^"]+)"', f.read()))
+    declared = {s for steps in names.STEPS.values() for s in steps}
+    assert literals == declared, (literals ^ declared)
+
+
+@pytest.fixture(scope="module")
+def lowered_twice(mesh):
+    """name -> (text as lowered, text lowered with ``jax.named_scope``
+    replaced by nothing), debug info stripped, of every ``_programs``
+    program.  The builders and JAX cache their traces, so the caches go
+    before the second lowering, and again after it."""
+    with_scopes = {n: low.as_text() for n, low in _programs(mesh)}
+    real = jax.named_scope
+    jax.named_scope = lambda name: contextlib.nullcontext()
+    jax.clear_caches()
+    try:
+        bare = {n: (low.as_text(), _scope_components(low))
+                for n, low in _programs(mesh)}
+    finally:
+        jax.named_scope = real
+        jax.clear_caches()
+    return with_scopes, bare
+
+
+@pytest.mark.parametrize(
+    "program", names.PROGRAMS + (names.KV_MAP_PREFIX + "edge_upper",))
+def test_scopes_change_no_operation_of_a_program(lowered_twice, program):
+    """Scopes are metadata (ISSUE 48): the StableHLO of a program, debug
+    info stripped, is the same text with its ``jax.named_scope``s and
+    without, so the persistent cache (whose key strips debug info) serves
+    the parent's executable and nothing recompiles."""
+    with_scopes, bare = lowered_twice
+    text, components = bare[program]
+    assert not components & set(names.steps_of(program)), program
+    assert with_scopes[program] == text
