@@ -125,9 +125,12 @@ def front_order(keep):
     int32 operand, the row index or a fill: no payload rides it, so it
     compiles in seconds where a sort that carries the rows takes minutes
     (7 s against 176 s at 6 x 10^7 rows of five operands on the v5e's
-    compiler, PERF.md §6, PR 43), and no scatter (``devkernels._pack``'s costs 0.115 us
-    a row there).  The rows come by ``order`` afterwards, as many as are
-    wanted (``parallel/devkernels.skv_scan``, ``parallel/group.join_sharded``)."""
+    compiler, PERF.md §6, PR 43), and no scatter (a prefix sum and two
+    scatters with dropped rows cost 0.115 us a row there: PERF.md §6,
+    PR 49; ``devkernels._pack`` sorts by this key with the rows riding,
+    because a mapper keeps most of its rows).  The rows come by ``order``
+    afterwards, as many as are wanted (``parallel/devkernels.skv_scan``,
+    ``parallel/group.join_sharded``)."""
     n = keep.shape[0]
     row = jnp.arange(n, dtype=jnp.int32)
     order, = lax.sort((jnp.where(keep, row, row + n),), num_keys=1,

@@ -13,10 +13,11 @@ same scalars the reference Allreduces after every op
 
 Kernel bodies follow one convention: they receive the shard's padded
 blocks and return ``(key_rows, value_rows, valid_mask)`` of one static
-shape; the wrapper packs valid rows to the front (stable, so emission
-order within a shard is deterministic), counts them, and wraps a new
-:class:`ShardedKV`.  Row counts per shard are data-dependent — the pack +
-count IS the TPU version of the reference's "emit into the open KV page".
+shape; the wrapper brings the valid rows to the front by one payload sort
+keyed by the row index (:func:`_pack`: emission order within a shard is
+kept, zero rows follow), counts them, and wraps a new :class:`ShardedKV`.
+Row counts per shard are data-dependent — the pack + count IS the TPU
+version of the reference's "emit into the open KV page".
 """
 
 from __future__ import annotations
@@ -31,7 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from .group import _local_segment_ids
 from .mesh import mesh_axes, mesh_axis_size, row_sharding, row_spec
-from ..ops.sort import front_order, take_together
+from ..ops.sort import front_order, sort_carrying, take_together
 from .sharded import (ShardedKMV, ShardedKV, SyncStats, fill_counts,
                       front_cap, round_cap, rows_below, window_rows)
 
@@ -54,20 +55,28 @@ def _body_name(fn) -> str:
 
 
 def _pack(ok, ov, valid):
-    """Stable front-packing via prefix-sum + scatter-with-drop — the same
-    idiom compact_word_matches documents (positions are unique by
-    construction).  "~20× cheaper than the sort-based form" was read on
-    the CPU backend: on the v5e a 16.8 M-element u64 scatter with dropped
-    rows costs 1.79 s against 0.055 s for a sort of the same size
-    (PERF.md §6, PR 25), and this pack of 8.4 M rows is 0.97 s
-    (``jit_kv_map_edge_upper``, PERF.md §5).  Inputs that are already
-    front-packed never need it: :func:`_append`."""
+    """The rows flagged in ``valid`` first, in their emission order, zero
+    rows after them, and their count as ``int32[1]``.
+
+    ONE unstable ``lax.sort`` keyed by the row index (kept) or the block's
+    length (dropped), the rows riding it as payloads
+    (:func:`~..ops.sort.sort_carrying`: a column rides within
+    ``RIDE_WORDS``; a float64 value or a wider row comes by the sorted row
+    index and one ``take``).  The kept rows' keys are distinct, so the
+    order is the stable one; the dropped rows tie, and what they carried
+    comes back as zeros.  No prefix sum and no scatter: on the v5e
+    ``edge_upper``'s 8.4 M rows of 17 bytes take 0.0327 s this way and
+    took 0.969 s by ``cumsum`` and two ``.at[].set(mode="drop")`` (PERF.md
+    §6, PR 49; the CPU backend says the opposite, ~20x).  Inputs that are
+    already front-packed never need it: :func:`_append`."""
     n = valid.shape[0]
-    pos = jnp.cumsum(valid.astype(jnp.int32)) - 1
-    tgt = jnp.where(valid, pos, n)
-    okey = jnp.zeros_like(ok).at[tgt].set(ok, mode="drop")
-    oval = jnp.zeros_like(ov).at[tgt].set(ov, mode="drop")
-    return okey, oval, jnp.sum(valid.astype(jnp.int32))[None]
+    row = jnp.arange(n, dtype=jnp.int32)
+    count = jnp.sum(valid, dtype=jnp.int32)
+    _, rows = sort_carrying((jnp.where(valid, row, n),), (ok, ov),
+                            stable=False)
+    okey, oval = (jnp.where(rows_below(count, n, x.ndim), x,
+                            jnp.zeros((), x.dtype)) for x in rows)
+    return okey, oval, count[None]
 
 
 @functools.lru_cache(maxsize=None)

@@ -154,23 +154,27 @@ def test_byte_keyed_host_frame_keeps_the_host_path(mesh, traced):
     assert sorted(got) == want
 
 
-# -- (b) the append against the scatter form it replaces -----------------------
+# -- (b) the append against the pack form it replaces --------------------------
 
 def _pack_concat(a: ShardedKV, b: ShardedKV):
     """The form before PR 27: concatenate the two blocks of a shard and
-    front-pack by prefix-sum + scatter (``devkernels._pack``)."""
+    front-pack the valid rows.  ``devkernels._pack`` did that by a prefix
+    sum and two scatters then and does it by one payload sort since PR 49
+    (``tests/test_pack.py``); the oracle for ``_append`` is neither: numpy's
+    own indexing, the kept rows first and zero rows after."""
     P = a.nprocs
     ks, vs, cs = [], [], []
     for i in range(P):
         blk = lambda x, cap: np.asarray(x)[i * cap:(i + 1) * cap]
         valid = np.concatenate([np.arange(a.cap) < a.counts[i],
                                 np.arange(b.cap) < b.counts[i]])
-        k, v, c = devkernels._pack(
-            jnp.concatenate([blk(a.key, a.cap), blk(b.key, b.cap)]),
-            jnp.concatenate([blk(a.value, a.cap), blk(b.value, b.cap)]),
-            jnp.asarray(valid))
-        ks.append(np.asarray(k)), vs.append(np.asarray(v))
-        cs.append(int(c[0]))
+        at = np.flatnonzero(valid)
+        for out, xa, xb in ((ks, a.key, b.key), (vs, a.value, b.value)):
+            rows = np.concatenate([blk(xa, a.cap), blk(xb, b.cap)])
+            packed = np.zeros_like(rows)
+            packed[:len(at)] = rows[at]
+            out.append(packed)
+        cs.append(len(at))
     return ks, vs, cs
 
 
@@ -217,7 +221,9 @@ def test_append_equals_the_pack_form(mesh, case, width):
 
 def test_append_lowers_to_a_copy(mesh):
     """(c) no scatter, no sort, no gather in the program — and the pack
-    form it replaces does hold a scatter (the check can fail)."""
+    form it replaces does hold a sort (the check can fail): exactly one,
+    with no scatter and no prefix sum beside it since PR 49, and one gather
+    where a float64 value cannot ride."""
     u64, u8, i32 = jnp.uint64, jnp.uint8, jnp.int32
     P = mesh_axis_size(mesh)
     SDS = jax.ShapeDtypeStruct
@@ -230,7 +236,16 @@ def test_append_lowers_to_a_copy(mesh):
     assert "dynamic_slice" in text and "select" in text
     packed = jax.jit(devkernels._pack).lower(
         SDS((48, 2), u64), SDS((48,), u8), SDS((48,), jnp.bool_)).as_text()
-    assert "scatter" in packed
+    for op in ("scatter", "gather", "cumsum", "reduce_window"):
+        assert op not in packed, op
+    assert packed.count('"stablehlo.sort"') == 1
+    by_index = jax.jit(devkernels._pack).lower(
+        SDS((48, 2), u64), SDS((48,), jnp.float64),
+        SDS((48,), jnp.bool_)).as_text()
+    for op in ("scatter", "cumsum", "reduce_window"):
+        assert op not in by_index, op
+    assert by_index.count('"stablehlo.sort"') == 1
+    assert by_index.count('"stablehlo.gather"') == 1
     placed = sharded._place_rows_jit(mesh, 32).lower(
         SDS((64, 2), u64), SDS((64,), u8), SDS((P,), i32),
         SDS((P,), i32)).as_text()

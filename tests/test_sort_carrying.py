@@ -1,9 +1,10 @@
 """``ops/sort.sort_carrying``: one payload sort, written once (ROADMAP
 C21).  Phase 1 of the shuffle (``tests/test_shuffle_phase1.py``), the
 per-shard ``sort_keys`` / ``sort_values`` program (``tests/test_terasort.py``)
-and ``convert``'s ``_local_sort`` (``tests/test_convert_sort.py``) call it;
-``_pack`` and ``rank_graph`` can take it up as call-site changes, so the
-helper is held to numpy here, alone."""
+and ``convert``'s ``_local_sort`` (``tests/test_convert_sort.py``) call it,
+and since PR 49 the device mappers' ``_pack`` (``tests/test_pack.py``);
+``rank_graph`` can take it up as a call-site change, so the helper is held
+to numpy here, alone."""
 
 import re
 
