@@ -1,5 +1,6 @@
 """TPC-H on the op algebra: three tables resident as MR objects, and
-Query 3 ("Shipping Priority") over them.
+Query 3 ("Shipping Priority") and Query 1 ("Pricing Summary Report",
+:func:`q1`, at the end) over them.
 
     select l_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,
            o_orderdate, o_shippriority
@@ -77,6 +78,15 @@ _O_PRIORITY = COLUMNS["orders"].index("shippriority")
 _L_PRICE = COLUMNS["lineitem"].index("extendedprice")
 _L_DISCOUNT = COLUMNS["lineitem"].index("discount")
 _L_SHIPDATE = COLUMNS["lineitem"].index("shipdate")
+_L_QUANTITY = COLUMNS["lineitem"].index("quantity")
+_L_TAX = COLUMNS["lineitem"].index("tax")
+_L_RETURNFLAG = COLUMNS["lineitem"].index("returnflag")
+_L_LINESTATUS = COLUMNS["lineitem"].index("linestatus")
+# the letters of the two flag columns' codes; Query 1's key is the letters
+# themselves, so the key's order is the ORDER BY's
+RETURNFLAGS = "ARN"
+LINESTATUSES = "FO"
+Q1_ANCHOR = "1998-12-01"
 
 
 def day(date: str) -> int:
@@ -153,12 +163,34 @@ def _rank_rows(xp, k, v):
     return k, xp.stack([v, -k[:, 2].astype(xp.int64)], 1), None
 
 
-def _mapper(rows: Callable, filters: bool = True) -> Callable:
+def _q1_rows(xp, k, v, bound):
+    """l_shipdate < :bound (the day after the query's last); keyed by the
+    letters of (l_returnflag, l_linestatus); six int64 columns: quantity,
+    extendedprice (cents), extendedprice * (100 - discount) (10^-4
+    dollars), that * (100 + tax) (10^-6), discount (hundredths), 1."""
+    i64 = lambda j: v[:, j].astype(xp.int64)
+    price = _i64(xp, v[:, _L_PRICE], v[:, _L_PRICE + 1])
+    net = price * (100 - i64(_L_DISCOUNT))
+    a, r, n = (xp.uint32(ord(c)) for c in RETURNFLAGS)
+    f, o = (xp.uint32(ord(c)) for c in LINESTATUSES)
+    flag, status = v[:, _L_RETURNFLAG], v[:, _L_LINESTATUS]
+    key = xp.stack([xp.where(flag == 0, a, xp.where(flag == 1, r, n)),
+                    xp.where(status == 0, f, o)], 1)
+    value = xp.stack([i64(_L_QUANTITY), price, net, net * (100 + i64(_L_TAX)),
+                      i64(_L_DISCOUNT), xp.ones(k.shape[0], xp.int64)], 1)
+    return key, value, v[:, _L_SHIPDATE] < bound
+
+
+def _mapper(rows: Callable, filters: bool = True,
+            folded: bool = False) -> Callable:
     """The ``map_mr(batch=True)`` callback of a ``rows`` function; ``ptr``
     is the tuple of its operands (a segment code, a date).  On a mesh a
     map that filters is a scan (``skv_scan``: program
     ``jit_kv_scan_tpch_<rows>``), one that keeps every row a plain map of
-    the rows where they lie (``skv_each``: ``jit_kv_map_tpch_<rows>``)."""
+    the rows where they lie (``skv_each``: ``jit_kv_map_tpch_<rows>``);
+    a scan whose rows ``compress`` folds next (``folded``) is counted and
+    deferred (``skv_keep``): the combiner applies it where the source's
+    rows lie, and nothing is written or packed."""
     import jax.numpy as jnp
 
     def dev(k, v, c, *operands):
@@ -180,8 +212,9 @@ def _mapper(rows: Callable, filters: bool = True) -> Callable:
             keep = slice(None) if keep is None else keep
             kv.add_batch(key[keep], value[keep])
         else:
-            from ..parallel.devkernels import skv_each, skv_scan
-            kv.add_frame((skv_scan if filters else skv_each)(
+            from ..parallel.devkernels import skv_each, skv_keep, skv_scan
+            run = skv_keep if folded else skv_scan if filters else skv_each
+            kv.add_frame(run(
                 fr, dev, extra=tuple(jnp.uint32(x) for x in operands)))
     return batch
 
@@ -191,18 +224,23 @@ SCAN = {"customer": _mapper(_customer_rows), "orders": _mapper(_orders_rows),
 _BY_ORDERKEY = _mapper(_by_orderkey_rows, filters=False)
 _GROUPS = _mapper(_groups_rows, filters=False)
 _RANK = _mapper(_rank_rows, filters=False)
+_Q1_SCAN = _mapper(_q1_rows, folded=True)
+# Query 1's two device programs, as a trace names them: the count of the
+# rows the date keeps, and the combiner that applies the map where they lie
+Q1_PROGRAMS = (names.KV_SCAN_PREFIX + "tpch_q1",
+               names.COMBINE_PREFIX + "tpch_q1")
 # the scans' device programs, as a trace names them
 SCAN_PROGRAMS = tuple(names.KV_SCAN_PREFIX + "tpch_" + t for t in TABLES)
 
 
 def _scan(new_mr: Callable, table: str, source: MapReduce,
-          operand: int, counts: dict) -> MapReduce:
+          operand: int, counts: dict, scan: Callable = None) -> MapReduce:
     mr = new_mr()
     with get_tracer().span(names.TPCH_SCAN, cat=names.HOST,
                            table=table) as sp:
         rows_in = int(source.kv_stats(0)[0])
-        rows_out = int(mr.map_mr(source, SCAN[table], ptr=(operand,),
-                                 batch=True))
+        rows_out = int(mr.map_mr(source, scan or SCAN[table],
+                                 ptr=(operand,), batch=True))
         sp.set(**{names.ATTR_ROWS_IN: rows_in,
                   names.ATTR_ROWS_OUT: rows_out,
                   names.ATTR_ROW_WORDS_IN: (
@@ -290,3 +328,88 @@ def message(segment: str, date: str, counts: dict, lines: int) -> str:
     return (f"TPC-H Q3 {segment} {date}: rows kept {kept}; "
             f"{counts['orders_joined']} orders and {counts['lines_joined']} "
             f"lines joined; {counts['groups']} groups, {lines} lines")
+
+
+# -- Query 1 ------------------------------------------------------------------
+#     select l_returnflag, l_linestatus, sum(l_quantity),
+#            sum(l_extendedprice), sum(l_extendedprice * (1 - l_discount)),
+#            sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)),
+#            avg(l_quantity), avg(l_extendedprice), avg(l_discount), count(*)
+#     from lineitem where l_shipdate <= date '1998-12-01' - :delta days
+#     group by l_returnflag, l_linestatus
+#     order by l_returnflag, l_linestatus
+
+def _decimal(x: int, places: int) -> str:
+    """The integer ``x`` of 10^-places units, with its decimal point."""
+    x, one = int(x), 10 ** places
+    return f"{'-' if x < 0 else ''}{abs(x) // one}.{abs(x) % one:0{places}d}"
+
+
+def _average(total: int, count: int, scale: int) -> int:
+    """``total * scale / count`` rounded half up, in integers."""
+    return (2 * int(total) * scale + int(count)) // (2 * int(count))
+
+
+def q1_line(key, sums) -> str:
+    """``l_returnflag|l_linestatus|sum_qty|sum_base_price|sum_disc_price|
+    sum_charge|avg_qty|avg_price|avg_disc|count_order`` of a group: the
+    sums from their integers (cents, 10^-4 and 10^-6 dollars), the
+    averages of quantity and price with two decimals and of the discount
+    with four, each the integer sum over the count rounded half up."""
+    qty, price, net, charge, disc, count = (int(x) for x in sums)
+    return "|".join((
+        chr(int(key[0])), chr(int(key[1])), str(qty), _decimal(price, 2),
+        _decimal(net, 4), _decimal(charge, 6),
+        _decimal(_average(qty, count, 100), 2),
+        _decimal(_average(price, count, 1), 2),
+        _decimal(_average(disc, count, 100), 4), str(count)))
+
+
+def q1(new_mr: Callable, lineitem: MapReduce, delta_days: int,
+       path: Optional[str] = None):
+    """Run Query 1 over the resident ``lineitem``: ``(groups, lines,
+    counts)``, the MR object of the groups ((l_returnflag, l_linestatus)
+    as their letters' codes -> six int64: the four sums, the discounts'
+    sum, the count), their lines in the key's order, written to ``path``
+    (closed when this returns), and ``lineitem -> (rows, rows kept)`` and
+    ``groups``.  One map that keeps l_shipdate <= 1998-12-01 -
+    ``delta_days`` (on a mesh counted now and run inside the combiner),
+    ``compress(sum)`` (the combiner folds the table's rows where they
+    lie), ``collate``,
+    ``reduce(sum)``, ``gather(1)`` + ``sort_keys``.  The table is left as
+    it was."""
+    if isinstance(delta_days, bool) or not isinstance(delta_days, int) \
+            or delta_days < 0:
+        raise MRError(f"tpch: DELTA {delta_days!r} is no number of days "
+                      f"(a whole number, 0 or more)")
+    bound = max(0, day(Q1_ANCHOR) - delta_days + 1)
+    tracer = get_tracer()
+    counts = {}
+    with tracer.span(names.TPCH_Q1, cat=names.ENTRY, delta=delta_days):
+        groups = _scan(new_mr, "lineitem", lineitem, bound, counts,
+                       scan=_Q1_SCAN)
+        groups.compress(sum_values, batch=True)
+        groups.collate()
+        counts["groups"] = groups.reduce(sum_values, batch=True)
+        groups.gather(1)
+        groups.sort_keys(1)
+        with tracer.span(names.TPCH_EMIT, cat=names.HOST,
+                         rows=counts["groups"]) as sp:
+            fr = groups.kv.one_frame()
+            fr = fr if isinstance(fr, KVFrame) else fr.to_host()
+            lines = [q1_line(k, v) for k, v in zip(
+                np.asarray(fr.key.data).reshape(-1, 2).tolist(),
+                np.asarray(fr.value.data).reshape(-1, 6).tolist())]
+            if path is not None:
+                os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+                with open(path, "w") as f:
+                    f.write("".join(l + "\n" for l in lines))
+                sp.set(bytes=os.path.getsize(path))
+    return groups, lines, counts
+
+
+def q1_message(delta_days: int, counts: dict, lines: int) -> str:
+    """What the command says of a run: rows scanned, rows kept, groups."""
+    rows, kept = counts["lineitem"]
+    return (f"TPC-H Q1 DELTA {delta_days}: {rows} lineitem rows scanned, "
+            f"{kept} kept; {counts['groups']} groups, {lines} lines")

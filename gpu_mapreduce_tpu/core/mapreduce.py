@@ -991,10 +991,51 @@ class MapReduce:
     @_traced
     def compress(self, func: Callable, ptr=None, batch: bool = False,
                  block_rows: Optional[int] = None) -> int:
-        """Local convert + reduce, KV→KV — the combiner (reference
-        src/mapreduce.cpp:749-851).  ``block_rows`` as in :meth:`reduce`."""
+        """The combiner, KV→KV: every shard's pairs of one key become one
+        pair, locally, nothing exchanged (reference
+        src/mapreduce.cpp:749-851).  The same KV as :meth:`convert` then
+        :meth:`reduce` by ``func``, and as a rule just that; ``block_rows``
+        as in :meth:`reduce`.
+
+        Not in one case.  ``func`` one of ``ops/reduces``' registered
+        segment reduces (``sum_values``, ``count``, ``min_values``,
+        ``max_values``) with ``batch=True``, over a mesh frame of plain
+        integer values whose shards each hold at most
+        ``parallel/group.COMBINE_GROUPS`` distinct keys, is folded where
+        the rows lie by ``parallel/group.combine_sharded`` (program
+        ``jit_combine``): the distinct keys by masked minima, one masked
+        reduction a key, no sort, gather or scatter of the rows.  Which
+        road ran is decided by ``func``, the value's dtype and the
+        distinct keys the one count sync finds, never by a setting; a
+        traced run's ``compress`` span says ``combined`` 1 or 0."""
+        if batch and ptr is None and block_rows is None:
+            n = self._combine(func)
+            if n is not None:
+                return n
         self.convert()
         return self.reduce(func, ptr, batch=batch, block_rows=block_rows)
+
+    def _combine(self, func: Callable) -> Optional[int]:
+        """:meth:`compress` by the combiner where it applies: the new
+        KV's pairs, or None with the dataset as it was."""
+        op = getattr(func, "segment_op", None)
+        if op is None or self._plan is not None or self.settings.fuse:
+            return None         # a callback; or the planner's to fuse
+        from ..parallel.group import combine_sharded, combines
+        kv = self._require_kv("compress")
+        if kv.nframes != 1 or self._mesh_over_budget(kv):
+            return None
+        frame = kv.one_frame()
+        if not combines(op, frame):
+            return None
+        self._begin_op()
+        out = combine_sharded(frame, op)
+        if out is None:
+            return None
+        kv.free()
+        self.kv = self._new_kv()
+        self.kv.add_frame(out)
+        return self._finish_kv("compress")
 
     # ------------------------------------------------------------------
     # scan / print (read-only)

@@ -28,6 +28,7 @@ SORT_ROWS = "jit_sort_rows"
 SORT_INTERNED = "jit_sort_interned"
 JOIN_ROWS = "jit_join_rows"                         # both sides' keys in one sort
 JOIN_TAKE = "jit_join_take"                         # the joined rows' values
+COMBINE = "jit_combine"                             # compress of few keys: no sort
 # parallel/shuffle.py
 SHUFFLE_PHASE1 = "jit_shuffle_phase1"
 SHUFFLE_PHASE2 = "jit_shuffle_phase2"
@@ -63,7 +64,7 @@ SSSP_WEIGHTS = "jit_sssp_weights"                   # int32 where exact
 PROGRAMS = (
     INVINDEX_EXTRACT, INVINDEX_COLLISIONS, CONVERT_SORT, CONVERT_LAYOUT,
     REDUCE_SEGMENTS, GROUP_FIRST, SORT_MULTIVALUES, SORT_ROWS, SORT_INTERNED,
-    JOIN_ROWS, JOIN_TAKE, TAKE_ROWS,
+    JOIN_ROWS, JOIN_TAKE, COMBINE, TAKE_ROWS,
     SHUFFLE_PHASE1, SHUFFLE_PHASE2, SHUFFLE_PHASE2_WIRE, STAGE_RANK_GRAPH,
     STAGE_TRIM_VERTS, PLACE_ROWS, CONCAT_ROWS, LEVEL_ROWS, REMAP_IDS,
     CC_LOOP, PAGERANK_LOOP, RMAT_EDGES, RMAT_EDGE_ROWS,
@@ -77,7 +78,11 @@ PROGRAMS = (
 KV_MAP_PREFIX = "jit_kv_map_"
 KMV_MAP_PREFIX = "jit_kmv_map_"
 KV_SCAN_PREFIX = "jit_kv_scan_"
-PROGRAM_PREFIXES = (KV_MAP_PREFIX, KMV_MAP_PREFIX, KV_SCAN_PREFIX)
+# the combiner over a deferred scan (``skv_keep``) applies the scan's body
+# inside its own program: ``jit_combine_<body>``
+COMBINE_PREFIX = "jit_combine_"
+PROGRAM_PREFIXES = (KV_MAP_PREFIX, KMV_MAP_PREFIX, KV_SCAN_PREFIX,
+                    COMBINE_PREFIX)
 
 
 def declared_program(module: str) -> bool:
@@ -117,6 +122,10 @@ STEPS = {
     SORT_INTERNED: ("rank", "sort", "take"),
     JOIN_ROWS: ("sort_sides", "partners", "joined_rows_first"),
     JOIN_TAKE: ("positions", "take"),
+    # parallel/group.combine_sharded: the rows that count, the shard's
+    # distinct keys by masked minima (a bounded loop), one masked reduction
+    # a key found, the largest group's rows
+    COMBINE: ("distinct_keys", "fold", "largest_group"),
     TAKE_ROWS: ("take",),
     SHUFFLE_PHASE1: ("dest", "dest_sort", "dest_counts", "wire_stats"),
     # the exchange's helpers carry them (parallel/shuffle._send_windows,
@@ -159,6 +168,7 @@ STEPS = {
     KV_MAP_PREFIX: ("kernel", "pack"),
     KMV_MAP_PREFIX: ("kernel", "pack"),
     KV_SCAN_PREFIX: ("kernel", "pack"),
+    COMBINE_PREFIX: ("distinct_keys", "fold", "largest_group", "kernel"),
 }
 
 
@@ -185,6 +195,7 @@ INVINDEX_RUN = "invindex.run"       # apps/invertedindex.InvertedIndex.run
 OINK_SCRIPT = "oink.script"         # oink/script: the outermost script run
 TERASORT_RUN = "terasort.run"       # apps/terasort.TeraSort.run
 TPCH_Q3 = "tpch.q3"                 # apps/tpch.q3, inside the oink.script root
+TPCH_Q1 = "tpch.q1"                 # apps/tpch.q1, likewise
 
 # -- host-phase spans (cat HOST unless said) ----------------------------------
 # parallel/shuffle.aggregate_kv, before the exchange / the one-chip early-out
@@ -194,6 +205,8 @@ AGGREGATE_INTERN = "aggregate.intern"           # rows
 AGGREGATE_SHARD = "aggregate.shard"             # rows, bytes
 # parallel/group.convert_sharded: the pull between sort and layout
 CONVERT_COUNT_SYNC = "convert.count_sync"       # groups
+# parallel/group.combine_sharded: the pull of the shards' distinct-key counts
+COMBINE_COUNT_SYNC = "combine.count_sync"       # groups
 # oink/commands/rmat.py
 RMAT_GENERATE = "rmat.generate"                 # rows, d2h_bytes
 # oink/objects.py
@@ -303,7 +316,7 @@ OINK_RMAT = "oink.rmat"                         # rounds
 
 SPANS = (
     AGGREGATE_ONE_FRAME, AGGREGATE_INTERN, AGGREGATE_SHARD,
-    CONVERT_COUNT_SYNC, RMAT_GENERATE, OINK_INPUT, OINK_OUTPUT, CC_STAGE,
+    CONVERT_COUNT_SYNC, COMBINE_COUNT_SYNC, RMAT_GENERATE, OINK_INPUT, OINK_OUTPUT, CC_STAGE,
     CC_EMIT, CC_ENGINE, PAGERANK_STAGE, PAGERANK_EMIT, PAGERANK_ENGINE,
     MAP_PLAN, MAP_PAD, MAP_COLLISIONS, PARTS_PULL, PARTS_WRITE,
     SHUFFLE_EXCHANGE, SHUFFLE_COUNT_SYNC, OINK_RMAT,
@@ -313,7 +326,7 @@ SPANS = (
     INVINDEX_RUN, OINK_SCRIPT,
     INGEST_RECORDS_PLAN, INGEST_RECORDS_READ, INGEST_RECORDS_H2D,
     TERASORT_RUN, TERASORT_SAMPLE, TERASORT_PULL, TERASORT_WRITE,
-    TPCH_Q3, TPCH_LOAD, TPCH_SCAN, TPCH_TOPN, TPCH_EMIT,
+    TPCH_Q3, TPCH_Q1, TPCH_LOAD, TPCH_SCAN, TPCH_TOPN, TPCH_EMIT,
 )
 
 # -- attrs that metrics quote by name -----------------------------------------
@@ -323,6 +336,14 @@ CONVERT_SPAN = "convert"
 ATTR_ROWS = "rows"
 ATTR_GROUPS = "groups"
 ATTR_GROUP_ROWS_MAX = "group_rows_max"
+# on the ``compress`` op span of a mesh frame and a registered segment
+# reduce (parallel/group.combine_sharded): the rows, the 32-bit words of a
+# key and of a value, which road ran (``combined`` 1: the combiner folded
+# the rows where they lay; 0: ``convert`` + ``reduce`` sorted them), and on
+# the combiner's road the groups and the largest group's rows
+COMPRESS_SPAN = "compress"
+ATTR_VALUE_WORDS = "value_words"
+ATTR_COMBINED = "combined"
 # on the ``sort_keys`` / ``sort_values`` op spans of a mesh dataset
 # (parallel/group.sort_sharded): the rows sorted, the 32-bit key operands
 # of the sort, the carried words that rode it and those taken by the row
@@ -378,7 +399,8 @@ ATTR_JIT_LOWER_S = "jit_lower_s"
 ATTR_JIT_BACKEND_S = "jit_backend_s"
 ATTR_JIT_CACHE_LOADS = "jit_cache_loads"
 SPAN_ATTRS = (
-    ATTR_ROWS, ATTR_GROUPS, ATTR_GROUP_ROWS_MAX, ATTR_RECORDS, ATTR_KEY_WORDS,
+    ATTR_ROWS, ATTR_GROUPS, ATTR_GROUP_ROWS_MAX, ATTR_VALUE_WORDS,
+    ATTR_COMBINED, ATTR_RECORDS, ATTR_KEY_WORDS,
     ATTR_RODE_WORDS, ATTR_TAKEN_WORDS, ATTR_HBM_ROW_BYTES,
     ATTR_PROBE_ROWS, ATTR_BUILD_ROWS, ATTR_MATCHED_ROWS,
     ATTR_ROWS_IN, ATTR_ROWS_OUT, ATTR_ROW_WORDS_IN,

@@ -1,4 +1,4 @@
-"""tpch_load / tpch_q3 commands: ``apps/tpch`` from a script.
+"""tpch_load / tpch_q3 / tpch_q1 commands: ``apps/tpch`` from a script.
 
 ``tpch_load -i customer_files orders_files lineitem_files -o NULL customer
 -o NULL orders -o NULL lineitem``: the three inputs are files of the
@@ -9,7 +9,13 @@ put it (on the mesh, under a mesh).
 ``tpch_q3 SEGMENT DATE -i customer orders lineitem -o q3.txt mrq3``:
 Query 3 over three named tables; output 1's path takes the ten lines
 ``l_orderkey|revenue|o_orderdate|o_shippriority``, its MR name every
-group of the pre-limit result.  The tables are left as they were."""
+group of the pre-limit result.  The tables are left as they were.
+
+``tpch_q1 DELTA -i lineitem -o q1.txt mrq1``: Query 1 over the named
+``lineitem``, l_shipdate <= 1998-12-01 - DELTA days; output 1's path takes
+a line a group (``l_returnflag|l_linestatus|sum_qty|sum_base_price|
+sum_disc_price|sum_charge|avg_qty|avg_price|avg_disc|count_order``), its
+MR name the groups.  The table is left as it was."""
 
 from __future__ import annotations
 
@@ -68,4 +74,30 @@ class TpchQ3(Command):
             obj.name_mr(out.mr_name, groups)
         self.message(tpch.message(self.segment, self.date, self.counts,
                                   len(self.lines)))
+        obj.cleanup()
+
+
+@command("tpch_q1")
+class TpchQ1(Command):
+    ninputs = 1
+    noutputs = 1
+
+    def params(self, args):
+        if len(args) != 1 or not args[0].isdigit():
+            raise MRError("Illegal tpch_q1 command")
+        self.delta = int(args[0])
+
+    def run(self):
+        obj = self.obj
+        if len(obj.inputs) != 1 or obj.inputs[0].mr_name is None:
+            raise MRError("tpch_q1 reads the named lineitem table "
+                          "(tpch_load)")
+        out = obj.outputs[0] if obj.outputs else None
+        groups, self.lines, self.counts = tpch.q1(
+            obj.create_mr, obj.input(1), self.delta,
+            path=out.path if out else None)
+        if out is not None and out.mr_name is not None:
+            obj.name_mr(out.mr_name, groups)
+        self.message(tpch.q1_message(self.delta, self.counts,
+                                     len(self.lines)))
         obj.cleanup()

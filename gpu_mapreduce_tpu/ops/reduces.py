@@ -28,6 +28,11 @@ def count(frame, kv, ptr=None):
         kv.add_batch(frame.key, np.asarray(frame.nvalues))
 
 
+# what ``MapReduce.compress`` reads to know a registered segment reduce
+# (the combiner's vocabulary: parallel/group.COMBINE_OPS)
+count.segment_op = "count"
+
+
 def cull(frame, kv, ptr=None):
     """(key, [v...]) → (key, first value) — dedupe, oink reduce_cull."""
     if _is_sharded(frame):
@@ -49,6 +54,7 @@ def _segment_op(op):
             out = segment_reduce(vals, seg, len(frame), op)
             kv.add_batch(frame.key, out)
     fn.__name__ = f"reduce_{op}"
+    fn.segment_op = op
     fn.__doc__ = f"(key, [v...]) → (key, {op}(values)), columnar."
     return fn
 

@@ -216,6 +216,109 @@ def skv_scan(skv: ShardedKV, fn, static=(), extra=()) -> ShardedKV:
 
 
 @functools.lru_cache(maxsize=None)
+def _skv_keep_jit(mesh, fn, static, nextra):
+    """The program of :func:`skv_keep`: the count of the rows the body
+    keeps.  Nothing else of the body is an output, so nothing else of it
+    is computed."""
+    spec = row_spec(mesh)
+
+    def run(key, value, count, *extra):
+        def body(k, v, c, *ex):
+            with jax.named_scope("kernel"):
+                keep = fn(k, v, c[0], *ex, *static)[2]
+            with jax.named_scope("pack"):   # what is left of it: the count
+                return jnp.sum(keep, dtype=jnp.int32)[None]
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(spec, spec, spec) + (P(),) * nextra,
+            out_specs=spec)(key, value, count, *extra)
+
+    run.__name__ = "kv_scan_" + _body_name(fn)
+    return jax.jit(run)
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_types(fn, static, key, value, *extra):
+    """The key and value a kernel body makes of blocks of these types:
+    traced abstractly once a body and shape, not once a job."""
+    k, v, _ = jax.eval_shape(
+        lambda k, v, c, *ex: fn(k, v, c, *ex, *static), key, value,
+        jax.ShapeDtypeStruct((), jnp.int32), *extra)
+    return k, v
+
+
+class ScannedKV(ShardedKV):
+    """What :func:`skv_keep` returns: a scan not yet run.  It holds the
+    ``source`` frame, the kernel body and its operands (``scan``), and the
+    rows the body keeps a shard (``counts``), so it counts as the frame
+    the scan would make.  ``MapReduce.compress``'s combiner folds it as it
+    is: its program applies the body to the source's rows where they lie,
+    a tile at a time (`parallel/group.combine_sharded`), and the mapped
+    rows never exist as a block.  To every other reader it is a plain
+    :class:`ShardedKV`: the first read of ``key`` or ``value`` runs
+    :func:`skv_scan` (the kept rows ordered and taken), once, and the frame
+    is a plain one from then on."""
+
+    def __init__(self, source, fn, static, extra, counts):
+        self.mesh, self.counts, self.source = source.mesh, counts, source
+        self.scan = (fn, tuple(static), tuple(extra))
+        self.key_decode = self.value_decode = None
+        self._made = None
+
+    @property
+    def row_types(self):
+        if self._made is not None:
+            return self._made
+        fn, static, extra = self.scan
+        sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return _scan_types(fn, static, sds(self.source.key),
+                           sds(self.source.value), *map(sds, extra))
+
+    def _rows(self):
+        if self._made is None:
+            fn, static, extra = self.scan
+            made = skv_scan(self.source, fn, static, extra)
+            self._made, self.scan, self.source = (made.key, made.value), \
+                None, None
+        return self._made
+
+    key = property(lambda self: self._rows()[0])
+    value = property(lambda self: self._rows()[1])
+
+    @property
+    def cap(self) -> int:
+        rows = self.source.key if self._made is None else self._made[0]
+        return rows.shape[0] // self.nprocs
+
+    def nbytes(self) -> int:
+        return 0 if self._made is None else sum(
+            x.nbytes for x in self._made)
+
+
+def skv_keep(skv: ShardedKV, fn, static=(), extra=()) -> ScannedKV:
+    """:func:`skv_scan` for a filter whose kept rows are folded next
+    (``compress``), not read row by row: the same kernel-body convention
+    (``fn -> (okey, ovalue, keep)``), but only the kept rows are COUNTED
+    now (program ``jit_kv_scan_<body>``, its one output the count, its one
+    sync that pull); the scan itself is deferred in a :class:`ScannedKV`.
+    A scan that keeps nearly all of a wide table then costs ``compress``
+    one more read of the table's columns, where :func:`skv_scan` would
+    write the mapped rows, order the kept ones' indices and gather a copy
+    of them (about 20 ns a row kept: PERF.md §6, PR 43).  ``fn`` must be
+    row-wise (row i of its result from row i of the block and the count):
+    the combiner applies it to runs of the block's rows.  Plain numeric
+    frames only; the source must outlive the frame unread (a resident
+    table does)."""
+    _check_decodes(skv, False, "skv_keep")
+    counts = jax.device_put(skv.counts.astype(np.int32),
+                            row_sharding(skv.mesh))
+    c = _skv_keep_jit(skv.mesh, fn, tuple(static), len(extra))(
+        skv.key, skv.value, counts, *extra)
+    SyncStats.bump()
+    return ScannedKV(skv, fn, static, extra,
+                     np.asarray(c).astype(np.int32))
+
+
+@functools.lru_cache(maxsize=None)
 def _skmv_map_jit(mesh, fn, static, nextra):
     spec = row_spec(mesh)
 

@@ -28,9 +28,9 @@ from ..core.runtime import bump_dispatch
 from ..obs import get_tracer, names
 from ..ops.sort import (columns, front_order, riding, sort_carrying,
                         sort_operands, take_together)
-from .mesh import mesh_axis_size, row_sharding, row_spec
+from .mesh import mesh_axes, mesh_axis_size, row_sharding, row_spec
 from .sharded import (ShardedKMV, ShardedKV, SyncStats, _decode_col,
-                      front_cap, round_cap)
+                      front_cap, round_cap, rows_below)
 
 
 def _local_sort(key, value, count):
@@ -307,6 +307,265 @@ def reduce_sharded(kmv: ShardedKMV, op: str = "sum",
     ukey, out = run(kmv.ukey, kmv.nvalues, kmv.voffsets, kmv.values, vcounts_dev)
     return ShardedKV(kmv.mesh, ukey, out, kmv.gcounts.copy(),
                      key_decode=kmv.key_decode)
+
+
+# ---------------------------------------------------------------------------
+# the combiner: compress of a frame with few distinct keys, without a sort
+# ---------------------------------------------------------------------------
+
+# The most distinct keys a shard may hold for `combine_sharded` to fold it;
+# past it `compress` is `convert` + `reduce`.  Arithmetic on rows, groups
+# and words: the combiner reads the key columns once a key column a key
+# found and once more (groups + 1 rounds of masked minima), then key and
+# value once a key found (one masked reduction each), so it moves about
+#     rows x 4 B x (groups x (key_words + 1) x key_words
+#                   + groups x (key_words + value_words)),
+# where the sort road orders the rows (`jit_convert_sort`), lays the
+# groups out (`jit_convert_layout`) and scatter-adds every row
+# (`jit_reduce_segments`) at a cost that does not fall with the groups.
+# On the v5e, 2^23 rows a frame (PERF.md §6, PR 50): a u64[n, 2] key with
+# a NULL value counted, 0.0039 / 0.0043 / 0.0065 s at 1 / 4 / 16 keys
+# where the sort road takes 0.0818 s at any number of them (19 times at
+# four keys; the combiner stays ahead to about 450); two u32 key words
+# with six int64 summed, 0.0107 / 0.0202 s at 4 / 16 keys against 1.037 s
+# (97 times).  So the edge is not where the fold stops winning but where
+# a FAILED probe stops being cheap: a frame of many keys pays the rounds
+# of minima and one more count sync before it sorts, 0.0062 s for 17
+# rounds there, 7.6 % of the sort it then runs; every round more is
+# 0.0004 s more.  (`COMBINE_SAMPLE` below takes most of that away for a
+# frame whose first rows already show it.)
+COMBINE_GROUPS = 16
+COMBINE_OPS = ("sum", "count", "min", "max")
+# rows a fold reads at a time: what a deferred scan's body makes of a tile
+# (and a 64-bit column's two halves, which the chip keeps apart) is a
+# tile's worth of temporaries, not the block's
+COMBINE_TILE = 1 << 22
+# rows at the head of a block that are asked first: a frame of many keys
+# shows more than COMBINE_GROUPS of them there, and the probe of the whole
+# block (COMBINE_GROUPS + 1 rounds over every row) is not paid
+COMBINE_SAMPLE = 1 << 16
+
+
+def combines(op, frame) -> bool:
+    """Whether ``compress`` by the registered reduce ``op`` may take the
+    combiner for ``frame``, by what the frame itself says: a mesh frame of
+    plain integer values (an interned or float value, a callback, ``cull``
+    go through ``convert``).  The distinct-key count decides the rest, in
+    :func:`combine_sharded`."""
+    if op not in COMBINE_OPS or not isinstance(frame, ShardedKV) \
+            or frame.value_decode is not None:
+        return False
+    key, value = frame.row_types
+    return value.dtype.kind in "iu" and value.ndim <= 2 and key.ndim <= 2
+
+
+def _at(x, j):
+    """Row ``j`` (traced) of a small array: a dynamic slice, no gather."""
+    return jax.lax.dynamic_index_in_dim(x, j, 0, keepdims=False)
+
+
+def _put(x, row, j):
+    """``x`` with row ``j`` (traced) replaced: a dynamic update slice, no
+    scatter."""
+    return jax.lax.dynamic_update_index_in_dim(x, row, j, 0)
+
+
+def _lex_gt(cols, last):
+    """Rows whose key (the columns, the first the most significant) is
+    above ``last``."""
+    gt = jnp.zeros(cols[0].shape, bool)
+    for c, l in zip(reversed(cols), reversed(last)):
+        gt = (c > l) | ((c == l) & gt)
+    return gt
+
+
+def _distinct_keys(cols, valid, gmax: int, vary):
+    """A shard's distinct keys in ascending order, as far as ``gmax + 1``
+    of them: ``(columns each [gmax + 1], found)``.  One masked minimum a
+    key column a key: the least key above the last one found, until none
+    is left or ``gmax + 1`` are found (``found`` = ``gmax + 1`` says "more
+    than ``gmax``").  Nothing is sorted, nothing written but the keys.
+    ``vary`` makes a loop's first carry a per-shard value."""
+    def cond(s):
+        return (s[0] <= gmax) & ~s[1]
+
+    def step(s):
+        i, _, last, ukeys = s
+        cand = valid & ((i == 0) | _lex_gt(cols, last))
+        found = jnp.any(cand)
+        key = []
+        for c in cols:
+            m = jnp.min(jnp.where(cand, c, _huge(c.dtype)))
+            cand = cand & (c == m)
+            key.append(m)
+        ukeys = tuple(_put(u, jnp.where(found, k, 0), i)
+                      for u, k in zip(ukeys, key))
+        return i + found.astype(jnp.int32), ~found, tuple(key), ukeys
+
+    zero = tuple(jnp.zeros((), c.dtype) for c in cols)
+    i, _, _, ukeys = jax.lax.while_loop(
+        cond, step, vary((jnp.int32(0), jnp.bool_(False), zero,
+                          tuple(jnp.zeros(gmax + 1, c.dtype) for c in cols))))
+    return ukeys, i
+
+
+_FOLD = {   # op -> (a group's rows folded, two folds merged, the identity)
+    "sum": (lambda x, m: jnp.sum(jnp.where(m, x, 0), axis=0, dtype=x.dtype),
+            jnp.add, lambda dt: jnp.zeros((), dt)),
+    "max": (lambda x, m: jnp.max(jnp.where(m, x, _tiny(x.dtype)), axis=0),
+            jnp.maximum, lambda dt: _tiny(dt)),
+    "min": (lambda x, m: jnp.min(jnp.where(m, x, _huge(x.dtype)), axis=0),
+            jnp.minimum, lambda dt: _huge(dt)),
+}
+
+
+def _fold(tile_rows, ntiles: int, ukeys, found, cap: int, op: str, vary):
+    """``([cap, ...] one row a key found, [cap] its rows)``: a key's rows
+    are the valid rows whose columns equal it, folded by one masked
+    reduction, a key after another, a tile of rows after another
+    (``tile_rows(t) -> (key columns, value, valid)``).  The value rows are
+    read where they lie: no order, no gather, no scatter; integers stay
+    what they are."""
+    count = op == "count"
+    like = tile_rows(jnp.int32(0))[1]       # the value's dtype and shape
+    dtype = jnp.int64 if count else like.dtype
+    shape = (cap,) if count else (cap,) + like.shape[1:]
+
+    def tile(t, acc):
+        cols, value, valid = tile_rows(t)
+
+        def one(j, acc):
+            out, sizes = acc
+            m = valid
+            for c, u in zip(cols, ukeys):
+                m = m & (c == _at(u, j))
+            n = jnp.sum(m, dtype=jnp.int32)
+            if count:
+                r = _at(out, j) + n.astype(jnp.int64)
+            else:
+                fold, merge, _ = _FOLD[op]
+                r = merge(_at(out, j), fold(value, _bmask(m, value)))
+            return _put(out, r, j), _put(sizes, _at(sizes, j) + n, j)
+        return jax.lax.fori_loop(0, found, one, acc)
+
+    first = jnp.zeros((), dtype) if count else _FOLD[op][2](dtype)
+    out, sizes = jax.lax.fori_loop(
+        0, ntiles, tile, vary((jnp.full(shape, first, dtype),
+                               jnp.zeros(cap, jnp.int32))))
+    return jnp.where(rows_below(found, cap, out.ndim), out, 0), sizes
+
+
+@functools.lru_cache(maxsize=None)
+def _combine_jit(mesh, op: str, fn=None, static=(), nextra: int = 0):
+    """The combiner's program over a plain frame (``fn`` None: program
+    ``jit_combine``) or over a deferred scan's source (``fn`` the scan's
+    body, with its static operands and the number of its traced ones:
+    ``jit_combine_<body>``, the body applied inside, to all rows for their
+    keys and a tile at a time for their values)."""
+    spec = row_spec(mesh)
+    gmax = COMBINE_GROUPS
+    cap = round_cap(gmax)
+    axes = mesh_axes(mesh)
+    vary = lambda tree: jax.tree.map(
+        lambda x: jax.lax.pcast(x, axes, to="varying"), tree)
+
+    def rows_from(k, v, c, ex, start):
+        """``(key, value, valid)`` of the block's rows from ``start`` on, as
+        long as ``k`` is: the frame's own, or what the body makes."""
+        if fn is None:
+            return k, v, start + jnp.arange(k.shape[0], dtype=jnp.int32) < c
+        with jax.named_scope("kernel"):
+            return fn(k, v, c - start, *ex, *static)
+
+    def combine(key, value, count, *extra):
+        def body(k, v, c, *ex):
+            n = k.shape[0]
+            with jax.named_scope("distinct_keys"):
+                allk, _, valid = rows_from(k, v, c[0], ex, 0)
+                head = min(n, COMBINE_SAMPLE)
+                _, few = _distinct_keys(
+                    [x[:head] for x in columns(allk)], valid[:head], gmax,
+                    vary)
+                ukeys, found = _distinct_keys(
+                    columns(allk), valid & (few <= gmax), gmax, vary)
+                found = jnp.maximum(found, few)
+            step = min(n, COMBINE_TILE)
+
+            def tile_rows(t):
+                # the last tile ends at the block's end and starts inside
+                # the one before it, whose rows it leaves out
+                start = jnp.minimum(t * step, n - step).astype(jnp.int32)
+                kt, vt, ok = rows_from(
+                    jax.lax.dynamic_slice_in_dim(k, start, step, 0),
+                    jax.lax.dynamic_slice_in_dim(v, start, step, 0),
+                    c[0], ex, start)
+                new = start + jnp.arange(step, dtype=jnp.int32) >= t * step
+                return columns(kt), vt, ok & new
+
+            with jax.named_scope("fold"):
+                # more keys than the rule folds: nothing is folded, the
+                # caller sorts
+                out, sizes = _fold(tile_rows, -(-n // step), ukeys,
+                                   jnp.where(found > gmax, 0, found), cap,
+                                   op, vary)
+            with jax.named_scope("largest_group"):
+                ucols = [_fit(u[:gmax], cap, 0) for u in ukeys]
+                ukey = ucols[0] if allk.ndim == 1 else jnp.stack(ucols, 1)
+                return ukey, out, found[None], jnp.max(sizes)[None]
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(spec,) * 3 + (P(),) * nextra,
+            out_specs=(spec,) * 4)(key, value, count, *extra)
+
+    if fn is not None:
+        from .devkernels import _body_name
+        combine.__name__ = "combine_" + _body_name(fn)
+    return jax.jit(combine)
+
+
+def combine_sharded(skv: ShardedKV, op: str):
+    """``compress`` by a registered segment reduce without ordering the
+    rows: each shard's distinct keys found by masked minima, then one
+    masked reduction a key (program ``jit_combine``).  Returns the KV
+    ``convert_sharded`` + ``reduce_sharded`` would (a row a distinct key a
+    shard, keys ascending, the same values bit for bit, the same counts),
+    or None when some shard holds more than `COMBINE_GROUPS` distinct keys:
+    the one count sync this op pays says so, and the caller takes the sort
+    road.  Local to a shard, as the reference's compress is
+    (src/mapreduce.cpp:749-851): nothing is exchanged.  A deferred scan
+    (`devkernels.ScannedKV`) is folded from its source's rows, where they
+    lie (``jit_combine_<body>``), and never made, ordered or packed."""
+    mesh = skv.mesh
+    if skv.scan is None:
+        run, rows, extra = _combine_jit(mesh, op), skv, ()
+    else:
+        fn, static, extra = skv.scan
+        run = _combine_jit(mesh, op, fn, static, len(extra))
+        rows = skv.source
+    counts_dev = jax.device_put(rows.counts.astype(np.int32),
+                                row_sharding(mesh))
+    bump_dispatch()
+    ukey, out, found, most = run(rows.key, rows.value, counts_dev, *extra)
+    SyncStats.bump()
+    tracer = get_tracer()
+    with tracer.span(names.COMBINE_COUNT_SYNC, cat=names.HOST) as sp:
+        gcounts = np.asarray(found).astype(np.int32)
+        sp.set(groups=int(gcounts.sum()))
+    combined = int(gcounts.max(initial=0)) <= COMBINE_GROUPS
+    if tracer.enabled:          # on the ``compress`` op span
+        key, value = skv.row_types
+        tracer.annotate(**{
+            names.ATTR_ROWS: int(skv.counts.sum()),
+            names.ATTR_KEY_WORDS: sort_operands(key),
+            names.ATTR_VALUE_WORDS: sort_operands(value),
+            names.ATTR_COMBINED: int(combined)})
+        if combined:
+            tracer.annotate(**{
+                names.ATTR_GROUPS: int(gcounts.sum()),
+                names.ATTR_GROUP_ROWS_MAX: int(np.asarray(most).max(
+                    initial=0))})
+    if not combined:
+        return None
+    return ShardedKV(mesh, ukey, out, gcounts, key_decode=skv.key_decode)
 
 
 def _bmask(valid, x):

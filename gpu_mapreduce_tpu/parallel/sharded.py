@@ -183,6 +183,16 @@ class ShardedKV:
     value_decode: dict = None   # id→bytes/object when VALUES are interned
     #                             (VERDICT r2 #4: byte values shard too)
 
+    # a deferred scan (devkernels.ScannedKV) names here the kernel body that
+    # makes its rows from another frame's; a plain frame holds its own
+    scan = None
+
+    @property
+    def row_types(self):
+        """``(key, value)`` as two things with a ``dtype``, an ``ndim`` and
+        a ``shape``, without touching the rows."""
+        return self.key, self.value
+
     @property
     def nprocs(self) -> int:
         return mesh_axis_size(self.mesh)

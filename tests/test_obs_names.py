@@ -118,6 +118,8 @@ def _programs(mesh):
             SDS((64, 2), u32), SDS((64, 4), u32), SDS((64, 1), u32))),
         (names.TAKE_ROWS, devkernels._take_rows_jit(mesh, 4).lower(
             key, val, cnt2)),
+        (names.COMBINE, group._combine_jit(mesh, "sum").lower(
+            key, SDS((64, 3), jnp.int64), cnt)),
     ]
 
 
@@ -159,6 +161,12 @@ def test_every_program_lowers_under_its_declared_name(mesh):
             ops = re.findall(r"stablehlo\.(\w+)", lowered.as_text())
             assert ops.count("sort") == 2 and not set(ops) & {
                 "scatter", "gather", "while"}, ops
+        if want == names.COMBINE:
+            # and for the combiner (ISSUE 50): the rows are read where they
+            # lie; nothing orders, gathers or scatters them
+            ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
+            assert "while" in ops and not ops & {
+                "sort", "scatter", "gather"}, ops
         if want in (names.TRI_ORIENT, names.TRI_TILES, names.TRI_WEDGES):
             # the same rule for the wedge walk: sorts, no scatter, and no
             # ``while`` (a searchsorted is a gather a round)
@@ -602,8 +610,8 @@ def _terasort(mesh, out):
 
 
 def _tpch(mesh, out):
-    """TPC-H Query 3 (ISSUE 43) over tiny seeded tables through the two
-    OINK commands: the ten lines and what the command said."""
+    """TPC-H Query 3 (ISSUE 43) and Query 1 (ISSUE 50) over tiny seeded
+    tables through the OINK commands: their lines and what they said."""
     import io
     from benchmark.gen import tpch as gen
     from gpu_mapreduce_tpu.oink.script import OinkScript
@@ -615,8 +623,10 @@ def _tpch(mesh, out):
                       "-o NULL customer -o NULL orders -o NULL lineitem")
     script.run_string(f"tpch_q3 BUILDING 1995-03-15 -i customer orders "
                       f"lineitem -o {out}/q3.txt mrq3")
-    with open(os.path.join(out, "q3.txt")) as f:
-        return f.read(), script.screen.getvalue()
+    script.run_string(f"tpch_q1 90 -i lineitem -o {out}/q1.txt mrq1")
+    with open(os.path.join(out, "q3.txt")) as f, \
+            open(os.path.join(out, "q1.txt")) as g:
+        return f.read() + g.read(), script.screen.getvalue()
 
 
 def test_terasort_on_four_devices_says_how_its_rows_were_sent(traced,
@@ -851,7 +861,7 @@ def test_tracer_off_constructs_no_span_and_changes_nothing(
     assert set(names.SPANS) <= set(built)
     assert on_graph == off_graph and on_enum == off_enum
     assert on_sorted == off_sorted and on_sorted[0] == 603
-    assert on_q3 == off_q3 and len(on_q3[0].splitlines()) == 10
+    assert on_q3 == off_q3 and len(on_q3[0].splitlines()) == 10 + 4
     assert (on_counts, on_parts, on_rows, on_words) == (
         off_counts, off_parts, off_rows, off_words)
 
@@ -934,7 +944,51 @@ def test_the_scan_programs_are_named_for_their_bodies(mesh):
         "sort", "scatter", "gather", "while"}
 
 
+def test_query_1s_programs_are_named_for_its_map(mesh):
+    """ISSUE 50: ``skv_keep`` counts the rows the map keeps under the
+    scan's name, and the combiner applies the map inside a program of its
+    own, ``jit_combine_<body>``; neither orders, gathers or scatters a row,
+    and the count program reads nothing it does not need."""
+    import gpu_mapreduce_tpu.apps.tpch as tpch
+    from gpu_mapreduce_tpu.parallel import devkernels, group
+    (dev,) = [c.cell_contents for c in tpch._Q1_SCAN.__closure__
+              if getattr(c.cell_contents, "__name__", "") == "tpch_q1"]
+    table = (SDS((64, 2), jnp.uint32), SDS((64, 15), jnp.uint32),
+             SDS((8,), jnp.int32), SDS((), jnp.uint32))
+    count = devkernels._skv_keep_jit(mesh, dev, (), 1).lower(*table)
+    fold = group._combine_jit(mesh, "sum", dev, (), 1).lower(*table)
+    got = [re.search(r"module @(\w+)", low.as_text()).group(1)
+           for low in (count, fold)]
+    assert tuple(got) == tpch.Q1_PROGRAMS == (
+        names.KV_SCAN_PREFIX + "tpch_q1", names.COMBINE_PREFIX + "tpch_q1")
+    assert all(names.declared_program(p) for p in got)
+    for low in (count, fold):
+        ops = set(re.findall(r"stablehlo\.(\w+)", low.as_text()))
+        assert not ops & {"sort", "scatter", "gather"}, ops
+    ops = set(re.findall(r"stablehlo\.(\w+)", count.as_text()))
+    assert not ops & {"while", "multiply"}, ops     # the predicate alone
+    out = jax.eval_shape(
+        group._combine_jit(mesh, "sum", dev, (), 1),
+        *(SDS(x.shape, x.dtype) for x in table))
+    assert [(o.shape, str(o.dtype)) for o in out] == [
+        ((8 * 16, 2), "uint32"), ((8 * 16, 6), "int64"), ((8,), "int32"),
+        ((8,), "int32")]
+
+
 def test_the_new_spans_and_attrs_are_declared():
+    for span in (names.TPCH_Q1, names.COMBINE_COUNT_SYNC):
+        assert span in names.SPANS
+    assert names.COMPRESS_SPAN == "compress"
+    for attr in (names.ATTR_VALUE_WORDS, names.ATTR_COMBINED,
+                 names.ATTR_GROUPS, names.ATTR_GROUP_ROWS_MAX,
+                 names.ATTR_KEY_WORDS):
+        assert attr in names.SPAN_ATTRS
+    assert names.COMBINE in names.PROGRAMS
+    assert names.COMBINE_PREFIX in names.PROGRAM_PREFIXES
+    assert names.steps_of("jit_combine_tpch_q1") == names.STEPS[
+        names.COMBINE_PREFIX]
+    assert set(names.STEPS[names.COMBINE]) < set(
+        names.STEPS[names.COMBINE_PREFIX])
     for span in (names.TPCH_Q3, names.TPCH_LOAD, names.TPCH_SCAN,
                  names.TPCH_TOPN, names.TPCH_EMIT):
         assert span in names.SPANS and span.startswith("tpch.")
@@ -974,6 +1028,8 @@ def _step_programs(mesh):
             col, SDS((64,), i32), SDS((64,), i32), col, cnt, cnt)]
     out[names.KV_SCAN_PREFIX] = [devkernels._skv_rows_jit(
         mesh, _keep_even_dev, (), 0, True).lower(key, col, cnt)]
+    out[names.COMBINE_PREFIX] = [group._combine_jit(
+        mesh, "sum", _keep_even_dev, (), 0).lower(key, col, cnt)]
     out[names.CONVERT_SORT] = [
         group._convert_phase1_jit(mesh).lower(key, f64, cnt)]
     out[names.SORT_ROWS] = [

@@ -316,3 +316,228 @@ def test_the_query_emits_its_spans(tables, tmp_path, backend):
     programs = {names.KV_SCAN_PREFIX + "tpch_" + t for t in ref.TABLES}
     assert set(app.SCAN_PROGRAMS) == programs
     assert all(names.declared_program(p) for p in programs)
+
+
+# -- Query 1 (ISSUE 50) -------------------------------------------------------
+
+from benchmark.refs import tpch_q1 as refq1      # noqa: E402
+
+
+def _q1(script, out, delta=90):
+    script.run_string(f"tpch_q1 {delta} -i lineitem -o {out} mrq1")
+    with open(out) as f:
+        return f.read().splitlines()
+
+
+def _q1_groups(script):
+    kv = script.obj.get_mr("mrq1").kv
+    fr = kv.one_frame()
+    fr = fr if hasattr(fr.key, "data") else fr.to_host()
+    key = np.asarray(fr.key.to_host().data).reshape(-1, 2)
+    value = np.asarray(fr.value.to_host().data).reshape(-1, 6)
+    return {"returnflag": key[:, 0], "linestatus": key[:, 1],
+            **{name: value[:, i] for i, name in enumerate(refq1.SUMS)}}
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("delta", [90, 0, 1270, 2000])
+def test_q1_equals_the_reference(tables, tmp_path, backend, delta):
+    """Every sum and count of every group, and the lines in the ORDER BY's
+    order; the later DELTAs cut whole groups away (N/O is shipped after
+    1995-06-17, N/F received after it)."""
+    paths, tabs = tables
+    script = _loaded(_comm(backend), paths)
+    printed = _q1(script, str(tmp_path / "q1.txt"), delta)
+    want = refq1.q1(tabs[2], delta)
+    facts = refq1.check_q1(want, _q1_groups(script), printed)
+    assert [l[:3] for l in printed] == {
+        90: ["A|F", "N|F", "N|O", "R|F"], 0: ["A|F", "N|F", "N|O", "R|F"],
+        1270: ["A|F", "N|F", "R|F"], 2000: ["A|F", "R|F"]}[delta]
+    assert facts["groups"] == len(printed)
+    rows, kept = want["scanned"]["lineitem"]
+    assert script.screen.getvalue().splitlines()[1] == (
+        f"TPC-H Q1 DELTA {delta}: {rows} lineitem rows scanned, {kept} "
+        f"kept; {facts['groups']} groups, {len(printed)} lines")
+    assert 0 < kept <= rows and (kept == rows) == (delta == 0)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_a_delta_that_keeps_nothing_gives_an_empty_file(tables, tmp_path,
+                                                        backend):
+    paths, tabs = tables
+    script = _loaded(_comm(backend), paths)
+    for delta in (2526, 5000):      # the day before the first; before day 0
+        assert _q1(script, str(tmp_path / "q1.txt"), delta) == []
+        assert script.obj.get_mr("mrq1").kv.nkv == 0
+        assert refq1.q1(tabs[2], delta)["scanned"]["lineitem"][1] == 0
+    assert "0 kept; 0 groups, 0 lines" in script.screen.getvalue()
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_three_q1_jobs_leave_the_tables_bit_for_bit(tables, tmp_path,
+                                                    backend):
+    paths, tabs = tables
+    script = _loaded(_comm(backend), paths)
+    before = _bits(script)
+    frames = [script.obj.get_mr(t).kv.one_frame() for t in ref.TABLES]
+    first = _q1(script, str(tmp_path / "q1-0.txt"))
+    for i in (1, 2):
+        assert _q1(script, str(tmp_path / f"q1-{i}.txt")) == first
+    assert len(first) == 4
+    for t, fr, (k, v), (k1, v1) in zip(ref.TABLES, frames, before,
+                                       _bits(script)):
+        assert script.obj.get_mr(t).kv.one_frame() is fr, t
+        np.testing.assert_array_equal(k, k1)
+        np.testing.assert_array_equal(v, v1)
+
+
+def _q1_checked(paths, tabs, tmp_path, comm):
+    script = _loaded(comm, paths)
+    printed = _q1(script, str(tmp_path / "q1.txt"))
+    return refq1.check_q1(refq1.q1(tabs[2], 90), _q1_groups(script), printed)
+
+
+def test_a_float_sum_in_q1_is_caught(tables, tmp_path, monkeypatch):
+    """float32; a float64 sum is exact at this size (under 2^53) and is
+    planted at cell size on the chip (PERF.md §6, PR 50)."""
+    import jax.numpy as jnp
+    lossy = "float32"
+    from gpu_mapreduce_tpu.parallel import group
+    paths, tabs = tables
+    real = group._FOLD["sum"]
+    monkeypatch.setitem(group._FOLD, "sum", (
+        lambda x, m: real[0](x.astype(lossy), m).astype(jnp.int64),
+        *real[1:]))
+    group._combine_jit.cache_clear()
+    try:
+        with pytest.raises(CheckFailure, match="groups differ"):
+            _q1_checked(paths, tabs, tmp_path, make_mesh(1))
+    finally:
+        monkeypatch.undo()
+        group._combine_jit.cache_clear()
+    assert _q1_checked(paths, tabs, tmp_path, make_mesh(1))["groups"] == 4
+
+
+@pytest.mark.parametrize("backend", ["mesh1", "mesh4"])
+def test_a_fold_that_loses_a_tiles_last_row_is_caught(tables, tmp_path,
+                                                      monkeypatch, backend):
+    """Tiles of 256 rows, so that every shard's block is several and the
+    row each one loses is a row of the table."""
+    import jax.numpy as jnp
+    from gpu_mapreduce_tpu.parallel import group
+    paths, tabs = tables
+    real = group._FOLD["sum"]
+
+    def short(x, m):
+        last = jnp.arange(m.shape[0]).reshape(m.shape[:1] + (1,) * (
+            m.ndim - 1)) == m.shape[0] - 1
+        return real[0](x, m & ~last)
+    monkeypatch.setattr(group, "COMBINE_TILE", 256)
+    monkeypatch.setitem(group._FOLD, "sum", (short, *real[1:]))
+    group._combine_jit.cache_clear()
+    try:
+        with pytest.raises(CheckFailure, match="groups differ"):
+            _q1_checked(paths, tabs, tmp_path, _comm(backend))
+        monkeypatch.setitem(group._FOLD, "sum", real)   # whole, by tiles
+        group._combine_jit.cache_clear()
+        assert _q1_checked(paths, tabs, tmp_path,
+                           _comm(backend))["groups"] == 4
+    finally:
+        monkeypatch.undo()
+        group._combine_jit.cache_clear()
+
+
+def test_a_dropped_group_and_a_wrong_line_are_caught(tables, tmp_path):
+    paths, tabs = tables
+    script = _loaded(make_mesh(1), paths)
+    printed = _q1(script, str(tmp_path / "q1.txt"))
+    want, got = refq1.q1(tabs[2], 90), _q1_groups(script)
+    smallest = int(np.argmin(got["count"]))             # N/F
+    assert chr(got["returnflag"][smallest]) + chr(
+        got["linestatus"][smallest]) == "NF"
+    fewer = {k: np.delete(v, smallest) for k, v in got.items()}
+    with pytest.raises(CheckFailure, match="3 groups where the reference"):
+        refq1.check_q1(want, fewer, printed)
+    with pytest.raises(CheckFailure, match="printed lines differ"):
+        refq1.check_q1(want, got, printed[:smallest] + printed[smallest + 1:])
+    with pytest.raises(CheckFailure, match="printed lines differ"):
+        refq1.check_q1(want, got, printed[::-1])
+    off = dict(got, sum_charge=got["sum_charge"] + (got["count"] == max(
+        got["count"])))
+    with pytest.raises(CheckFailure, match="1 groups differ"):
+        refq1.check_q1(want, off, printed)
+
+
+def test_bad_q1_arguments_are_refused(tables, tmp_path):
+    paths, _ = tables
+    script = _loaded(None, paths)
+    out = str(tmp_path / "q1.txt")
+    for args in ("", "ninety", "-90", "90 91"):
+        with pytest.raises(MRError, match="Illegal tpch_q1"):
+            script.run_string(f"tpch_q1 {args} -i lineitem -o {out} mrq1")
+    with pytest.raises(MRError, match="named lineitem table"):
+        script.run_string(f"tpch_q1 90 -i {paths['lineitem'][0]} -o {out} "
+                          f"mrq1")
+    for delta in (-1, 1.5, True, "90"):
+        with pytest.raises(MRError, match="no number of days"):
+            app.q1(script.obj.create_mr, script.obj.get_mr("lineitem"),
+                   delta)
+
+
+def test_q1_and_its_reference_agree_on_the_letters_and_the_lines():
+    assert app.RETURNFLAGS == refq1.RETURNFLAGS == "ARN"
+    assert app.LINESTATUSES == refq1.LINESTATUSES == "FO"
+    assert app.Q1_ANCHOR == refq1.ANCHOR == "1998-12-01"
+    assert refq1.bound(90) == app.day("1998-09-02") + 1
+    sums = (7, 1999, 10 ** 10 + 5, -(10 ** 12) - 7, 1, 2)
+    assert app.q1_line((65, 70), sums) == (
+        "A|F|7|19.99|1000000.0005|-1000000.000007|3.50|10.00|0.0050|2")
+    one = {"returnflag": [78], "linestatus": [79],
+           **{n: [x] for n, x in zip(refq1.SUMS, (7, 1999, 5, 7, 1, 2))}}
+    assert refq1.lines(one) == [app.q1_line((78, 79), (7, 1999, 5, 7, 1, 2))]
+    assert refq1.lines(one) == ["N|O|7|19.99|0.0005|0.000007|3.50|10.00|"
+                                "0.0050|2"]
+
+
+@pytest.mark.parametrize("backend", ["mesh1", "mesh4"])
+def test_q1_emits_its_spans(tables, tmp_path, backend):
+    paths, tabs = tables
+    tracer = get_tracer()
+    tracer.enable()
+    try:
+        tracer.clear()
+        script = _loaded(_comm(backend), paths)
+        _q1(script, str(tmp_path / "q1.txt"))
+        events = tracer.events()
+    finally:
+        tracer.disable()
+    want = refq1.q1(tabs[2], 90)
+    by = lambda name: [e for e in events if e["name"] == name]
+    (root,) = by(names.TPCH_Q1)
+    assert root["cat"] == names.ENTRY and root["args"]["delta"] == 90
+    (scan,) = by(names.TPCH_SCAN)
+    assert scan["args"]["table"] == "lineitem"
+    assert [scan["args"][names.ATTR_ROWS_IN],
+            scan["args"][names.ATTR_ROWS_OUT]] == want["scanned"]["lineitem"]
+    assert scan["args"][names.ATTR_ROW_WORDS_IN] == 17
+    (compress,) = by(names.COMPRESS_SPAN)
+    nshards = 1 if backend == "mesh1" else 4
+    assert compress["args"][names.ATTR_COMBINED] == 1
+    assert compress["args"][names.ATTR_ROWS] == want["scanned"]["lineitem"][1]
+    assert 4 <= compress["args"][names.ATTR_GROUPS] <= 4 * nshards
+    assert compress["args"][names.ATTR_KEY_WORDS] == 2
+    assert compress["args"][names.ATTR_VALUE_WORDS] == 12
+    assert compress["args"][names.ATTR_GROUP_ROWS_MAX] <= max(want["count"])
+    if nshards == 1:
+        assert compress["args"][names.ATTR_GROUP_ROWS_MAX] == max(
+            want["count"])
+    (sync,) = by(names.COMBINE_COUNT_SYNC)
+    assert sync["args"]["groups"] == compress["args"][names.ATTR_GROUPS]
+    (emit,) = by(names.TPCH_EMIT)
+    assert emit["args"]["rows"] == 4 and emit["args"]["bytes"] > 0
+    lo, hi = root["ts"], root["ts"] + root["dur"]
+    for e in [scan, compress, sync, emit] + by(names.CONVERT_SPAN):
+        assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1
+    assert app.Q1_PROGRAMS == (names.KV_SCAN_PREFIX + "tpch_q1",
+                               names.COMBINE_PREFIX + "tpch_q1")
+    assert all(names.declared_program(p) for p in app.Q1_PROGRAMS)
