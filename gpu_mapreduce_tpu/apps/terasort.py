@@ -21,8 +21,9 @@ In MR-MPI's terms, and built from ``MapReduce`` operations only:
 * ``sort_keys(1)`` — one device sort a shard
   (``parallel/group.sort_sharded``: the key words are the sort's keys,
   the value comes by the row index);
-* the part writer: each shard's rows pulled once and written as they
-  were read, ``part-%05d``, 100 bytes a record.
+* the part writer: each shard's rows put together again as records by
+  one small device program, pulled a window at a time and written as
+  they were read, ``part-%05d``, 100 bytes a record.
 
 Private to the application: the record format's numbers, the splitter
 sampling and the binary writer.  Ties come out in any order (the Sort
@@ -39,19 +40,23 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..core.frame import KVFrame
 from ..core.mapreduce import MapReduce
 from ..obs import get_tracer, names
 from ..parallel.mesh import row_sharding, row_spec
+from ..parallel.sharded import shard_blocks
 from ..parallel.shuffle import TotalOrder
 from ..utils.io import RecordFormat, findfiles
 
 RECORD_BYTES = 100
 KEY_BYTES = 10
 SAMPLE = 100_000        # keys sampled for the splitters: Hadoop's default
-WRITE_ROWS = 1 << 20    # records put together and written at a time
-WRITE_AHEAD = 4         # blocks the pool joins ahead of the write
+WRITE_ROWS = 1 << 18    # records put together and written at a time (the
+#                         device join's temporaries are 512 bytes a row)
+WRITE_AHEAD = 4         # windows (the serial backend: blocks) joined ahead
+#                         of the one being written
 
 
 def sample_shares(counts) -> np.ndarray:
@@ -82,6 +87,43 @@ def _sample_jit(mesh, slots: int):
                              out_specs=spec)(key, count, take)
 
     return terasort_sample
+
+
+@functools.lru_cache(maxsize=8)
+def _join_jit(record_bytes: int, key_bytes: int, rows: int):
+    """The part writer's device program, over ONE shard's own block on
+    that shard's chip: ``rows`` rows from row ``start`` on (a traced
+    offset: one program whatever the window), put together as the
+    records' own bytes (``RecordFormat.join_words``: shifts, masks and ORs
+    of the words the shard holds; nothing is ordered, gathered or
+    scattered, and the sorted dataset stays as it is).  The window comes
+    out FLAT, ``u32[rows * record words]``, record after record: the chip
+    keeps a ``[rows, words]`` array with the rows minor and its host copy
+    keeps that order, so only a flat array reaches the host as the file
+    has it."""
+    fmt = RecordFormat(record_bytes, key_bytes)
+
+    @jax.jit
+    def join_records(key, value, start):
+        with jax.named_scope("join"):
+            return fmt.join_words(
+                lax.dynamic_slice_in_dim(key, start, rows),
+                lax.dynamic_slice_in_dim(value, start, rows)).reshape(-1)
+
+    return join_records
+
+
+def _drawn_ahead(items, ahead: int):
+    """``items`` in their order, each handed on when ``ahead`` more have
+    been drawn behind it (or none are left): work that starts as an item
+    is drawn runs ``ahead`` items ahead of whoever consumes them."""
+    drawn = collections.deque()
+    for item in items:
+        drawn.append(item)
+        if len(drawn) > ahead:
+            yield drawn.popleft()
+    while drawn:
+        yield drawn.popleft()
 
 
 class TeraSort:
@@ -160,40 +202,92 @@ class TeraSort:
 
     # -- the part files -------------------------------------------------------
     def _write_parts(self, outdir: str) -> None:
-        """``part-<shard>`` from each shard's own rows, in their order:
-        one pull a shard, then the records put together again and
-        written block by block (the pool joins the blocks ahead of the
-        write)."""
+        """``part-<shard>`` from each shard's own rows, in their order.  A
+        mesh frame's records are put together on the device and come back
+        as the file's bytes, window by window; the serial backend's frame
+        is on the host already and goes through the host's ``join``."""
         fr = self.mr.kv.one_frame()
+        if isinstance(fr, KVFrame):
+            self._write_part_host(outdir, fr)
+        else:
+            self._write_windows(outdir, fr)
+
+    def _write_windows(self, outdir: str, fr) -> None:
+        """One queue over every (shard, window) in file order: this thread
+        waits for the oldest window on its way (``terasort.pull``) and
+        writes its rows below the shard's count (``terasort.write``), while
+        the next ``WRITE_AHEAD`` cross behind it, the last of a shard
+        beside the first of the next shard's, from another chip.  A file
+        is written front to back by this one thread and closed with its
+        last window."""
         tracer = get_tracer()
-        on_host = isinstance(fr, KVFrame)   # the serial backend's frame
-        nshards = 1 if on_host else fr.nprocs
-        for p in range(nshards):
-            with tracer.span(names.TERASORT_PULL, cat=names.HOST,
-                             shard=p) as sp:
-                host = fr if on_host else fr.shard_to_host(p)
-                key = np.asarray(host.key.data)
-                value = np.asarray(host.value.data)
-                sp.set(records=len(key), d2h_bytes=(
-                    0 if on_host
-                    else (fr.key.nbytes + fr.value.nbytes) // nshards))
+        rows = min(WRITE_ROWS, fr.cap)
+        windows = self._joined_windows(fr, rows)
+        for p, n in enumerate(int(c) for c in fr.counts):
             path = os.path.join(outdir, f"part-{p:05d}")
-            with tracer.span(names.TERASORT_WRITE, cat=names.HOST, shard=p,
-                             records=len(key)) as sp:
-                self._write_part(path, key, value)
-                sp.set(bytes=os.path.getsize(path))
+            with open(path, "wb") as out:   # no rows: an empty file
+                for lo in range(0, n, rows):
+                    with tracer.span(
+                            names.TERASORT_PULL, cat=names.HOST, shard=p,
+                            d2h_bytes=rows * self.format.record_bytes) as sp:
+                        records = next(windows)
+                        sp.set(records=len(records))
+                    with tracer.span(names.TERASORT_WRITE, cat=names.HOST,
+                                     shard=p, records=len(records),
+                                     joined="device", windows=1) as sp:
+                        sp.set(bytes=out.write(records))
+                        if lo + rows >= n:
+                            out.close()     # inside the last write's span
             self.parts.append(path)
+
+    def _joined_windows(self, fr, rows: int):
+        """The records ``u32[n, record words]`` on the host of every window
+        of ``rows`` rows of every shard, in file order, the rows from the
+        shard's count on and the windows past it left out; the next
+        ``WRITE_AHEAD`` windows are joined and on their way to the host
+        when one is waited for."""
+        join = _join_jit(self.format.record_bytes, self.format.key_bytes,
+                         rows)
+        keys = shard_blocks(fr.key, fr.nprocs)
+        values = shard_blocks(fr.value, fr.nprocs)
+
+        def dispatched():
+            for p, n in enumerate(int(c) for c in fr.counts):
+                for lo in range(0, n, rows):
+                    # the last window starts where a whole one still
+                    # fits: its first rows are the window's before it
+                    at = min(lo, fr.cap - rows)
+                    window = join(keys[p], values[p], np.int32(at))
+                    window.copy_to_host_async()
+                    yield window, lo - at, min(lo + rows, n) - at
+
+        for window, first, stop in _drawn_ahead(dispatched(), WRITE_AHEAD):
+            yield np.asarray(window).reshape(rows, -1)[first:stop]
+
+    def _write_part_host(self, outdir: str, fr: KVFrame) -> None:
+        """The serial backend's one part file: the frame's rows put
+        together again by the host's ``join`` and written block by block
+        (the pool joins the blocks ahead of the write)."""
+        tracer = get_tracer()
+        with tracer.span(names.TERASORT_PULL, cat=names.HOST, shard=0,
+                         d2h_bytes=0) as sp:   # nothing crosses
+            key = np.asarray(fr.key.data)
+            value = np.asarray(fr.value.data)
+            sp.set(records=len(key))
+        path = os.path.join(outdir, "part-00000")
+        with tracer.span(names.TERASORT_WRITE, cat=names.HOST, shard=0,
+                         records=len(key), joined="host",
+                         windows=-(-len(key) // WRITE_ROWS)) as sp:
+            self._write_part(path, key, value)
+            sp.set(bytes=os.path.getsize(path))
+        self.parts.append(path)
 
     def _write_part(self, path: str, key: np.ndarray,
                     value: np.ndarray) -> None:
         pool = self.mr._ingest_pool()
-        joined = collections.deque()    # blocks being put together, in order
+        blocks = (pool.submit(self.format.join, key[lo:lo + WRITE_ROWS],
+                              value[lo:lo + WRITE_ROWS])
+                  for lo in range(0, len(key), WRITE_ROWS))
         with open(path, "wb") as out:
-            for lo in range(0, len(key), WRITE_ROWS):
-                joined.append(pool.submit(
-                    self.format.join, key[lo:lo + WRITE_ROWS],
-                    value[lo:lo + WRITE_ROWS]))
-                if len(joined) > WRITE_AHEAD:
-                    joined.popleft().result().tofile(out)
-            while joined:
-                joined.popleft().result().tofile(out)
+            for block in _drawn_ahead(blocks, WRITE_AHEAD):
+                block.result().tofile(out)

@@ -138,7 +138,7 @@ def fixed_key_words(raw: np.ndarray, out=None) -> np.ndarray:
     :func:`fixed_key_bytes` gives the bytes back.  ``out``: the ``[n,
     words]`` u32 array to fill (a slice of a shard's block)."""
     rows = _padded_rows(raw, out)
-    words = rows.view(np.uint32).reshape(rows.shape[0], -1)
+    words = rows.view(np.uint32).reshape(rows.shape[0], rows.shape[1] // 4)
     if np.little_endian:
         words.byteswap(inplace=True)
     return words
@@ -147,7 +147,7 @@ def fixed_key_words(raw: np.ndarray, out=None) -> np.ndarray:
 def fixed_key_bytes(words: np.ndarray, width: int) -> np.ndarray:
     """The ``[n, width]`` key bytes of :func:`fixed_key_words`' words."""
     be = np.ascontiguousarray(words, np.uint32).astype(">u4")
-    return be.view(np.uint8).reshape(len(words), -1)[:, :width]
+    return be.view(np.uint8).reshape(len(be), 4 * be.shape[1])[:, :width]
 
 
 def fixed_value_words(raw: np.ndarray, out=None) -> np.ndarray:
@@ -155,13 +155,40 @@ def fixed_value_words(raw: np.ndarray, out=None) -> np.ndarray:
     bytes in place (host byte order) and zeros after them: a payload
     that travels with its key and is never compared."""
     rows = _padded_rows(raw, out)
-    return rows.view(np.uint32).reshape(rows.shape[0], -1)
+    return rows.view(np.uint32).reshape(rows.shape[0], rows.shape[1] // 4)
 
 
 def fixed_value_bytes(words: np.ndarray, width: int) -> np.ndarray:
     """The ``[n, width]`` value bytes of :func:`fixed_value_words`' words."""
     rows = np.ascontiguousarray(words, np.uint32)
-    return rows.view(np.uint8).reshape(len(words), -1)[:, :width]
+    return rows.view(np.uint8).reshape(len(rows), 4 * rows.shape[1])[:, :width]
+
+
+def fixed_record_words(key: ArrayLike, value: ArrayLike,
+                       key_bytes: int) -> ArrayLike:
+    """The records ``[n, (key_bytes + value bytes) / 4]`` (u32) whose
+    bytes, on a little-endian host, are the ``key_bytes`` bytes of
+    :func:`fixed_key_words`' ``key`` and then the value bytes of
+    :func:`fixed_value_words`' ``value``: what ``fixed_key_bytes`` and
+    ``fixed_value_bytes`` give side by side, by word arithmetic alone
+    (numpy arrays, or jax arrays inside a program).  The key words are
+    byte-swapped; the whole ones are the record's first words.  With
+    ``s = key_bytes % 4`` bytes of key left over, the word in which the key
+    ends takes its low ``8 s`` bits from the key and the rest from the
+    first value word, and word ``j`` after it is ``v[j-1] >> (32 - 8 s) |
+    v[j] << 8 s``; at ``s = 0`` the value words follow as they are.  The
+    record is a whole number of words (``(key_bytes + value bytes) % 4 ==
+    0``): then the last value word's zero fill falls off the end."""
+    xp = jnp if _is_device(key) or _is_device(value) else np
+    whole, s = divmod(key_bytes, 4)
+    le = ((key >> 24) | ((key >> 8) & 0xFF00) | ((key << 8) & 0xFF0000)
+          | (key << 24))
+    if s == 0:
+        return xp.concatenate([le, value], axis=1)
+    low = 8 * s
+    carried = xp.concatenate([le[:, whole:] & ((1 << low) - 1),
+                              value[:, :-1] >> (32 - low)], axis=1)
+    return xp.concatenate([le[:, :whole], carried | (value << low)], axis=1)
 
 
 class BytesColumn(Column):

@@ -35,6 +35,8 @@ SHUFFLE_PHASE2 = "jit_shuffle_phase2"
 SHUFFLE_PHASE2_WIRE = "jit_shuffle_phase2_wire"
 # apps/terasort.py
 TERASORT_SAMPLE_KEYS = "jit_terasort_sample"        # a stride of each shard's keys
+TERASORT_JOIN_RECORDS = "jit_join_records"          # a window of a shard's rows
+#                                                     as the part file's bytes
 # parallel/staging.py
 STAGE_RANK_GRAPH = "jit_stage_rank_graph"           # vertex table + edge ranks
 STAGE_TRIM_VERTS = "jit_stage_trim_verts"
@@ -70,6 +72,7 @@ PROGRAMS = (
     CC_LOOP, PAGERANK_LOOP, RMAT_EDGES, RMAT_EDGE_ROWS,
     TRI_ORIENT, TRI_TILES, TRI_WEDGES, TRI_APPEND, TRI_GROW, TRI_ROWS,
     LUBY_LOOP, SSSP_LOOP, SSSP_WEIGHTS, TERASORT_SAMPLE_KEYS,
+    TERASORT_JOIN_RECORDS,
 )
 
 # parallel/devkernels.py's two generic mappers run one program per kernel
@@ -135,6 +138,9 @@ STEPS = {
     SHUFFLE_PHASE2: ("windows", "exchange", "unpack"),
     SHUFFLE_PHASE2_WIRE: ("windows", "exchange", "unpack"),
     TERASORT_SAMPLE_KEYS: ("sample",),
+    # the key words byte-swapped, the value words shifted in behind them:
+    # elementwise over one window (core/column.fixed_record_words)
+    TERASORT_JOIN_RECORDS: ("join",),
     STAGE_RANK_GRAPH: ("endpoints", "sort", "rank", "table", "return"),
     STAGE_TRIM_VERTS: ("trim",),
     PLACE_ROWS: ("window",),
@@ -297,8 +303,20 @@ INGEST_RECORDS_H2D = "ingest.records.h2d"       # shard, bytes; the puts'
 # apps/terasort.py
 TERASORT_SAMPLE = "terasort.sample"             # sampled, splitters,
 #                                                 d2h_bytes
-TERASORT_PULL = "terasort.pull"                 # shard, records, d2h_bytes
-TERASORT_WRITE = "terasort.write"               # shard, records, bytes
+# the part writer: on a mesh a pull and a write span a WINDOW of a shard's
+# rows (the device put the records together); the serial backend's frame
+# is on the host, and its one pull and one write span the whole file
+TERASORT_PULL = "terasort.pull"                 # shard, records (the
+#                             window's rows below the shard's count),
+#                             d2h_bytes (what crossed: whole windows; 0 on
+#                             the serial backend); the exposed wait for
+#                             the oldest window on its way, and the
+#                             dispatch of the one WRITE_AHEAD behind it
+TERASORT_WRITE = "terasort.write"               # shard, records, bytes,
+#                             joined ("device", or "host" on the serial
+#                             backend), windows (1; the serial backend's
+#                             blocks): the write of those rows, and with a
+#                             shard's last the file's close
 # apps/tpch.py
 TPCH_LOAD = "tpch.load"                         # table, files, rows, bytes
 TPCH_SCAN = "tpch.scan"                         # table, and ATTR_ROWS_IN,

@@ -57,7 +57,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from .mesh import mesh_axis_size, row_sharding
-from .sharded import ShardedKMV, ShardedKV, round_cap
+from .sharded import ShardedKMV, ShardedKV, round_cap, shard_blocks
 from .shuffle import exchange
 
 
@@ -75,20 +75,6 @@ def _offsets(counts) -> Tuple[int, ...]:
     """Exclusive prefix sum: shard i's global row offset."""
     return tuple(int(x) for x in
                  np.concatenate([[0], np.cumsum(counts)])[:-1])
-
-
-def _blocks(arr, nprocs: int) -> list:
-    """Per-shard single-device blocks of a row-sharded array, shard
-    order.  Single-controller scope: every shard must be addressable
-    (the multi-host variant would swap this for a per-process slice)."""
-    cap = arr.shape[0] // nprocs
-    out = [None] * nprocs
-    for sh in arr.addressable_shards:
-        out[(sh.index[0].start or 0) // cap] = sh.data
-    if any(b is None for b in out):
-        raise ValueError("reshard: not every shard is addressable "
-                         "from this controller")
-    return out
 
 
 def _assemble(blocks: list, new_mesh: Mesh):
@@ -123,7 +109,7 @@ def _widen(skv: ShardedKV, new_mesh: Mesh) -> ShardedKV:
     devs = list(np.asarray(new_mesh.devices).reshape(-1))
 
     def grow(arr):
-        blocks = _blocks(arr, N)
+        blocks = shard_blocks(arr, N)
         pad = [_zeros_like_block(blocks[0], devs[j])
                for j in range(N, M)]
         return _assemble(blocks + pad, new_mesh)
@@ -148,8 +134,8 @@ def _narrow(skv: ShardedKV, new_mesh: Mesh) -> ShardedKV:
     assert all(int(c) == 0 for c in skv.counts[M:]), \
         "narrow: rows routed past the target width"
     return ShardedKV(new_mesh,
-                     _assemble(_blocks(skv.key, N)[:M], new_mesh),
-                     _assemble(_blocks(skv.value, N)[:M], new_mesh),
+                     _assemble(shard_blocks(skv.key, N)[:M], new_mesh),
+                     _assemble(shard_blocks(skv.value, N)[:M], new_mesh),
                      skv.counts[:M].copy(),
                      key_decode=skv.key_decode,
                      value_decode=skv.value_decode)

@@ -166,6 +166,21 @@ def _pad_rows(arr: np.ndarray, cap: int) -> np.ndarray:
     return np.pad(arr, width)
 
 
+def shard_blocks(arr, nprocs: int) -> list:
+    """Per-shard single-device blocks of a row-sharded array, shard
+    order: the shards' own buffers, nothing copied.  Single-controller
+    scope: every shard must be addressable (the multi-host variant would
+    swap this for a per-process slice)."""
+    cap = arr.shape[0] // nprocs
+    out = [None] * nprocs
+    for sh in arr.addressable_shards:
+        out[(sh.index[0].start or 0) // cap] = sh.data
+    if any(b is None for b in out):
+        raise ValueError("not every shard is addressable from this "
+                         "controller")
+    return out
+
+
 @dataclass
 class ShardedKV:
     """Sharded KV frame: key/value row blocks + per-shard counts.

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import glob
 import os
+import sys
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 
@@ -144,7 +145,8 @@ class RecordFormat:
     bytes' ``memcmp`` order) and its values ``fixed_value_words``; on a
     mesh ``map_files`` hands the instance to
     ``parallel/ingest.mesh_map_records``, which cuts every file straight
-    into its shard's block.  ``join`` is the way back, for a writer."""
+    into its shard's block.  ``join`` is the way back, for a writer,
+    and ``join_words`` the same inside a device program."""
 
     def __init__(self, record_bytes: int, key_bytes: int):
         if not 0 < key_bytes < record_bytes:
@@ -198,3 +200,19 @@ class RecordFormat:
         out[:, :self.key_bytes] = fixed_key_bytes(key, self.key_bytes)
         out[:, self.key_bytes:] = fixed_value_bytes(value, self.value_bytes)
         return out
+
+    def join_words(self, key, value):
+        """``join``'s twin for a device program: ``u32[n, record_bytes /
+        4]`` whose bytes in the host's memory are ``join``'s, from the
+        same key and value words by shifts, masks and ORs
+        (``core/column.fixed_record_words``).  It exists where a record
+        is a whole number of u32 words and a word's first byte in memory
+        is its lowest; elsewhere it raises."""
+        from ..core.runtime import MRError
+        if self.record_bytes % 4 or sys.byteorder != "little":
+            raise MRError(
+                f"no word form of a {self.record_bytes}-byte record on a "
+                f"{sys.byteorder}-endian host: join_words needs whole u32 "
+                f"words, the lowest byte first")
+        from ..core.column import fixed_record_words
+        return fixed_record_words(key, value, self.key_bytes)

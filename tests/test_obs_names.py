@@ -111,6 +111,9 @@ def _programs(mesh):
             SDS((64,), jnp.float64), edges[2])),
         (names.TERASORT_SAMPLE_KEYS, terasort._sample_jit(mesh, 4).lower(
             SDS((64, 3), u32), cnt, cnt)),
+        (names.TERASORT_JOIN_RECORDS,
+         terasort._join_jit(100, 10, 4).lower(
+             SDS((64, 3), u32), SDS((64, 23), u32), SDS((), i32))),
         (names.JOIN_ROWS, group._join_jit(mesh).lower(
             SDS((64, 2), u32), cnt, SDS((64, 2), u32), cnt)),
         (names.JOIN_TAKE, group._join_take_jit(mesh, 4).lower(
@@ -167,6 +170,15 @@ def test_every_program_lowers_under_its_declared_name(mesh):
             ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
             assert "while" in ops and not ops & {
                 "sort", "scatter", "gather"}, ops
+        if want == names.TERASORT_JOIN_RECORDS:
+            # and for the part writer's join (ISSUE 51): a window cut at a
+            # traced offset and word arithmetic over it, on one shard's own
+            # block (no mesh, no collective); the sorted rows are not
+            # ordered or moved again
+            ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
+            assert "dynamic_slice" in ops and not ops & {
+                "sort", "scatter", "gather", "while"}, ops
+            assert "sdy.manual_computation" not in lowered.as_text()
         if want in (names.TRI_ORIENT, names.TRI_TILES, names.TRI_WEDGES):
             # the same rule for the wedge walk: sorts, no scatter, and no
             # ``while`` (a searchsorted is a gather a round)

@@ -277,22 +277,31 @@ def _datasets(tmp_path, rng, n):
     return jobs
 
 
-def _traced_jobs(mesh, jobs, tmp_path):
-    """``TeraSort.run`` over each of ``jobs`` on a fresh application, the
-    tracer on: ``(applications, events)``."""
+def _traced(fn):
+    """``fn()`` with the tracer on: its events."""
     from gpu_mapreduce_tpu.obs import get_tracer
     tracer = get_tracer()
     tracer.reset()
     tracer.enable(ring=1 << 14)
     try:
-        apps = []
-        for j, paths in enumerate(jobs):
-            apps.append(app.TeraSort(comm=mesh))
-            apps[-1].run(paths, outdir=str(tmp_path / f"out{j}"))
-        return apps, tracer.events()
+        fn()
+        return tracer.events()
     finally:
         tracer.clear()
         tracer.disable()
+
+
+def _traced_jobs(mesh, jobs, tmp_path):
+    """``TeraSort.run`` over each of ``jobs`` on a fresh application, the
+    tracer on: ``(applications, events)``."""
+    apps = []
+
+    def run():
+        for j, paths in enumerate(jobs):
+            apps.append(app.TeraSort(comm=mesh))
+            apps[-1].run(paths, outdir=str(tmp_path / f"out{j}"))
+
+    return apps, _traced(run)
 
 
 def test_a_second_job_over_other_records_builds_no_phase_1(
@@ -547,18 +556,10 @@ def test_sort_keys_returns_when_the_sorted_rows_are_there(meshes, tmp_path,
 
 
 def test_the_spans_of_a_job(meshes, tmp_path, rng):
-    from gpu_mapreduce_tpu.obs import get_tracer, names
+    from gpu_mapreduce_tpu.obs import names
     paths = _write(tmp_path, _random(rng))
-    tracer = get_tracer()
-    tracer.reset()
-    tracer.enable(ring=1 << 14)
-    try:
-        app.TeraSort(comm=meshes[4]).run(paths,
-                                         outdir=str(tmp_path / "out"))
-        events = tracer.events()
-    finally:
-        tracer.clear()
-        tracer.disable()
+    events = _traced(lambda: app.TeraSort(comm=meshes[4]).run(
+        paths, outdir=str(tmp_path / "out")))
     by_name = {}
     for e in events:
         by_name.setdefault(e["name"], []).append(e)
@@ -599,3 +600,200 @@ def test_the_spans_of_a_job(meshes, tmp_path, rng):
                for e in by_name[names.TERASORT_WRITE]) == 315100
     for e in events:
         assert names.ATTR_CPU_S in e["args"], e["name"]
+
+
+# -- the part writer: records joined on the device, pulled window by window -------
+
+WIDTHS = [(100, 10), (16, 4), (12, 1), (24, 11), (8, 7), (20, 8), (104, 13)]
+
+
+@pytest.mark.parametrize("nrows", [0, 1, 37])
+@pytest.mark.parametrize("record,key", WIDTHS)
+def test_the_device_join_gives_the_hosts_bytes(record, key, nrows, rng):
+    """``RecordFormat.join_words``: the u32 words whose bytes in memory
+    are ``join``'s, by word arithmetic alone, in numpy and inside a
+    jitted program; key bytes that end inside a word (s = 1, 2, 3) and
+    on its edge (s = 0)."""
+    fmt = RecordFormat(record, key)
+    raw = rng.integers(0, 256, (nrows, record), dtype=np.uint8)
+    raw[:nrows // 2, key - 1:key + 1] = 0xFF     # both sides of the seam
+    words = fixed_key_words(raw[:, :key])
+    carried = fixed_value_words(raw[:, key:])
+    assert np.array_equal(fmt.join(words, carried), raw)
+    for joined in (fmt.join_words(words, carried),
+                   np.asarray(jax.jit(fmt.join_words)(words, carried))):
+        assert joined.dtype == np.uint32
+        assert joined.shape == (nrows, record // 4)
+        assert np.array_equal(
+            np.ascontiguousarray(joined).view(np.uint8).reshape(
+                nrows, record), raw)
+
+
+def _sorted_shards(mesh, counts, rng, fmt=None):
+    """A sorted mesh dataset whose shard *p* holds ``counts[p]`` records
+    (the first key byte is the shard, the splitters its steps), and the
+    records."""
+    from gpu_mapreduce_tpu.parallel import shuffle
+    from gpu_mapreduce_tpu.parallel.shuffle import TotalOrder
+    # the block's capacity from these counts, not from the plan another
+    # test's exchange left behind
+    shuffle._SPEC_CACHE.clear()
+    fmt = fmt or RecordFormat(ref.RECORD, ref.KEY)
+    raw = rng.integers(0, 256, (sum(counts), fmt.record_bytes),
+                       dtype=np.uint8)
+    raw[:, 0] = np.repeat(np.arange(len(counts)), counts)
+    raw = raw[rng.permutation(len(raw))]
+    key = fixed_key_words(raw[:, :fmt.key_bytes])
+    value = fixed_value_words(raw[:, fmt.key_bytes:])
+    mr = MapReduce(mesh)
+    mr.map(1, lambda itask, kv, ptr: kv.add_batch(key, value))
+    steps = np.zeros((len(counts) - 1, fmt.key_bytes), np.uint8)
+    steps[:, 0] = np.arange(1, len(counts))
+    mr.aggregate(TotalOrder(fixed_key_words(steps)) if len(steps) else None)
+    mr.sort_keys(1)
+    return mr, raw
+
+
+def _span_args(events, name):
+    return [e["args"] for e in events if e["name"] == name]
+
+
+def _read(paths):
+    out = []
+    for p in paths:
+        with open(p, "rb") as f:
+            out.append(f.read())
+    return out
+
+
+# rows a window, then the shards' counts on a mesh of 1 and of 4
+WRITER_CASES = {
+    "whole_windows": (64, {1: [256], 4: [128, 256, 64, 192]}),
+    "ragged": (64, {1: [301], 4: [130, 257, 63, 1]}),
+    "a_shard_with_no_rows": (64, {1: [0], 4: [100, 0, 0, 77]}),
+    "a_shard_under_one_window": (64, {1: [10], 4: [10, 200, 5, 64]}),
+    # a block the window does not divide: the last window starts at
+    # cap - 100 and its first rows are in the file already
+    "the_last_window_steps_back": (100, {1: [1010],
+                                        4: [1010, 3, 1001, 1024]}),
+    "a_block_smaller_than_write_rows": (None, {1: [300],
+                                               4: [300, 200, 100, 50]}),
+}
+
+
+@pytest.mark.parametrize("nshards", [1, 4])
+@pytest.mark.parametrize("case", WRITER_CASES)
+def test_the_windowed_writer_writes_the_host_joins_files(
+        case, nshards, meshes, tmp_path, rng, monkeypatch):
+    """``_write_parts`` on a mesh: every part file byte for byte what
+    the host's ``join`` makes of the same shard's pulled rows, whatever
+    the counts are to the window; the spans say which road ran and what
+    crossed."""
+    from gpu_mapreduce_tpu.obs import names
+    rows, counts = WRITER_CASES[case]
+    counts = counts[nshards]
+    if rows is not None:
+        monkeypatch.setattr(app, "WRITE_ROWS", rows)
+    monkeypatch.setattr(app, "WRITE_AHEAD", 2)
+    mr, raw = _sorted_shards(meshes[nshards], counts, rng)
+    fr = mr.kv.one_frame()
+    assert fr.counts.tolist() == counts
+    rows = min(app.WRITE_ROWS, fr.cap)
+    if case == "the_last_window_steps_back":
+        assert fr.cap % rows and max(counts) > fr.cap - fr.cap % rows
+    if case == "a_block_smaller_than_write_rows":
+        assert rows == fr.cap < app.WRITE_ROWS
+    ts = app.TeraSort(mr=mr)
+    events = _traced(lambda: ts._write_parts(str(tmp_path)))
+    got = _read(ts.parts)
+    assert [os.path.basename(p) for p in ts.parts] == [
+        f"part-{p:05d}" for p in range(nshards)]
+    for p in range(nshards):
+        host = fr.shard_to_host(p)      # no rows: no columns to join
+        assert got[p] == (ts.format.join(
+            np.asarray(host.key.data), np.asarray(host.value.data)).tobytes()
+            if counts[p] else b"")
+    assert [len(g) for g in got] == [n * ref.RECORD for n in counts]
+    order = np.lexsort(raw[:, :ref.KEY].T[::-1])
+    assert b"".join(got) == raw[order].tobytes()
+    # a pull and a write a window; only windows below a shard's count
+    windows = sum(-(-n // rows) for n in counts)
+    pulls = _span_args(events, names.TERASORT_PULL)
+    writes = _span_args(events, names.TERASORT_WRITE)
+    assert len(pulls) == len(writes) == windows
+    assert {a["joined"] for a in writes} <= {"device"}
+    assert sum(a["windows"] for a in writes) == windows
+    assert [a["shard"] for a in writes] == sorted(a["shard"] for a in writes)
+    assert sum(a["bytes"] for a in writes) == sum(counts) * ref.RECORD
+    assert sum(a["records"] for a in pulls) == sum(counts)
+    # what crossed: whole windows of records, nothing of the capacity
+    # block's padding and no row above a window that holds a valid one
+    crossed = sum(a["d2h_bytes"] for a in pulls)
+    assert crossed == windows * rows * ref.RECORD
+    if case == "whole_windows":
+        assert crossed == sum(counts) * ref.RECORD
+    assert crossed < (sum(counts) + rows * nshards) * ref.RECORD
+
+
+def test_a_record_of_no_whole_words_has_no_device_join(
+        meshes, tmp_path, rng, monkeypatch):
+    """10-byte records with 3-byte keys: no word form, so ``join_words``
+    refuses, and with it a mesh frame's part writer (nothing is written
+    in another record's shape); the serial backend's ``join`` takes any
+    width."""
+    from gpu_mapreduce_tpu import MRError
+    fmt = RecordFormat(10, 3)
+    raw = rng.integers(0, 256, (201, 10), dtype=np.uint8)
+    key, value = fixed_key_words(raw[:, :3]), fixed_value_words(raw[:, 3:])
+    assert np.array_equal(fmt.join(key, value), raw)
+    with pytest.raises(MRError, match="no word form of a 10-byte record"):
+        fmt.join_words(key, value)
+    monkeypatch.setattr(app, "RECORD_BYTES", 10)
+    monkeypatch.setattr(app, "KEY_BYTES", 3)
+    mr, _ = _sorted_shards(meshes[4], [130, 0, 64, 7], rng, fmt)
+    with pytest.raises(MRError, match="no word form of a 10-byte record"):
+        app.TeraSort(mr=mr)._write_parts(str(tmp_path))
+
+
+def test_the_serial_backend_is_joined_on_the_host(tmp_path, rng,
+                                                  monkeypatch):
+    from gpu_mapreduce_tpu.obs import names
+    monkeypatch.setattr(app, "WRITE_ROWS", 1000)
+    paths = _write(tmp_path, _random(rng))
+    ts = app.TeraSort()
+    events = _traced(lambda: ts.run(paths, outdir=str(tmp_path / "out")))
+    ref.validate(ts.parts, ref.summary(paths))
+    (write,) = _span_args(events, names.TERASORT_WRITE)
+    (pull,) = _span_args(events, names.TERASORT_PULL)
+    assert (write["joined"], write["windows"], write["bytes"]) == (
+        "host", 4, 315100)
+    assert (pull["records"], pull["d2h_bytes"]) == (3151, 0)
+
+
+def test_a_job_on_a_mesh_joins_its_records_on_the_device(meshes, tmp_path,
+                                                         rng, monkeypatch):
+    """Whole jobs on four shards: the writer's program is built once a
+    (cap, window) pair (the exchange's plan may give the first job of a
+    process another capacity than the jobs after it), and what crosses
+    for the part files is the records' own bytes up to a window a
+    shard."""
+    from gpu_mapreduce_tpu import obs
+    from gpu_mapreduce_tpu.obs import names
+    monkeypatch.setattr(app, "WRITE_ROWS", 256)
+    app._join_jit.cache_clear()
+    jobs = _datasets(tmp_path, rng, 3)
+    ran, events = _traced_jobs(meshes[4], jobs, tmp_path)
+    for ts, paths in zip(ran, jobs):
+        ref.validate(ts.parts, ref.summary(paths))
+    caps = [ts.mr.kv.one_frame().cap for ts in ran]
+    assert caps[1] == caps[2]
+    assert obs.programs()[names.TERASORT_JOIN_RECORDS]["lowerings"] \
+        == len(set(caps))
+    assert app._join_jit.cache_info().currsize == 1
+    writes = _span_args(events, names.TERASORT_WRITE)
+    pulls = _span_args(events, names.TERASORT_PULL)
+    assert {a["joined"] for a in writes} == {"device"}
+    assert sum(a["bytes"] for a in writes) == 3 * 2880 * ref.RECORD
+    crossed = sum(a["d2h_bytes"] for a in pulls)
+    assert 3 * 2880 * ref.RECORD <= crossed == len(pulls) * 256 * ref.RECORD
+    assert crossed < (3 * 2880 + 3 * 4 * 256) * ref.RECORD
